@@ -28,22 +28,23 @@ from incubator_mxnet_tpu.serving import (GenerationEngine,
 VOCAB, BOS, EOS = 64, 1, 2
 
 
-def build_model():
+def build_model(ctx):
     mx.random.seed(0)
     net = Seq2Seq(VOCAB, VOCAB, embed_dim=32, hidden=48, num_layers=2)
-    net.initialize()
+    net.initialize(ctx=ctx)
     # one tiny forward gives the deferred LSTM params concrete shapes
-    net(nd.array(np.ones((1, 4), np.int32)),
-        nd.array(np.ones((1, 1), np.int32)))
+    net(nd.array(np.ones((1, 4), np.int32), ctx=ctx),
+        nd.array(np.ones((1, 1), np.int32), ctx=ctx))
     return net
 
 
 def main():
-    net = build_model()
+    ctx = mx.tpu(0) if mx.num_tpus() else mx.cpu()
+    net = build_model(ctx)
 
     # ---- engine lifecycle -------------------------------------------
-    eng = GenerationEngine(net, bos=BOS, eos=EOS, slots=4, max_len=32,
-                           prompt_buckets=(8, 16))
+    eng = GenerationEngine(net, bos=BOS, eos=EOS, ctx=ctx, slots=4,
+                           max_len=32, prompt_buckets=(8, 16))
     warm = eng.warmup()
     print("warmup:", warm["wall_s"], "s —",
           len(warm["prompt_buckets"]), "prompt buckets,",
@@ -71,7 +72,7 @@ def main():
     eng.close()
 
     # ---- KV-aware admission through the registry --------------------
-    reg = ModelRegistry(devices=[mx.cpu()], hbm_budget=1 << 20)
+    reg = ModelRegistry(devices=[ctx], hbm_budget=1 << 20)
     try:
         reg.register_generator("chat_big", net, BOS, EOS,
                                slots=4096, max_len=32)
@@ -90,5 +91,4 @@ def main():
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     main()

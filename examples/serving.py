@@ -24,7 +24,7 @@ from incubator_mxnet_tpu.serving import DeadlineExceeded
 
 
 def main():
-    ctx = mx.cpu()
+    ctx = mx.tpu(0) if mx.num_tpus() else mx.cpu()
     net = resnet18_v1(classes=10, thumbnail=True)
     net.initialize(ctx=ctx)
     net.hybridize(static_alloc=True, static_shape=True)

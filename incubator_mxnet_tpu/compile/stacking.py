@@ -129,26 +129,6 @@ def verify_parity(layer_fn, params_list, x):
     return out
 
 
-def _clear_compile_caches():
-    """Drop jax's in-process trace/executable caches (feature-
-    detected; a no-op on builds without `jax.clear_caches`).  The
-    CPU client dedupes byte-identical HLO within one process, which
-    would report N identical per-layer compiles as nearly one — but
-    the quantity the fleet actually pays is the COLD per-executable
-    compile (each serving replica / bench / test process builds its
-    own, which is exactly why the AOT disk cache exists), so the
-    measurement isolates each compile."""
-    import jax
-    fn = getattr(jax, "clear_caches", None)
-    if fn is None:
-        return False
-    try:
-        fn()
-        return True
-    except Exception:               # noqa: BLE001
-        return False
-
-
 def measure(layer_fn, params_list, x, calls=20, label="stacking"):
     """Measured compile-wall + dispatch comparison: N per-layer
     executables (one fresh ``jit`` per layer — the status quo this
@@ -156,28 +136,26 @@ def measure(layer_fn, params_list, x, calls=20, label="stacking"):
 
     Compile wall is timed through ``lower().compile()`` with the
     in-process trace/executable caches cleared before every compile
-    (`_clear_compile_caches`), so each executable pays its honest
+    (`jax.clear_caches`), so each executable pays its honest
     cold cost — N identical layers would otherwise dedupe to ~one
     compile inside this process while every OTHER process still pays
     N.  Dispatch is the per-forward host wall over ``calls``
     synchronized calls.  The stacked executable files a cost-registry
     row (kind="stacked") so teletop/blackbox attribute it.  Returns
-    the delta dict the MULTICHIP compile block embeds (including
-    ``cold_isolated`` — False means the cache could not be cleared
-    and the compile-wall columns understate the unstacked cost)."""
+    the delta dict the MULTICHIP compile block embeds."""
     import jax
     n = len(params_list)
     stacked = stack_params(params_list)
 
     # unstacked: one executable per layer, compiled back to back,
     # each from a cold cache (the N-process reality)
-    isolated = _clear_compile_caches()
+    jax.clear_caches()
     t0 = time.perf_counter()
     per_layer = []
     for p in params_list:
         lowered = jax.jit(layer_fn).lower(p, x)
         per_layer.append(lowered.compile())
-        _clear_compile_caches()
+        jax.clear_caches()
     compile_unstacked = time.perf_counter() - t0
 
     def scanned(s, v):
@@ -231,7 +209,6 @@ def measure(layer_fn, params_list, x, calls=20, label="stacking"):
         "dispatch_stacked_us": int(dispatch_stacked * 1e6),
         "parity_ok": bool(parity["ok"]),
         "parity_max_abs_diff": parity["max_abs_diff"],
-        "cold_isolated": bool(isolated),
     }
     _bb.record("compile", "stack_measure", label=str(label), **result)
     return result

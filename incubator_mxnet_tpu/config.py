@@ -187,8 +187,8 @@ register("MXNET_PALLAS_INTERPRET", bool, False,
 register("MXNET_AOT_CACHE_DIR", str, "",
          "Directory for serialized compiled executables (aot_cache."
          "aot_jit): fresh processes deserialize instead of recompiling "
-         "— the workaround for backends whose remote compile path "
-         "bypasses the JAX persistent cache. Empty = off")
+         "— built for an earlier setup where JAX's persistent cache "
+         "did not engage. Empty = off (no default is set anywhere)")
 register("MXNET_FLASH_BLOCK_Q", int, 0,
          "Flash-attention Q block size (0 = auto)")
 register("MXNET_FLASH_BLOCK_K", int, 0,
@@ -202,8 +202,6 @@ register("MXNET_FLASH_BWD_PALLAS", str, "1",
          "scan fallback")
 register("MXNET_FLASH_BWD_BYTES", float, 5e8,
          "Bytes threshold for the recompute-free flash backward")
-register("MXNET_TEST_DEVICE", str, "cpu",
-         "Test corpus device: 'cpu' (virtual 8-chip mesh) or 'tpu'")
 register("MXNET_KVSTORE_BIGARRAY_BOUND", int, 1 << 20,
          "Array size above which kvstore push/pull prefers sharded "
          "reduce (parity knob; XLA collectives auto-tune)")

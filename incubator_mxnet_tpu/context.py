@@ -70,9 +70,14 @@ class Context:
             devs = jax.local_devices(backend="cpu") \
                 if jax.default_backend() != "cpu" else jax.local_devices()
         else:
-            # Virtual-mesh testing: accelerator contexts fall back to
-            # host devices so the same test corpus runs everywhere
-            # (ref test strategy: tests/python/gpu reruns the CPU corpus).
+            # an accelerator context never resolves to the host: a
+            # script that asks for tpu()/gpu() on a chip-less machine
+            # fails here instead of running on the CPU under that name
+            if jax.default_backend() == "cpu":
+                raise MXNetError(
+                    "context %r: no accelerator; the JAX backend found "
+                    "is 'cpu' (use mx.cpu(), or mx.num_tpus() to probe)"
+                    % (self,))
             devs = jax.local_devices()
         if self.device_id >= len(devs):
             raise MXNetError(

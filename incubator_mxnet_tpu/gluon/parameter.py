@@ -146,7 +146,7 @@ class Parameter:
         for ctx in ctx_list:
             # HOST zeros: the device buffer is about to be overwritten
             # by the initializer's device_put anyway — a jnp.zeros here
-            # costs one remote compile per distinct shape at startup
+            # costs one compile per distinct shape at startup
             arr = NDArray(_np.zeros(self._shape,
                                     _np.dtype(self.dtype)
                                     if not isinstance(self.dtype, str)
@@ -277,8 +277,7 @@ class Parameter:
     def cast(self, dtype, _convert=True):
         """_convert=False defers the data conversion — Block.cast
         batches every parameter's convert into ONE executable (a
-        per-shape eager astype costs a remote compile each on this
-        backend)."""
+        per-shape eager astype costs a compile each)."""
         self.dtype = dtype
         if self._data is None or not _convert:
             return

@@ -26,18 +26,18 @@ _SRC = os.path.join(os.path.dirname(os.path.dirname(
 _SO = os.path.join(os.path.dirname(_SRC), "libmxtpu_io.so")
 
 
-def build_library(force=False, src=None, out=None, march_native=True):
+def build_library(force=False, src=None, out=None):
     """Compile the pipeline .so (idempotent; also the ONE compile
-    recipe setup.py's wheel build calls — keep flags here)."""
+    recipe setup.py's wheel build calls — keep flags here).  No
+    -march=native: the mtime check trusts a library built on another
+    host (a copied tree, a wheel), so the build must run on any CPU."""
     src = src or _SRC
     out = out or _SO
     if os.path.exists(out) and not force and \
             os.path.getmtime(out) >= os.path.getmtime(src):
         return out
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread"]
-    if march_native:
-        cmd.append("-march=native")
-    cmd += [src, "-ljpeg", "-o", out]
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread",
+           src, "-ljpeg", "-o", out]
     subprocess.run(cmd, check=True, capture_output=True)
     return out
 

@@ -194,7 +194,7 @@ class RCNNTrainLoss(HybridBlock):
     """Hybridizable Faster-RCNN head loss (classification CE over
     sampled ROIs + smooth-L1 on weighted box targets), so the training
     forward's 8 outputs feed ONE fused loss program instead of a chain
-    of eager ops (PROFILE.md r4).
+    of eager ops.
 
     forward(cls_pred, box_pred, labels, bbox_targets, bbox_weights)
     → scalar loss.  (Proposal/ProposalTarget already ran inside the

@@ -218,7 +218,7 @@ class SSDTrainLoss(HybridBlock):
     """Hybridizable SSD training loss: MultiBoxTarget + softmax-CE +
     smooth-L1 in ONE cached-op block, so net(x) → loss(...) composes
     into a single fused train-step executable (the eager target/loss
-    ops otherwise break whole-step fusion — PROFILE.md r4).
+    ops otherwise break whole-step fusion).
 
     forward(anchors, cls_preds, box_preds, labels) → scalar loss.
     """

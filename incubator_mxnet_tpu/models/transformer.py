@@ -60,10 +60,7 @@ class MultiHeadAttention(HybridBlock):
         import jax as _jax
         from jax.sharding import (PartitionSpec as JP, NamedSharding,
                                   SingleDeviceSharding)
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from ..parallel import ring_attention
         mesh, axis = self._seq_parallel
         spec = JP(None, axis)

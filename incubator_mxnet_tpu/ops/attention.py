@@ -10,13 +10,15 @@ softmax (flash) recurrence, so the T×T score matrix never hits HBM:
   VMEM scratch carries (m, l, acc) across k blocks; outputs are written
   on the last k step.  Forward also emits the log-sum-exp row statistics
   so the backward pass can rebuild P = exp(S - lse) block-free in XLA
-  (one fused executable; dispatch cost matters more than HBM here, see
-  PROFILE.md).
+  (one fused executable).
 
-Fallback: plain jnp einsum-softmax path (identical math) when not on a
-TPU backend, when shapes don't tile (T % block != 0), or when
-MXNET_USE_PALLAS=0.  MXNET_PALLAS_INTERPRET=1 forces the Pallas kernel
-in interpreter mode so the CPU test suite exercises the real kernel.
+MXNET_USE_PALLAS=1 (auto) takes the plain jnp einsum-softmax path
+(identical math) when not on a TPU backend, when shapes don't tile
+(T % block != 0), or below MXNET_FLASH_AUTO_BYTES; =0 always does.
+A FORCED kernel (MXNET_USE_PALLAS=2, MXNET_FLASH_BWD_PALLAS=2) that
+cannot be used raises with the reason — it never gives way to the
+reference silently.  MXNET_PALLAS_INTERPRET=1 runs the Pallas kernel in
+interpreter mode so the CPU test suite exercises the real kernel.
 """
 from __future__ import annotations
 
@@ -26,22 +28,11 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..base import MXNetError
 from .registry import register
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_OK = True
-except Exception:                                       # pragma: no cover
-    pl = pltpu = None
-    _PALLAS_OK = False
-
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams across 0.4->0.5;
-# resolve whichever this jaxlib ships so the kernels build on both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams", None) if pltpu is not None \
-    else None
 
 __all__ = ["flash_attention", "naive_attention"]
 
@@ -60,9 +51,9 @@ def _largest_divisor(T, cap):
 
 
 def _block_sizes(T):
-    """Measured on this chip (PROFILE.md): per-grid-step overhead is
-    ~0.1–0.3 ms, so fewer+bigger blocks win.  Defaults keep the f32
-    score block ≤ 8 MB of VMEM."""
+    """Fewer+bigger blocks (caps chosen on an earlier setup; not
+    re-measured on this chip).  Defaults keep the f32 score block
+    ≤ 8 MB of VMEM."""
     from .. import config as _cfg
     bq = int(_cfg.get("MXNET_FLASH_BLOCK_Q")) \
         or _largest_divisor(T, 1024)
@@ -76,30 +67,46 @@ def _interpret():
     return bool(_cfg.get("MXNET_PALLAS_INTERPRET"))
 
 
-def _tiles_ok(T, d):
-    bq, bk = _block_sizes(T)
-    return (bq and bk and T % bq == 0 and T % bk == 0
-            and (bq % 8 == 0 or bq == T) and (bk % 8 == 0 or bk == T))
+def _tiles(T, bq, bk):
+    return bool(bq and bk and T % bq == 0 and T % bk == 0
+                and (bq % 8 == 0 or bq == T) and (bk % 8 == 0 or bk == T))
+
+
+def _unusable(T, d, blocks):
+    """Why the Pallas kernels cannot take this shape here, or None."""
+    bq, bk = blocks(T)
+    if not _tiles(T, bq, bk):
+        return "T=%d does not tile into blocks (%d, %d)" % (T, bq, bk)
+    if _interpret():
+        return None
+    if jax.default_backend() != "tpu":
+        return ("the backend is %r, not 'tpu', and "
+                "MXNET_PALLAS_INTERPRET is off" % jax.default_backend())
+    if d > 256:
+        return "head dim %d > 256" % d
+    return None
 
 
 def _pallas_enabled(BH, T, d):
-    """Dispatch policy, measured on this chip (see PROFILE.md):
-    the one-fused-XLA-program path is HBM-roofline-bound and faster up
-    to ~T=4096, but its B·H·T·T f32 score matrix stops compiling well
+    """Dispatch policy (thresholds chosen on an earlier setup; not
+    re-measured on this chip): the one-fused-XLA-program path wins at
+    short T, but its B·H·T·T f32 score matrix stops compiling well
     before T=8192; the Pallas kernel streams k/v blocks through VMEM
     and keeps working.  MXNET_USE_PALLAS: 0=never, 1=auto (score bytes
-    > MXNET_FLASH_AUTO_BYTES), 2=always."""
+    > MXNET_FLASH_AUTO_BYTES), 2=always (raises where it cannot)."""
     from .. import config as _cfg
     mode = _cfg.get("MXNET_USE_PALLAS")
-    if mode == "0" or not _PALLAS_OK:
+    if mode == "0":
         return False
-    if not _tiles_ok(T, d):
+    why = _unusable(T, d, _block_sizes)
+    if mode == "2":
+        if why:
+            raise MXNetError("MXNET_USE_PALLAS=2 forces the flash "
+                             "attention kernel, but " + why)
+        return True
+    if why:
         return False
     if _interpret():
-        return True
-    if jax.default_backend() != "tpu" or d > 256:
-        return False
-    if mode == "2":
         return True
     auto_bytes = float(_cfg.get("MXNET_FLASH_AUTO_BYTES"))
     return BH * T * T * 4.0 > auto_bytes
@@ -204,7 +211,7 @@ def _flash_fwd(q, k, v, scale, causal):
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(q, k, v)
@@ -350,7 +357,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal):
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(q, k, v, do, out, lse)
@@ -379,7 +386,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(k, v, q, do, out, lse)
@@ -405,30 +412,29 @@ def _flash_attention_fwd(q, k, v, scale, causal):
 
 def _flash_attention_bwd(scale, causal, res, do):
     """Backward from the saved lse row statistics: P = exp(S - lse)
-    rebuilt blockwise.  Default path is the Pallas dq/dkv kernel pair —
-    O(T·d) HBM traffic like the forward, which is what makes seq-4k/8k
-    training fit (VERDICT r3 #4).  MXNET_FLASH_BWD_PALLAS=0 falls back
-    to a fused-XLA `lax.scan` whose live score slab is bounded by
-    MXNET_FLASH_BWD_BYTES."""
+    rebuilt blockwise.  The Pallas dq/dkv kernel pair has O(T·d) HBM
+    traffic like the forward, which is what makes seq-4k/8k training
+    fit.  MXNET_FLASH_BWD_PALLAS: 0 = a fused-XLA `lax.scan` whose live
+    score slab is bounded by MXNET_FLASH_BWD_BYTES; 1 = the kernels
+    once the score slab outgrows that bound (threshold chosen on an
+    earlier setup; not re-measured on this chip); 2 = the kernels
+    always (raises where they cannot run)."""
     q, k, v, out, lse = res
     from .. import config as _cfg
+    BH, T, d = q.shape
     mode = _cfg.get("MXNET_FLASH_BWD_PALLAS")
     if mode != "0":
-        BH_, T_, _ = q.shape
-        bq, bk = _bwd_block_sizes(T_)
-        # measured on this chip (PROFILE.md): the fused-XLA path wins
-        # under grid overhead at short T; Pallas wins once the score
-        # slab outgrows MXNET_FLASH_BWD_BYTES (and is the only path
-        # whose HBM stays O(T·d) at seq 4k/8k)
+        why = _unusable(T, d, _bwd_block_sizes)
+        if mode == "2" and why:
+            raise MXNetError("MXNET_FLASH_BWD_PALLAS=2 forces the flash "
+                             "attention backward kernels, but " + why)
         want = (mode == "2" or
-                BH_ * T_ * T_ * 4.0 >
+                BH * T * T * 4.0 >
                 float(_cfg.get("MXNET_FLASH_BWD_BYTES")))
-        if want and bq and bk and T_ % bq == 0 and T_ % bk == 0:
-            lse128 = jnp.broadcast_to(lse[..., None],
-                                      (BH_, T_, 128))
+        if want and not why:
+            lse128 = jnp.broadcast_to(lse[..., None], (BH, T, 128))
             return _flash_bwd_pallas(q, k, v, out, lse128, do,
                                      scale, causal)
-    BH, T, d = q.shape
     f32 = jnp.float32
     qf, kf, vf, dof = (t.astype(f32) for t in (q, k, v, do))
     D = jnp.sum(dof * out.astype(f32), axis=-1, keepdims=True)  # (BH, T, 1)

@@ -267,7 +267,7 @@ def _hyper_array(values):
             # buffer per training step forever
             _HYPER_CACHE.clear()
         # host build + device_put (see engine.host_const: a jnp.asarray
-        # of a host list is a remote compile per length on this backend)
+        # of a host list is a compile per length)
         import numpy as _nph
         import jax as _jax
         v = _jax.device_put(_nph.asarray(key, _nph.float32))

@@ -19,51 +19,6 @@ __all__ = ["make_mesh", "Mesh", "NamedSharding", "P", "replicated",
            "mesh_devices", "surviving_mesh"]
 
 
-_PCACHE_GUARDED = [False]
-
-
-def _guard_cpu_mesh_pcache(devices):
-    """Disable the JAX persistent compilation cache the first time a
-    MULTI-DEVICE CPU mesh is built in this process (ISSUE 8 satellite).
-
-    A WARM persistent-cache hit for a multi-device DONATED executable
-    segfaults this jaxlib's CPU backend (verified in the PR 7 elastic
-    bench: identical runs pass cold and crash mid-step warm) — and
-    every mesh consumer (ShardedTrainer steps, the elastic rebuild,
-    ZeRO updates) donates buffers.  PR 7 disabled the cache in the
-    bench child only; this is the library-level gate, at the one
-    chokepoint every CPU-mesh scenario passes through.  Real
-    accelerator meshes are untouched, as is the single-device CPU
-    path (where the cache is the verified 39s→10s win), and the gate
-    only fires when a cache dir is actually configured — without one
-    the cache cannot engage anyway."""
-    if _PCACHE_GUARDED[0] or len(devices) < 2:
-        return
-    if not all(getattr(d, "platform", "") == "cpu" for d in devices):
-        return
-    import os
-    if not (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or getattr(jax.config, "jax_compilation_cache_dir", None)):
-        return
-    try:
-        jax.config.update("jax_enable_compilation_cache", False)
-    except Exception:           # noqa: BLE001 — ancient jax: no knob,
-        return                  # no cache, nothing to guard
-    _PCACHE_GUARDED[0] = True
-    import warnings
-    warnings.warn(
-        "JAX persistent compilation cache disabled: multi-device "
-        "donated executables on the CPU backend segfault this jaxlib "
-        "on a warm cache hit (single-device processes keep the cache)")
-    from ..monitor import events
-    events.incr("aot.pcache_disabled")
-    try:
-        from ..telemetry import flightrec as _bb
-        _bb.record("aot", "pcache_disabled", devices=len(devices))
-    except Exception:           # noqa: BLE001 — forensics best-effort
-        pass
-
-
 def make_mesh(shape: Sequence[int] = None,
               axis_names: Sequence[str] = ("data",),
               devices=None) -> Mesh:
@@ -76,7 +31,6 @@ def make_mesh(shape: Sequence[int] = None,
     if shape is None:
         shape = (len(devices),)
     arr = _np.asarray(devices[:int(_np.prod(shape))]).reshape(shape)
-    _guard_cpu_mesh_pcache(list(arr.flat))
     return Mesh(arr, tuple(axis_names))
 
 
@@ -149,14 +103,5 @@ def squeeze_stage_axis(tree):
 
 def mark_varying(x, axis_name):
     """Tag an unvarying value as device-varying for shard_map's vma
-    type system (scan carries that become per-device): lax.pcast on
-    current jax, lax.pvary fallback, no-op where vma doesn't exist."""
-    from jax import lax as _lax
-    try:
-        return _lax.pcast(x, (axis_name,), to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return _lax.pvary(x, axis_name)
-    except AttributeError:
-        return x
+    type system (scan carries that become per-device)."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")

@@ -413,12 +413,7 @@ class ShardedTrainer:
         Everything donates: params + optimizer state alias in place.
         """
         import jax
-        try:
-            from jax import shard_map as _shard_map
-            shard_map = _shard_map.shard_map if hasattr(
-                _shard_map, "shard_map") else _shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         fwd = self._fwd
         loss_fn = self.loss_fn
         opt_update = self._opt_update
@@ -522,7 +517,7 @@ class ShardedTrainer:
             body, mesh=self.mesh,
             in_specs=(pspecs_in, opt_specs, P(axis), P(axis), P()),
             out_specs=(pspecs_out, opt_specs, P()),
-            check_rep=False)
+            check_vma=False)
         return _costs.metered_jit(
             smapped, donate_argnums=donate_argnums,
             kind="train", label="sharded.zstep",
@@ -554,11 +549,7 @@ class ShardedTrainer:
             return self._dispatch.place(arr, sharding)
         return jax.device_put(jnp.asarray(arr), sharding)
 
-    def step(self, batch, labels, rng_bits=None):
-        """batch/labels: jax or numpy arrays (global batch; in
-        multi-controller runs, this process's rows of it). Returns loss
-        (device scalar — don't block on it every step)."""
-        from .. import random as _rnd
+    def _ensure_step(self):
         if self._step is None:
             # zero>=2 on a real multi-replica mesh takes the explicit
             # overlap-first path; a 1-replica mesh degenerates to the
@@ -567,6 +558,28 @@ class ShardedTrainer:
                 self._step = self._build_step_zero()
             else:
                 self._step = self._build_step()
+
+    def lower_step(self, batch, labels):
+        """The `jax.stages.Lowered` of the step `step(batch, labels)`
+        runs, lowered with the REAL shardings (sharded batch, placed
+        params and optimizer state) — for an audit that reads the
+        compiled program (`.compile().as_text()`: which collectives did
+        XLA emit?).  Unsharded avals would compile a single-device
+        program with none.  Nothing executes and nothing is donated."""
+        from .. import random as _rnd
+        self._ensure_step()
+        return self._step.lower(
+            self.params, self.opt_state,
+            self._place_batch(batch, self._batch_sharding),
+            self._place_batch(labels, self._batch_sharding),
+            jax.random.key_data(_rnd.split_key()))
+
+    def step(self, batch, labels, rng_bits=None):
+        """batch/labels: jax or numpy arrays (global batch; in
+        multi-controller runs, this process's rows of it). Returns loss
+        (device scalar — don't block on it every step)."""
+        from .. import random as _rnd
+        self._ensure_step()
         # telemetry: one bool read when disabled; enabled, the step
         # records data-wait (placement) vs dispatch wall.  The loss
         # deliberately stays on device (async dispatch), so compute

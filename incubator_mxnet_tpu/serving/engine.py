@@ -5,9 +5,8 @@ The ROADMAP north star is "heavy traffic from millions of users", and
 the serving-side analogue of the training recompilation problem is the
 RECOMPILATION CLIFF: eager `block(x)` compiles one executable per input
 batch size, so organic traffic (every batch size from 1 to N) triggers
-a fresh trace+compile on this backend's remote compiler — seconds to
-minutes of tail latency per new shape (PROFILE.md; the hazard TVM
-arxiv 1802.04799 and the XLA fusion analysis arxiv 2301.13062 both
+a fresh trace+compile — seconds of tail latency per new shape (the
+hazard TVM arxiv 1802.04799 and the XLA fusion analysis arxiv 2301.13062 both
 center on).  The engine closes the executable set instead:
 
 1. **Shape buckets.**  Requests are coalesced by a background
@@ -18,9 +17,8 @@ center on).  The engine closes the executable set instead:
 2. **AOT warm.**  `warmup()` pre-compiles every (device, bucket)
    executable before traffic, through `aot_cache.aot_jit` — with
    `MXNET_AOT_CACHE_DIR` set, a restarted serving host deserializes
-   the whole executable set from disk instead of recompiling
-   (sub-second vs 75-260 s per executable on the remote-compile
-   backend).  `serve.traces` counts executable traces; it stays FLAT
+   the whole executable set from disk instead of recompiling.
+   `serve.traces` counts executable traces; it stays FLAT
    after warmup under mixed-size traffic — the zero-recompile
    contract `bench.py serve` asserts.
 3. **Concurrency.**  Callers `submit()` single examples (or

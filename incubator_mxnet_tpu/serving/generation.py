@@ -419,8 +419,9 @@ class GenerationEngine:
         except Exception:               # noqa: BLE001
             from .. import nd
             src = nd.array(_np.full((1, int(self._buckets[0])),
-                                    self._bos, _np.int32))
-            tgt = nd.array(_np.full((1, 1), self._bos, _np.int32))
+                                    self._bos, _np.int32), ctx=self._ctx)
+            tgt = nd.array(_np.full((1, 1), self._bos, _np.int32),
+                           ctx=self._ctx)
             block(src, tgt)
         self._build_executables()
         self._cache = None              # device cache (built on warmup
@@ -535,15 +536,19 @@ class GenerationEngine:
 
     def kv_cache_bytes(self):
         """Total device bytes held by the slot cache (the KV term of
-        generation admission), and the per-slot share."""
+        generation admission), the per-slot share, and the devices the
+        leaves actually sit on."""
         import jax
         if self._cache is None:
             self._init_cache_arrays()
+        leaves = jax.tree_util.tree_leaves(self._cache)
         total = sum(int(_np.prod(a.shape))
-                    * _np.dtype(a.dtype).itemsize
-                    for a in jax.tree_util.tree_leaves(self._cache))
+                    * _np.dtype(a.dtype).itemsize for a in leaves)
         return {"total": total, "per_slot": total // self._S,
-                "slots": self._S}
+                "slots": self._S,
+                "devices": sorted({"%s:%d" % (d.platform, d.id)
+                                   for a in leaves
+                                   for d in a.devices()})}
 
     # -- warmup ---------------------------------------------------------
     def warmup(self):

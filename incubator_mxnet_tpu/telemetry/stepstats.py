@@ -24,7 +24,7 @@
     train.steps_skipped     guarded steps whose update was not applied
     train.steps_compiling   steps that traced a new executable
                             (`train.traces` moved — the recompile
-                            smoke alarm, PROFILE.md's dominant tail)
+                            smoke alarm)
     train.checkpoint_us     checkpoint write wall
 
 `ResilientTrainer` / `ShardedTrainer` instantiate one lazily when

@@ -34,11 +34,7 @@ _DEFAULT_ATOL = {
 
 
 def default_context() -> Context:
-    """ref: test_utils.default_context — env-overridable test context."""
-    dev = os.environ.get("MXNET_TEST_DEVICE", "")
-    if dev.startswith("tpu") or dev.startswith("gpu"):
-        from .context import tpu
-        return tpu(int(dev.split(":")[-1]) if ":" in dev else 0)
+    """ref: test_utils.default_context — the test context."""
     return current_context()
 
 

@@ -23,13 +23,11 @@ class BuildWithNativeIO(build_py):
         out = os.path.join(here, "incubator_mxnet_tpu", "io",
                            "libmxtpu_io.so")
         try:
-            # the ONE compile recipe lives in io/native.py; wheels are
-            # portable artifacts, so no -march=native here
+            # the ONE compile recipe lives in io/native.py
             import sys
             sys.path.insert(0, here)
             from incubator_mxnet_tpu.io.native import build_library
-            build_library(force=True, src=src, out=out,
-                          march_native=False)
+            build_library(force=True, src=src, out=out)
             print("built native io pipeline ->", out)
         except Exception as e:
             # pure-python install still works (python RecordIO fallback)

@@ -1,6 +1,5 @@
 """Executable AOT cache (aot_cache.py): store / reload / corruption
-fallback.  (The cache is the workaround for backends whose remote
-compile path bypasses the JAX persistent cache — PROFILE.md r5.)"""
+fallback."""
 import os
 
 import numpy as np

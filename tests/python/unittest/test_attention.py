@@ -28,14 +28,8 @@ def _rand_qkv(BH=4, T=256, d=64, dtype=np.float32):
     return mk(), mk(), mk()
 
 
-# On the MXNET_TEST_DEVICE=tpu corpus run, f32 matmuls go through the
-# MXU at reduced internal precision — both paths sit ~4e-4 from a
-# float64 ground truth, so compare them at that scale there.
 def _tol():
-    # on-chip both paths run bf16-ish MXU math: a handful of elements
-    # land ~1.3e-3 from each other (bf16 eps is 7.8e-3) — 2e-3 is the
-    # right scale for "same computation, different reduction order"
-    return 2e-5 if jax.default_backend() == "cpu" else 2e-3
+    return 2e-5
 
 
 def test_flash_fwd_matches_naive(pallas_interpret):

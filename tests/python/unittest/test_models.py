@@ -385,13 +385,6 @@ def test_quality_config_converges_and_matches_r5_shape():
     below."""
     import os
     import sys
-    # bench.py's module-level env setup (AOT cache dir etc.) must not
-    # leak into the rest of the pytest process — save/restore
-    _keys = ("MXNET_AOT_CACHE_DIR", "JAX_COMPILATION_CACHE_DIR",
-             "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
-             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS")
-    _saved = {k: os.environ.get(k) for k in _keys}
-    os.environ["MXNET_AOT_CACHE_DIR"] = ""
     sys.path.insert(0, os.path.join(os.path.dirname(__file__),
                                     "..", "..", ".."))
     try:
@@ -405,11 +398,6 @@ def test_quality_config_converges_and_matches_r5_shape():
                                 eval_n=128, amp=3.0)
     finally:
         sys.path.pop(0)
-        for k, v in _saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
     curve = out["quality_loss_curve"]
     assert curve[-1] < curve[0] * 0.8, curve
     assert out["quality_resnet18_synth_eval_acc"] > 0.7, out

@@ -24,36 +24,6 @@ def test_mesh_creation():
         assert mesh2.axis_names == ("data", "model")
 
 
-@requires_multidevice
-def test_cpu_mesh_gates_persistent_compilation_cache(monkeypatch,
-                                                     tmp_path):
-    """Building a multi-device CPU mesh with a JAX persistent
-    compilation cache configured must disable the cache at the
-    library level (ISSUE 8 satellite): a warm cache hit for a
-    multi-device donated executable segfaults this jaxlib's CPU
-    backend (PR 7 verified it cold-pass/warm-crash and disabled it in
-    the bench child only)."""
-    from incubator_mxnet_tpu.monitor import events
-    from incubator_mxnet_tpu.parallel import mesh as pmesh
-    if jax.devices()[0].platform != "cpu":
-        pytest.skip("gate is CPU-backend-only")
-    prev = jax.config.jax_enable_compilation_cache
-    monkeypatch.setattr(pmesh, "_PCACHE_GUARDED", [False])
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    try:
-        jax.config.update("jax_enable_compilation_cache", True)
-        n0 = events.get("aot.pcache_disabled")
-        with pytest.warns(UserWarning, match="persistent compilation"):
-            pmesh.make_mesh()
-        assert jax.config.jax_enable_compilation_cache is False
-        assert events.get("aot.pcache_disabled") == n0 + 1
-        # idempotent: a second mesh doesn't re-fire the gate
-        pmesh.make_mesh()
-        assert events.get("aot.pcache_disabled") == n0 + 1
-    finally:
-        jax.config.update("jax_enable_compilation_cache", prev)
-
-
 def test_functionalize_matches_imperative():
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(8, activation="relu"), gluon.nn.Dense(3))

@@ -171,12 +171,9 @@ def main(argv=None) -> int:
         # environment verdicts, not compile-loop regressions:
         #   - the backend cannot deserialize its own blobs (breaker)
         #   - the cold run never populated the cache (cold-cache pair)
-        #   - the cold-cache isolation shim is absent, so the
-        #     unstacked compile wall was deduped to ~one compile
         inconclusive = (warm["aot_load_disabled"] > 0
                         or cold["aot_miss"] == 0
-                        or warm["manifest_entries"] == 0
-                        or not stack.get("cold_isolated", False))
+                        or warm["manifest_entries"] == 0)
         ok = stack_ok and warm_ok
         verdicts.append(None if (inconclusive and not ok) else ok)
         trial_rows.append({

@@ -12,8 +12,12 @@ base.ensure_jax_distributed) — so launching N workers on this host is:
 
 Each worker gets DMLC_NUM_WORKER / DMLC_WORKER_ID / DMLC_PS_ROOT_URI /
 DMLC_PS_ROOT_PORT; `--devices-per-worker` additionally forces an
-N-device virtual CPU platform per worker (multi-chip simulation —
-omit it on real TPU hosts, where each worker sees its local chips).
+N-device virtual CPU platform per worker (multi-chip simulation).
+Do NOT start several workers on one TPU host: a chip belongs to one
+process, every worker would claim all the local chips, and all but the
+first fail or hang.  One process drives all the chips of a host
+(`ShardedTrainer` over `make_mesh`, as chip_smoke.py's multichip phase
+does); on TPU this launcher is for one worker PER HOST (--base-rank).
 Output is streamed with a `[rank]` prefix; the first failing worker
 kills the rest (fail-fast, like the reference's local tracker).
 Multi-HOST launches set DMLC_PS_ROOT_URI to worker 0's address and run

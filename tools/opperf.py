@@ -4,12 +4,10 @@ rule-based per-op benchmarks emitting a machine-readable table).
 Measures, per op × shape:
   - ``dispatch_ms``: median host-side cost of one imperative invoke()
     WITHOUT waiting on the device (the tape/dispatch overhead a chain of
-    eager ops pays — the number that explains every "dispatch-bound" row
-    in PROFILE.md);
+    eager ops pays);
   - ``e2e_ms``: per-call wall time of a DEPENDENT chain (each call
-    consumes the previous result) ended by a host fetch — the only
-    honest device timing on this backend (PROFILE.md "timing pitfall":
-    block_until_ready on independent enqueues measures enqueue rate).
+    consumes the previous result) ended by a host fetch
+    (block_until_ready on independent enqueues measures enqueue rate).
 
 Usage:
   python tools/opperf.py                    # default op set, one JSON doc
