@@ -199,10 +199,13 @@ class _AotJitted:
     One compiled executable per input aval signature."""
 
     def __init__(self, fn, donate_argnums=(), label=None, kind="aot",
-                 expect_donated=None):
-        self._jit = jax.jit(fn, donate_argnums=donate_argnums)
-        self._compiled = {}
+                 expect_donated=None, role=None):
         self._label = label or getattr(fn, "__name__", "fn")
+        # the same executable name as MeteredJit gives without a cache
+        # directory: jit__traced_<label or role>
+        self._jit = jax.jit(_costs.traced_as(fn, self._label, role),
+                            donate_argnums=donate_argnums)
+        self._compiled = {}
         self._kind = kind
         self._cost_keys = {}        # sig -> costs registry row key
         # donation audit (ISSUE 10 satellite): same warn-once contract
@@ -392,7 +395,8 @@ class _AotJitted:
         comp = self._compiled.get(sig)
         if comp is None:
             try:
-                comp = self._get_compiled(args, sig)
+                with _tele.phase("compile.call", self._label):
+                    comp = self._get_compiled(args, sig)
             except Exception as e:      # any AOT failure → plain jit
                 import warnings
                 warnings.warn(
@@ -414,7 +418,7 @@ class _AotJitted:
 
 
 def aot_jit(fn, donate_argnums=(), label=None, kind="aot",
-            expect_donated=None):
+            expect_donated=None, role=None):
     """`jax.jit(fn, donate_argnums=...)` with executable persistence
     under `MXNET_AOT_CACHE_DIR` (no-op passthrough when unset).
 
@@ -425,14 +429,19 @@ def aot_jit(fn, donate_argnums=(), label=None, kind="aot",
     `MeteredJit` (invocation counts + lazily-resolved cost analysis).
     Unlabeled calls keep the original zero-overhead contract.
     `expect_donated` arms the donation audit (warn once, by label,
-    when a donatable argnum is not in `donate_argnums`)."""
+    when a donatable argnum is not in `donate_argnums`).  A labeled
+    executable is named `jit__traced_<slug>` in both modes, the slug
+    made of `role` where the call site fixes one, else of `label`
+    (`costs.traced_as`); its first call with a new signature leaves a
+    `compile.call` row (ident = label) in the phase log."""
     if not cache_dir():
         if label is not None:
             return _costs.metered_jit(fn, donate_argnums=donate_argnums,
                                       kind=kind, label=label,
-                                      expect_donated=expect_donated)
+                                      expect_donated=expect_donated,
+                                      role=role)
         _costs._audit_donation(label or getattr(fn, "__name__", "fn"),
                                donate_argnums, expect_donated)
         return jax.jit(fn, donate_argnums=donate_argnums)
     return _AotJitted(fn, donate_argnums=donate_argnums, label=label,
-                      kind=kind, expect_donated=expect_donated)
+                      kind=kind, expect_donated=expect_donated, role=role)

@@ -21,6 +21,7 @@ from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from .. import optimizer as opt_mod
 from ..kvstore import create as kv_create, KVStore
+from ..telemetry import spans as _spans
 from .parameter import Parameter, ParameterDict
 
 __all__ = ["Trainer"]
@@ -52,6 +53,7 @@ class Trainer:
         self._update_on_kvstore = update_on_kvstore
         self._kv_initialized = False
         self._params_to_init = []
+        self._n_step = 0
 
     # ------------------------------------------------------------------
     def _check_contexts(self):
@@ -121,17 +123,20 @@ class Trainer:
     # ------------------------------------------------------------------
     def step(self, batch_size, ignore_stale_grad=False):
         """allreduce + fused update (ref: Trainer.step → push/pull +
-        optimizer update ops)."""
-        if not self._kv_initialized:
-            self._init_kvstore()
-        self._optimizer.rescale_grad = self._scale / batch_size
-        if not self._update_on_kvstore:
-            # update_on_kvstore: update() pushes raw grads and pulls
-            # weights — aggregation happens IN the store; a prior
-            # allreduce would double-count by num_workers (ref:
-            # Trainer.step's _allreduce_grads/_update split)
-            self.allreduce_grads()
-        self.update(batch_size, ignore_stale_grad)
+        optimizer update ops).  One `gluon.step` row of the phase log
+        (ident = step number): the host's cost of dispatching it."""
+        with _spans.phase("gluon.step", self._n_step):
+            self._n_step += 1
+            if not self._kv_initialized:
+                self._init_kvstore()
+            self._optimizer.rescale_grad = self._scale / batch_size
+            if not self._update_on_kvstore:
+                # update_on_kvstore: update() pushes raw grads and pulls
+                # weights — aggregation happens IN the store; a prior
+                # allreduce would double-count by num_workers (ref:
+                # Trainer.step's _allreduce_grads/_update split)
+                self.allreduce_grads()
+            self.update(batch_size, ignore_stale_grad)
 
     def allreduce_grads(self):
         if not self._kv_initialized:

@@ -596,31 +596,34 @@ class ShardedTrainer:
         # step (dispatch fan-out, kvstore, feed) carry the step id —
         # the cross-process correlation key
         _tele.set_global_step(self._n_step)
-        t0 = time.perf_counter()
-        batch = self._place_batch(batch, self._batch_sharding)
-        labels = self._place_batch(
-            labels, NamedSharding(self.mesh, P(self.batch_axis)))
-        if rng_bits is None:
-            rng_bits = jax.random.key_data(_rnd.split_key())
-        t1 = time.perf_counter() if tele is not None else 0.0
-        try:
-            self.params, self.opt_state, loss = self._step(
-                self.params, self.opt_state, batch, labels, rng_bits)
-        except Exception as e:
-            # allocator OOM at dispatch: dump committed-vs-measured
-            # per tenant before the unwind frees the evidence
-            # (ISSUE 20); zero-cost until an exception actually raises
-            from ..telemetry import memwatch as _mw
-            _mw.guard_oom("train.step", e)
-            raise
-        self._n_step += 1
-        if self._zero_plan is not None:
-            # bytes-on-wire attribution: bump every bucket collective's
-            # registry row once per step (gated on the recorder inside)
-            self._zero_plan.invoke_cost_rows()
-            if getattr(self, "_zero_host_gather", False):
-                self._broadcast_solo_params()
-        t2 = time.perf_counter()
+        # one sharded.step row of the phase log (ident = step number):
+        # the host's cost of placing the batch and dispatching the step;
+        # the meters below take the phase's own stamps
+        with _tele.phase("sharded.step", self._n_step) as ph:
+            batch = self._place_batch(batch, self._batch_sharding)
+            labels = self._place_batch(
+                labels, NamedSharding(self.mesh, P(self.batch_axis)))
+            if rng_bits is None:
+                rng_bits = jax.random.key_data(_rnd.split_key())
+            t1 = time.monotonic() if tele is not None else 0.0
+            try:
+                self.params, self.opt_state, loss = self._step(
+                    self.params, self.opt_state, batch, labels, rng_bits)
+            except Exception as e:
+                # allocator OOM at dispatch: dump committed-vs-measured
+                # per tenant before the unwind frees the evidence
+                # (ISSUE 20); zero-cost until an exception actually raises
+                from ..telemetry import memwatch as _mw
+                _mw.guard_oom("train.step", e)
+                raise
+            self._n_step += 1
+            if self._zero_plan is not None:
+                # bytes-on-wire attribution: bump every bucket collective's
+                # registry row once per step (gated on the recorder inside)
+                self._zero_plan.invoke_cost_rows()
+                if getattr(self, "_zero_host_gather", False):
+                    self._broadcast_solo_params()
+        t0, t2 = ph.t0, ph.t1
         # always-on flight-recorder step record (loss stays on device —
         # forcing it here would forfeit dispatch/compute overlap); AMP
         # runs tag their records AND feed a labeled step-wall ring, so

@@ -191,9 +191,13 @@ scope = _Scope
 
 def start_jax_trace(logdir="/tmp/jax-trace"):
     """XLA-level tracing (XPlane/TensorBoard) — inside-executable timeline
-    the op-level chrome trace cannot see."""
+    the op-level chrome trace cannot see.  The phase log's intervals
+    (telemetry/spans.py) are in it as host events.  The Python tracer is
+    off, as in the benchmark's sessions: it slows the host it measures."""
     import jax
-    jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
 
 
 def stop_jax_trace():

@@ -503,7 +503,8 @@ class InferenceEngine:
         # ModelRegistry passes serve.infer:<model> so admission can
         # find THIS model's measured footprint) — the per-bucket
         # FLOPs/HBM attribution the blackbox dump reports
-        return aot_jit(infer, label=self._cost_label, kind="serve")
+        return aot_jit(infer, label=self._cost_label, kind="serve",
+                       role="serve_infer")
 
     def refresh_params(self):
         """(Re-)replicate the block's current parameters onto every

@@ -54,11 +54,33 @@ latency land in the labeled percentile rings `gen.ttft_us` /
 `gen.intertoken_us` split by lane), or call `.result()` for the final
 token array.  `drain()`/`close()` resolve every stream exactly once.
 
-**Observability.**  Spans `serve.prefill` / `serve.decode_step`,
-`gen.*` counters, a slot-occupancy gauge (`gen.slots_live` ring +
-flight-recorder events on every join/retire), and per-lane TTFT SLO
-targets (`slo_targets()`) that `telemetry/slo.py`'s default generation
-rules alert on.
+**Observability.**  The engine writes the always-on phase log
+(`telemetry/spans.py`: rows `(name, t0, t1, ident, parent, n)` on
+`time.monotonic()`, mirrored to the profiler as `TraceAnnotation`s, so
+a device trace names its idle gaps by these names):
+
+- per scheduler round `gen.tick` (ident = tick number, n = live
+  slots) and, with parent = that tick, `gen.admit` (n = requests
+  admitted), per admitted request `gen.prefill` (pad + `device_put` +
+  dispatch) and `gen.join` (dispatch), ident = the request's id;
+  `gen.decode` (dispatch, n = live), `gen.sync` (the wait on the
+  step's tokens) and `gen.emit` (the per-slot loop: push, observe,
+  retire; n = tokens pushed).  A round with nothing queued and
+  nothing live writes no row; the wait for work is one `gen.idle`.
+- per request, ident = its id, parent = the tick that admitted it:
+  `gen.req.queue` [enqueue, pop], `gen.req.admit` [pop, joined],
+  `gen.req.first` [joined, first token pushed].  The same stamps fill
+  the request journal (`reqtrace.Record`: `t_collect`, `t_exec`,
+  `t_first`).
+
+Dispatch is asynchronous: `gen.prefill`, `gen.join` and `gen.decode`
+are the host's time to dispatch, and the device's time for all three
+shows up in `gen.sync`.  The executables are named by role whatever
+the cost label (`jit__traced_gen_prefill`, `_gen_join`, `_gen_decode`).
+Beside the log: `gen.*` counters, a slot-occupancy gauge
+(`gen.slots_live` ring + flight-recorder events on every join/retire),
+and per-lane TTFT SLO targets (`slo_targets()`) that
+`telemetry/slo.py`'s default generation rules alert on.
 
 Model contract (``models/seq2seq.py``, ``models/transformer.py``):
 
@@ -292,8 +314,8 @@ class GenerationStream:
 
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "deadline", "lane", "tenant",
-                 "stream", "t_enq", "tele", "future", "n", "acct",
-                 "rec")
+                 "stream", "t_enq", "t_exec", "tick", "future", "n",
+                 "acct", "rec", "rid")
 
     def __init__(self, prompt, max_new, deadline, lane, tenant):
         self.prompt = prompt
@@ -307,17 +329,18 @@ class _GenRequest:
         self.future = self.stream.future    # _LaneQueue/engine duck type
         self.n = 1
         self.acct = False       # queue/tenant accounting released once
-        self.tele = _tele.current()
+        self.t_exec = None      # joined into its slot (phase log)
+        self.tick = None        # the tick that admitted it
         self.rec = None         # reqtrace.Record (journal lifecycle)
+        self.rid = None         # the journal's id, or the engine's own
 
 
 class _Slot:
-    __slots__ = ("req", "emitted", "t_join", "t_last")
+    __slots__ = ("req", "emitted", "t_last")
 
     def __init__(self, req):
         self.req = req
         self.emitted = 0
-        self.t_join = time.monotonic()
         self.t_last = None      # last token wall (inter-token meter)
 
 
@@ -403,6 +426,7 @@ class GenerationEngine:
         self._prefill_ewma = {}         # bucket -> prefill seconds
         self._step_ewma = None          # decode-step seconds
         self._steps = 0
+        self._ticks = 0
         self._thread = None
         self._draining = False
         self._stop = False
@@ -492,14 +516,17 @@ class GenerationEngine:
         # and join donate the cache — the PR 10 audit arms the
         # donation contract at build time, the runtime probe below
         # proves no silent copy on the live path
+        # the role names the executable, whatever the user's cost label
         self._prefill = aot_jit(prefill, label=self._label + ":prefill",
-                                kind="serve")
+                                kind="serve", role="gen_prefill")
         self._decode = aot_jit(decode_step, donate_argnums=(1,),
                                label=self._label + ":decode_step",
-                               kind="serve", expect_donated=(1,))
+                               kind="serve", expect_donated=(1,),
+                               role="gen_decode")
         self._join = aot_jit(join, donate_argnums=(0,),
                              label=self._label + ":join",
-                             kind="serve", expect_donated=(0,))
+                             kind="serve", expect_donated=(0,),
+                             role="gen_join")
         dev = self._ctx.jax_device
         self._params = {n: jax.device_put(v, dev)
                         for n, v in extract_params(block).items()}
@@ -661,6 +688,8 @@ class GenerationEngine:
         tenant = str(tenant) if tenant is not None else None
         req = _GenRequest(prompt, max_new, deadline, lane, tenant)
         req.rec = self._journal.start(req.t_enq, lane, tenant)
+        req.rid = req.rec.rid if req.rec is not None \
+            else _reqtrace.next_rid()
         if req.deadline is not None and req.deadline <= req.t_enq:
             self._shed_mark(lane, tenant, "deadline", deadline=True)
             exc = DeadlineExceeded("deadline is not in the future")
@@ -740,6 +769,7 @@ class GenerationEngine:
             return
         wake = weakref.ref(eng0._work)  # the Event may outlive checks
         del eng0                        # but must not pin the engine
+        waiting = None                  # the open gen.idle phase
         while True:
             eng = ref()
             if eng is None:
@@ -771,8 +801,14 @@ class GenerationEngine:
                 # still GCs (wait() wakes on timeout and re-derefs).
                 ev = wake()
                 if ev is not None:
-                    ev.wait(0.05)
-                    ev.clear()
+                    # one gen.idle row per idle period, not per poll:
+                    # an engine left idle must not fill the ring
+                    if waiting is None:
+                        waiting = _tele.phase("gen.idle").start()
+                    if ev.wait(0.05):
+                        ev.clear()
+                        waiting.stop()
+                        waiting = None
 
     def _live(self):
         return [i for i, s in enumerate(self._slots) if s is not None]
@@ -788,14 +824,19 @@ class GenerationEngine:
         run the idempotent leftover flush.)"""
         if self._stop:
             return "closed"
-        self._admit()
-        live = self._live()
-        if not live:
-            return "idle"
-        self._step(live)
+        if not self._q.qsize() and not any(self._slots):
+            return "idle"               # nothing to do: no row
+        self._ticks += 1
+        with _tele.phase("gen.tick", self._ticks) as tick:
+            self._admit(tick.ident)
+            live = self._live()
+            tick.n = len(live)
+            if not live:
+                return "idle"
+            self._step(live, tick.ident)
         return "ran"
 
-    def _admit(self):
+    def _admit(self, tick):
         """Fill free slots from the lane queue.  Continuous mode joins
         whenever a slot is free; drain mode only when EVERY slot is
         free (the baseline the TTFT comparison measures against)."""
@@ -804,18 +845,25 @@ class GenerationEngine:
             return
         if not self._continuous and len(free) != self._S:
             return
-        while free:
-            try:
-                req = self._q.get_nowait()
-            except queue.Empty:
-                return
-            if req.rec is not None:     # queue phase ends at the pop
-                req.rec.t_collect = time.monotonic()
-            slot = free.pop(0)
-            if not self._admit_one(req, slot):
-                free.insert(0, slot)    # shed — the slot stays free
+        with _tele.phase("gen.admit", parent=tick) as admit:
+            while free:
+                try:
+                    req = self._q.get_nowait()
+                except queue.Empty:
+                    return
+                t_collect = time.monotonic()    # queue phase ends here
+                _tele.phase_at("gen.req.queue", req.t_enq, t_collect,
+                               req.rid, tick)
+                if req.rec is not None:
+                    req.rec.t_collect = t_collect
+                req.tick = tick
+                slot = free.pop(0)
+                if self._admit_one(req, slot, t_collect):
+                    admit.n += 1
+                else:
+                    free.insert(0, slot)    # shed — the slot stays free
 
-    def _admit_one(self, req, slot):
+    def _admit_one(self, req, slot, now):
         """Prefill + join one request into `slot`.  Returns True when
         the slot was taken.  Sheds born-expired and
         infeasible-deadline requests (prefill EWMA + one step says no
@@ -827,7 +875,6 @@ class GenerationEngine:
             self._resolve(req, exc=EngineClosed(
                 "engine closed before dispatch"))
             return False
-        now = time.monotonic()
         bucket = self._bucket_for(req.prompt.size)
         if req.rec is not None:
             req.rec.bucket = bucket
@@ -852,32 +899,30 @@ class GenerationEngine:
             return False
         import jax
         dev = self._ctx.jax_device
-        padded = _np.zeros((1, bucket), _np.int32)
-        padded[0, :req.prompt.size] = req.prompt
-        t0 = time.monotonic()
-        span = _tele.span("serve.prefill", parent=req.tele)
-        span.start()
         try:
-            fault.maybe_raise("serve.infer", step=self._steps)
-            row = self._prefill(
-                self._params, jax.device_put(padded, dev),
-                jax.device_put(
-                    _np.array([req.prompt.size], _np.int32), dev))
+            with _tele.phase("gen.prefill", req.rid, req.tick) as pre:
+                padded = _np.zeros((1, bucket), _np.int32)
+                padded[0, :req.prompt.size] = req.prompt
+                fault.maybe_raise("serve.infer", step=self._steps)
+                row = self._prefill(
+                    self._params, jax.device_put(padded, dev),
+                    jax.device_put(
+                        _np.array([req.prompt.size], _np.int32), dev))
         except Exception as e:          # noqa: BLE001 — prefill does
-            span.stop()                 # not donate: only THIS request
-            events.incr("gen.failed")   # fails, the engine survives
-            self._resolve(req, exc=e)
+            events.incr("gen.failed")   # not donate: only THIS request
+            self._resolve(req, exc=e)   # fails, the engine survives
             return False
         if self._cache is None:
             self._init_cache_arrays()
         try:
-            self._cache = self._join(
-                self._cache, row,
-                jax.device_put(_np.int32(slot), dev))
+            with _tele.phase("gen.join", req.rid, req.tick) as join:
+                self._cache = self._join(
+                    self._cache, row,
+                    jax.device_put(_np.int32(slot), dev))
         except Exception as e:          # noqa: BLE001 — join DONATES
-            span.stop()                 # the cache: running slots lose
-            events.incr("gen.failed")   # their state too — fail them,
-            self._resolve(req, exc=e)   # rebuild, stay serviceable
+            events.incr("gen.failed")   # the cache: running slots lose
+            self._resolve(req, exc=e)   # their state too — fail them,
+                                        # rebuild, stay serviceable
             for i in self._live():
                 self._retire(i, exc=EngineClosed(
                     "slot state lost to a failed join (%s)"
@@ -885,10 +930,12 @@ class GenerationEngine:
             self._init_cache_arrays()
             _bb.record("gen", "join_failed", error=type(e).__name__)
             return False
-        span.stop()
-        if req.rec is not None:         # prefill phase ends here
-            req.rec.t_exec = time.monotonic()
-        dt = time.monotonic() - t0
+        req.t_exec = join.t1            # the admit phase ends here
+        _tele.phase_at("gen.req.admit", now, req.t_exec, req.rid,
+                       req.tick)
+        if req.rec is not None:
+            req.rec.t_exec = req.t_exec
+        dt = join.t1 - pre.t0
         prev = self._prefill_ewma.get(bucket)
         self._prefill_ewma[bucket] = dt if prev is None \
             else 0.3 * dt + 0.7 * prev
@@ -905,15 +952,15 @@ class GenerationEngine:
                 return b
         return self._buckets[-1]
 
-    def _step(self, live):
+    def _step(self, live, tick):
         """Advance every live slot one token; stream, then retire
         finished sequences at this boundary.  A terminal decode
         failure fails every LIVE sequence (typed, exactly once) and
         rebuilds the cache — donated buffers cannot be retried."""
         import jax
         from ..parallel.resilience import retry_transient
-        t0 = time.monotonic()
-        with _tele.span("serve.decode_step"):
+        with _tele.phase("gen.decode", self._steps, tick,
+                         len(live)) as decode:
             # injected transient faults fire HOST-side (before the
             # executable), so the retry budget is donation-safe;
             # serve.decode_slow stalls a step (deadline/straggler
@@ -930,15 +977,30 @@ class GenerationEngine:
             try:
                 nxt, self._cache = self._decode(self._params,
                                                 self._cache)
-                toks = _np.asarray(nxt)     # (S,) host sync
-            except Exception as e:          # noqa: BLE001 — terminal:
-                events.incr("gen.failed")   # the donated cache may be
-                for i in list(live):        # gone; fail live slots +
-                    self._retire(i, exc=e)  # rebuild
-                self._init_cache_arrays()
-                _bb.record("gen", "step_failed",
-                           error=type(e).__name__)
-                return
+            except Exception as e:          # noqa: BLE001
+                return self._step_failed(live, e)
+        try:
+            with _tele.phase("gen.sync", self._steps, tick) as sync:
+                toks = _np.asarray(nxt)     # (S,) the device's time
+        except Exception as e:              # noqa: BLE001
+            return self._step_failed(live, e)
+        with _tele.phase("gen.emit", self._steps, tick) as emit:
+            emit.n = self._emit(live, toks, old_probe,
+                                sync.t1 - decode.t0, emit.t0)
+
+    def _step_failed(self, live, e):
+        """Terminal: the donated cache may be gone.  Fail the live
+        slots and rebuild it."""
+        events.incr("gen.failed")
+        for i in list(live):
+            self._retire(i, exc=e)
+        self._init_cache_arrays()
+        _bb.record("gen", "step_failed", error=type(e).__name__)
+
+    def _emit(self, live, toks, old_probe, dt, now):
+        """The host's share of a step, after its tokens arrived: meter
+        it, push each live slot's token, retire what finished.
+        Returns the number of tokens pushed."""
         if old_probe is not None:
             self._donation_checked = True
             if not old_probe.is_deleted():
@@ -951,7 +1013,6 @@ class GenerationEngine:
                     "aliased — per-step HBM traffic doubles "
                     "(backend ignores donation)"
                     % (self._label + ":decode_step"))
-        dt = time.monotonic() - t0
         self._step_ewma = dt if self._step_ewma is None \
             else 0.3 * dt + 0.7 * self._step_ewma
         self._steps += 1
@@ -959,7 +1020,7 @@ class GenerationEngine:
         events.incr("gen.steps")
         events.incr("gen.tokens", len(live))
         events.observe("gen.slots_live", len(live))
-        now = time.monotonic()
+        pushed = 0
         for i in live:
             slot = self._slots[i]
             if slot is None:    # a racing close() swept this slot —
@@ -968,6 +1029,10 @@ class GenerationEngine:
             tok = int(toks[i])
             slot.emitted += 1
             if slot.t_last is None:
+                _tele.phase_at("gen.req.first", req.t_exec, now,
+                               req.rid, req.tick)
+                if req.rec is not None:
+                    req.rec.t_first = now
                 events.observe_time("gen.ttft_us", now - req.t_enq)
                 events.observe("gen.ttft_us",
                                int((now - req.t_enq) * 1e6),
@@ -980,6 +1045,7 @@ class GenerationEngine:
                                labels={"lane": req.lane})
             slot.t_last = now
             req.stream._push(tok)
+            pushed += 1
             if req.deadline is not None and now > req.deadline:
                 # mid-decode deadline: shed, free the slot THIS step
                 self._shed_mark(req.lane, req.tenant, "deadline",
@@ -989,6 +1055,7 @@ class GenerationEngine:
                     % slot.emitted))
             elif tok == self._eos or slot.emitted >= req.max_new:
                 self._retire(i)
+        return pushed
 
     def _occupancy_event(self, kind, slot, req):
         live = len(self._live())
@@ -1145,4 +1212,5 @@ class GenerationEngine:
                 "tenants_queued": tenants,
                 "continuous": self._continuous,
                 "steps": self._steps,
+                "phases": _tele.phase_totals("gen."),
                 "warm": self._warm}

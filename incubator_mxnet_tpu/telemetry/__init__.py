@@ -1,11 +1,18 @@
 """Unified telemetry backbone (ISSUE 4): spans, metrics export, and
 per-step training telemetry over the `monitor.events` ledger.
 
-Three layers, one ledger:
+Four layers, one ledger:
 
+- `telemetry.phase(name, ident, parent, n)` / `phase_at` / `phase_log` /
+  `phase_totals` — the ALWAYS-ON phase log (spans.py, PR 27): one ring
+  of the newest 65536 rows `(name, t0, t1, ident, parent, n)` on
+  `time.monotonic()`, each `phase()` mirrored to the profiler as a
+  `jax.profiler.TraceAnnotation`.  The generation engine (`gen.*`),
+  the trainers (`gluon.step`, `sharded.step`) and the compile path
+  (`compile.call`, `compile.jax.*`) write it; no switch gates it.
 - `telemetry.span(name, parent=ctx)` — thread-safe spans with explicit
   cross-thread parent propagation, emitted into the profiler's
-  chrome-trace sink (spans.py).
+  chrome-trace sink (spans.py).  A span is a phase too.
 - `telemetry.MetricsExporter` — `monitor.events` counters + latency
   percentiles rendered as Prometheus text / JSON, with periodic file
   export and an optional `/metrics` + `/healthz` HTTP thread
@@ -13,8 +20,9 @@ Three layers, one ledger:
 - `telemetry.StepTelemetry` — per-step `train.*` counters/samples,
   wired into `ResilientTrainer` / `ShardedTrainer` (stepstats.py).
 
-Switch: `MXNET_TELEMETRY=1` or `telemetry.enable()`.  Disabled, every
-hot-path hook is a single bool read.  `telemetry.start()` boots the
+Switch (spans, exporter, step telemetry; not the phase log):
+`MXNET_TELEMETRY=1` or `telemetry.enable()`.  Disabled, every hot-path
+hook of theirs is a single bool read.  `telemetry.start()` boots the
 process-wide exporter off the MXNET_TELEMETRY_* knobs;
 `python -m incubator_mxnet_tpu.tools.teletop` renders a live or
 file-snapshot table.  See docs/observability.md.
@@ -51,8 +59,9 @@ ISSUE 12 makes the telemetry DURABLE and JUDGED:
 from __future__ import annotations
 
 from .spans import (SpanContext, TraceContext, current, emit_foreign,
-                    enable, enabled, get_global_step, propagate,
-                    recording, set_global_step, span)
+                    enable, enabled, get_global_step, phase, phase_at,
+                    phase_log, phase_totals, propagate, recording,
+                    set_global_step, span)
 from .export import MetricsExporter
 from .stepstats import StepTelemetry
 from . import costs
@@ -69,6 +78,7 @@ from .slo import (AnomalyRule, BurnRateRule, ThresholdRule,
 
 __all__ = ["SpanContext", "TraceContext", "span", "current", "enable",
            "enabled", "recording", "propagate", "set_global_step",
+           "phase", "phase_at", "phase_log", "phase_totals",
            "get_global_step", "emit_foreign", "MetricsExporter",
            "StepTelemetry", "start", "stop", "get_exporter",
            "snapshot_dict", "costs", "flightrec", "fleet", "history",

@@ -39,9 +39,12 @@ jit call.
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 import weakref
+
+from . import spans as _spans
 
 __all__ = ["note_executable", "note_collective", "invoke", "table",
            "totals", "snapshot", "reset", "metered_jit", "MeteredJit",
@@ -367,6 +370,22 @@ def reset():
         _ROWS.clear()
 
 
+def traced_as(fn, label, role=None):
+    """`fn` behind a function named `_traced_<slug>`, so that the
+    executable `jax.jit` makes of it reads `jit__traced_<slug>` in HLO
+    module names and profiler traces: an executable is found by what
+    it is for.  The slug is `role` if the call site fixes one (the
+    generation engine's do not follow the user's cost label), else the
+    label with every run of other characters turned into `_`."""
+    slug = re.sub(r"[^0-9A-Za-z]+", "_", str(role or label)).strip("_")
+
+    def _traced(*a):
+        return fn(*a)
+
+    _traced.__name__ = _traced.__qualname__ = "_traced_" + slug[:40]
+    return _traced
+
+
 _DONATION_WARNED = set()
 
 
@@ -406,7 +425,7 @@ class MeteredJit:
     jit."""
 
     def __init__(self, fn, donate_argnums=(), kind="jit", label=None,
-                 expect_donated=None):
+                 expect_donated=None, role=None):
         import jax
         self._kind = kind
         self._label = label or getattr(fn, "__name__", "fn")
@@ -419,7 +438,7 @@ class MeteredJit:
         # the training thread traces concurrently
         self._tls = threading.local()
 
-        def _traced(*a):
+        def hooked(*a):
             # trace-time only: a jit cache hit never runs this
             if not getattr(self._tls, "resolving", False):
                 self._pending.append(jax.tree_util.tree_map(
@@ -427,7 +446,8 @@ class MeteredJit:
                     a))
             return fn(*a)
 
-        self._jit = jax.jit(_traced, donate_argnums=donate_argnums)
+        self._jit = jax.jit(traced_as(hooked, self._label, role),
+                            donate_argnums=donate_argnums)
 
     def _register_pending(self, wall_s):
         """Turn trace-time aval captures into pending cost rows (the
@@ -461,13 +481,16 @@ class MeteredJit:
         from . import flightrec as _bb
         if not _bb.enabled():
             return self._jit(*args)
-        t0 = time.perf_counter()
+        t0 = time.monotonic()
         out = self._jit(*args)
         if self._pending:
             # this call traced a new signature: register it, with the
             # call's wall (≈ trace + compile + one execution) as the
-            # honest compile-wall proxy
-            self._register_pending(time.perf_counter() - t0)
+            # honest compile-wall proxy, and say in the phase log
+            # which step it was that recompiled
+            t1 = time.monotonic()
+            _spans.phase_at("compile.call", t0, t1, self._label)
+            self._register_pending(t1 - t0)
         if self._keys:
             # cache-hit calls attribute to the newest row — knowing the
             # exact signature would cost a per-call pytree flatten,
@@ -482,11 +505,13 @@ class MeteredJit:
 
 
 def metered_jit(fn, donate_argnums=(), kind="jit", label=None,
-                expect_donated=None):
+                expect_donated=None, role=None):
     """`jax.jit(fn, donate_argnums=...)` with a cost-registry row per
     input signature and cumulative invocation counts.
     ``expect_donated`` arms the donation audit: argnums named there but
     absent from ``donate_argnums`` warn once with the executable
-    label."""
+    label.  The executable is named `jit__traced_<label or role>`
+    (`traced_as`)."""
     return MeteredJit(fn, donate_argnums=donate_argnums, kind=kind,
-                      label=label, expect_donated=expect_donated)
+                      label=label, expect_donated=expect_donated,
+                      role=role)

@@ -37,6 +37,7 @@ import weakref
 
 from .. import config as _cfg
 from ..monitor import events
+from . import spans as _spans
 
 __all__ = ["MetricsExporter"]
 
@@ -329,6 +330,12 @@ class MetricsExporter:
             out["labeled"] = {"counters": lcounts,
                               "percentiles": llats}
         if self._c is events:
+            # the phase log's totals: {name: [count, seconds, n]} over
+            # the rows the ring still holds (spans.py)
+            phases = _spans.phase_totals()
+            if phases:
+                out["phases"] = {k: [c, round(s, 6), n]
+                                 for k, (c, s, n) in phases.items()}
             try:
                 from . import costs as _costs
                 block = _costs.snapshot()

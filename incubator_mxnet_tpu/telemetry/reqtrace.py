@@ -12,7 +12,9 @@ autopsy, not the gauge.  This module keeps it:
   hot path beyond the stamps the engine takes anyway.  The serve
   ladder is queue-wait → coalesce → dispatch → device-infer →
   join/D2H → future-resolution; generation maps queue → prefill →
-  decode → resolution onto the same slots.  Sheds and deadline kills
+  first token → decode → resolution onto the same slots (the first
+  three are the phase log's `gen.req.queue` / `.admit` / `.first`
+  rows, from the same stamps).  Sheds and deadline kills
   record their termination reason and which phase ate the budget (the
   first phase whose end stamp never landed).
 - **A bounded per-engine ring** (`MXNET_REQTRACE_RING`) of retired
@@ -67,7 +69,8 @@ PHASES = {
               ("dispatch", "t_infer0"), ("infer", "t_infer1"),
               ("join", "t_fin"), ("resolve", "t_done")),
     "gen": (("queue", "t_collect"), ("prefill", "t_exec"),
-            ("decode", "t_fin"), ("resolve", "t_done")),
+            ("first", "t_first"), ("decode", "t_fin"),
+            ("resolve", "t_done")),
 }
 
 #: rolling-p99 promotion needs this many completed requests in the
@@ -105,6 +108,12 @@ def enable(flag=True):
 _rids = itertools.count(1)      # CPython-atomic next(); no lock
 
 
+def next_rid():
+    """A fresh request id from the journal's own counter: what an engine
+    names a request by in the phase log while the journal is off."""
+    return next(_rids)
+
+
 class Record:
     """One request's lifecycle struct — pre-sized slots, filled by
     plain attribute writes from stamps the engine already takes.
@@ -112,12 +121,12 @@ class Record:
     or render time, never on the submit path."""
 
     __slots__ = ("rid", "lane", "tenant", "bucket", "n",
-                 "t_enq", "t_collect", "t_exec", "t_infer0",
+                 "t_enq", "t_collect", "t_exec", "t_first", "t_infer0",
                  "t_infer1", "t_fin", "t_done",
                  "status", "reason", "e2e_us")
 
     def __init__(self, t_enq, lane, tenant):
-        self.rid = next(_rids)
+        self.rid = next_rid()
         self.lane = lane
         self.tenant = tenant
         self.bucket = None
@@ -125,6 +134,7 @@ class Record:
         self.t_enq = t_enq
         self.t_collect = None
         self.t_exec = None
+        self.t_first = None
         self.t_infer0 = None
         self.t_infer1 = None
         self.t_fin = None

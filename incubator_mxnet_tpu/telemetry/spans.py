@@ -1,11 +1,35 @@
-"""Cross-thread spans on the profiler's chrome-trace timeline (ISSUE 4
-tentpole part 1).
+"""Spans: one always-on phase log, and the id-minting spans on top of it.
 
-The op-dispatch profiler (profiler.py) sees imperative dispatches; the
-async layers — DeviceFeed's transfer worker, the serving dispatcher and
-its replica workers, checkpoint writes — are invisible to it because
-their work happens on framework threads, between dispatches.  A span
-names one such interval:
+**The phase log (PR 27).**  `phase(name, ident, parent, n)` is a context
+manager that is always on.  Entering it enters a
+`jax.profiler.TraceAnnotation(name)` (resolved on first use, skipped
+while `jax` is not imported; free while no profiler session collects)
+and reads `time.monotonic()`; leaving it appends one tuple
+
+    (name, t0, t1, ident, parent, n)
+
+to a process-wide ring of the newest 65536 rows.  No lock, no id minted,
+no string built, no dict: whatever the caller passed is stored as it is.
+`phase_at(...)` appends an interval whose stamps the caller already
+holds (a request's phases cross threads); it is not mirrored to the
+profiler.  `phase_log(since, until, prefix)` reads rows by start time,
+`phase_totals(prefix)` gives `{name: (count, seconds, n)}`.
+
+One clock: `time.monotonic()`, which is also the clock the benchmark
+opens and closes its window on, so a reader filters rows to a window
+with no conversion.  The `TraceAnnotation` puts the same interval on
+the profiler's clock, in the `/host:CPU` plane of a trace, beside the
+device's ops: a reduction that names an idle gap of the device by the
+host event over it names it by phase.  The names, who writes them and
+which metric reads them are listed in docs/observability.md.
+
+**Spans** (ISSUE 4; `MXNET_TELEMETRY=1` / `telemetry.enable()`) are
+the heavier kind: they mint a trace id and a span id, propagate
+parents across threads and processes, and land in the chrome-trace
+sink profiler.py dumps and in the flight-recorder ring.  A span is
+also a phase: it enters the same `TraceAnnotation` and leaves the same
+row (ident = span id, parent = parent span id), so every span site is
+on the profiler's clock too.
 
     with telemetry.span("serve.dispatch"):
         ...
@@ -27,8 +51,8 @@ with the op events; trace/span/parent ids ride in each event's `args`.
 Cost model (revised in ISSUE 5): span OBJECTS exist whenever telemetry
 is enabled (`telemetry.enable()` / `MXNET_TELEMETRY=1`); with
 telemetry off, `span()` returns a shared no-op — one bool read, no
-allocation.  A completed span lands in TWO sinks with independent
-gates:
+allocation.  A completed span lands in the phase log and in TWO sinks
+with independent gates:
 
 - the profiler's chrome-trace sink, ONLY while the profiler is
   collecting (`set_state("run")`, not paused — the sink is unbounded,
@@ -64,8 +88,10 @@ tags land in the chrome event args and the ring record.
 """
 from __future__ import annotations
 
+import collections
 import itertools
 import os
+import sys
 import threading
 import time
 
@@ -75,7 +101,98 @@ from . import flightrec as _bb
 
 __all__ = ["SpanContext", "TraceContext", "enabled", "enable", "span",
            "current", "recording", "propagate", "set_global_step",
-           "get_global_step", "emit_foreign", "wall_of"]
+           "get_global_step", "emit_foreign", "wall_of", "phase",
+           "phase_at", "phase_log", "phase_totals"]
+
+# -- the phase log ------------------------------------------------------
+# The newest rows (name, t0, t1, ident, parent, n), stamps from
+# time.monotonic().  deque.append is atomic and drops the oldest row
+# itself: writers take no lock.  The length is a constant, not a knob.
+_LOG = collections.deque(maxlen=65536)
+_now = time.monotonic
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, once jax is there
+
+
+def _annotation():
+    """`jax.profiler.TraceAnnotation`, or None while `jax` is not
+    imported: the log must not be what imports it."""
+    global _ANNOTATION
+    jax = sys.modules.get("jax")
+    if jax is not None and hasattr(jax, "profiler"):
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    return _ANNOTATION
+
+
+class phase:
+    """An always-on interval: `with phase("gen.tick", 7, n=3) as ph:`
+    (or `.start()` / `.stop()`).  `ident` names the thing (a tick, a
+    request, a step), `parent` is the ident of the phase that caused
+    it, `n` counts what it handled and may be set until the exit.
+    `t0`/`t1` (and `seconds` after the exit) are the stamps the row
+    carries, for a caller that needs the duration too."""
+
+    __slots__ = ("name", "ident", "parent", "n", "t0", "t1", "_ann")
+
+    def __init__(self, name, ident=None, parent=None, n=0):
+        self.name, self.ident, self.parent, self.n = name, ident, parent, n
+        self.t0 = self.t1 = self._ann = None
+
+    def start(self):
+        cls = _ANNOTATION or _annotation()
+        if cls is not None:
+            self._ann = cls(self.name)
+            self._ann.__enter__()
+        self.t0 = _now()
+        return self
+
+    def stop(self):
+        if self.t0 is None or self.t1 is not None:
+            return
+        self.t1 = _now()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _LOG.append((self.name, self.t0, self.t1, self.ident, self.parent,
+                     self.n))
+
+    __enter__ = start
+
+    def __exit__(self, *exc):
+        self.stop()
+        return False
+
+    @property
+    def seconds(self):
+        return self.t1 - self.t0
+
+
+def phase_at(name, t0, t1, ident=None, parent=None, n=0):
+    """A row for an interval whose `time.monotonic()` stamps the caller
+    already holds.  Not mirrored to the profiler."""
+    _LOG.append((name, t0, t1, ident, parent, n))
+
+
+def phase_log(since=None, until=None, prefix=None):
+    """Rows (name, t0, t1, ident, parent, n) that START in
+    [since, until] and whose name starts with `prefix`, oldest first."""
+    while True:
+        try:
+            rows = list(_LOG)
+            break
+        except RuntimeError:        # a writer appended mid-copy
+            continue
+    return [r for r in rows
+            if (since is None or r[1] >= since)
+            and (until is None or r[1] <= until)
+            and (prefix is None or r[0].startswith(prefix))]
+
+
+def phase_totals(prefix=None):
+    """{name: (count, seconds, n)} over the rows the ring still holds."""
+    out = {}
+    for name, t0, t1, _, _, n in phase_log(prefix=prefix):
+        c, s, k = out.get(name, (0, 0.0, 0))
+        out[name] = (c + 1, s + (t1 - t0), k + n)
+    return out
 
 
 def wall_of(t_mono):
@@ -278,7 +395,7 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "ctx", "parent_id", "tags", "_t0")
+    __slots__ = ("name", "ctx", "parent_id", "tags", "_t0", "_phase")
 
     def __init__(self, name, parent, tags=None):
         if parent is None:
@@ -293,6 +410,8 @@ class _Span:
         self.name = name
         self.tags = tags
         self._t0 = None
+        # the same interval in the phase log and on the profiler's clock
+        self._phase = phase(name, self.ctx.span_id, self.parent_id)
 
     def __enter__(self):
         return self.start()
@@ -302,6 +421,7 @@ class _Span:
         return False
 
     def start(self):
+        self._phase.start()
         self._t0 = time.perf_counter()
         _stack().append(self.ctx)
         return self
@@ -316,6 +436,7 @@ class _Span:
         elif self.ctx in st:        # mispaired stop(): drop ours only
             st.remove(self.ctx)
         dur = time.perf_counter() - t0
+        self._phase.stop()
         args = {"trace_id": self.ctx.trace_id,
                 "span_id": self.ctx.span_id}
         if self.parent_id is not None:
