@@ -65,8 +65,8 @@ class Seq2Seq(HybridBlock):
 
     # -- explicit-cache decode (serving.generation contract) -----------
     # Every cache leaf is SLOT-MAJOR (axis 0 = request/slot), so the
-    # GenerationEngine's join/retire are cheap masked updates along one
-    # axis.  Exactness under right-padding: RNN_varlen freezes the
+    # GenerationEngine's join is an indexed in-place write of the
+    # slot's row along that axis.  Exactness under right-padding: RNN_varlen freezes the
     # encoder recurrence at src_valid_len (the decoder init state is
     # the state AT the prompt's real end, not after the pad tail), the
     # zeroed pad outputs are additionally masked out of the attention

@@ -26,14 +26,17 @@ metered on `serve.traces` exactly like the one-shot engine):
    (slot-count bucket, max_len bucket): a fixed (S, …) batch advances
    every slot one token.  Its KV/state buffers are DONATED between
    steps (`donate_argnums` + the PR 10 `expect_donated` audit at
-   build, plus a runtime no-copy probe on the first steps — a backend
+   build, plus a runtime no-copy probe on the first step — a backend
    that silently copies warns with the executable label and counts
    ``gen.donation_copy``).  Per-sequence state (cur position, last
    token, emitted tokens) lives in device arrays indexed by slot
    INSIDE the donated cache.
-3. ``join`` — admit one prefilled request into a free slot: a one-hot
-   masked update on every cache leaf (cache donated).  Joins and
-   retires never reshape anything.
+3. ``join`` — admit one prefilled request into a free slot: an
+   indexed in-place write of the slot's row into every leaf of the
+   donated cache (`lax.dynamic_update_slice` at the slot, axis 0), so
+   an admission moves one slot's bytes whatever the slot count.  The
+   warm-up's join runs the same no-copy probe as the first decode
+   step.  Joins and retires never reshape anything.
 
 **Continuous batching.**  The decode loop advances the fixed-slot
 batch step by step.  A sequence that finishes (EOS / token budget /
@@ -459,7 +462,7 @@ class GenerationEngine:
         from ..aot_cache import aot_jit
         from ..parallel.functional import extract_params
         block = self._block
-        S, L = self._S, self._L
+        L = self._L
         eos = self._eos
         pure_init = _pure_method(block, "init_cache")
         pure_step = _pure_method(block, "decode_step")
@@ -496,21 +499,20 @@ class GenerationEngine:
 
         def join(cache, row, slot):
             events.incr("serve.traces")
-            keep = jnp.arange(S, dtype=jnp.int32) == slot
 
-            def upd(c, r):
-                m = keep.reshape((S,) + (1,) * (c.ndim - 1))
-                return jnp.where(m, r.astype(c.dtype), c)
+            def put(c, r):
+                # every leaf is slot-major: the slot's row is one
+                # contiguous block, written in place into the donated
+                # leaf — the other S-1 slots are never read
+                return jax.lax.dynamic_update_slice(
+                    c, r.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1))
 
-            m = jax.tree_util.tree_map(upd, cache["m"], row)
-            bos = jnp.full((S,), self._bos, jnp.int32)
-            zero = jnp.zeros((S,), jnp.int32)
-            return {"m": m,
-                    "tok": jnp.where(keep, bos, cache["tok"]),
-                    "pos": jnp.where(keep, zero, cache["pos"]),
-                    "out": jnp.where(keep[:, None],
-                                     jnp.full((S, L), eos, jnp.int32),
-                                     cache["out"])}
+            return {"m": jax.tree_util.tree_map(put, cache["m"], row),
+                    "tok": put(cache["tok"],
+                               jnp.full((1,), self._bos, jnp.int32)),
+                    "pos": put(cache["pos"], jnp.zeros((1,), jnp.int32)),
+                    "out": put(cache["out"],
+                               jnp.full((1, L), eos, jnp.int32))}
 
         # prefill: one signature per prompt bucket, AOT-warmed; decode
         # and join donate the cache — the PR 10 audit arms the
@@ -606,8 +608,10 @@ class GenerationEngine:
                 jax.block_until_ready(
                     jax.tree_util.tree_leaves(row)[0])
                 per_bucket[b] = round(time.monotonic() - tb, 4)
+            old_probe = jax.tree_util.tree_leaves(self._cache["m"])[0]
             self._cache = self._join(self._cache, row,
                                      jax.device_put(_np.int32(0), dev))
+            self._donation_probe(old_probe, "join")
             nxt, self._cache = self._decode(self._params, self._cache)
             _np.asarray(nxt)            # sync
         except Exception as e:
@@ -979,14 +983,17 @@ class GenerationEngine:
                                                 self._cache)
             except Exception as e:          # noqa: BLE001
                 return self._step_failed(live, e)
+            if old_probe is not None:
+                self._donation_checked = True
+                self._donation_probe(old_probe, "decode_step")
         try:
             with _tele.phase("gen.sync", self._steps, tick) as sync:
                 toks = _np.asarray(nxt)     # (S,) the device's time
         except Exception as e:              # noqa: BLE001
             return self._step_failed(live, e)
         with _tele.phase("gen.emit", self._steps, tick) as emit:
-            emit.n = self._emit(live, toks, old_probe,
-                                sync.t1 - decode.t0, emit.t0)
+            emit.n = self._emit(live, toks, sync.t1 - decode.t0,
+                                emit.t0)
 
     def _step_failed(self, live, e):
         """Terminal: the donated cache may be gone.  Fail the live
@@ -997,22 +1004,24 @@ class GenerationEngine:
         self._init_cache_arrays()
         _bb.record("gen", "step_failed", error=type(e).__name__)
 
-    def _emit(self, live, toks, old_probe, dt, now):
+    def _donation_probe(self, old_leaf, what):
+        """`old_leaf` is a cache leaf held from before a call of the
+        donating executable `what`: still alive after it, the
+        build-time audit passed (the argnums ARE donated) but the
+        backend copied anyway — count it and say so by label."""
+        if old_leaf.is_deleted():
+            return
+        events.incr("gen.donation_copy")
+        import warnings
+        warnings.warn(
+            "executable %r: donated KV cache was COPIED, not aliased — "
+            "its HBM traffic doubles (backend ignores donation)"
+            % (self._label + ":" + what))
+
+    def _emit(self, live, toks, dt, now):
         """The host's share of a step, after its tokens arrived: meter
         it, push each live slot's token, retire what finished.
         Returns the number of tokens pushed."""
-        if old_probe is not None:
-            self._donation_checked = True
-            if not old_probe.is_deleted():
-                # the build-time audit passed (argnums ARE donated)
-                # but the backend copied anyway — say so by label
-                events.incr("gen.donation_copy")
-                import warnings
-                warnings.warn(
-                    "executable %r: donated KV cache was COPIED, not "
-                    "aliased — per-step HBM traffic doubles "
-                    "(backend ignores donation)"
-                    % (self._label + ":decode_step"))
         self._step_ewma = dt if self._step_ewma is None \
             else 0.3 * dt + 0.7 * self._step_ewma
         self._steps += 1

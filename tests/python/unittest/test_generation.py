@@ -214,6 +214,136 @@ def test_kv_donation_no_copy():
         eng.close()
 
 
+# -- join: indexed in-place write of one slot ---------------------------
+
+def _join_operands(eng, seed, cast_leaf=None):
+    """A random non-zero cache of the engine's decode signature, a
+    random one-slot row of its prefill signature and their NumPy
+    copies.  `cast_leaf` names a model leaf whose CACHE side is made
+    float16 while the row stays float32 (the join casts the row)."""
+    import jax
+    rs = onp.random.RandomState(seed)
+
+    def rand(a):
+        if onp.issubdtype(a.dtype, onp.integer):
+            return rs.randint(1, 99, a.shape).astype(a.dtype)
+        return rs.standard_normal(a.shape).astype(a.dtype)
+
+    eng._init_cache_arrays()
+    cache = jax.tree_util.tree_map(lambda a: rand(onp.asarray(a)),
+                                   eng._cache)
+    eng._cache = None
+    row = {k: rand(v[:1]) for k, v in cache["m"].items()}
+    if cast_leaf is not None:
+        assert cache["m"][cast_leaf].dtype == onp.float32
+        cache["m"][cast_leaf] = cache["m"][cast_leaf].astype(onp.float16)
+    dev = eng._ctx.jax_device
+    return cache, row, jax.device_put(cache, dev), jax.device_put(row, dev)
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+@pytest.mark.parametrize("family,cast_leaf", [("seq2seq", "h"),
+                                              ("transformer", "k0")])
+def test_join_writes_one_slot_bit_exact(family, cast_leaf, where):
+    """The join against a plain NumPy reference, bit for bit on every
+    leaf: the slot's row holds the prefilled row (cast to the cache
+    leaf's dtype), bos / position 0 / a row of eos, and every other
+    slot keeps its bytes."""
+    import jax
+    net = _seq2seq(seed=11) if family == "seq2seq" else _transformer(11)
+    S = 5
+    eng = _engine(net, slots=S)
+    slot = {"first": 0, "middle": 2, "last": S - 1}[where]
+    try:
+        ref, row, cache_d, row_d = _join_operands(eng, 17 + slot,
+                                                  cast_leaf)
+        got = eng._join(cache_d, row_d, jax.device_put(
+            onp.int32(slot), eng._ctx.jax_device))
+        for k, r in row.items():
+            ref["m"][k][slot] = r[0].astype(ref["m"][k].dtype)
+        ref["tok"][slot] = BOS
+        ref["pos"][slot] = 0
+        ref["out"][slot] = EOS
+        tree = jax.tree_util
+        assert tree.tree_structure(got) == tree.tree_structure(ref)
+        for (path, want), have in zip(tree.tree_leaves_with_path(ref),
+                                      tree.tree_leaves(got)):
+            have = onp.asarray(have)
+            assert have.dtype == want.dtype and have.shape == want.shape
+            assert have.tobytes() == want.tobytes(), (path, slot)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("family", ["seq2seq", "transformer"])
+def test_join_lowers_to_indexed_update(family):
+    """The property that makes an admission cost one slot's bytes,
+    guarded where no chip is: the lowered join holds one
+    dynamic_update_slice per cache leaf, and no select (the old
+    masked update) over a leaf of the cache's (S, ...) shape."""
+    import re
+    import jax
+    net = _seq2seq(seed=12) if family == "seq2seq" else _transformer(12)
+    S = 5                           # no other dimension of the cache is 5
+    eng = _engine(net, slots=S)
+    try:
+        _, _, cache_d, row_d = _join_operands(eng, 3)
+        text = eng._join.lower(
+            cache_d, row_d, jax.device_put(
+                onp.int32(1), eng._ctx.jax_device)).as_text()
+        n_leaves = len(jax.tree_util.tree_leaves(cache_d))
+        assert n_leaves == len(cache_d["m"]) + 3
+        assert text.count("stablehlo.dynamic_update_slice") == n_leaves
+        # a select line ends ": <predicate type>, <result type>"
+        whole = [ln for ln in text.splitlines()
+                 if "stablehlo.select" in ln
+                 and re.search(r"tensor<%dx" % S, ln.rsplit(":", 1)[-1])]
+        assert not whole, whole
+    finally:
+        eng.close()
+
+
+def test_warmup_join_donates_the_cache():
+    """The warm-up's join takes the donated cache: the leaf held from
+    before it is deleted after, and the probe's counter did not move."""
+    import jax
+    net = _transformer(seed=13)
+    eng = _engine(net, slots=2)
+    try:
+        eng._init_cache_arrays()
+        old_leaf = jax.tree_util.tree_leaves(eng._cache["m"])[0]
+        before = events.get("gen.donation_copy") or 0
+        eng.warmup()
+        assert old_leaf.is_deleted(), \
+            "join copied the KV cache instead of donating it"
+        assert (events.get("gen.donation_copy") or 0) == before
+    finally:
+        eng.close()
+
+
+def test_warmup_join_copy_is_counted_and_named():
+    """A join that leaves the old cache alive (a backend that ignores
+    the donation) counts gen.donation_copy and warns with the join's
+    label."""
+    import jax
+    net = _seq2seq(seed=14)
+    eng = _engine(net, slots=2, cost_label="serve.gen:probe")
+    try:
+        donating = eng._join
+
+        def copying(cache, row, slot):
+            return donating(jax.tree_util.tree_map(lambda a: a + 0,
+                                                   cache), row, slot)
+
+        eng._join = copying
+        before = events.get("gen.donation_copy") or 0
+        with pytest.warns(UserWarning, match="serve.gen:probe:join"):
+            eng.warmup()
+        assert (events.get("gen.donation_copy") or 0) == before + 1
+    finally:
+        eng.close()
+
+
 def test_prefill_bucket_warmup_counts():
     """warmup() compiles exactly the closed executable set: one
     prefill per prompt bucket + join + decode."""
