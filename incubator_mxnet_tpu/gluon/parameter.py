@@ -261,6 +261,27 @@ class Parameter:
                 data._data if isinstance(data, NDArray)
                 else _np.asarray(data), ctx.jax_device).astype(arr._data.dtype)
 
+    def adopt(self, data):
+        """Take `data` (an NDArray already on the device it is to live
+        on) as this parameter's storage, as it is: no initializer runs,
+        nothing is filled on the host first and nothing is copied.  For
+        weights that were made on the device (a loader, a benchmark);
+        the parameter takes the array's shape and type."""
+        self.shape = data.shape
+        self.dtype = data.dtype
+        self._data = OrderedDict([(data.context, data)])
+        self._deferred_init = ()
+        if self._grad_req != "null":
+            self._init_grad()
+
+    def release(self):
+        """Drop this parameter's storage: its arrays are freed as soon as
+        nothing else holds them, without waiting for the block that owns
+        the parameter to be collected.  The parameter is uninitialized
+        afterwards."""
+        self._data = None
+        self._grad = None
+
     def reset_ctx(self, ctx):
         if isinstance(ctx, Context):
             ctx = [ctx]

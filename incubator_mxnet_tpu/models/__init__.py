@@ -9,6 +9,8 @@ from .ssd import (SSD, ssd_300, ssd_512, ssd_512_vgg16, ssd_toy,
                   VGG16ReducedFeatures, ssd_training_targets,
                   SSDTrainLoss)
 from .seq2seq import Seq2Seq, GNMT, gnmt_large, gnmt_sym_gen
+from .sparse_decoder import (SparseDecoder, SelectAttention, HeldExperts,
+                             RMSNorm)
 from .faster_rcnn import (FasterRCNN, faster_rcnn_toy,
                           faster_rcnn_resnet50_v1b,
                           rcnn_training_targets, RCNNTrainLoss)
@@ -23,4 +25,5 @@ __all__ = ["transformer", "BERTModel", "TransformerEncoder", "bert_base",
            "FasterRCNN", "faster_rcnn_toy", "faster_rcnn_resnet50_v1b",
            "rcnn_training_targets",
            "RCNNTrainLoss",
-           "gnmt_sym_gen"]
+           "gnmt_sym_gen", "SparseDecoder", "SelectAttention",
+           "HeldExperts", "RMSNorm"]

@@ -34,7 +34,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..base import MXNetError
 from .registry import register
 
-__all__ = ["flash_attention", "naive_attention"]
+__all__ = ["flash_attention", "naive_attention", "index_scores",
+           "select_mask", "masked_decode_attention",
+           "blocked_select_attention"]
 
 _NEG_INF = -1e30
 
@@ -522,3 +524,165 @@ def _contrib_flash_attention(query, key, value, num_heads=1, scale=None,
     out = flash_attention(split(query), split(key), split(value),
                           scale=scale, causal=causal)
     return out.transpose(0, 2, 1, 3).reshape(B, T, C)
+
+
+# ---------------------------------------------------------------------------
+# select-then-attend: attention that reads a learned top-k of its cache
+# ---------------------------------------------------------------------------
+# An indexer scores every cached position for each query,
+#   I[t, s] = sum_j w[t, j] * relu(qi[t, j] . ki[s]),
+# the k best positions are kept (exactly: ties go to the lower position,
+# no approx_max_k), and softmax attention runs over those alone.
+#
+# The selection is kept as a MASK over the positions (`select_mask`: the
+# k-th largest score of a row by bisection on the float's bits, counting
+# passes and no sort), and attention runs over the cache under it:
+#
+# - decode, one query a slot: `masked_decode_attention` over the slot's
+#   rows, head-major.  Measured on a v5e (PERF.md, PR 29): gathering the k
+#   selected rows instead moves one row a descriptor, about 24 ns a row of
+#   2 KB, a tenth of the memory's rate, so at k = 2048 it loses to
+#   streaming the whole cache until contexts pass some 40 k positions, and
+#   no such form is kept here.
+# - prefill, a query for every position: `blocked_select_attention`, blocks
+#   of queries each against the keys at or before its last position.  No
+#   (T, T) matrix of a head is ever whole.
+
+def index_scores(qi, ki, w):
+    """I (Tq, Tk) float32 from indexer queries qi (Tq, J, d), keys ki
+    (Tk, d) and head weights w (Tq, J)."""
+    s = jnp.einsum("qjd,kd->qjk", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("qjk,qj->qk", jax.nn.relu(s), w.astype(jnp.float32))
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 with the same order (-inf lowest; -0.0 counts as
+    0.0, as it does in a comparison)."""
+    x = x.astype(jnp.float32)
+    u = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def _kth_largest_bits(bits, k):
+    """The k-th largest of each row of `bits` (uint32; 0 where the row has
+    fewer than k non-zero entries), found from the top bit down.  A small
+    array (a decode step's scores) takes four bits a pass, eight unrolled
+    passes of fifteen counts: its cost is the number of passes.  A large one
+    (a block of a prefill) takes one bit a pass in a loop: its cost is the
+    comparisons, and more bits a pass multiply them."""
+    acc = jnp.zeros(bits.shape[:-1], jnp.uint32)
+    if bits.size <= 1 << 20:
+        steps = jnp.arange(1, 16, dtype=jnp.uint32)
+        for shift in range(28, -1, -4):
+            cand = acc[..., None] | (steps << shift)            # (..., 15)
+            enough = jnp.sum(bits[..., None, :] >= cand[..., None], -1,
+                             dtype=jnp.int32) >= k
+            acc = acc | (jnp.sum(enough, -1).astype(jnp.uint32) << shift)
+        return acc
+
+    def narrow(i, acc):
+        cand = acc | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(bits >= cand[..., None], -1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, acc)
+
+    return jax.lax.fori_loop(0, 32, narrow, acc)
+
+
+def select_mask(scores, valid, k):
+    """Mask (..., N) of the min(k, valid count) valid entries of each row
+    with the largest score, ties to the lower index."""
+    bits = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
+    kth = _kth_largest_bits(bits, k)[..., None]
+    above = bits > kth
+    room = k - jnp.sum(above, -1, dtype=jnp.int32, keepdims=True)
+    equal = bits == kth
+
+    def first_of_equal(_):
+        return above | (equal & (jnp.cumsum(equal, -1, dtype=jnp.int32)
+                                 <= room))
+
+    # more entries equal the k-th than there is room for: rare, and the
+    # running count that settles it costs a pass of its own
+    tied = jnp.any(jnp.sum(equal, -1, dtype=jnp.int32, keepdims=True) > room)
+    return valid & jax.lax.cond(tied, first_of_equal,
+                                lambda _: above | equal, None)
+
+
+def masked_decode_attention(q, k_rows, v_rows, mask, scale):
+    """One query a slot over the slot's cached rows under `mask`.
+    q (S, H, d); k_rows, v_rows (S, G, L, d), head-major, H a multiple of
+    the G key/value heads (query head i reads head i // (H/G)); mask
+    (S, L).  Returns (S, H, d) float32."""
+    S, H, d = q.shape
+    G = k_rows.shape[1]
+    qg = q.reshape(S, G, H // G, d)
+    s = jnp.einsum("sghd,sgld->sghl", qg, k_rows,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, _NEG_INF), -1)
+    o = jnp.einsum("sghl,sgld->sghd", p.astype(v_rows.dtype), v_rows,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(S, H, d)
+
+
+def _masked_block(qg, k, v, mask, scale, chunk):
+    """qg (G, h, bq, d) against k, v (G, Tk, d) under mask (bq, Tk), the
+    keys in chunks of `chunk` with a running maximum and sum (the flash
+    recurrence), so that the float32 scores alive are (G, h, bq, chunk).
+    Measured on a v5e (PERF.md, PR 29): a softmax over whole rows of 8192
+    keys takes XLA fifty times as long as these chunks of 512."""
+    G, h, bq, d = qg.shape
+    n = k.shape[1] // chunk
+    split = lambda t: t.reshape(G, n, chunk, d).transpose(1, 0, 2, 3)
+    masks = mask.reshape(bq, n, chunk).transpose(1, 0, 2)
+
+    def one(carry, xs):
+        top, total, acc = carry
+        kc, vc, mc = xs
+        s = jnp.einsum("ghqd,gkd->ghqk", qg, kc,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mc[None, None], s, _NEG_INF)
+        new_top = jnp.maximum(top, jnp.max(s, -1))
+        # a row with no selected key in this chunk adds nothing
+        p = jnp.where(mc[None, None], jnp.exp(s - new_top[..., None]), 0.0)
+        shrink = jnp.exp(top - new_top)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            "ghqk,gkd->ghqd", p.astype(vc.dtype), vc,
+            preferred_element_type=jnp.float32)
+        return (new_top, total * shrink + jnp.sum(p, -1), acc), None
+
+    init = (jnp.full((G, h, bq), _NEG_INF, jnp.float32),
+            jnp.zeros((G, h, bq), jnp.float32),
+            jnp.zeros((G, h, bq, d), jnp.float32))
+    (_, total, acc), _ = jax.lax.scan(one, init, (split(k), split(v), masks))
+    return acc / total[..., None]                       # (G, h, bq, d)
+
+
+def blocked_select_attention(q, k, v, qi, ki, w, top_k, scale, block=1024,
+                             chunk=512):
+    """Causal select-then-attend for a whole prompt.  q (T, H, d); k, v
+    (T, G, d); indexer qi (T, J, di), ki (T, di), w (T, J).  Query block
+    b sees keys [0, end of b), in chunks of `chunk`: the blocks are
+    unrolled with static shapes, so what lies after a block is neither
+    scored nor read.  Returns (T, H, d) float32."""
+    T, H, d = q.shape
+    G = k.shape[1]
+    bq = min(int(block), T)
+    chunk = min(int(chunk), bq)
+    if T % bq or bq % chunk:
+        raise ValueError("%d positions are no whole number of query "
+                         "blocks of %d in key chunks of %d" % (T, bq, chunk))
+    qg = q.reshape(T, G, H // G, d).transpose(1, 2, 0, 3)   # (G, h, T, d)
+    kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)     # (G, T, d)
+    out = []
+    for q0 in range(0, T, bq):
+        end = q0 + bq
+        pos_q = q0 + jnp.arange(bq)
+        mask = jnp.arange(end)[None, :] <= pos_q[:, None]   # causal
+        if end > top_k:         # else every causal key is selected
+            mask = select_mask(
+                index_scores(qi[q0:end], ki[:end], w[q0:end]), mask, top_k)
+        o = _masked_block(qg[:, :, q0:end], kg[:, :end], vg[:, :end], mask,
+                          scale, chunk)                     # (G, h, bq, d)
+        out.append(o.transpose(2, 0, 1, 3).reshape(bq, H, d))
+    return jnp.concatenate(out, 0)

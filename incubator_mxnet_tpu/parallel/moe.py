@@ -10,9 +10,18 @@ factored top-1 einsum dispatch — fixed shapes, no sorting, fully
 XLA-compilable; overflowing tokens are dropped (residual passes them
 through, the standard Switch behaviour).
 
-All functions are shard_map-body functions (like ring_attention):
-call them inside `shard_map` with `axis_name` bound to the expert
-axis.  Gradients flow through `all_to_all`/einsum natively.
+`switch_route`/`moe_apply` are shard_map-body functions (like
+ring_attention): call them inside `shard_map` with `axis_name` bound to
+the expert axis.  Gradients flow through `all_to_all`/einsum natively.
+
+**Dropless top-k over held experts** (`topk_route`, `held_experts`):
+the serving form.  The router scores ALL experts, each token keeps its
+top k with gates renormalised over those k, and a device computes the
+terms of the experts it HOLDS (`first_held`, and as many as its weight
+stacks carry) for the tokens routed to them.  Nothing is dropped and
+nothing stands in for the experts held elsewhere: their devices add
+their terms.  On one device the layer runs as it is, without an
+exchange.
 """
 from __future__ import annotations
 
@@ -20,7 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["switch_route", "moe_apply", "moe_ffn"]
+__all__ = ["switch_route", "moe_apply", "moe_ffn", "topk_route",
+           "held_experts", "held_load"]
 
 
 def switch_route(router_logits, capacity):
@@ -126,3 +136,95 @@ def moe_ffn(d_model, d_hidden, n_experts, key=None):
         return h @ p["w2"]
 
     return params, expert_fn
+
+
+# -- dropless top-k over the experts a device holds ----------------------
+
+def topk_route(router_logits, k):
+    """Softmax over all experts, the k largest, renormalised over those
+    k.  router_logits (T, E) -> (gate (T, k) float32, expert (T, k)
+    int32), best first, ties to the lower expert."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    top, expert = lax.top_k(probs, k)
+    return top / jnp.sum(top, axis=-1, keepdims=True), expert
+
+
+def held_load(expert, first_held, n_held):
+    """What routing asks of the held experts: (picks (T,) the number of a
+    token's k experts that are held, at_fullest (T,) how many of them are
+    the held expert with the most tokens).  Their sums over tokens are
+    the held picks and the fullest held expert's tokens."""
+    local = expert - first_held
+    held = (local >= 0) & (local < n_held)
+    load = jnp.sum(jax.nn.one_hot(jnp.where(held, local, n_held), n_held,
+                                  dtype=jnp.int32), axis=(0, 1))
+    fullest = jnp.argmax(load)
+    return (jnp.sum(held, axis=-1, dtype=jnp.int32),
+            jnp.sum(held & (local == fullest), axis=-1, dtype=jnp.int32))
+
+
+def _swiglu(x, wg, wu, wd):
+    """One expert: wd (silu(wg x) * wu x); weights are (out, in)."""
+    f32 = jnp.float32
+    a = jax.nn.silu(jnp.einsum("td,fd->tf", x, wg,
+                               preferred_element_type=f32)) \
+        * jnp.einsum("td,fd->tf", x, wu, preferred_element_type=f32)
+    return jnp.einsum("tf,df->td", a.astype(x.dtype), wd,
+                      preferred_element_type=f32)
+
+
+def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256):
+    """sum over a token's picks e that this device holds of
+    gate_e * expert_e(x): the device's share of a dropless top-k layer.
+
+    x (T, D); gate, expert (T, k) from `topk_route`; wg, wu
+    (n_held, F, D) and wd (n_held, D, F) the held experts' weights, expert
+    `first_held + i` at index i.  Returns (T, D) float32.
+
+    Few tokens (T <= tile, a decode step): every held expert multiplies
+    every token under its gate, zero where it was not picked; the weights
+    stream once either way and there is nothing to sort.  Many tokens (a
+    prefill): the held picks are sorted by expert and each expert's run
+    is multiplied in tiles of `tile` rows, as many tiles as its tokens
+    need and no more, so the work follows the picks, however uneven."""
+    T, D = x.shape
+    k = gate.shape[1]
+    n_held = wg.shape[0]
+    f32 = jnp.float32
+    local = expert - first_held
+    held = (local >= 0) & (local < n_held)
+    if T <= tile:
+        g = jnp.sum(jax.nn.one_hot(jnp.where(held, local, n_held), n_held,
+                                   dtype=f32) * gate[..., None], axis=1)
+        a = jax.nn.silu(jnp.einsum("td,efd->tef", x, wg,
+                                   preferred_element_type=f32)) \
+            * jnp.einsum("td,efd->tef", x, wu, preferred_element_type=f32)
+        return jnp.einsum("tef,edf->td", (a * g[..., None]).astype(x.dtype),
+                          wd, preferred_element_type=f32)
+
+    # picks in token order, flattened; held ones first after the sort,
+    # grouped by expert
+    key = jnp.where(held, local, n_held).reshape(-1)             # (T*k,)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    rank = jnp.argsort(order).astype(jnp.int32)         # where a pick went
+    count = jnp.sum(jax.nn.one_hot(key, n_held, dtype=jnp.int32), axis=0)
+    start = jnp.cumsum(count) - count                   # first row of e
+    tiles = (count + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)                        # tiles up to e
+    order_pad = jnp.concatenate([order, jnp.zeros((tile,), jnp.int32)])
+    # the results, one row a held pick in sorted order (a run's last tile
+    # overhangs into the next run, whose own tile then overwrites it)
+    rows = jnp.zeros((T * k + tile, D), x.dtype)
+
+    def one_tile(j, rows):
+        e = jnp.sum(tile_end <= j).astype(jnp.int32)    # whose tile j is
+        base = start[e] + (j - (tile_end[e] - tiles[e])) * tile
+        picks = lax.dynamic_slice(order_pad, (base,), (tile,))
+        y = _swiglu(x[picks // k], wg[e], wu[e], wd[e])
+        return lax.dynamic_update_slice(rows, y.astype(rows.dtype),
+                                        (base, 0))
+
+    rows = lax.fori_loop(0, tile_end[-1], one_tile, rows)
+    mine = rows[jnp.where(held, rank.reshape(T, k), 0)]          # (T, k, D)
+    return jnp.einsum("tkd,tk->td", mine, jnp.where(held, gate, 0.0),
+                      preferred_element_type=f32)

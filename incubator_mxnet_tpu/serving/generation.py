@@ -51,6 +51,18 @@ device.  ``continuous=False`` degrades to drain batching (a new batch
 only forms when every slot is free) — the A/B baseline
 `bench.py generate` and `tools/check_decode.py` measure TTFT against.
 
+**The step dispatched early.**  Dispatch is asynchronous, and the host
+needs a few milliseconds between one step's report and the next step's
+dispatch (read-back, the per-slot loop, the call).  Where a step boundary
+can decide nothing — every slot is taken by a stream that is in the step
+in flight, and none of them is on its last token by budget or position —
+the engine dispatches the next step BEFORE it waits for this one's
+report (`_settled`), so the device goes from one step into the next.
+With a free slot, or a stream about to end by its budget, it goes step
+by step: an admission is never a step late.  A stream that ends by
+``eos`` or its deadline under a step dispatched early is found one step
+late; its slot computes one token more, which nobody reads.
+
 **Streaming.**  `submit()` returns a `GenerationStream`: iterate it
 for tokens as they are emitted (time-to-first-token and inter-token
 latency land in the labeled percentile rings `gen.ttft_us` /
@@ -85,13 +97,27 @@ Beside the log: `gen.*` counters, a slot-occupancy gauge
 and per-lane TTFT SLO targets (`slo_targets()`) that
 `telemetry/slo.py`'s default generation rules alert on.
 
-Model contract (``models/seq2seq.py``, ``models/transformer.py``):
+Model contract (``models/seq2seq.py``, ``models/transformer.py``,
+``models/sparse_decoder.py``), one for encoder-decoder and decoder-only
+models:
 
-- ``init_cache(src, src_valid_len, max_len=, mem_len=)`` → dict of
+- ``init_cache(prompt, valid_len, max_len=, mem_len=)`` → dict of
   NDArray leaves, ALL slot-major (axis 0 = request), shapes a pure
-  function of (prompt bucket, max_len, mem_len).
+  function of (prompt bucket, max_len, mem_len).  A stream starts at
+  token ``bos``, position 0, unless the row says otherwise in two more
+  leaves, ``start_tok`` and ``start_pos`` (B,): a decoder-only model's
+  prefill IS its prompt, so it starts at the prompt's last token and
+  position.  ``join`` writes the start into the slot with the row.
 - ``decode_step(tok, pos, cache)`` → (next-token logits (B, V),
   updated cache).  One token per slot per call; position is data.
+  A leaf ``counts`` (B, k) int32, if the cache has one, is what the
+  step did for each slot under the k names of the model's
+  ``step_counts``: it comes back with the step's tokens and the engine
+  adds each column, summed over the live slots, to the counter of that
+  name.
+- A stream ends at ``eos``, at its token budget, or when its position
+  reaches ``max_len``: a prompt that lives in the cache and its new
+  tokens share the slot's ``max_len`` rows.
 """
 from __future__ import annotations
 
@@ -118,6 +144,15 @@ __all__ = ["GenerationEngine", "GenerationStream",
            "project_generation_footprint"]
 
 _END = object()          # stream sentinel: normal end
+
+# Bytes of prefilled rows admitted between two decode steps (at least one
+# row; two of the 356 MB rows of a 16-layer, 10 k-token slot).  Dispatch is
+# asynchronous and a dispatched prefill holds its row and its temporaries
+# from then on, so a tick that fills many free slots at once would hold them
+# all: with rows of a few MB that is nothing, with rows of hundreds of MB it
+# is the device's memory.  The step's sync at the end of a tick is what
+# bounds the queue.
+_ADMIT_BYTES = 768 << 20
 
 
 def _parse_prompt_buckets(spec, max_len):
@@ -197,6 +232,19 @@ def _pure_method(block, method, training=False):
     return pure
 
 
+def _split_start(row, bos):
+    """A prefilled row as the engine keeps it: the model's leaves under
+    ``m``, and where the stream starts under ``tok``/``pos`` — the
+    row's own ``start_tok``/``start_pos`` leaves, or (bos, 0)."""
+    import jax
+    import jax.numpy as jnp
+    m = dict(row)
+    n = jax.tree_util.tree_leaves(m)[0].shape[0]
+    tok = m.pop("start_tok", jnp.full((n,), bos, jnp.int32))
+    pos = m.pop("start_pos", jnp.zeros((n,), jnp.int32))
+    return {"m": m, "tok": tok, "pos": pos}
+
+
 def project_generation_footprint(block, slots, max_len, buckets,
                                  vocab_hint=None, temp_factor=None):
     """Projected per-device HBM bytes for GENERATION serving: param
@@ -219,8 +267,8 @@ def project_generation_footprint(block, slots, max_len, buckets,
                           for p in block.collect_params().values())}
     src = jax.ShapeDtypeStruct((1, mem_len), _np.int32)
     vl = jax.ShapeDtypeStruct((1,), _np.int32)
-    cache = jax.eval_shape(lambda pv, s, v: pure(
-        pv, s, v, int(max_len), mem_len), pvals, src, vl)
+    cache = jax.eval_shape(lambda pv, s, v: _split_start(pure(
+        pv, s, v, int(max_len), mem_len), 0)["m"], pvals, src, vl)
     kv_slot = sum(int(_np.prod(a.shape[1:]))
                   * _np.dtype(a.dtype).itemsize
                   for a in jax.tree_util.tree_leaves(cache))
@@ -339,12 +387,24 @@ class _GenRequest:
 
 
 class _Slot:
-    __slots__ = ("req", "emitted", "t_last")
+    __slots__ = ("req", "emitted", "t_last", "pos")
 
     def __init__(self, req):
         self.req = req
         self.emitted = 0
         self.t_last = None      # last token wall (inter-token meter)
+        self.pos = None         # the position its last token was read at
+
+
+class _Flight:
+    """A dispatched decode step whose report the host has not read: the
+    report, who sat in each slot when it was dispatched, and when."""
+    __slots__ = ("report", "seats", "t0")
+
+    def __init__(self, report, seats, t0):
+        self.report = report
+        self.seats = seats      # [(slot index, _Slot)]
+        self.t0 = t0
 
 
 class GenerationEngine:
@@ -353,13 +413,16 @@ class GenerationEngine:
 
     block: a model implementing ``init_cache``/``decode_step`` (the
         explicit-cache contract — `models.Seq2Seq`,
-        `models.TransformerNMT`).  Parameters must be initialized.
-    bos / eos: special token ids (decode starts from bos; an emitted
-        eos retires the sequence).
+        `models.TransformerNMT`, `models.SparseDecoder`).  Parameters
+        must be initialized.
+    bos / eos: special token ids (decode starts from bos unless the
+        prefilled row names its own start; an emitted eos retires the
+        sequence).
     slots / max_len: the (slot-count bucket, max_len bucket) the ONE
         decode executable is specialized to (`MXNET_GEN_SLOTS`,
         `MXNET_GEN_MAX_LEN`).  max_len bounds prompt length AND
-        emitted tokens per request.
+        emitted tokens per request, and a stream retires when its
+        position reaches it.
     prompt_buckets: closed prompt-length bucket set
         (`MXNET_GEN_BUCKETS`; empty = powers of two up to max_len).
     continuous: True = continuous batching (join at step boundaries);
@@ -381,6 +444,8 @@ class GenerationEngine:
                     "decode contract (missing %r) — see "
                     "models/seq2seq.py / models/transformer.py" % m)
         self._bos, self._eos = int(bos), int(eos)
+        # the names of the model's per-slot counts (decode_step contract)
+        self._count_names = tuple(getattr(block, "step_counts", ()))
         self._ctx = ctx if isinstance(ctx, Context) else (
             Context(*ctx) if ctx is not None else current_context())
         self._S = int(slots if slots is not None
@@ -430,6 +495,8 @@ class GenerationEngine:
         self._step_ewma = None          # decode-step seconds
         self._steps = 0
         self._ticks = 0
+        self._ahead = None              # the _Flight dispatched early
+        self._t_report = 0.0            # when the last report arrived
         self._thread = None
         self._draining = False
         self._stop = False
@@ -463,7 +530,9 @@ class GenerationEngine:
         from ..parallel.functional import extract_params
         block = self._block
         L = self._L
-        eos = self._eos
+        bos, eos = self._bos, self._eos     # not `self`: a closure that
+        # held the engine would tie it into a cycle with its own
+        # executables, and its cache would wait for the collector
         pure_init = _pure_method(block, "init_cache")
         pure_step = _pure_method(block, "decode_step")
         mem_len = self._mem_len
@@ -474,7 +543,8 @@ class GenerationEngine:
             # zero-recompile contract is asserted on (the same
             # serve.traces the one-shot engine meters)
             events.incr("serve.traces")
-            return pure_init(params, src, valid, max_len, mem_len)
+            return _split_start(
+                pure_init(params, src, valid, max_len, mem_len), bos)
 
         def decode_step(params, cache):
             events.incr("serve.traces")
@@ -489,7 +559,13 @@ class GenerationEngine:
             # state) read without a per-step host hop
             oh = jax.nn.one_hot(pos, L, dtype=jnp.int32)
             out = cache["out"] * (1 - oh) + nxt[:, None] * oh
-            return nxt, {
+            # what the host reads of a step, in one array: the token,
+            # the position it was read at, the model's counts
+            report = jnp.concatenate(
+                [nxt[:, None], pos[:, None],
+                 new_m.get("counts", jnp.zeros((nxt.shape[0], 0),
+                                               jnp.int32))], axis=1)
+            return report, {
                 "m": new_m, "tok": nxt,
                 # clamp keeps dead slots' one-hot writes in range; a
                 # LIVE slot never reaches the clamp (the host retires
@@ -507,10 +583,10 @@ class GenerationEngine:
                 return jax.lax.dynamic_update_slice(
                     c, r.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1))
 
-            return {"m": jax.tree_util.tree_map(put, cache["m"], row),
-                    "tok": put(cache["tok"],
-                               jnp.full((1,), self._bos, jnp.int32)),
-                    "pos": put(cache["pos"], jnp.zeros((1,), jnp.int32)),
+            return {"m": jax.tree_util.tree_map(put, cache["m"],
+                                                row["m"]),
+                    "tok": put(cache["tok"], row["tok"]),
+                    "pos": put(cache["pos"], row["pos"]),
                     "out": put(cache["out"],
                                jnp.full((1, L), eos, jnp.int32))}
 
@@ -548,8 +624,8 @@ class GenerationEngine:
                  for n, v in self._params.items()}
         src = jax.ShapeDtypeStruct((1, self._mem_len), _np.int32)
         vl = jax.ShapeDtypeStruct((1,), _np.int32)
-        row = jax.eval_shape(lambda pv, s, v: pure(
-            pv, s, v, self._L, self._mem_len), pvals, src, vl)
+        row = jax.eval_shape(lambda pv, s, v: _split_start(pure(
+            pv, s, v, self._L, self._mem_len), 0)["m"], pvals, src, vl)
         dev = self._ctx.jax_device
         m = jax.tree_util.tree_map(
             lambda a: jax.device_put(
@@ -562,6 +638,9 @@ class GenerationEngine:
             "pos": jax.device_put(jnp.zeros((S,), jnp.int32), dev),
             "out": jax.device_put(
                 jnp.full((S, L), self._eos, jnp.int32), dev)}
+        self._slot_bytes = sum(
+            int(_np.prod(a.shape[1:])) * _np.dtype(a.dtype).itemsize
+            for a in jax.tree_util.tree_leaves(self._cache))
 
     def kv_cache_bytes(self):
         """Total device bytes held by the slot cache (the KV term of
@@ -608,7 +687,7 @@ class GenerationEngine:
                 jax.block_until_ready(
                     jax.tree_util.tree_leaves(row)[0])
                 per_bucket[b] = round(time.monotonic() - tb, 4)
-            old_probe = jax.tree_util.tree_leaves(self._cache["m"])[0]
+            old_probe = self._probe_leaf()
             self._cache = self._join(self._cache, row,
                                      jax.device_put(_np.int32(0), dev))
             self._donation_probe(old_probe, "join")
@@ -849,8 +928,11 @@ class GenerationEngine:
             return
         if not self._continuous and len(free) != self._S:
             return
+        if self._cache is None:
+            self._init_cache_arrays()
+        room = max(1, _ADMIT_BYTES // self._slot_bytes)
         with _tele.phase("gen.admit", parent=tick) as admit:
-            while free:
+            while free and admit.n < room:
                 try:
                     req = self._q.get_nowait()
                 except queue.Empty:
@@ -904,7 +986,8 @@ class GenerationEngine:
         import jax
         dev = self._ctx.jax_device
         try:
-            with _tele.phase("gen.prefill", req.rid, req.tick) as pre:
+            with _tele.phase("gen.prefill", req.rid, req.tick,
+                             int(req.prompt.size)) as pre:
                 padded = _np.zeros((1, bucket), _np.int32)
                 padded[0, :req.prompt.size] = req.prompt
                 fault.maybe_raise("serve.infer", step=self._steps)
@@ -960,49 +1043,101 @@ class GenerationEngine:
         """Advance every live slot one token; stream, then retire
         finished sequences at this boundary.  A terminal decode
         failure fails every LIVE sequence (typed, exactly once) and
-        rebuilds the cache — donated buffers cannot be retried."""
-        import jax
+        rebuilds the cache — donated buffers cannot be retried.
+
+        The step read here is the one dispatched early in the last
+        tick, if there is one.  And before the host waits for it, the
+        NEXT step is dispatched early whenever this boundary can decide
+        nothing (`_settled`): the device then goes from one step into
+        the next while the host reads, emits and dispatches."""
+        flight, self._ahead = self._ahead, None
+        if flight is not None and not any(
+                self._slots[i] is slot for i, slot in flight.seats):
+            flight = None       # every stream it ran over has ended
+        if flight is None:
+            flight = self._dispatch(live, tick, self._steps)
+            if flight is None:
+                return
+        if self._settled(flight):
+            self._ahead = self._dispatch(live, tick, self._steps + 1)
+            if self._ahead is None:     # failed: every stream is failed,
+                return                  # the cache rebuilt
+        try:
+            with _tele.phase("gen.sync", self._steps, tick) as sync:
+                report = _np.asarray(flight.report)     # (S, 2 + k):
+                                                        # the device's time
+        except Exception as e:              # noqa: BLE001
+            return self._step_failed(live, e)
+        # a step that waited in line behind its predecessor took what
+        # passed since that one's report, not since its own dispatch
+        dt = sync.t1 - max(flight.t0, self._t_report)
+        self._t_report = sync.t1
+        with _tele.phase("gen.emit", self._steps, tick) as emit:
+            emit.n = self._emit(flight.seats, report, dt, emit.t0)
+
+    def _dispatch(self, live, tick, step):
+        """Dispatch decode step number `step` over the donated cache.
+        Returns its _Flight, or None after a terminal failure."""
         from ..parallel.resilience import retry_transient
-        with _tele.phase("gen.decode", self._steps, tick,
-                         len(live)) as decode:
+        with _tele.phase("gen.decode", step, tick, len(live)) as decode:
             # injected transient faults fire HOST-side (before the
             # executable), so the retry budget is donation-safe;
             # serve.decode_slow stalls a step (deadline/straggler
             # tests) without failing it
-            fault.maybe_slow("serve.decode_slow", step=self._steps)
+            fault.maybe_slow("serve.decode_slow", step=step)
             retry_transient(
-                lambda: fault.maybe_raise("serve.infer",
-                                          step=self._steps),
+                lambda: fault.maybe_raise("serve.infer", step=step),
                 what="gen.decode_step", event="gen.retries")
             old_probe = None
             if not self._donation_checked:
-                old_probe = jax.tree_util.tree_leaves(
-                    self._cache["m"])[0]
+                old_probe = self._probe_leaf()
             try:
                 nxt, self._cache = self._decode(self._params,
                                                 self._cache)
             except Exception as e:          # noqa: BLE001
-                return self._step_failed(live, e)
+                self._step_failed(live, e)
+                return None
             if old_probe is not None:
                 self._donation_checked = True
                 self._donation_probe(old_probe, "decode_step")
-        try:
-            with _tele.phase("gen.sync", self._steps, tick) as sync:
-                toks = _np.asarray(nxt)     # (S,) the device's time
-        except Exception as e:              # noqa: BLE001
-            return self._step_failed(live, e)
-        with _tele.phase("gen.emit", self._steps, tick) as emit:
-            emit.n = self._emit(live, toks, sync.t1 - decode.t0,
-                                emit.t0)
+        return _Flight(nxt, [(i, self._slots[i]) for i in live],
+                       decode.t0)
+
+    def _settled(self, flight):
+        """True when the boundary after `flight` can decide nothing, so
+        the step after it may be dispatched before its report is read:
+        every slot is taken by a stream that is in `flight`, and none of
+        them can end there by its budget or its position.  (A stream
+        that ends by `eos` or its deadline is then found a step late:
+        its slot computes one token more, which nobody reads.)  With a
+        free slot, a stream on its last token or one not yet read from,
+        the engine goes step by step as it always did."""
+        if len(flight.seats) != self._S:
+            return False
+        last_row = self._L - 1
+        for i, slot in flight.seats:
+            if self._slots[i] is not slot or slot.pos is None \
+                    or slot.emitted + 1 >= slot.req.max_new \
+                    or slot.pos + 1 >= last_row:
+                return False
+        return True
 
     def _step_failed(self, live, e):
         """Terminal: the donated cache may be gone.  Fail the live
         slots and rebuild it."""
         events.incr("gen.failed")
+        self._ahead = None
         for i in list(live):
             self._retire(i, exc=e)
         self._init_cache_arrays()
         _bb.record("gen", "step_failed", error=type(e).__name__)
+
+    def _probe_leaf(self):
+        """The largest leaf of the model's cache: the one whose copy
+        would cost most, and one every donating executable writes."""
+        import jax
+        return max(jax.tree_util.tree_leaves(self._cache["m"]),
+                   key=lambda a: a.size)
 
     def _donation_probe(self, old_leaf, what):
         """`old_leaf` is a cache leaf held from before a call of the
@@ -1018,10 +1153,16 @@ class GenerationEngine:
             "its HBM traffic doubles (backend ignores donation)"
             % (self._label + ":" + what))
 
-    def _emit(self, live, toks, dt, now):
-        """The host's share of a step, after its tokens arrived: meter
-        it, push each live slot's token, retire what finished.
-        Returns the number of tokens pushed."""
+    def _emit(self, seats, report, dt, now):
+        """The host's share of a step, after its report arrived (a row
+        a slot: token, the position it was read at, the model's
+        counts): meter it, push the token of each stream that still has
+        the seat it had when the step was dispatched, retire what
+        finished.  Returns the number of tokens pushed."""
+        # a racing close() swept a slot (its stream is resolved), or a
+        # step dispatched early ran over a stream that had just ended
+        seats = [(i, slot) for i, slot in seats if self._slots[i] is slot]
+        live = [i for i, _ in seats]
         self._step_ewma = dt if self._step_ewma is None \
             else 0.3 * dt + 0.7 * self._step_ewma
         self._steps += 1
@@ -1029,14 +1170,19 @@ class GenerationEngine:
         events.incr("gen.steps")
         events.incr("gen.tokens", len(live))
         events.observe("gen.slots_live", len(live))
+        if self._count_names:
+            for name, total in zip(self._count_names,
+                                   report[live, 2:].sum(axis=0)):
+                events.incr(name, int(total))
+        last_row = self._L - 1
         pushed = 0
-        for i in live:
-            slot = self._slots[i]
-            if slot is None:    # a racing close() swept this slot —
-                continue        # its stream is already resolved
+        for i, slot in seats:
+            if self._slots[i] is not slot:      # close() swept it just now
+                continue
             req = slot.req
-            tok = int(toks[i])
+            tok = int(report[i, 0])
             slot.emitted += 1
+            slot.pos = int(report[i, 1])
             if slot.t_last is None:
                 _tele.phase_at("gen.req.first", req.t_exec, now,
                                req.rid, req.tick)
@@ -1062,7 +1208,8 @@ class GenerationEngine:
                 self._retire(i, exc=DeadlineExceeded(
                     "deadline expired after %d token(s)"
                     % slot.emitted))
-            elif tok == self._eos or slot.emitted >= req.max_new:
+            elif tok == self._eos or slot.emitted >= req.max_new \
+                    or slot.pos >= last_row:
                 self._retire(i)
         return pushed
 
@@ -1170,6 +1317,13 @@ class GenerationEngine:
         with self._lock:
             self._closed = True
         self._flush_leftovers()
+        if joined:
+            # give the device its memory back now, not when the last
+            # reference to the engine goes: whoever closes an engine of
+            # gigabytes wants the room (the loop is gone, nothing reads
+            # these again)
+            self._cache = None
+            self._params = None
         return joined
 
     def __enter__(self):

@@ -585,7 +585,11 @@ class ModelRegistry:
                            max_len=None, prompt_buckets=None,
                            **engine_kw):
         """Admit `block` as GENERATION model `name` on one pool device
-        (a `serving.generation.GenerationEngine`).
+        (a `serving.generation.GenerationEngine`).  `block` is any model
+        of the explicit-cache contract, encoder-decoder or decoder-only:
+        the slot's bytes are whatever its `init_cache` returns (for a
+        decoder-only model the prompt's K/V rows live there too, so
+        ``max_len`` bounds prompt + new tokens).
 
         Admission accounts what one-shot serving has no analogue for:
         the KV term — ``slots × kv_bytes_per_slot`` from

@@ -60,6 +60,13 @@ def _ref_tokens(net, prompt, max_new):
     return [int(t) for t in toks]
 
 
+def _unending(net, prompt, n):
+    """`n` greedy tokens with an end-of-sequence id the model cannot emit."""
+    out = greedy_translate(net, nd.array(prompt[None], dtype="int32"),
+                           BOS, V + 7, max_len=n)[0]
+    return [int(t) for t in out]
+
+
 # -- variable-length RNN substrate -------------------------------------
 
 def test_rnn_varlen_matches_truncated_run():
@@ -233,7 +240,9 @@ def _join_operands(eng, seed, cast_leaf=None):
     cache = jax.tree_util.tree_map(lambda a: rand(onp.asarray(a)),
                                    eng._cache)
     eng._cache = None
-    row = {k: rand(v[:1]) for k, v in cache["m"].items()}
+    # a prefilled row: the model's leaves and where its stream starts
+    row = {"m": {k: rand(v[:1]) for k, v in cache["m"].items()},
+           "tok": rand(cache["tok"][:1]), "pos": rand(cache["pos"][:1])}
     if cast_leaf is not None:
         assert cache["m"][cast_leaf].dtype == onp.float32
         cache["m"][cast_leaf] = cache["m"][cast_leaf].astype(onp.float16)
@@ -247,8 +256,8 @@ def _join_operands(eng, seed, cast_leaf=None):
 def test_join_writes_one_slot_bit_exact(family, cast_leaf, where):
     """The join against a plain NumPy reference, bit for bit on every
     leaf: the slot's row holds the prefilled row (cast to the cache
-    leaf's dtype), bos / position 0 / a row of eos, and every other
-    slot keeps its bytes."""
+    leaf's dtype), the row's start token and position, a row of eos,
+    and every other slot keeps its bytes."""
     import jax
     net = _seq2seq(seed=11) if family == "seq2seq" else _transformer(11)
     S = 5
@@ -259,10 +268,10 @@ def test_join_writes_one_slot_bit_exact(family, cast_leaf, where):
                                                   cast_leaf)
         got = eng._join(cache_d, row_d, jax.device_put(
             onp.int32(slot), eng._ctx.jax_device))
-        for k, r in row.items():
+        for k, r in row["m"].items():
             ref["m"][k][slot] = r[0].astype(ref["m"][k].dtype)
-        ref["tok"][slot] = BOS
-        ref["pos"][slot] = 0
+        ref["tok"][slot] = row["tok"][0]
+        ref["pos"][slot] = row["pos"][0]
         ref["out"][slot] = EOS
         tree = jax.tree_util
         assert tree.tree_structure(got) == tree.tree_structure(ref)
@@ -397,15 +406,18 @@ def test_born_expired_and_infeasible_shed():
         eng.warmup()
         with pytest.raises(DeadlineExceeded):
             eng.submit(onp.array([3, 4, 5]), deadline=-1.0)
-        # lane-quota shed: with the decode loop parked (stop flag),
-        # the low lane's cap (0.25 x 8 = 2) sheds the 3rd submit
-        # deterministically — no race against admission
+        # lane-quota shed: with the decode loop parked, the low lane's
+        # cap (0.25 x 8 = 2) sheds the 3rd submit deterministically.
+        # Parked means never started: a loop that finds the stop flag
+        # flushes the queue as it leaves, and a submit that then found
+        # room again raced it (the test failed under the six-worker
+        # run for that reason, the engine's shed path was sound)
         small = GenerationEngine(
             net, bos=BOS, eos=EOS, slots=1, max_len=16,
             prompt_buckets=(4,), queue_cap=8,
             lanes=("hi", "lo"), lane_quotas=(1.0, 0.25))
         try:
-            small._stop = True
+            small._ensure_loop = lambda: None
             with pytest.raises(Shed):
                 for _ in range(4):
                     small.submit(onp.array([3, 4]), lane="lo",
@@ -602,3 +614,79 @@ def test_check_decode_gate_runs():
          "--trials", "1", "--duration", "1.5"],
         capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stdout + res.stderr
+
+
+def test_close_gives_the_device_memory_back_without_the_collector():
+    """A closed engine holds no cache and no parameters, and dropping its
+    last name frees it by reference count: no closure of its executables
+    holds the engine (the benchmark's driver closes the system while the
+    collector is frozen, and the reference that runs next needs the
+    room)."""
+    import gc
+    import weakref
+    net = _seq2seq(seed=21)
+    eng = _engine(net, slots=2)
+    eng.warmup()
+    eng.submit(onp.array([3, 4, 5]), max_new_tokens=3).result(timeout=60)
+    cache_leaf = weakref.ref(eng._probe_leaf())
+    gc.collect()
+    gc.disable()
+    try:
+        assert eng.close()
+        assert eng._cache is None and eng._params is None
+        assert cache_leaf() is None
+        ref = weakref.ref(eng)
+        del eng
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
+# -- the step dispatched early ------------------------------------------
+
+@pytest.mark.parametrize("ends", ["budget", "eos"])
+def test_a_full_engine_dispatches_the_next_step_early_and_streams_the_same(
+        ends):
+    """With every slot taken and no stream on its last token the engine
+    dispatches step n + 1 before it reads step n.  The streams are what one
+    step at a time gives: by budget no boundary is ever late; by eos the
+    stream that ends is found a step late and its one token more is read by
+    nobody, not by the request that takes its slot either."""
+    net = _transformer(4)
+    rs = onp.random.RandomState(12)
+    prompts = [rs.randint(3, V, n).astype(onp.int32) for n in (3, 7, 5, 4)]
+    budgets = [19, 26, 11, 23]
+    want = [_unending(net, p, n) for p, n in zip(prompts, budgets)]
+    eos = V + 7
+    if ends == "eos":
+        eos = want[0][14]       # only the first stream has it: it ends
+        assert eos not in want[0][:14] + want[1] + want[2] + want[3]
+        want = [w[:w.index(eos) + 1] if eos in w else w for w in want]
+    eng = GenerationEngine(net, bos=BOS, eos=eos, slots=2, max_len=32,
+                           prompt_buckets=(4, 8))
+    early, late = [], []
+    settled, emit = eng._settled, eng._emit
+
+    def counted(flight):
+        early.append(settled(flight))
+        return early[-1]
+
+    def watched(seats, *a):
+        late.extend(i for i, slot in seats if eng._slots[i] is not slot)
+        return emit(seats, *a)
+
+    eng._settled, eng._emit = counted, watched
+    try:
+        eng.warmup()
+        streams = [eng.submit(p, max_new_tokens=n)
+                   for p, n in zip(prompts, budgets)]
+        got = [[int(t) for t in s.result(120)] for s in streams]
+    finally:
+        eng.close()
+    assert got == want
+    assert sum(early) >= (8 if ends == "budget" else 2), early  # it did
+    assert not all(early)               # and not across a budget's end
+    if ends == "budget":
+        assert not late                 # no seat was ever read too late
+    else:
+        assert late                     # the ended stream's one step more
