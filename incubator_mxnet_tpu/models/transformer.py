@@ -521,24 +521,51 @@ def _mem_mask_for(F, src, src_valid_len):
 
 
 # -- explicit-cache decode (serving.generation contract) ---------------
-# TransformerNMT grows init_cache/decode_step: per-decoder-layer
-# self-attention K/V buffers pre-allocated at (B, max_len, U) written
-# by one-hot masked updates at each slot's own position (continuous
-# batching = slots at DIFFERENT positions in one fixed-shape
-# executable), plus cross-attention K/V precomputed from the encoder
-# memory once at prefill.  All cache leaves are slot-major.  Padding
-# is exactly neutral: attention masks underflow pad weights to 0 and
-# every other op is position-wise.
+# TransformerNMT grows init_cache/decode_step.  Per decoder layer the
+# cache holds self-attention K/V rows for `max_len` positions and the
+# cross-attention K/V precomputed from the encoder memory at prefill,
+# every leaf slot-major and in the layout a step's attention reads:
+# (B, G, T, W), G groups of P heads whose d-wide rows lie side by side
+# in one W = P·d wide row (`_lane_heads`).  A step writes the one new
+# row of every slot at the slot's own position by an indexed update of
+# the donated leaf (continuous batching = slots at DIFFERENT positions
+# in one fixed-shape executable) and contracts over the leaves as they
+# lie, so it reads each byte of the cache once and copies none.
+# Padding is exactly neutral: attention masks underflow pad weights to
+# 0 and every other op is position-wise.
+
+_LANES = 128    # the width of a TPU vector register's minor dimension
+
+
+def _lane_heads(num_heads, head_dim):
+    """How many heads share one cache row: the most that divide
+    `num_heads` and fit `_LANES`.  A (…, T, d) leaf with d = 64 is not
+    kept that way on a TPU: XLA puts T on the lanes, and writing one
+    position then rewrites whole tiles (30 ms a step for `nmt_base`'s
+    twelve leaves against 1.8 ms for full-lane rows; PERF.md, PR 31)."""
+    p = max(1, min(num_heads, _LANES // head_dim))
+    while num_heads % p:
+        p -= 1
+    return p
+
+
+def _cache_rows(F, t, groups):
+    """(B, T, U) → (B, G, T, U/G): the cache's layout."""
+    return F.transpose(F.reshape(t, (0, 0, groups, -1)), axes=(0, 2, 1, 3))
+
 
 def _nmt_init_cache(self, src, src_valid_len, max_len, mem_len=None):
     """Prefill: run the encoder over `src` (B, Ts) with the padding
     mask, precompute each decoder layer's cross-attention K/V, and
     allocate zeroed self-attention K/V buffers for `max_len` decode
-    positions.  `mem_len` pads the memory axis so every prompt bucket
-    produces ONE decode signature."""
+    positions, all in the cache's (B, G, T, W) layout.  `mem_len` pads
+    the memory axis so every prompt bucket produces ONE decode
+    signature."""
     from .. import ndarray as F
     B = src.shape[0]
     Ts = src.shape[1]
+    H = self._num_heads
+    G = H // _lane_heads(H, self._units // H)
     mem_mask = _mem_mask_for(F, src, src_valid_len)
     memory = self.encoder(self._embed(self.src_embed, self.enc_ln,
                                       src), mask=mem_mask)  # (B, Ts, U)
@@ -547,12 +574,12 @@ def _nmt_init_cache(self, src, src_valid_len, max_len, mem_len=None):
             memory, F.zeros((B, int(mem_len) - int(Ts), self._units)),
             dim=1)
     cache = {"src_len": src_valid_len.reshape((-1,))}
-    zeros = F.zeros((B, int(max_len), self._units))
+    zeros = F.zeros((B, G, int(max_len), self._units // G))
     for i, layer in enumerate(self.decoder.layers._children.values()):
         ca = layer.cross_attn
-        cache["mem_k%d" % i] = ca.key(memory)               # (B, M, U)
-        cache["mem_v%d" % i] = ca.value(memory)
-        cache["k%d" % i] = zeros
+        cache["mem_k%d" % i] = _cache_rows(F, ca.key(memory), G)
+        cache["mem_v%d" % i] = _cache_rows(F, ca.value(memory), G)
+        cache["k%d" % i] = zeros                            # (B, G, L, W)
         cache["v%d" % i] = zeros
     return cache
 
@@ -560,50 +587,63 @@ def _nmt_init_cache(self, src, src_valid_len, max_len, mem_len=None):
 def _nmt_decode_step(self, tok, pos, cache):
     """One decode step: token `tok` (B,) at target position `pos`
     (B,) against the cached K/V.  Returns (logits (B, V), updated
-    cache).  The K/V write is a one-hot masked update at each row's
-    own position — no reshape, no gather/scatter with dynamic
-    shapes."""
+    cache).  Each layer's new K/V row is written at the slot's own
+    position by an indexed update (a position outside the cache writes
+    nothing), then one batched contraction over (B, G) gives the
+    scores and one the context: no cache leaf is reshaped, transposed
+    or rewritten."""
+    import jax
+    import jax.numpy as jnp
     from .. import ndarray as F
-    H = self._num_heads
-    L = cache["k0"].shape[1]
-    M = cache["mem_k0"].shape[1]
-    scale = 1.0 / math.sqrt(self._units // H)
-    x = self.tgt_embed(tok.reshape((-1, 1))) \
-        * math.sqrt(self._units) \
+    from ..ndarray.ndarray import NDArray
+    H, U = self._num_heads, self._units
+    d = U // H
+    P = _lane_heads(H, d)
+    B, G, L, W = cache["k0"].shape
+    M = cache["mem_k0"].shape[2]
+    scale = 1.0 / math.sqrt(d)
+    x = self.tgt_embed(tok.reshape((-1, 1))) * math.sqrt(U) \
         + self.pos_embed(pos.reshape((-1, 1)))              # (B, 1, U)
     x = self.dec_ln(x)
     # additive masks: self-attention sees positions <= pos (one query
     # row per slot, each at its OWN position — the continuous-batching
     # point), cross-attention sees the real source positions
     steps = F.arange(0, L).reshape((1, 1, 1, L))
-    self_mask = (steps > pos.reshape((-1, 1, 1, 1))) * -1e9
+    self_mask = ((steps > pos.reshape((-1, 1, 1, 1))) * -1e9)._data
     msteps = F.arange(0, M).reshape((1, 1, 1, M))
-    mem_mask = (msteps >=
-                cache["src_len"].reshape((-1, 1, 1, 1))) * -1e9
-    oh = F.expand_dims(F.one_hot(pos, L), axis=2)           # (B, L, 1)
+    mem_mask = ((msteps >=
+                 cache["src_len"].reshape((-1, 1, 1, 1))) * -1e9)._data
+    row = (jnp.arange(B)[:, None], jnp.arange(G)[None, :],
+           pos._data.reshape((-1, 1)))
+    own = jnp.eye(P)[:, :, None]                            # (P, P, 1)
     new_cache = dict(cache)
 
+    def _write(leaf, new):
+        leaf = leaf._data
+        return NDArray(leaf.at[row].set(new._data.reshape(B, G, W)
+                                        .astype(leaf.dtype)))
+
     def _attend(q, k, v, mask):
-        qh = _split_heads(F, q, H)                          # (B·H, 1, d)
-        kh = _split_heads(F, k, H)
-        vh = _split_heads(F, v, H)
-        sc = F.batch_dot(qh, kh, transpose_b=True) * scale  # (B·H,1,T)
-        sc = F.reshape(sc, (-4, -1, H, 0, 0)) + mask        # (B,H,1,T)
-        at = F.reshape(F.softmax(sc, axis=-1), (-3, 0, 0))
-        return F.batch_dot(at, vh)                          # (B·H, 1, d)
+        # head j of a group reads its own d of the row's W lanes: its
+        # query is zero on the others', and of the (P, W) context it
+        # keeps its own d
+        q = q._data.reshape(B, G, P, 1, d)
+        qh = (q * own.astype(q.dtype)).reshape(B, G, P, W)
+        sc = jnp.einsum("bgjw,bgtw->bgjt", qh, k._data) * scale + mask
+        at = jax.nn.softmax(sc, axis=-1)
+        ctx = jnp.einsum("bgjt,bgtw->bgjw", at, v._data)
+        ctx = jnp.einsum("bgjjd->bgjd", ctx.reshape(B, G, P, P, d))
+        return NDArray(ctx.reshape(B, 1, U))
 
     for i, layer in enumerate(self.decoder.layers._children.values()):
         sa = layer.self_attn
-        kc = cache["k%d" % i] * (1.0 - oh) + sa.key(x) * oh
-        vc = cache["v%d" % i] * (1.0 - oh) + sa.value(x) * oh
-        new_cache["k%d" % i] = kc
-        new_cache["v%d" % i] = vc
-        ctx = _attend(sa.query(x), kc, vc, self_mask)
-        x = layer.ln1(x + sa.proj(_merge_heads(F, ctx, H)))
+        kc = new_cache["k%d" % i] = _write(cache["k%d" % i], sa.key(x))
+        vc = new_cache["v%d" % i] = _write(cache["v%d" % i], sa.value(x))
+        x = layer.ln1(x + sa.proj(_attend(sa.query(x), kc, vc, self_mask)))
         ca = layer.cross_attn
         ctx = _attend(ca.query(x), cache["mem_k%d" % i],
                       cache["mem_v%d" % i], mem_mask)
-        x = layer.ln2(x + ca.proj(_merge_heads(F, ctx, H)))
+        x = layer.ln2(x + ca.proj(ctx))
         x = layer.ln3(x + layer.ffn(x))
     if self.out_proj is None:
         raise ValueError("decode_step needs the vocab projection "

@@ -221,6 +221,116 @@ def test_kv_donation_no_copy():
         eng.close()
 
 
+# -- the NMT decode step: one row a slot, written where it lies ----------
+
+def _one_hot_step(net, tok, pos, cache):
+    """`TransformerNMT.decode_step` with the arithmetic it had before the
+    indexed write, on the cache's own rows: every K/V leaf goes whole
+    through `cache * (1 - oh) + new * oh`, `oh` the one-hot of each slot's
+    position.  Eager NumPy-style code, independent of the step's write."""
+    import math
+    import jax
+    import jax.numpy as jnp
+    H, U = net._num_heads, net._units
+    d = U // H
+    B, G, L, W = cache["k0"].shape
+    P = W // d
+    M = cache["mem_k0"].shape[2]
+    x = net.dec_ln(net.tgt_embed(tok.reshape((-1, 1))) * math.sqrt(U)
+                   + net.pos_embed(pos.reshape((-1, 1))))
+    at_pos = jnp.arange(L)[None, :] == pos._data[:, None]
+    oh = at_pos.astype(jnp.float32)[:, None, :, None]       # (B, 1, L, 1)
+    self_mask = jnp.where(jnp.arange(L)[None, :] > pos._data[:, None],
+                          -1e9, 0.0)[:, None, None, :].astype(jnp.float32)
+    mem_mask = jnp.where(
+        jnp.arange(M)[None, :] >= cache["src_len"]._data[:, None],
+        -1e9, 0.0)[:, None, None, :].astype(jnp.float32)
+    own = jnp.eye(P)[:, :, None]
+
+    def attend(q, k, v, mask):
+        q = q._data.reshape(B, G, P, 1, d)
+        qh = (q * own.astype(q.dtype)).reshape(B, G, P, W)
+        sc = jnp.einsum("bgjw,bgtw->bgjt", qh, k) / math.sqrt(d) + mask
+        ctx = jnp.einsum("bgjt,bgtw->bgjw", jax.nn.softmax(sc, axis=-1), v)
+        ctx = jnp.einsum("bgjjd->bgjd", ctx.reshape(B, G, P, P, d))
+        return nd.NDArray(ctx.reshape(B, 1, U))
+
+    rows = lambda t: t._data.reshape(B, 1, G, W).transpose(0, 2, 1, 3)
+    out = dict(cache)
+    for i, layer in enumerate(net.decoder.layers._children.values()):
+        sa, ca = layer.self_attn, layer.cross_attn
+        kc = cache["k%d" % i]._data * (1.0 - oh) + rows(sa.key(x)) * oh
+        vc = cache["v%d" % i]._data * (1.0 - oh) + rows(sa.value(x)) * oh
+        out["k%d" % i], out["v%d" % i] = nd.NDArray(kc), nd.NDArray(vc)
+        x = layer.ln1(x + sa.proj(attend(sa.query(x), kc, vc, self_mask)))
+        x = layer.ln2(x + ca.proj(attend(
+            ca.query(x), cache["mem_k%d" % i]._data,
+            cache["mem_v%d" % i]._data, mem_mask)))
+        x = layer.ln3(x + layer.ffn(x))
+    return net.out_proj(x).reshape((0, -1)), out
+
+
+_NMT_L = 12
+_NMT_WRITES = {
+    # positions of the four slots, step after step
+    "spread": [[0, 5, _NMT_L - 1, 8], [1, 6, _NMT_L - 1, 9]],
+    "first_row": [[0, 0, 0, 0]],
+    "last_row": [[_NMT_L - 1] * 4],     # dead slots, held at the clamp
+    # slot 2 leaves and a new request joins it between two steps
+    "rejoined": [[4, 7, 9, 2], [5, 8, 0, 3], [6, 9, 1, 4]],
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_NMT_WRITES))
+def test_nmt_step_writes_one_row_like_the_one_hot_rewrite(case, dtype):
+    """The indexed write against the one-hot rewrite it replaced, bit for
+    bit in every cache leaf and every logit: slots at different
+    positions, position 0, position L - 1, and a slot that is handed to
+    a new request between two steps.  With bfloat16 weights the K/V
+    leaves stay float32 and the memory leaves are bfloat16, as in the
+    benchmark's cell."""
+    net = _transformer(seed=21)
+    if dtype != "float32":
+        net(nd.array(onp.ones((1, 2), onp.int32)),
+            nd.array(onp.ones((1, 2), onp.int32)))
+        net.cast(dtype)
+    S, L, M = 4, _NMT_L, 8
+    rs = onp.random.RandomState(len(case))
+
+    def prefilled(n):
+        src = nd.array(rs.randint(3, V, (n, M)), dtype="int32")
+        return net.init_cache(src, nd.array(rs.randint(2, M + 1, (n,)),
+                                            dtype="int32"), L, M)
+
+    cache = prefilled(S)
+    assert cache["k0"].shape[0] == S and cache["k0"].shape[2] == L
+    assert str(cache["k0"].dtype) == "float32"
+    assert str(cache["mem_k0"].dtype) == dtype
+    for name in [k for k in cache if k[0] in "kv"]:  # a running generation
+        cache[name] = nd.array(
+            rs.standard_normal(cache[name].shape).astype(onp.float32))
+    ref = dict(cache)
+    for n, pos in enumerate(_NMT_WRITES[case]):
+        if case == "rejoined" and n == 1:
+            row = prefilled(1)
+            for name in cache:
+                for c in (cache, ref):
+                    a = onp.array(c[name].asnumpy())
+                    a[2] = row[name].asnumpy()[0]
+                    c[name] = nd.array(a, dtype=a.dtype)
+        tok = nd.array(rs.randint(3, V, (S,)), dtype="int32")
+        pos = nd.array(onp.array(pos), dtype="int32")
+        logits, cache = net.decode_step(tok, pos, cache)
+        want, ref = _one_hot_step(net, tok, pos, ref)
+        assert set(cache) == set(ref)
+        for name in sorted(ref):
+            have, need = cache[name].asnumpy(), ref[name].asnumpy()
+            assert have.dtype == need.dtype and have.shape == need.shape
+            assert have.tobytes() == need.tobytes(), (name, n)
+        assert logits.asnumpy().tobytes() == want.asnumpy().tobytes()
+
+
 # -- join: indexed in-place write of one slot ---------------------------
 
 def _join_operands(eng, seed, cast_leaf=None):

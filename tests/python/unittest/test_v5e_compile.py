@@ -27,14 +27,44 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_for(eng, chip, slots, max_len, bucket, join=False):
+    """Compiles the engine's decode step (and its join) for the described
+    chip over `slots` rows of what a prefill of one `bucket`-long prompt
+    returns, JAX's persistent cache off.  Returns the abstract cache and
+    the compiled executables; the engine is closed."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+    ints = lambda *shape: sds(jnp.zeros(shape, jnp.int32))
+    params = {n: sds(v) for n, v in eng._params.items()}
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        pre = eng._prefill._jit.lower(params, ints(1, bucket), ints(1))
+        row = jax.tree_util.tree_map(sds, pre.out_info)
+        cache = {"m": {k: jax.ShapeDtypeStruct((slots,) + v.shape[1:],
+                                               v.dtype, sharding=chip)
+                       for k, v in row["m"].items()},
+                 "tok": ints(slots), "pos": ints(slots),
+                 "out": ints(slots, max_len)}
+        step = eng._decode._jit.lower(params, cache).compile()
+        if join:
+            join = eng._join._jit.lower(cache, row, ints()).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+        eng.close()
+    return cache, step, join
+
+
 def test_sparse_decoder_step_writes_its_cache_in_place(one_chip):
     """The decode step and the join of a SparseDecoder at the published
     widths (2 layers, 4 slots of 4096 rows): the donated cache comes back
     aliased, and the step's temporaries stay far below one cache leaf, so
     there is no cache-sized copy, gather or slice in the compiled program."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental.compilation_cache import compilation_cache as cc
     from incubator_mxnet_tpu.models.sparse_decoder import SparseDecoder
 
     S, L, layers = 4, 4096, 2
@@ -45,33 +75,50 @@ def test_sparse_decoder_step_writes_its_cache_in_place(one_chip):
     net.cast("bfloat16")
     eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
                            max_len=L, prompt_buckets=(2048,), queue_cap=4)
-    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-    params = {n: sds(v) for n, v in eng._params.items()}
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
-        pre = eng._prefill._jit.lower(
-            params, sds(jnp.zeros((1, 2048), jnp.int32)),
-            sds(jnp.zeros((1,), jnp.int32)))
-        row = jax.tree_util.tree_map(sds, pre.out_info)
-        cache = {"m": {k: jax.ShapeDtypeStruct((S,) + v.shape[1:], v.dtype,
-                                               sharding=one_chip)
-                       for k, v in row["m"].items()},
-                 "tok": sds(jnp.zeros((S,), jnp.int32)),
-                 "pos": sds(jnp.zeros((S,), jnp.int32)),
-                 "out": sds(jnp.zeros((S, L), jnp.int32))}
-        leaf = S * layers * 4 * L * 128 * 2              # k, or v: 33.5 MB
-        total = 2 * leaf + S * layers * L * 64 * 2
-        step = eng._decode._jit.lower(params, cache).compile() \
-            .memory_analysis()
-        join = eng._join._jit.lower(
-            cache, row, sds(jnp.zeros((), jnp.int32))).compile() \
-            .memory_analysis()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        cc.reset_cache()
+    _, step, join = _compile_for(eng, one_chip, S, L, 2048, join=True)
+    step, join = step.memory_analysis(), join.memory_analysis()
+    leaf = S * layers * 4 * L * 128 * 2              # k, or v: 33.5 MB
+    total = 2 * leaf + S * layers * L * 64 * 2
     assert step.alias_size_in_bytes >= total
     assert step.temp_size_in_bytes < leaf // 4, step.temp_size_in_bytes
     assert join.alias_size_in_bytes >= total
     assert join.temp_size_in_bytes < leaf // 4, join.temp_size_in_bytes
+
+
+def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
+    """The decode step of `transformer_nmt_base` at the benchmark cell's
+    widths (6 layers, 512 units, 8 heads; 64 slots of 128 rows): the
+    donated cache comes back aliased, the step's temporaries stay under a
+    quarter of one K leaf, and no copy, transpose or select in the
+    optimized program is as large as a cache leaf: each row is written
+    where it lies and attention reads the leaves as they lie."""
+    import re
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.models.transformer import transformer_nmt_base
+
+    S, L, V = 64, 128, 512
+    net = transformer_nmt_base(V, V, max_length=L, dropout=0.0)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    one = nd.array(onp.ones((1, 2), onp.int32), ctx=mx.cpu(0), dtype="int32")
+    net(one, one)                       # shapes are deferred until here
+    net.cast("bfloat16")
+    eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
+                           max_len=L, prompt_buckets=(L,), queue_cap=4)
+    cache, step, _ = _compile_for(eng, one_chip, S, L, L)
+    m = cache["m"]
+    assert m["k0"].dtype == jnp.float32 and m["mem_k0"].dtype == jnp.bfloat16
+    leaf = S * L * 512                                  # elements of a leaf
+    total = 6 * leaf * (2 * 4 + 2 * 2)                  # K/V f32, memory bf16
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= total
+    assert mem.temp_size_in_bytes < leaf * 4 // 4, mem.temp_size_in_bytes
+    # `%name = type[dims]{layout} op(`: results as large as a memory leaf
+    moved = []
+    for name, dims, op in re.findall(
+            r"%?([\w.\-]+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(", step.as_text()):
+        if op in ("copy", "transpose", "select") and \
+                onp.prod([int(n) for n in dims.split(",")]) >= leaf:
+            moved.append((op, name, dims))
+    assert not moved, moved
