@@ -629,13 +629,6 @@ def run_serve(n_images=512, max_batch=32, seed=0, extra=None):
     base_rate = n_images / (time.perf_counter() - t0)
 
     # ---- engine: warm every bucket, then a mixed-size request stream
-    def _stale_reasons():
-        # the labeled aot.stale reason counts (ISSUE 11 satellite):
-        # {reason: cumulative count} from the classifier's labelsets
-        return {row["labels"].get("reason", "?"): row["value"]
-                for row in events.labeled_snapshot().get("aot.stale",
-                                                         ())}
-    stale0 = _stale_reasons()
     eng = net.inference_engine(ctx=ctx, max_batch=max_batch,
                                queue_cap=max(64, n_images))
     warm = eng.warmup(example_shape=(3, 32, 32), wire_dtype="float32")
@@ -685,14 +678,6 @@ def run_serve(n_images=512, max_batch=32, seed=0, extra=None):
         "serve_traces_after_warmup_delta":
             events.get("serve.traces") - traces0,
     }
-    # the labeled stale-reason split (ISSUE 12 satellite): the
-    # BENCH_serve "aot.stale: 7" smoking gun becomes per-reason keys —
-    # 'stale' is a lower-better fragment, so bench_diff trends a
-    # reason-count increase as the regression it is
-    stale = {k: v - stale0.get(k, 0) for k, v in
-             _stale_reasons().items() if v - stale0.get(k, 0)}
-    out["serve_aot_stale_reasons"] = stale
-    out["serve_aot_stale_total"] = sum(stale.values())
     # counter/percentile snapshot block (ISSUE 4): bench runs double as
     # telemetry fixtures — teletop --file renders this, and the
     # BENCH_serve.json trajectory keeps the tails next to the rates
@@ -1057,11 +1042,6 @@ def run_generate(duration_s=5.0, capacity_s=1.5, hi_frac=0.2,
         "generate_cb_win": bool(
             cb["ttft_hi_p99_ms"] < dr["ttft_hi_p99_ms"]),
     })
-    # the aot load-path breaker verdict rides along (ISSUE 14
-    # satellite): a backend whose deserialize path is broken now says
-    # so once instead of a stale storm
-    out["generate_aot_load_disabled"] = \
-        events.get("aot.load_disabled") or 0
     achieved_2x = (cb["achieved_rps"] >= 1.3 * capacity
                    and dr["achieved_rps"] >= 1.3 * capacity)
     if achieved_2x:
@@ -1480,45 +1460,6 @@ def _fleet_straggler_proof(n_devices, inject_at=4, stale=6, steps=12):
     return out
 
 
-def _bench_prewarm_child():
-    """`--prewarm-child` body: one fresh process against the shared
-    AOT cache dir the parent passed via MXNET_AOT_CACHE_DIR — replay
-    the pre-warm manifest, then run two AOT-cached executables (the
-    cold invocation populates cache + manifest; the warm one must
-    load from disk with zero stale entries).  Prints ONE JSON line of
-    the aot/prewarm counters."""
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_enable_compilation_cache", False)
-    import jax.numpy as jnp
-    from incubator_mxnet_tpu import aot_cache
-    from incubator_mxnet_tpu.compile import prewarm
-    from incubator_mxnet_tpu.monitor import events
-
-    rep = prewarm.replay()
-
-    def mm(w, v):
-        return v @ w
-
-    def act(w, v):
-        return jnp.tanh(v @ w)
-
-    w = jnp.ones((256, 256), jnp.float32)
-    x = jnp.ones((8, 256), jnp.float32)
-    for label, fn in (("bench.prewarm.mm", mm),
-                      ("bench.prewarm.act", act)):
-        f = aot_cache.aot_jit(fn, label=label, kind="bench")
-        jax.block_until_ready(f(w, x))
-    print(json.dumps({
-        "aot_hit": events.get("aot.hit"),
-        "aot_miss": events.get("aot.miss"),
-        "aot_stale": events.get("aot.stale"),
-        "aot_load_disabled": events.get("aot.load_disabled"),
-        "prewarm_hits": rep.get("hits", 0),
-        "prewarm_missing": rep.get("missing", 0),
-        "manifest_entries": rep.get("entries", 0)}))
-
-
 def _compile_loop_proof(n_devices):
     """ISSUE 18 acceptance, measured: (1) lax.scan layer-stacking
     collapses N per-layer executables into one with compile-wall AND
@@ -1526,10 +1467,7 @@ def _compile_loop_proof(n_devices):
     autotuner's bucket cap beats `costs.suggest_bucket_mb` on >= 2
     mesh configs by measured step wall (the probes this sweep writes
     ARE the evidence the tuner reads back — the loop, closed in one
-    run); (3) a fresh process warm-starts from the pre-warm manifest
-    with aot stale=0."""
-    import shutil
-    import subprocess
+    run)."""
     import tempfile
     import jax as _j
     import jax.numpy as jnp
@@ -1662,47 +1600,9 @@ def _compile_loop_proof(n_devices):
                        "configs_beating_heuristic": beats}
     tune_ok = beats >= 2
 
-    # -- (3) manifest warm-start: two fresh child processes share one
-    # AOT cache dir; the warm one must replay the manifest and load
-    # every executable from disk (stale=0)
-    cache = tempfile.mkdtemp(prefix="mxtpu-bench-prewarm-")
-    try:
-        env = dict(os.environ, MXNET_AOT_CACHE_DIR=cache,
-                   JAX_PLATFORMS="cpu", MXNET_PREWARM="1")
-        env.pop(_MULTICHIP_CHILD_MARK, None)
-        cmd = [sys.executable, os.path.abspath(__file__),
-               "--prewarm-child"]
-        here = os.path.dirname(os.path.abspath(__file__))
-        runs = []
-        for _ in range(2):
-            res = subprocess.run(cmd, capture_output=True, text=True,
-                                 timeout=300, env=env, cwd=here)
-            line = next((ln for ln in reversed(
-                (res.stdout or "").strip().splitlines())
-                if ln.startswith("{")), None)
-            if line is None:
-                raise RuntimeError("prewarm child rc=%d: %s"
-                                   % (res.returncode,
-                                      (res.stderr or "")[-200:]))
-            runs.append(json.loads(line))
-        cold, warm = runs
-        out["prewarm"] = {"cold": cold, "warm": warm}
-        warm_ok = bool(warm["aot_stale"] == 0 and warm["aot_hit"] > 0
-                       and warm["prewarm_hits"] > 0
-                       and warm["manifest_entries"] > 0)
-        if warm["aot_load_disabled"] > 0:
-            # PR 7 jaxlib load breaker: an environment waiver, the
-            # check_feed/fleet-trace convention
-            out["prewarm"]["waived_host"] = "aot load breaker tripped"
-            warm_ok = None
-    finally:
-        shutil.rmtree(cache, ignore_errors=True)
-
     out["stacking_ok"] = stack_ok
     out["autotune_ok"] = tune_ok
-    out["prewarm_ok"] = warm_ok
-    out["ok"] = bool(stack_ok and tune_ok
-                     and warm_ok is not False)
+    out["ok"] = bool(stack_ok and tune_ok)
     return out
 
 
@@ -1943,7 +1843,6 @@ def _write_multichip_scaling(parsed, rc=0):
     comp = parsed.get("compile", {})
     cstack = comp.get("stacking", {})
     ctune = comp.get("autotune", {})
-    cwarm = (comp.get("prewarm") or {}).get("warm", {})
     tail = ("multichip scaling: weak_eff=%.2f (legacy %.2f, %.1fx) "
             "zero=%s sched=%s buckets cap=%.1fMB zero3 param "
             "bytes/replica=%.0f%% of unsharded, %d collective rows, "
@@ -1952,8 +1851,7 @@ def _write_multichip_scaling(parsed, rc=0):
             "say slow@step%s), trace merge %s proc / steps %s -> %s\n"
             "compile: stack %s exes -> %s (compile wall %.2fs -> "
             "%.2fs, dispatch %sus -> %sus, parity %s), tuner beat "
-            "heuristic on %s/2 cfgs, warm-start stale=%s "
-            "prewarm_hits=%s -> %s\n"
+            "heuristic on %s/2 cfgs -> %s\n"
             % (eff, eff_l, parsed.get("weak_eff_gain", 0.0),
                parsed.get("zero_level"),
                parsed.get("overlap_schedule"),
@@ -1976,8 +1874,6 @@ def _write_multichip_scaling(parsed, rc=0):
                cstack.get("dispatch_stacked_us", "?"),
                cstack.get("parity_ok", "?"),
                ctune.get("configs_beating_heuristic", 0),
-               cwarm.get("aot_stale", "?"),
-               cwarm.get("prewarm_hits", "?"),
                "ok" if comp.get("ok") else "FAILED"))
     blob = {"n_devices": parsed.get("multichip_devices", 0), "rc": rc,
             "ok": (rc == 0 and exercised and improved
@@ -3221,7 +3117,7 @@ def _cfg_resnet():
     extra = {}
     imgs, batch = _try_batches(run_cachedop, (128, 64, 32), extra=extra)
     extra.update({"value": round(imgs, 2), "batch": batch})
-    # feed./train./aot. counter+tail snapshot of this config's process
+    # feed./train. counter+tail snapshot of this config's process
     # (ISSUE 4): the e2e feed counters above are deltas, this is the
     # whole-ledger block teletop --file renders
     try:
@@ -3568,11 +3464,6 @@ if __name__ == "__main__":
     if len(sys.argv) >= 2 and sys.argv[1] == "--multichip-child":
         # marked child of run_multichip (same virtual-platform recipe)
         _multichip_scenario(int(sys.argv[2]))
-        sys.exit(0)
-    if len(sys.argv) >= 2 and sys.argv[1] == "--prewarm-child":
-        # fresh-process warm-start probe against the shared AOT cache
-        # dir in MXNET_AOT_CACHE_DIR (ISSUE 18 compile proof)
-        _bench_prewarm_child()
         sys.exit(0)
     if len(sys.argv) >= 2 and sys.argv[1] == "quant":
         # standalone quant bench (ISSUE 15): ONE JSON line; quant_*

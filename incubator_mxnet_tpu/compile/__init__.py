@@ -5,7 +5,7 @@ The repo measures everything about its executables — per-executable
 flops/bytes/compile-wall in the cost registry (ISSUE 5), persisted
 across runs by the durable history (ISSUE 12) — and this package is
 where those measurements steer compilation instead of just describing
-it.  Three cooperating parts:
+it.  Two cooperating parts:
 
 - :mod:`~incubator_mxnet_tpu.compile.autotune` — a search over the
   knobs that shape executables (ZeRO bucket cap, batch size,
@@ -20,14 +20,9 @@ it.  Three cooperating parts:
   ``lax.scan`` over stacked parameters, with a bit-parity oracle
   against the unstacked path and measured compile-wall/dispatch
   deltas.
-- :mod:`~incubator_mxnet_tpu.compile.prewarm` — a persistent
-  cross-process manifest of (label, signature) pairs written at
-  warmup/bench/test time, replayed through the existing ``aot_cache``
-  disk path so later processes (serving warmup, bench, tests) pay no
-  cold compiles before first traffic.
 """
 from __future__ import annotations
 
-from . import autotune, prewarm, stacking  # noqa: F401
+from . import autotune, stacking  # noqa: F401
 
-__all__ = ["autotune", "prewarm", "stacking"]
+__all__ = ["autotune", "stacking"]

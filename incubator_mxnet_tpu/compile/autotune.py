@@ -467,18 +467,12 @@ def decisions():
 
 
 def block():
-    """The blackbox ``autotune`` block: decisions + the pre-warm
-    manifest activity (None when nothing happened — dump_blackbox
-    drops empty blocks)."""
+    """The blackbox ``autotune`` block: the decisions (None when
+    nothing happened — dump_blackbox drops empty blocks)."""
     decs = decisions()
-    try:
-        from . import prewarm as _pw
-        pw = _pw.stats()
-    except Exception:               # noqa: BLE001
-        pw = {}
-    if not decs and not any(pw.values()):
+    if not decs:
         return None
-    return {"decisions": decs, "prewarm": pw}
+    return {"decisions": decs}
 
 
 def reset():

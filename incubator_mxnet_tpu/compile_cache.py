@@ -3,9 +3,9 @@
 One rule, for every entry point that compiles (chip_smoke.py, bench.py):
 the cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, and otherwise
 to ``<checkout>/.jax_cache`` — a fixed, gitignored path, because the
-directory is part of what a later process must find again.  No other
-cache directory is set in code (``MXNET_AOT_CACHE_DIR`` has no
-default), and nothing here runs at package import: an entry point
+directory is part of what a later process must find again.  It is the
+package's only cache of compiled code: no other directory is set
+anywhere, and nothing here runs at package import — an entry point
 calls `enable()` before its first compile.
 
 `enable()` also puts compile time into the phase log

@@ -184,11 +184,6 @@ register("MXNET_USE_PALLAS", str, "1",
          "bytes), 2=always", choices=("0", "1", "2"))
 register("MXNET_PALLAS_INTERPRET", bool, False,
          "Run Pallas kernels in interpret mode (CPU debugging)")
-register("MXNET_AOT_CACHE_DIR", str, "",
-         "Directory for serialized compiled executables (aot_cache."
-         "aot_jit): fresh processes deserialize instead of recompiling "
-         "— built for an earlier setup where JAX's persistent cache "
-         "did not engage. Empty = off (no default is set anywhere)")
 register("MXNET_FLASH_BLOCK_Q", int, 0,
          "Flash-attention Q block size (0 = auto)")
 register("MXNET_FLASH_BLOCK_K", int, 0,
@@ -434,11 +429,6 @@ register("MXNET_ELASTIC_MIN_REPLICAS", int, 1,
          "ElasticTrainer: smallest mesh the supervisor will shrink to; "
          "losing a replica below this floor is a hard error (the job "
          "cannot meaningfully continue)")
-register("MXNET_AOT_CACHE_MAX", int, 0,
-         "aot_cache: max on-disk serialized executables; older entries "
-         "(by mtime; cache hits refresh it, so this is keep-K LRU) are "
-         "evicted after each store. 0 = unbounded (training default; "
-         "long-lived serving hosts should bound it)")
 register("MXNET_BN_STABLE_VAR", bool, False,
          "BatchNorm batch statistics: 1 = shifted two-pass variance "
          "E[(x-mean)^2] (numerically safe when |mean| >> std, e.g. f32 "
@@ -507,15 +497,6 @@ register("MXNET_AUTOTUNE", bool, True,
          "typed autotune/decision records (ring event + history row + "
          "blackbox block).  0 = every suggest_* returns its fallback "
          "(the pre-ISSUE-18 heuristics) and records nothing")
-register("MXNET_PREWARM", bool, True,
-         "Pre-warm manifest (compile/prewarm.py): record every "
-         "successful AOT compile-or-load as a (label, blob) line in "
-         "prewarm-manifest.jsonl inside MXNET_AOT_CACHE_DIR, plus "
-         "serving warmup signatures, so later processes replay the "
-         "manifest (mtime-refresh hit semantics; eviction protects "
-         "listed blobs) and serving warmup recovers its example "
-         "signature with no operator input.  Requires the AOT cache "
-         "dir; 0 = manifest neither written nor read")
 register("MXNET_ZERO_SOLO_KB", int, 256,
          "Param size in KB above which a param with a data-divisible "
          "axis gets its OWN reduce-scatter along that axis (no "
@@ -557,8 +538,8 @@ register("MXNET_STRAGGLER_SIGMA", float, 4.0,
 register("MXNET_FLEET_PUBLISH_STEPS", int, 1,
          "Fleet telemetry publish cadence: every N supervised steps "
          "each replica pushes its compact snapshot (step time, "
-         "dispatch/collective walls, HBM watermark, aot hit/miss/"
-         "stale) through the kvstore at __mesh__/telemetry/<rid> for "
+         "dispatch/collective walls, HBM watermark) "
+         "through the kvstore at __mesh__/telemetry/<rid> for "
          "rank 0 to merge into the FleetView.  0 disables fleet "
          "publishing/straggler detection")
 register("MXNET_HISTORY_DIR", str, "",

@@ -37,6 +37,7 @@ from ..ndarray.ndarray import NDArray, apply_fn
 from ..ops import registry as _registry
 from .. import autograd as _ag
 from .. import random as _rnd
+from ..telemetry import costs as _costs
 from .parameter import (Parameter, ParameterDict,
                         DeferredInitializationError)
 
@@ -167,13 +168,12 @@ def _param_symbol(param):
 
 def _batch_cast_params(pd, dtype):
     """Convert every initialized parameter to `dtype` in ONE jitted
-    (and AOT-disk-cached) executable.  The per-param eager astype it
-    replaces costs one compile per distinct shape (batched on an
-    earlier setup where each cost seconds; not re-measured on this
-    chip)."""
+    executable.  The per-param eager astype it replaces costs one
+    compile per distinct shape (batched on an earlier setup where each
+    cost seconds; not re-measured on this chip)."""
+    import jax
     import jax.numpy as jnp
     from collections import OrderedDict
-    from ..aot_cache import aot_jit
     tgt = jnp.dtype(dtype)
     # grouped by context: one batched convert EXECUTABLE PER DEVICE —
     # mixing leaves committed to different devices in one jit call is a
@@ -196,7 +196,7 @@ def _batch_cast_params(pd, dtype):
     touched = []
     for ctx, ps in groups.items():
         leaves = tuple(p._data[ctx]._data for p in ps)
-        outs = aot_jit(convert)(*leaves)
+        outs = jax.jit(convert)(*leaves)
         for p, o in zip(ps, outs):
             p._data[ctx] = NDArray(o, ctx=ctx)
         touched.extend(ps)
@@ -553,9 +553,8 @@ class _FusedProgram:
 
         def fwd(*leaves):
             return jax.vjp(raw, *leaves)
-        from ..aot_cache import aot_jit
-        self.fwd_jit = aot_jit(fwd, label="gluon.fused_fwd_vjp",
-                               kind="train")
+        self.fwd_jit = _costs.metered_jit(
+            fwd, label="gluon.fused_fwd_vjp", kind="train")
         self.keep = keep
         self.n_net = n_net_leaves
         self.n_loss = n_loss
@@ -938,8 +937,7 @@ class _CachedGraph:
         def fwd(*leaves):
             outs, vjp_fn = jax.vjp(pure_flat, *leaves)
             return outs, vjp_fn
-        from ..aot_cache import aot_jit
-        self._jit_fwdvjp[fkey] = aot_jit(
+        self._jit_fwdvjp[fkey] = _costs.metered_jit(
             fwd, label=self.block.name + ".fwd_vjp", kind="train")
         return self._jit_fwdvjp[fkey]
 
@@ -1002,8 +1000,7 @@ class _CachedGraph:
                     training, np_, ni_)(*leaf_data)
             else:
                 if fkey not in self._jitted:
-                    from ..aot_cache import aot_jit
-                    self._jitted[fkey] = aot_jit(
+                    self._jitted[fkey] = _costs.metered_jit(
                         self._get_flat(training, np_, ni_),
                         label=self.block.name + ".fwd", kind="infer")
                 result = self._jitted[fkey](*leaf_data)
@@ -1253,7 +1250,7 @@ class HybridBlock(Block):
 
     def inference_engine(self, **kwargs):
         """Build a `serving.InferenceEngine` over this block: concurrent
-        request API, shape-bucketed dynamic batching, AOT-warmed
+        request API, shape-bucketed dynamic batching, pre-compiled
         executables (ISSUE 3).  Any installed `set_input_transform`
         (e.g. `io.device_feed.normalize_transform`) is traced into every
         bucket executable, so uint8-on-wire inference matches the

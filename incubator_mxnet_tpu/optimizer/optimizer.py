@@ -200,11 +200,11 @@ def _build_train_step(raw, opname, static_kv, nparam, nstates, gidx,
     # donate the parameter leaves (updated in place) and the optimizer
     # states; NOT the input/cotangent leaves (reused across steps)
     donate = tuple(gidx) + (n_leaves + 1,)
-    from ..aot_cache import aot_jit
+    from ..telemetry import costs as _costs
     # the fused imperative train step (fwd+vjp+update, ONE program) —
     # the headline row in the cost registry's train family
-    return aot_jit(f, donate_argnums=donate,
-                   label="gluon.train_step", kind="train")
+    return _costs.metered_jit(f, donate_argnums=donate,
+                              label="gluon.train_step", kind="train")
 
 
 def _train_step_dispatch(prod, pending, opname, static_kv, weights,

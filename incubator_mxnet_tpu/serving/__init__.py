@@ -1,5 +1,5 @@
 """Inference serving subsystem (ISSUE 3 + ISSUE 8): shape-bucketed
-dynamic batching over AOT-warmed executables, hardened for sustained
+dynamic batching over pre-compiled executables, hardened for sustained
 multi-tenant overload — the deploy-side counterpart of the resilient
 trainer (PR 1) and the async device feed (PR 2).
 

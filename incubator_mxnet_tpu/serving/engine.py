@@ -1,5 +1,5 @@
 """Inference serving engine: shape-bucketed dynamic batching over
-AOT-warmed executables (ISSUE 3 tentpole).
+pre-compiled executables (ISSUE 3 tentpole).
 
 The ROADMAP north star is "heavy traffic from millions of users", and
 the serving-side analogue of the training recompilation problem is the
@@ -14,10 +14,10 @@ center on).  The engine closes the executable set instead:
    default 1,2,4,...,`MXNET_SERVE_MAX_BATCH`) and padded up to the
    bucket size, so the set of compiled executables is CLOSED and
    known in advance.
-2. **AOT warm.**  `warmup()` pre-compiles every (device, bucket)
-   executable before traffic, through `aot_cache.aot_jit` — with
-   `MXNET_AOT_CACHE_DIR` set, a restarted serving host deserializes
-   the whole executable set from disk instead of recompiling.
+2. **Warm-up.**  `warmup()` compiles every (device, bucket)
+   executable before traffic (`telemetry.costs.metered_jit`); where
+   `compile_cache.enable()` has turned JAX's persistent compilation
+   cache on, a restarted serving host loads them from it.
    `serve.traces` counts executable traces; it stays FLAT
    after warmup under mixed-size traffic — the zero-recompile
    contract `bench.py serve` asserts.
@@ -97,6 +97,7 @@ from .. import fault
 from ..base import MXNetError
 from ..context import Context, current_context
 from ..monitor import events
+from ..telemetry import costs as _costs
 from ..telemetry import flightrec as _bb
 from ..telemetry import reqtrace as _reqtrace
 from ..telemetry import spans as _tele
@@ -479,7 +480,6 @@ class InferenceEngine:
 
     # -- executable construction ---------------------------------------
     def _make_infer(self):
-        from ..aot_cache import aot_jit
         from ..ndarray.ndarray import NDArray
         pure = self._pure
         block = self._block
@@ -503,8 +503,8 @@ class InferenceEngine:
         # ModelRegistry passes serve.infer:<model> so admission can
         # find THIS model's measured footprint) — the per-bucket
         # FLOPs/HBM attribution the blackbox dump reports
-        return aot_jit(infer, label=self._cost_label, kind="serve",
-                       role="serve_infer")
+        return _costs.metered_jit(infer, label=self._cost_label,
+                                  kind="serve", role="serve_infer")
 
     def refresh_params(self):
         """(Re-)replicate the block's current parameters onto every
@@ -1327,38 +1327,17 @@ class InferenceEngine:
 
     # -- warmup --------------------------------------------------------
     def warmup(self, example_shape=None, wire_dtype=None):
-        """Pre-compile (or AOT-deserialize) EVERY (device, bucket)
-        executable before traffic, so no organic request ever pays a
-        compile.  Needs the example signature — from the constructor,
-        a prior request, or the arguments here.  Returns a summary
-        dict; after it, `serve.traces` stays flat under any mix of
-        request sizes ≤ the largest bucket."""
+        """Compile EVERY (device, bucket) executable before traffic,
+        so no organic request ever pays a compile.  Needs the example
+        signature — from the constructor, a prior request, or the
+        arguments here.  Returns a summary dict; after it,
+        `serve.traces` stays flat under any mix of request sizes ≤ the
+        largest bucket."""
         if self._example_shape is None and example_shape is None:
-            # pre-warm manifest (ISSUE 18): a previous process that
-            # warmed this cost label recorded its signature — replay
-            # it so a fresh serving host warms with no operator input
-            # (and its bucket executables resolve straight off the
-            # shared AOT disk cache, stale=0)
-            hint = None
-            try:
-                from ..compile import prewarm as _prewarm
-                hint = _prewarm.serve_hint(self._cost_label)
-            except Exception:       # noqa: BLE001 — the manifest is
-                hint = None         # advisory, never a blocker
-            if hint and hint.get("example_shape") is not None:
-                example_shape = tuple(hint["example_shape"])
-                wire_dtype = wire_dtype or hint.get("wire_dtype")
-                events.incr("serve.warmup_from_manifest")
-                _bb.record("serve", "warmup_manifest",
-                           label=self._cost_label,
-                           shape=str(example_shape),
-                           dtype=str(wire_dtype))
-            else:
-                raise ValueError(
-                    "warmup() before any request needs example_shape= "
-                    "(and wire_dtype=) — the executable signature "
-                    "(no pre-warm manifest entry for label %r either)"
-                    % self._cost_label)
+            raise ValueError(
+                "warmup() of %r before any request needs example_shape= "
+                "(and wire_dtype=) — the executable signature"
+                % self._cost_label)
         # route through the SAME signature gate as submits: a warmup
         # conflicting with an already-locked shape/dtype must raise,
         # not silently re-point the executable set away from traffic
@@ -1368,14 +1347,6 @@ class InferenceEngine:
             wire_dtype or self._wire_dtype or "float32")
         dtype = _np.dtype(self._wire_dtype)
         t0 = time.monotonic()
-        try:
-            # refresh the manifest-listed blobs' LRU credit before the
-            # loads below (hit semantics, ISSUE 18) — a long-lived
-            # host's keep-K trim must not evict the warm set first
-            from ..compile import prewarm as _prewarm
-            _prewarm.replay(label_prefix=self._cost_label)
-        except Exception:           # noqa: BLE001
-            _prewarm = None
         per_bucket = {}
         try:
             # the deterministic OOM drill: the serve.oom fault site
@@ -1414,15 +1385,6 @@ class InferenceEngine:
                     source="serve.warmup", devices=len(self._ctxs))
         except Exception:           # noqa: BLE001 — evidence is
             pass                    # advisory, never blocks warmup
-        if _prewarm is not None:
-            try:
-                # durably record THIS warmup's signature so the next
-                # process can warm from the manifest alone
-                _prewarm.note_serve(self._cost_label,
-                                    self._example_shape,
-                                    self._wire_dtype, self._buckets)
-            except Exception:       # noqa: BLE001
-                pass
         return {"buckets": list(self._buckets),
                 "devices": len(self._ctxs),
                 "wall_s": round(time.monotonic() - t0, 3),

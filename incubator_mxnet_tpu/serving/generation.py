@@ -12,8 +12,9 @@ of traffic, and every piece of dynamic behavior — who is in the batch,
 at what length, with what prompt — is expressed as DATA flowing
 through fixed-shape executables, never as shapes that would retrace.
 
-**Executables** (all AOT-warmed through `aot_cache.aot_jit`, recompiles
-metered on `serve.traces` exactly like the one-shot engine):
+**Executables** (all compiled by `warmup()` through
+`telemetry.costs.metered_jit`, recompiles metered on `serve.traces`
+exactly like the one-shot engine):
 
 1. ``prefill`` — one signature per power-of-two PROMPT bucket
    (`MXNET_GEN_BUCKETS`): encode the padded prompt, produce one slot's
@@ -133,6 +134,7 @@ from .. import config as _cfg
 from .. import fault
 from ..context import Context, current_context
 from ..monitor import events
+from ..telemetry import costs as _costs
 from ..telemetry import flightrec as _bb
 from ..telemetry import reqtrace as _reqtrace
 from ..telemetry import spans as _tele
@@ -526,7 +528,6 @@ class GenerationEngine:
     def _build_executables(self):
         import jax
         import jax.numpy as jnp
-        from ..aot_cache import aot_jit
         from ..parallel.functional import extract_params
         block = self._block
         L = self._L
@@ -590,21 +591,21 @@ class GenerationEngine:
                     "out": put(cache["out"],
                                jnp.full((1, L), eos, jnp.int32))}
 
-        # prefill: one signature per prompt bucket, AOT-warmed; decode
+        # prefill: one signature per prompt bucket, warmed; decode
         # and join donate the cache — the PR 10 audit arms the
         # donation contract at build time, the runtime probe below
         # proves no silent copy on the live path
         # the role names the executable, whatever the user's cost label
-        self._prefill = aot_jit(prefill, label=self._label + ":prefill",
-                                kind="serve", role="gen_prefill")
-        self._decode = aot_jit(decode_step, donate_argnums=(1,),
-                               label=self._label + ":decode_step",
-                               kind="serve", expect_donated=(1,),
-                               role="gen_decode")
-        self._join = aot_jit(join, donate_argnums=(0,),
-                             label=self._label + ":join",
-                             kind="serve", expect_donated=(0,),
-                             role="gen_join")
+        self._prefill = _costs.metered_jit(
+            prefill, label=self._label + ":prefill", kind="serve",
+            role="gen_prefill")
+        self._decode = _costs.metered_jit(
+            decode_step, donate_argnums=(1,),
+            label=self._label + ":decode_step", kind="serve",
+            expect_donated=(1,), role="gen_decode")
+        self._join = _costs.metered_jit(
+            join, donate_argnums=(0,), label=self._label + ":join",
+            kind="serve", expect_donated=(0,), role="gen_join")
         dev = self._ctx.jax_device
         self._params = {n: jax.device_put(v, dev)
                         for n, v in extract_params(block).items()}
@@ -660,7 +661,7 @@ class GenerationEngine:
 
     # -- warmup ---------------------------------------------------------
     def warmup(self):
-        """Pre-compile (or AOT-deserialize) the WHOLE executable set:
+        """Compile the WHOLE executable set before traffic:
         one prefill per prompt bucket, the join, and the (S, max_len)
         decode step — after it `serve.traces` stays flat under any mix
         of prompt lengths and batch membership (the zero-recompile

@@ -23,7 +23,7 @@ arithmetic is BEFORE the buckets are traced, not inside them.
    the fleet-capacity story, not just the latency one.
 
 The returned block then goes through the SAME `InferenceEngine` /
-`ModelRegistry` paths as any f32 model: `warmup()` traces/AOT-warms
+`ModelRegistry` paths as any f32 model: `warmup()` compiles
 the power-of-two buckets, `serve.traces` stays flat under organic
 traffic, and `warmup()`→`reconcile()` swaps the int8 projection for
 the measured memory-analysis rows.

@@ -36,7 +36,7 @@ replace when a run dies:
   rollback/preemption/uncaught exceptions/SIGUSR2 or an explicit
   `telemetry.dump_blackbox()` (`MXNET_BLACKBOX=0` disarms).
 - `telemetry.costs` — the per-executable FLOPs/HBM cost registry every
-  jitted executable (aot_cache, fused imperative step, trainer steps,
+  jitted executable (fused imperative step, trainer steps,
   serving buckets) reports into.
 
 `python -m incubator_mxnet_tpu.tools.blackbox <dump>` summarizes a
@@ -89,10 +89,9 @@ __all__ = ["SpanContext", "TraceContext", "span", "current", "enable",
            "install_crash_hooks"]
 
 #: counter families the condensed snapshot (bench.py JSON) carries
-SNAPSHOT_PREFIXES = ("serve.", "feed.", "train.", "aot.",
-                     "resilience.", "mem.", "fault.", "blackbox.",
-                     "mesh.", "fleet.", "slo.", "history.",
-                     "memwatch.")
+SNAPSHOT_PREFIXES = ("serve.", "feed.", "train.", "resilience.",
+                     "mem.", "fault.", "blackbox.", "mesh.", "fleet.",
+                     "slo.", "history.", "memwatch.")
 
 _exporter = None
 

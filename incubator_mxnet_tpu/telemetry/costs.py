@@ -7,28 +7,28 @@ hardware was ASKED to do.  XLA exposes exactly that per executable —
 (argument/output/temp/alias bytes) — and the repo already touches the
 surface per-op (ndarray.py:77) but never aggregates it.  This registry
 is the aggregation point: every jitted executable the framework builds
-(the aot_cache entries, the fused imperative train step in
-gluon/block.py + optimizer.py, ShardedTrainer/ResilientTrainer steps,
-the serving bucket executables) registers one row per input signature,
-and every call bumps the row's invocation count — so a blackbox dump
-or a `/metrics` scrape can say "this run spent N invocations × M
-GFLOPs on `resilient.gstep`, and the serving buckets held K bytes of
-HBM".
+(the fused imperative train step in gluon/block.py + optimizer.py,
+ShardedTrainer/ResilientTrainer steps, the serving bucket executables)
+registers one row per input signature, and every call bumps the row's
+invocation count — so a blackbox dump or a `/metrics` scrape can say
+"this run spent N invocations × M GFLOPs on `resilient.gstep`, and the
+serving buckets held K bytes of HBM".
 
 Two registration paths:
 
-- `note_executable(...)` — the aot_cache path: a `Lowered` and/or
-  `Compiled` is already in hand, analysis is extracted eagerly (no
-  extra work was done to get it).
-- `metered_jit(fn, ...)` — the plain-jit path (ShardedTrainer /
-  ResilientTrainer steps, aot_jit's no-cache-dir fallback).  New
-  signatures are detected by a trace-time hook (a jit cache hit never
-  runs the python body — the `train.traces` pattern), which captures
-  the tracer avals and files a PENDING row; `table()`/`totals()`
-  resolve pending rows by lowering against the stored avals — off the
-  hot path, and (because jit shares its trace cache with `.lower()`)
-  usually without re-tracing.  The steady-state call pays two int
-  compares and one locked counter bump, never a pytree flatten.
+- `note_executable(...)` — for a caller that holds a `Lowered` and/or
+  `Compiled` already: analysis (memory analysis included) is extracted
+  eagerly.
+- `metered_jit(fn, ...)` — how every labelled executable of the
+  package is built (Gluon's CachedOp and fused train step, the trainer
+  steps, the serving and generation engines).  New signatures are
+  detected by a trace-time hook (a jit cache hit never runs the python
+  body — the `train.traces` pattern), which captures the tracer avals
+  and files a PENDING row; `table()`/`totals()` resolve pending rows
+  by lowering against the stored avals — off the hot path, and
+  (because jit shares its trace cache with `.lower()`) usually without
+  re-tracing.  The steady-state call pays two int compares and one
+  locked counter bump, never a pytree flatten.
 
 Both guards: `cost_analysis()`/`memory_analysis()` returning None or
 raising degrades to a row with the
@@ -305,11 +305,12 @@ def footprint_bytes(label_prefix, kind=None):
     + temp bytes from XLA's memory_analysis.  Buckets of one serving
     model share parameters, so the max row — the largest bucket — IS
     the family's working set.  Rows are labeled `<family>[<idx>]`
-    (aot_cache appends the signature ordinal), so the match is exact
+    (`MeteredJit` appends the signature ordinal), so the match is exact
     up to the '[' delimiter — plain startswith would let model
     'ranker' read model 'ranker2's footprint.  Returns 0 when no
-    matching row carries memory fields (plain-jit rows resolve
-    cost_analysis only; admission then falls back to projection)."""
+    matching row carries memory fields (`MeteredJit` rows resolve
+    cost_analysis only, ROADMAP R5; admission then falls back to
+    projection)."""
     best = 0
     for r in table():
         if kind is not None and r.get("kind") != kind:
@@ -412,8 +413,7 @@ def _audit_donation(label, donate_argnums, expect_donated):
 
 
 class MeteredJit:
-    """`jax.jit` + cost-row registration + invocation counting for the
-    plain-jit executables (no aot_cache involved).
+    """`jax.jit` + cost-row registration + invocation counting.
 
     Hot-path contract (the check_overhead.py gate): NO per-call
     signature computation.  New input signatures are detected by a
@@ -439,12 +439,15 @@ class MeteredJit:
         self._tls = threading.local()
 
         def hooked(*a):
-            # trace-time only: a jit cache hit never runs this
+            # trace-time only: a jit cache hit never runs this.  The
+            # avals are filed once `fn` has traced: a trace that raises
+            # leaves nothing for the next call to register
+            out = fn(*a)
             if not getattr(self._tls, "resolving", False):
                 self._pending.append(jax.tree_util.tree_map(
                     lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype),
                     a))
-            return fn(*a)
+            return out
 
         self._jit = jax.jit(traced_as(hooked, self._label, role),
                             donate_argnums=donate_argnums)

@@ -13,9 +13,9 @@ shares, the kvstore:
 - **`FleetReporter`** — one replica's side: every
   ``MXNET_FLEET_PUBLISH_STEPS`` steps it pushes a compact fixed-schema
   float64 vector (step id, step/dispatch/collective/data-wait µs, HBM
-  watermark, aot hit/miss/stale, skipped steps) to
+  watermark, skipped steps) to
   ``__mesh__/telemetry/<rid>`` — the same channel and pattern as the
-  elastic heartbeats, a dozen floats per publish, AFTER the step's
+  elastic heartbeats, nine floats per publish, AFTER the step's
   async dispatch returns.  Cost, measured on the 2-core dev box:
   ~0.65 ms per replica-publish (the kvstore's device_put round
   trip), so ~5 ms/step for the 8-replica single-controller
@@ -80,13 +80,12 @@ def robust_threshold(values, sigma, rel_floor=0.5):
                      float(rel_floor) * med)
 
 #: the fixed wire schema: one float64 per field, in this order.  A
-#: fixed schema (not pickles) keeps the payload a dozen numbers, makes
+#: fixed schema (not pickles) keeps the payload nine numbers, makes
 #: it language/version-agnostic, and lets the kvstore treat it as any
 #: other array key.
 FIELDS = ("step", "step_us", "dispatch_us", "collective_us",
-          "data_wait_us", "hbm_peak_bytes", "aot_hit", "aot_miss",
-          "aot_stale", "steps_skipped", "feed_stall_us",
-          "decode_batches")
+          "data_wait_us", "hbm_peak_bytes", "steps_skipped",
+          "feed_stall_us", "decode_batches")
 
 _KEY = "__mesh__/telemetry/%d"
 
@@ -102,9 +101,6 @@ def _counter_sample():
     the denominators are known)."""
     return {
         "hbm_peak_bytes": max(_bb.hbm_peaks().values(), default=0),
-        "aot_hit": events.get("aot.hit"),
-        "aot_miss": events.get("aot.miss"),
-        "aot_stale": events.get("aot.stale"),
         "steps_skipped": events.get("train.steps_skipped"),
         "feed_stall_us": events.get("feed.stall_us"),
         "decode_batches": events.get("io.decode.batches"),
@@ -289,7 +285,7 @@ class FleetTelemetry:
     ``fleet.step_us`` summary rings (the Prometheus children), run the
     detector, and return the straggler rids.  Publishing happens after
     the step's async dispatch has returned — the device is already
-    busy; the host-side cost is a dozen-float kvstore push per
+    busy; the host-side cost is a nine-float kvstore push per
     replica."""
 
     def __init__(self, kv, n_replicas: int, window=None, sigma=None,

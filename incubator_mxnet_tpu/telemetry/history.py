@@ -31,9 +31,8 @@ Model:
   cost       one row per cost-registry executable whose invocation
              count moved: ``flops``/``bytes_accessed``/``invocations``
              /``compile_wall_s`` (+ memory-analysis bytes when
-             present), ``v`` = invocations.  These rows — including
-             the ``aot.*`` compile/load walls riding the counter rows
-             — are the persisted measured-cost substrate the ROADMAP
+             present), ``v`` = invocations.  These rows are the
+             persisted measured-cost substrate the ROADMAP
              item 2 autotuner trains on.
   fleet      one row per replica from the rank-0 FleetView merge
              (``labels={"replica": rid}``, the FIELDS vector inlined,

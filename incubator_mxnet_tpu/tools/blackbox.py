@@ -320,10 +320,6 @@ def suspected_cause(doc: dict) -> str:
     if stall and step and stall > step:
         return ("input-pipeline starvation: feed stalls (%.1fs) exceed "
                 "compute wall between batches" % (stall / 1e6))
-    stale = c.get("aot.stale", 0) + c.get("aot.miss", 0)
-    if stale and stale > 2 * max(1, c.get("aot.hit", 0)):
-        return ("recompile storm: %d compile/stale executable-cache "
-                "events vs %d hits" % (stale, c.get("aot.hit", 0)))
     if c.get("serve.deadline_expired"):
         return ("serving overload: %d request(s) expired in queue"
                 % c["serve.deadline_expired"])

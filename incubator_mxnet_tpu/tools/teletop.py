@@ -67,11 +67,6 @@ def _derived(c):
     if r is not None:
         out.append(("feed stall fraction",
                     "%.1f%% of consumer wall" % r))
-    hit, miss = c.get("aot.hit", 0), c.get("aot.miss", 0)
-    r = _ratio(hit, hit + miss)
-    if r is not None:
-        out.append(("aot cache hit rate", "%.1f%% (%d hit / %d miss)"
-                    % (r, hit, miss)))
     steps = c.get("train.steps", 0)
     if steps:
         out.append(("train steps skipped", "%d / %d (%.2f%%)"
@@ -139,13 +134,9 @@ def _autotune_lines(tune):
     """The compile-loop block (ISSUE 18) as table rows, next to the
     cost table: one line per autotune decision — knob, label, chosen
     value, evidence tier, the heuristic's answer (the tuned-vs-
-    heuristic delta an operator audits) — plus the pre-warm manifest
-    activity (replayed hits / noted / missing)."""
-    if not tune:
-        return []
-    decs = tune.get("decisions") or []
-    pw = tune.get("prewarm") or {}
-    if not decs and not any(pw.values()):
+    heuristic delta an operator audits)."""
+    decs = (tune or {}).get("decisions") or []
+    if not decs:
         return []
     lines = ["", "autotune (%d decision(s))" % len(decs),
              "%-14s %-22s %12s %-10s %12s"
@@ -159,18 +150,13 @@ def _autotune_lines(tune):
                         str(d.get("chosen", "?"))[:12],
                         str(d.get("source", "?"))[:10],
                         "" if heur is None else str(heur)[:12]))
-    if any(pw.values()):
-        lines.append("%-14s %s" % (
-            "prewarm", "%d replayed hit(s) / %d noted / %d missing"
-            % (pw.get("hits", 0), pw.get("noted", 0),
-               pw.get("missing", 0))))
     return lines
 
 
 def _fleet_lines(fleet):
     """The merged per-replica fleet view (ISSUE 11) as one table:
-    a row per replica — step, step/dispatch/collective µs, HBM peak,
-    aot stale count — with stragglers marked ``*SLOW*``."""
+    a row per replica — step, step/dispatch/collective µs, HBM peak
+    — with stragglers marked ``*SLOW*``."""
     reps = (fleet or {}).get("replicas") or {}
     if not reps:
         return []
@@ -179,18 +165,17 @@ def _fleet_lines(fleet):
         ", straggler window=%s sigma=%s"
         % (fleet.get("straggler_window", "?"),
            fleet.get("straggler_sigma", "?"))),
-        "%-8s %8s %10s %10s %10s %10s %8s %s"
+        "%-8s %8s %10s %10s %10s %10s %s"
         % ("replica", "step", "step_us", "disp_us", "coll_us",
-           "hbm_peak", "aot_st", ""),
+           "hbm_peak", ""),
         "-" * 78]
     for rid in sorted(reps, key=lambda r: int(r)):
         row = reps[rid]
         lines.append(
-            "%-8s %8d %10d %10d %10d %10s %8d %s"
+            "%-8s %8d %10d %10d %10d %10s %s"
             % (rid, row.get("step", 0), row.get("step_us", 0),
                row.get("dispatch_us", 0), row.get("collective_us", 0),
                _fmt_qty(row.get("hbm_peak_bytes", 0), "B"),
-               row.get("aot_stale", 0),
                "*SLOW*" if rid in stragglers else ""))
     return lines
 

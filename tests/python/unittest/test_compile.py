@@ -1,13 +1,10 @@
 """Compile-loop tests (ISSUE 18): history-trained autotuner evidence
-ladder, lax.scan layer-stacking parity/measurement, and the pre-warmed
-shared AOT-cache manifest.
+ladder and lax.scan layer-stacking parity/measurement.
 
 Covers the satellite contracts explicitly:
 - history.query(kind="cost"/"autotune") across runs as the autotuner
   consumes it — labeled splits, torn-tail tolerance, and a two-process
   proof (run 2's tuner reads run 1's rows);
-- trim_cache evicting unlisted blobs before manifest-listed ones, and
-  replay counting as a hit (mtime refresh);
 - the suggest_bucket_mb deprecation shim warning once, only when it is
   the DECIDING input;
 - the blackbox/teletop autotune row.
@@ -16,18 +13,15 @@ import json
 import os
 import subprocess
 import sys
-import time
 import warnings
 
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
-from incubator_mxnet_tpu import aot_cache
 from incubator_mxnet_tpu import config as _cfg
-from incubator_mxnet_tpu.compile import autotune, prewarm, stacking
+from incubator_mxnet_tpu.compile import autotune, stacking
 from incubator_mxnet_tpu.parallel.zero import BucketPlan
 from incubator_mxnet_tpu.telemetry import costs as _costs
 from incubator_mxnet_tpu.telemetry import flightrec as _bb
@@ -41,29 +35,21 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 @pytest.fixture
 def fresh(tmp_path, monkeypatch):
-    """Isolated history + AOT cache dirs and clean per-process tuner /
-    manifest / warn-once state, restored afterwards."""
+    """An isolated history dir and clean per-process tuner /
+    warn-once state, restored afterwards."""
     hist_dir = tmp_path / "hist"
-    aot_dir = tmp_path / "aot"
-    aot_dir.mkdir()
     monkeypatch.setenv("MXNET_HISTORY_DIR", str(hist_dir))
-    monkeypatch.setenv("MXNET_AOT_CACHE_DIR", str(aot_dir))
     # env alone is not enough: earlier tests in the same process may
-    # leave a process-local config override (e.g. test_aot_cache
-    # restores MXNET_AOT_CACHE_DIR as an override of ""), and overrides
-    # win over the environment — pin ours and drop it afterwards.
+    # leave a process-local config override, and overrides win over
+    # the environment — pin ours and drop it afterwards.
     _cfg.set("MXNET_HISTORY_DIR", str(hist_dir))
-    _cfg.set("MXNET_AOT_CACHE_DIR", str(aot_dir))
     _hist.reset()
     autotune.reset()
-    prewarm.reset()
     _costs._HEURISTIC_WARNED.clear()
     yield tmp_path
     _cfg.unset("MXNET_HISTORY_DIR")
-    _cfg.unset("MXNET_AOT_CACHE_DIR")
     _hist.reset()
     autotune.reset()
-    prewarm.reset()
     _costs._HEURISTIC_WARNED.clear()
 
 
@@ -126,91 +112,6 @@ class TestStacking:
         assert m["dispatch_unstacked_us"] >= 0
 
 
-# -- pre-warm manifest -------------------------------------------------
-class TestPrewarm:
-    def test_note_entries_dedup_and_torn_tail(self, fresh):
-        d = str(fresh / "aot")
-        prewarm.note("lbl.a", "aaa.pjrtx", directory=d)
-        prewarm.note("lbl.a", "aaa.pjrtx", directory=d)  # process dedup
-        prewarm.note("lbl.b", "bbb.pjrtx", directory=d)
-        # a killed writer's torn tail must be skipped, not raised
-        with open(prewarm.manifest_path(d), "a") as f:
-            f.write('{"kind": "blob", "label": "torn", "blo')
-        ents = prewarm.entries(directory=d)
-        assert len(ents) == 2
-        assert prewarm.listed_blobs(d) == {"aaa.pjrtx", "bbb.pjrtx"}
-        assert prewarm.entries(label_prefix="lbl.a", directory=d)[0][
-            "blob"] == "aaa.pjrtx"
-
-    def test_replay_touches_and_counts(self, fresh):
-        d = str(fresh / "aot")
-        blob = os.path.join(d, "hit.pjrtx")
-        with open(blob, "wb") as f:
-            f.write(b"x" * 16)
-        old = time.time() - 3600
-        os.utime(blob, (old, old))
-        prewarm.note("lbl.hit", "hit.pjrtx", directory=d)
-        prewarm.note("lbl.gone", "gone.pjrtx", directory=d)
-        rep = prewarm.replay(directory=d)
-        assert rep["hits"] == 1 and rep["missing"] == 1
-        # hit semantics: the mtime was refreshed (LRU credit)
-        assert os.path.getmtime(blob) > old + 1800
-        st = prewarm.stats()
-        assert st["replays"] == 1 and st["hits"] == 1 \
-            and st["missing"] == 1
-
-    def test_serve_hint_roundtrip_newest_wins(self, fresh):
-        d = str(fresh / "aot")
-        prewarm.note_serve("srv", (4, 8), "float32", (1, 8),
-                           directory=d)
-        prewarm.note_serve("srv", (4, 16), "bfloat16", (1, 8, 32),
-                           directory=d)
-        hint = prewarm.serve_hint("srv", directory=d)
-        assert hint["example_shape"] == [4, 16]
-        assert hint["wire_dtype"] == "bfloat16"
-        assert hint["buckets"] == [1, 8, 32]
-        assert prewarm.serve_hint("other", directory=d) is None
-
-    def test_aot_jit_notes_manifest(self, fresh):
-        d = str(fresh / "aot")
-
-        def fn(w, v):
-            return v @ w
-
-        f = aot_cache.aot_jit(fn, label="test.prewarm.note",
-                              kind="bench")
-        w = jnp.ones((8, 8), jnp.float32)
-        jax.block_until_ready(f(w, w))
-        ents = [e for e in prewarm.entries(directory=d)
-                if e.get("kind") == "blob"]
-        assert any(e["label"].startswith("test.prewarm.note")
-                   for e in ents)
-        blob = ents[0]["blob"]
-        assert blob.endswith(".pjrtx")
-        assert os.path.exists(os.path.join(d, blob))
-        assert prewarm.replay(directory=d)["hits"] >= 1
-
-    def test_trim_protects_listed_blobs(self, fresh, monkeypatch):
-        d = str(fresh / "aot")
-        now = time.time()
-        for i, name in enumerate(["old.pjrtx", "mid.pjrtx",
-                                  "new.pjrtx"]):
-            p = os.path.join(d, name)
-            with open(p, "wb") as f:
-                f.write(b"x")
-            t = now - 3600 * (3 - i)
-            os.utime(p, (t, t))
-        # the OLDEST blob is the manifest-listed working set
-        prewarm.note("keep", "old.pjrtx", directory=d)
-        monkeypatch.setenv("MXNET_AOT_CACHE_MAX", "2")
-        removed = aot_cache.trim_cache()
-        assert removed == 1
-        left = {n for n in os.listdir(d) if n.endswith(".pjrtx")}
-        # plain mtime LRU would have evicted old.pjrtx; the manifest
-        # protects it, so the oldest UNLISTED blob went instead
-        assert left == {"old.pjrtx", "new.pjrtx"}
-
-
 # -- durable history as tuner input ------------------------------------
 class TestHistoryAsTunerInput:
     def test_cost_rows_across_runs_with_torn_tail(self, fresh):
@@ -221,7 +122,7 @@ class TestHistoryAsTunerInput:
                   labels={"kind": "step"}, bytes_accessed=64e6)
         w2.append("cost", "train.step[0]", 1.0,
                   labels={"kind": "step"}, bytes_accessed=96e6)
-        w2.append("cost", "other.fn", 1.0, labels={"kind": "aot"},
+        w2.append("cost", "other.fn", 1.0, labels={"kind": "serve"},
                   bytes_accessed=1e6)
         with open(w2.path, "a") as f:
             f.write('{"kind": "cost", "name": "torn')   # killed writer
@@ -229,9 +130,9 @@ class TestHistoryAsTunerInput:
         assert len(rows) == 2
         assert {r["run"] for r in rows} == {"run-one", "run-two"}
         # labeled split: the label subset filter selects per kind
-        aot_rows = _hist.query(kind="cost", labels={"kind": "aot"},
+        serve_rows = _hist.query(kind="cost", labels={"kind": "serve"},
                                directory=d)
-        assert [r["name"] for r in aot_rows] == ["other.fn"]
+        assert [r["name"] for r in serve_rows] == ["other.fn"]
 
     def test_modeled_tier_uses_measured_bytes(self, fresh):
         # cost rows (no probes) -> the 1/32 rule on MEASURED traffic,
@@ -380,19 +281,15 @@ class TestVisibility:
         assert dec["knob"] == "zero_bucket_mb"
         assert dec["label"] == "bb.see"
         assert dec["chosen"] == 4.0
-        assert "prewarm" in blk
 
     def test_teletop_renders_autotune_rows(self, fresh):
         from incubator_mxnet_tpu.tools.teletop import _autotune_lines
         blk = {"decisions": [
             {"knob": "zero_bucket_mb", "label": "train.step",
-             "chosen": 4.0, "source": "measured", "heuristic": 16.0}],
-            "prewarm": {"noted": 2, "replays": 1, "hits": 3,
-                        "missing": 1}}
+             "chosen": 4.0, "source": "measured", "heuristic": 16.0}]}
         text = "\n".join(_autotune_lines(blk))
         assert "autotune" in text
         assert "zero_bucket_mb" in text and "measured" in text
-        assert "3 replayed hit(s)" in text
         assert _autotune_lines(None) == []
 
 

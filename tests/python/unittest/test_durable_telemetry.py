@@ -690,7 +690,7 @@ class _FakeCompiled:
 key = costs.note_executable("serve", "serve.infer:demo[0]",
                             compiled=_FakeCompiled(), compile_s=0.5)
 costs.invoke(key, 7)
-events.incr("aot.stale", 7)
+events.incr("serve.shed", 7)
 assert history.tick() > 0
 print("RUN1_ID=%s" % history.get_writer().run)
 """
@@ -719,8 +719,8 @@ def test_two_process_proof(hist_dir, monkeypatch):
     assert rows, "run 1's cost rows not visible to run 2"
     assert rows[-1]["run"] == run1
     assert rows[-1]["flops"] == 2.5e9 and rows[-1]["invocations"] == 7
-    # the aot.* counters rode along in the same shard
-    assert history.query("aot.stale", kind="counter",
+    # the counters rode along in the same shard
+    assert history.query("serve.shed", kind="counter",
                          run=run1)[0]["v"] == 7.0
 
     # -- synthetic overload against the DEFAULT serving rules

@@ -3,7 +3,7 @@
 kvstore-aggregated per-replica telemetry (FleetReporter/FleetView),
 telemetry-driven straggler detection feeding ElasticTrainer's
 slow-(observed) state, the blackbox fleet block + merge CLI, and the
-ISSUE 11 satellites (aot stale reasons, bench_diff, gate reports).
+ISSUE 11 satellites (bench_diff, gate reports).
 All CPU, tier-1 fast."""
 import json
 import os
@@ -151,15 +151,31 @@ def test_fleet_reporter_view_roundtrip():
     for rid in range(3):
         rep = fleet.FleetReporter(kv, rid)
         rep.publish({"step": 7, "step_us": 1000.0 * (rid + 1),
-                     "dispatch_us": 10 * rid, "aot_stale": rid})
+                     "dispatch_us": 10 * rid, "steps_skipped": rid})
     merged = view.refresh(range(4))     # rid 3 never published
     assert sorted(merged) == [0, 1, 2]
     assert merged[1]["step_us"] == 2000.0
-    assert merged[2]["aot_stale"] == 2
+    assert merged[2]["steps_skipped"] == 2
     assert merged[0]["step"] == 7
     # re-publish replaces (the kvstore push-replace contract)
     fleet.FleetReporter(kv, 1).publish({"step": 8, "step_us": 5.0})
     assert view.refresh([1])[1]["step_us"] == 5.0
+
+
+def test_published_row_carries_its_families_and_no_disk_cache_field():
+    """The wire schema after the serialized-executable cache went: the
+    step, train and feed fields, and nothing named `aot*`."""
+    kv = kv_create("local")
+    sample = dict(fleet._counter_sample(), step=3, step_us=1500.0)
+    fleet.FleetReporter(kv, 0).publish(sample)
+    (row,) = FleetView(kv).refresh([0]).values()
+    assert set(sample) <= set(row)
+    assert not [k for k in row if k.startswith("aot")]
+    for field in ("step", "step_us", "dispatch_us", "collective_us",
+                  "data_wait_us", "hbm_peak_bytes", "steps_skipped",
+                  "feed_stall_us", "decode_batches"):
+        assert field in row
+    assert row["step"] == 3.0 and row["step_us"] == 1500.0
 
 
 def test_straggler_detector_flags_and_recovers(tele_ring):
@@ -213,7 +229,7 @@ def test_fleet_telemetry_update_and_block(tele_ring):
     assert set(block["replicas"]) == {"0", "1", "2", "3"}
     row = block["replicas"]["3"]
     for field in ("step", "step_us", "dispatch_us", "collective_us",
-                  "hbm_peak_bytes", "aot_stale"):
+                  "hbm_peak_bytes", "steps_skipped"):
         assert field in row
     # the dump embeds the same block through the provider hook
     assert flightrec.fleet_block()["stragglers"] == [3]
@@ -353,11 +369,9 @@ def test_teletop_fleet_columns():
     snap = {"counters": {"mesh.straggler": 1}, "percentiles": {},
             "fleet": {"replicas": {
                 "0": {"step": 5, "step_us": 1000, "dispatch_us": 10,
-                      "collective_us": 2, "hbm_peak_bytes": 1 << 20,
-                      "aot_stale": 0},
+                      "collective_us": 2, "hbm_peak_bytes": 1 << 20},
                 "1": {"step": 5, "step_us": 8000, "dispatch_us": 10,
-                      "collective_us": 2, "hbm_peak_bytes": 1 << 20,
-                      "aot_stale": 3}},
+                      "collective_us": 2, "hbm_peak_bytes": 1 << 20}},
                 "stragglers": [1], "straggler_window": 8,
                 "straggler_sigma": 4.0}}
     out = teletop.render(snap)
@@ -446,52 +460,6 @@ def test_decode_service_spans_reparent_under_consumer(tele_ring,
 # satellites
 # ---------------------------------------------------------------------------
 
-def test_aot_stale_reason_labeled(tmp_path, tele_ring):
-    import jax.numpy as jnp
-    from incubator_mxnet_tpu import aot_cache
-    mxcfg.set("MXNET_AOT_CACHE_DIR", str(tmp_path))
-    try:
-        def f(x):
-            return x * 2.0 + 1.0
-        x = jnp.ones((8,), jnp.float32)
-        first = aot_cache.aot_jit(f)
-        np.testing.assert_allclose(np.asarray(first(x)), 3.0)
-        blobs = [n for n in os.listdir(str(tmp_path))
-                 if n.endswith(".pjrtx")]
-        assert blobs, "no serialized executable written"
-        # corrupt the blob: a fresh wrapper's load must fail -> stale
-        with open(os.path.join(str(tmp_path), blobs[0]), "wb") as fh:
-            fh.write(b"not an executable")
-        base = events.get("aot.stale")
-        second = aot_cache.aot_jit(f)
-        np.testing.assert_allclose(np.asarray(second(x)), 3.0)
-        assert events.get("aot.stale") == base + 1
-        labeled = events.labeled_snapshot().get("aot.stale", [])
-        reasons = {r["labels"].get("reason") for r in labeled}
-        allowed = {"version", "backend_mismatch", "key_mismatch",
-                   "deserialize_error"}
-        assert reasons and reasons <= allowed
-        ev = [e for e in flightrec.ring_snapshot()
-              if e["kind"] == "aot" and e["name"] == "stale"]
-        assert ev and ev[-1]["reason"] in allowed
-        assert "blob" in ev[-1]
-    finally:
-        mxcfg.unset("MXNET_AOT_CACHE_DIR")
-
-
-def test_stale_reason_classifier():
-    from incubator_mxnet_tpu.aot_cache import _stale_reason
-    assert _stale_reason(RuntimeError(
-        "cached executable is format v3, this build is v4")) == \
-        "version"
-    assert _stale_reason(RuntimeError(
-        "blob compiled for platform tpu, loading on cpu")) == \
-        "backend_mismatch"
-    assert _stale_reason(ValueError(
-        "tree structure mismatch in out_tree")) == "key_mismatch"
-    assert _stale_reason(OSError("short read")) == "deserialize_error"
-
-
 def test_bench_diff_regression_and_direction(tmp_path):
     sys.path.insert(0, os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
@@ -504,10 +472,10 @@ def test_bench_diff_regression_and_direction(tmp_path):
     new = tmp_path / "new.json"
     old.write_text(json.dumps({
         "serve_p99_us": 1000, "imgs_per_s": 500.0, "ok": True,
-        "telemetry": {"counters": {"aot.stale": 0}}, "note": "x"}))
+        "telemetry": {"counters": {"serve.shed": 0}}, "note": "x"}))
     new.write_text(json.dumps({
         "serve_p99_us": 1500, "imgs_per_s": 505.0, "ok": True,
-        "telemetry": {"counters": {"aot.stale": 4}}, "note": "y"}))
+        "telemetry": {"counters": {"serve.shed": 4}}, "note": "y"}))
     rc = bench_diff.main([str(old), str(new), "--threshold", "10"])
     assert rc == 1                      # p99 +50% = regression
     rc = bench_diff.main([str(old), str(new), "--threshold", "10",

@@ -201,15 +201,19 @@ def test_top_lane_displaces_low_on_full_queue():
                           lanes=("hi", "lo"), lane_quotas=(1.0, 1.0))
     try:
         eng.warmup(example_shape=(8,), wire_dtype="float32")
-        # the stall must outlive every assertion below that needs the
-        # queue STILL full — 0.4s flaked under full-corpus load (the
-        # QueueFull probe ran after the dispatcher drained)
-        fault.install("serve.infer", at_calls=[2], times=1,
-                      seconds=3.0)
+        # the stall holds the dispatcher on the FIRST batch after this
+        # line (serve.slow stalls and does not fail, so nothing is
+        # retried), and outlives every assertion below that needs the
+        # queue STILL full: nothing leaves the queue until it ends
+        fault.install("serve.slow", at_calls=[1], times=1, seconds=3.0)
         x = _data(8)
         f0 = eng.submit(x[0], lane="lo")    # dispatcher stalls on it
-        time.sleep(0.25)
-        lo = [eng.submit(x[i], lane="lo") for i in (1, 2, 3)]  # full
+        t_end = time.monotonic() + 10.0
+        while not fault.fired_count("serve.slow"):
+            assert time.monotonic() < t_end, "the stall never fired"
+            time.sleep(0.005)
+        lo = [eng.submit(x[i], lane="lo") for i in (1, 2, 3)]
+        assert eng._q.qsize() == 3          # full, f0 in the stall
         fh = eng.submit(x[4], lane="hi")    # displaces newest lo
         with pytest.raises(Shed):
             lo[-1].result(timeout=5)
@@ -218,6 +222,7 @@ def test_top_lane_displaces_low_on_full_queue():
                    for r in lab)
         # a lo submit on the still-full queue has nothing lower to
         # displace: plain QueueFull backpressure
+        assert eng._q.qsize() == 3 and not f0.done()
         with pytest.raises(QueueFull):
             eng.submit(x[5], lane="lo")
         for f in (f0, lo[0], lo[1], fh):    # the survivors complete
@@ -227,27 +232,39 @@ def test_top_lane_displaces_low_on_full_queue():
         eng.close()
 
 
-def test_reregister_does_not_inherit_stale_footprint(tmp_path):
+def _note_measured_row(name):
+    """File a row WITH memory analysis under model `name`'s cost label,
+    from an executable compiled here: `MeteredJit` rows carry cost
+    analysis only (ROADMAP R5), so on the default path nothing else
+    gives `reconcile` a measured footprint."""
+    import jax
+    from incubator_mxnet_tpu.telemetry import costs as _costs
+    compiled = jax.jit(lambda w, x: x @ w).lower(
+        jax.ShapeDtypeStruct((8, 4), onp.float32),
+        jax.ShapeDtypeStruct((4, 8), onp.float32)).compile()
+    _costs.note_executable(kind="serve",
+                           label="serve.infer:%s[0]" % name,
+                           compiled=compiled)
+    return _costs.footprint_bytes("serve.infer:%s" % name, kind="serve")
+
+
+def test_reregister_does_not_inherit_stale_footprint():
     """unregister drops the model's cost rows: a re-registered name is
     admitted on a fresh projection of the NEW block, never on the old
     incarnation's measured footprint."""
     from incubator_mxnet_tpu.telemetry import costs as _costs
-    cfg.set("MXNET_AOT_CACHE_DIR", str(tmp_path / "aot"))
-    try:
-        reg = ModelRegistry(devices=[mx.cpu(0)])
-        reg.register("m", _dense_net(seed=69), example_shape=(8,),
-                     wire_dtype="float32", max_batch=4)
-        reg.warmup("m")
-        reg.unregister("m")
-        assert _costs.footprint_bytes("serve.infer:m",
-                                      kind="serve") == 0
-        rec = reg.register("m", _dense_net(units=32, seed=71),
-                           example_shape=(8,), wire_dtype="float32",
-                           max_batch=4)
-        assert rec["basis"] == "projected"
-        reg.close()
-    finally:
-        cfg.unset("MXNET_AOT_CACHE_DIR")
+    reg = ModelRegistry(devices=[mx.cpu(0)])
+    reg.register("m", _dense_net(seed=69), example_shape=(8,),
+                 wire_dtype="float32", max_batch=4)
+    reg.warmup("m")
+    assert _note_measured_row("m") > 0
+    reg.unregister("m")
+    assert _costs.footprint_bytes("serve.infer:m", kind="serve") == 0
+    rec = reg.register("m", _dense_net(units=32, seed=71),
+                       example_shape=(8,), wire_dtype="float32",
+                       max_batch=4)
+    assert rec["basis"] == "projected"
+    reg.close()
 
 
 def test_born_expired_is_shed_typed():
@@ -472,32 +489,30 @@ def test_registry_flow_errors_do_not_trip_breaker():
         cfg.unset("MXNET_SERVE_BREAKER_FAILS")
 
 
-def test_registry_warmup_reconciles_measured_footprint(tmp_path):
-    """With the AOT cache on, warmup compiles real executables whose
-    memory_analysis rows flow back into the admission ledger
-    (projection -> measured)."""
-    cfg.set("MXNET_AOT_CACHE_DIR", str(tmp_path / "aot"))
+def test_registry_warmup_reconciles_measured_footprint():
+    """A row with memory analysis under the model's cost label flows
+    back into the admission ledger (projection -> measured); warmup
+    alone leaves the admission projected (ROADMAP R5)."""
     net = _dense_net(seed=65)
-    try:
-        reg = ModelRegistry(devices=[mx.cpu(0)])
-        rec = reg.register("m", net, example_shape=(8,),
-                           wire_dtype="float32", max_batch=4)
-        assert rec["basis"] == "projected"
-        reg.warmup("m")
-        measured = reg.stats()["models"]["m"]
-        if measured["basis"] == "measured":     # backend exposed
-            fp = measured["footprint_bytes"]    # memory_analysis
-            assert fp > 0
-            assert reg.stats()["ledger"][0]["committed"] == fp
-            ring = [e for e in _bb.ring_snapshot()
-                    if e.get("kind") == "serve"
-                    and e["name"] == "footprint_reconciled"]
-            assert ring and ring[-1]["model"] == "m"
-        out = reg.submit("m", _data(1)[0]).result(timeout=30)
-        assert out is not None
-        reg.close()
-    finally:
-        cfg.unset("MXNET_AOT_CACHE_DIR")
+    reg = ModelRegistry(devices=[mx.cpu(0)])
+    rec = reg.register("m", net, example_shape=(8,),
+                       wire_dtype="float32", max_batch=4)
+    assert rec["basis"] == "projected"
+    reg.warmup("m")
+    assert reg.stats()["models"]["m"]["basis"] == "projected"
+    fp = _note_measured_row("m")
+    assert fp > 0 and reg.reconcile("m") == fp
+    measured = reg.stats()["models"]["m"]
+    assert measured["basis"] == "measured"
+    assert measured["footprint_bytes"] == fp
+    assert reg.stats()["ledger"][0]["committed"] == fp
+    ring = [e for e in _bb.ring_snapshot()
+            if e.get("kind") == "serve"
+            and e["name"] == "footprint_reconciled"]
+    assert ring and ring[-1]["model"] == "m"
+    out = reg.submit("m", _data(1)[0]).result(timeout=30)
+    assert out is not None
+    reg.close()
 
 
 # ---------------------------------------------------------------------------

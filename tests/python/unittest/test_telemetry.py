@@ -222,21 +222,21 @@ def test_prometheus_text_golden():
 
 def test_prometheus_renders_every_family():
     """The acceptance contract: every nonzero serve./feed./train./
-    resilience./aot. counter appears, and every observed _us series
+    resilience./mesh. counter appears, and every observed _us series
     gets quantile lines."""
     c = EventCounters()
     names = ("serve.batches", "feed.batches", "train.steps",
-             "resilience.checkpoint_written", "aot.hit")
+             "resilience.checkpoint_written", "mesh.straggler")
     for n in names:
         c.incr(n, 3)
     for n in ("serve.e2e_us", "feed.transfer_us", "train.step_us",
-              "aot.compile_us"):
+              "mesh.rebuild_us"):
         c.observe_time(n, 1e-3)
     text = MetricsExporter(c).prometheus_text()
     for n in names:
         assert "mxnet_%s 3" % n.replace(".", "_") in text
     for n in ("serve_e2e_us", "feed_transfer_us", "train_step_us",
-              "aot_compile_us"):
+              "mesh_rebuild_us"):
         assert '# TYPE mxnet_%s summary' % n in text
         assert 'mxnet_%s{quantile="0.5"}' % n in text
         assert 'mxnet_%s{quantile="0.99"}' % n in text
@@ -448,37 +448,25 @@ def test_step_telemetry_disabled_records_nothing():
 
 
 # ---------------------------------------------------------------------------
-# compile observability (aot.*)
+# the condensed snapshot
 # ---------------------------------------------------------------------------
 
-def test_aot_counters_hit_miss(tmp_path):
-    import jax
-    from incubator_mxnet_tpu import aot_cache
-    from incubator_mxnet_tpu import config as _cfg
-    # config.set, not setenv: other suites (test_aot_cache) leave an
-    # override behind, and overrides beat the environment
-    _cfg.set("MXNET_AOT_CACHE_DIR", str(tmp_path / "aot"))
-
-    def fn(x):
-        return x * 2.0 + 1.0
-
-    try:
-        x = jax.numpy.arange(8, dtype=jax.numpy.float32)
-        miss0, hit0 = events.get("aot.miss"), events.get("aot.hit")
-        f1 = aot_cache.aot_jit(fn)
-        np.testing.assert_allclose(
-            np.asarray(f1(x)), np.arange(8, dtype=np.float32) * 2 + 1)
-        assert events.get("aot.miss") == miss0 + 1
-        assert events.get("aot.compile_us.n") >= 1
-        assert events.get("aot.lower_us.n") >= 1
-        # fresh wrapper, same signature → disk hit, no new compile
-        f2 = aot_cache.aot_jit(fn)
-        f2(x)
-        assert events.get("aot.hit") == hit0 + 1
-        assert events.get("aot.miss") == miss0 + 1
-        assert events.get("aot.load_us.n") >= 1
-    finally:
-        _cfg.unset("MXNET_AOT_CACHE_DIR")
+def test_snapshot_keeps_its_families_and_none_of_the_disk_cache():
+    """`snapshot_dict()` (what bench.py embeds) carries the serve./
+    feed./train. families and no `aot.*` key, whatever else counted."""
+    events.incr("serve.batches", 2)
+    events.incr("feed.batches", 3)
+    events.incr("train.steps", 4)
+    events.incr("aot.hit")              # a name nothing counts any more
+    events.observe_time("serve.e2e_us", 1e-3)
+    snap = telemetry.snapshot_dict()
+    for fam in ("serve.", "feed.", "train."):
+        assert any(k.startswith(fam) for k in snap["counters"]), fam
+    assert "serve.e2e_us" in snap["percentiles"]
+    keys = list(snap["counters"]) + list(snap["percentiles"])
+    assert not [k for k in keys if k.startswith("aot")]
+    assert not [p for p in telemetry.SNAPSHOT_PREFIXES
+                if p.startswith("aot")]
 
 
 # ---------------------------------------------------------------------------
@@ -490,17 +478,17 @@ def test_teletop_render_and_file(tmp_path, capsys):
     c = EventCounters()
     c.incr("serve.batch_fill", 30)
     c.incr("serve.pad_waste", 10)
-    c.incr("aot.hit", 3)
-    c.incr("aot.miss", 1)
+    c.incr("feed.stall_us", 3)
+    c.incr("feed.step_us", 1)
     c.observe_time("serve.e2e_us", 2e-3)
     snap = MetricsExporter(c).json_dict()
     out = teletop.render(snap)
     assert "serve.batch_fill" in out and "30" in out
     assert "serve.e2e_us" in out and "p99" in out
     assert "serve batch fill" in out and "75.0%" in out
-    assert "aot cache hit rate" in out
+    assert "feed stall fraction" in out and "aot cache" not in out
     # --prefix filters the tables
-    assert "aot.hit" not in teletop.render(snap, prefix="serve.")
+    assert "feed.stall_us" not in teletop.render(snap, prefix="serve.")
     # file mode end-to-end through main()
     path = str(tmp_path / "snap.json")
     MetricsExporter(c).export_file(path)

@@ -1,24 +1,16 @@
 """check_compile — CI gate for the compile loop (ISSUE 18).
 
-Two claims, both measured in fresh child processes (the
-check_scaling discipline: interleaved best-of-k trials, inconclusive
-trials, all-inconclusive SKIP rc 0, gate_report artifact):
+One claim, measured in fresh child processes (the check_scaling
+discipline: best-of-k trials, all-errored SKIP rc 0, gate_report
+artifact):
 
-1. **Layer-stacking** (compile/stacking.py): ONE lax.scan executable
-   beats N structurally-identical per-layer executables on cold
-   compile wall AND per-forward dispatch, with the bit-parity oracle
-   green and the executable count reduced N -> 1.
-2. **Pre-warm manifest** (compile/prewarm.py + aot_cache): a cold
-   child populates the AOT cache + manifest; a warm child replaying
-   the manifest then measures aot stale=0, disk hits > 0, and
-   manifest-replay hits > 0 — the shared-cache warm-start contract.
+**Layer-stacking** (compile/stacking.py): ONE lax.scan executable
+beats N structurally-identical per-layer executables on cold compile
+wall AND per-forward dispatch, with the bit-parity oracle green and
+the executable count reduced N -> 1.
 
 Inconclusive (never a FAIL): single-core hosts (dispatch timing is
-meaningless under full serialization — SKIP up front), a warm child
-whose backend cannot deserialize its own blobs (the PR 13 load
-breaker tripped: that is an environment verdict, not a compile-loop
-regression), or a cold-cache warm child (hit=0 without the breaker —
-the cache dir did not survive between the pair).  Wired as a
+meaningless under full serialization — SKIP up front).  Wired as a
 slow+compile test in tests/python/unittest/test_compile.py so tier-1
 skips it but CI can run it.
 
@@ -30,10 +22,8 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import shutil
 import subprocess
 import sys
-import tempfile
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (_ROOT, os.path.join(_ROOT, "tools")):
@@ -66,44 +56,11 @@ def _child_stack(layers, dim):
                                       label="check_compile")))
 
 
-def _child_warm():
-    """Warm-start child (cold and warm runs share one body): replay
-    the manifest, run one AOT-cached executable, report the aot/
-    prewarm counters; print one JSON line.  MXNET_AOT_CACHE_DIR comes
-    from the parent's env."""
-    import jax
-    import jax.numpy as jnp
-    jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_enable_compilation_cache", False)
-    from incubator_mxnet_tpu import aot_cache
-    from incubator_mxnet_tpu.compile import prewarm
-    from incubator_mxnet_tpu.monitor import events
-
-    rep = prewarm.replay()
-
-    def fn(w, v):
-        return jnp.tanh(v @ w)
-
-    f = aot_cache.aot_jit(fn, label="check_compile.warm", kind="bench")
-    w = jnp.ones((256, 256), jnp.float32)
-    x = jnp.ones((8, 256), jnp.float32)
-    jax.block_until_ready(f(w, x))
-    print(json.dumps({
-        "aot_hit": events.get("aot.hit"),
-        "aot_miss": events.get("aot.miss"),
-        "aot_stale": events.get("aot.stale"),
-        "aot_load_disabled": events.get("aot.load_disabled"),
-        "prewarm_hits": rep.get("hits", 0),
-        "prewarm_missing": rep.get("missing", 0),
-        "manifest_entries": rep.get("entries", 0)}))
-
-
-def _run_child(args_list, extra_env=None, timeout_s=300):
+def _run_child(args_list, timeout_s=300):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env[_CHILD_MARK] = "1"
     env.setdefault("MXNET_BLACKBOX_DIR", "/tmp")
-    env.update(extra_env or {})
     cmd = [sys.executable, os.path.abspath(__file__)] + args_list
     res = subprocess.run(cmd, capture_output=True, text=True,
                          timeout=timeout_s, env=env, cwd=_ROOT)
@@ -142,63 +99,39 @@ def main(argv=None) -> int:
     verdicts = []
     trial_rows = []
     for trial in range(args.trials):
-        cache = tempfile.mkdtemp(prefix="mxtpu-gate-aot-")
         try:
             stack = _run_child(
                 ["--child", "stack", str(args.layers), str(args.dim)])
-            env = {"MXNET_AOT_CACHE_DIR": cache}
-            cold = _run_child(["--child", "warm"], extra_env=env)
-            warm = _run_child(["--child", "warm"], extra_env=env)
         except Exception as e:          # noqa: BLE001
             print("trial %d: ERROR %s" % (trial, e))
             verdicts.append(None)
             trial_rows.append({"trial": trial, "verdict": "error",
                                "error": str(e)[:200]})
             continue
-        finally:
-            shutil.rmtree(cache, ignore_errors=True)
 
-        stack_ok = (stack["parity_ok"]
-                    and stack["executables_stacked"]
-                    < stack["executables_unstacked"]
-                    and stack["compile_wall_stacked_s"]
-                    < stack["compile_wall_unstacked_s"]
-                    and stack["dispatch_stacked_us"]
-                    <= stack["dispatch_unstacked_us"]
-                    * args.dispatch_slack)
-        warm_ok = (warm["aot_stale"] == 0 and warm["aot_hit"] > 0
-                   and warm["prewarm_hits"] > 0)
-        # environment verdicts, not compile-loop regressions:
-        #   - the backend cannot deserialize its own blobs (breaker)
-        #   - the cold run never populated the cache (cold-cache pair)
-        inconclusive = (warm["aot_load_disabled"] > 0
-                        or cold["aot_miss"] == 0
-                        or warm["manifest_entries"] == 0)
-        ok = stack_ok and warm_ok
-        verdicts.append(None if (inconclusive and not ok) else ok)
-        trial_rows.append({
-            "trial": trial, "stack": stack, "cold": cold,
-            "warm": warm,
-            "verdict": "pass" if ok else
-            ("inconclusive" if inconclusive else "fail")})
+        ok = bool(stack["parity_ok"]
+                  and stack["executables_stacked"]
+                  < stack["executables_unstacked"]
+                  and stack["compile_wall_stacked_s"]
+                  < stack["compile_wall_unstacked_s"]
+                  and stack["dispatch_stacked_us"]
+                  <= stack["dispatch_unstacked_us"]
+                  * args.dispatch_slack)
+        verdicts.append(ok)
+        trial_rows.append({"trial": trial, "stack": stack,
+                           "verdict": "pass" if ok else "fail"})
         print("trial %d: stack compile %.3fs->%.3fs dispatch "
-              "%dus->%dus exec %d->%d parity=%s | warm stale=%d "
-              "hit=%d prewarm_hits=%d%s -> %s"
+              "%dus->%dus exec %d->%d parity=%s -> %s"
               % (trial, stack["compile_wall_unstacked_s"],
                  stack["compile_wall_stacked_s"],
                  stack["dispatch_unstacked_us"],
                  stack["dispatch_stacked_us"],
                  stack["executables_unstacked"],
                  stack["executables_stacked"], stack["parity_ok"],
-                 warm["aot_stale"], warm["aot_hit"],
-                 warm["prewarm_hits"],
-                 " [inconclusive]" if inconclusive and not ok else "",
-                 "PASS" if ok else
-                 ("skip" if inconclusive else "fail")))
+                 "PASS" if ok else "fail"))
         if ok:
             print("PASS: one scanned executable beats %d per-layer "
-                  "ones and the manifest warm-start measures stale=0"
-                  % args.layers)
+                  "ones" % args.layers)
             write_report("check_compile", "pass", trial_rows, rc=0,
                          params=params)
             return 0
@@ -220,7 +153,5 @@ if __name__ == "__main__":
     if len(sys.argv) >= 2 and sys.argv[1] == "--child":
         if sys.argv[2] == "stack":
             _child_stack(int(sys.argv[3]), int(sys.argv[4]))
-        elif sys.argv[2] == "warm":
-            _child_warm()
         sys.exit(0)
     sys.exit(main())
