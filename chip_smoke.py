@@ -320,7 +320,59 @@ def _kernel_case(run, B, H, T, d, causal):
             "rel_err": {name: round(e, 5) for name, e in errs.items()}}
 
 
+def _ragged_case(run, dtype):
+    """`decode_attention` as the decode step calls it (on the chip: the
+    Mosaic kernel) against the same attention in NumPy float64."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import attention as att
+
+    S, G, T, W, heads = (12, 2, 64, 128, 2) if run.dry \
+        else (40, 4, 256, 128, 2)
+    rs = np.random.RandomState(S)
+    dev = run.ctx().jax_device
+    q = jax.device_put(rs.randn(S, G, W).astype(np.float32), dev)
+    k, v = (jax.device_put(rs.randn(S, G, T, W).astype(np.float32), dev)
+            .astype(dtype) for _ in range(2))
+    tb = att.ragged_row_block(T, dtype)
+    lens = rs.randint(0, T + 1, (S,)).astype(np.int32)
+    lens[:5] = [0, 1, tb - 1, min(tb + 1, T), T]
+    lens[S // 2:S // 2 + 3] = 0
+    run.on_device([q, k, v], "kernels input")
+    compiled = jax.jit(lambda q, k, v, n: att.decode_attention(
+        q, k, v, n, heads=heads, scale=0.125)).lower(
+            q, k, v, jax.device_put(lens, dev)).compile()
+    n_calls = compiled.as_text().count("tpu_custom_call")
+    check(run.dry or n_calls == 1,
+          "decode_attention compiled %d tpu_custom_call(s) on the chip, "
+          "want the ragged kernel" % n_calls)
+    out = compiled(q, k, v, jax.device_put(lens, dev))
+    run.on_device([out], "kernels output")
+    out = np.asarray(out)
+    check(np.isfinite(out).all(), "non-finite ragged decode output")
+    d = W // heads
+    q64, k64, v64 = (np.asarray(a.astype(jnp.float32)).astype(np.float64)
+                     for a in (q, k, v))
+    worst = 0.0
+    for s in np.nonzero(lens)[0]:
+        for h in range(heads):
+            lanes = slice(h * d, (h + 1) * d)
+            sc = np.einsum("gd,gtd->gt", q64[s, :, lanes],
+                           k64[s, :, :lens[s], lanes]) * 0.125
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            ref = np.einsum("gt,gtd->gd", p / p.sum(-1, keepdims=True),
+                            v64[s, :, :lens[s], lanes])
+            worst = max(worst, float(np.abs(out[s, :, lanes] - ref).max()))
+    check(worst < 2e-5, "ragged decode attention over %s leaves is %.3g "
+          "away from float64" % (jnp.dtype(dtype).name, worst))
+    return {"dtype": jnp.dtype(dtype).name, "slots": S, "rows": T,
+            "row_block": tb, "tpu_custom_calls": n_calls,
+            "max_abs_err": round(worst, 8)}
+
+
 def phase_kernels(run):
+    import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import attention as att
 
     check(att._interpret() == run.dry,
@@ -330,7 +382,9 @@ def phase_kernels(run):
         cases = [_kernel_case(run, *case)
                  for case in run.sizes["kernels"]]
     return {"compile_s": round(sum(c["compile_s"] for c in cases), 2),
-            "cases": cases}
+            "cases": cases,
+            "ragged_decode": [_ragged_case(run, dt)
+                              for dt in (jnp.float32, jnp.bfloat16)]}
 
 
 # --------------------------------------------------------------------------

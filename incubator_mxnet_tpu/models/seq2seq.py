@@ -100,10 +100,12 @@ class Seq2Seq(HybridBlock):
                 "h": h.transpose((1, 0, 2)),                # (B, L, H)
                 "c": c.transpose((1, 0, 2))}
 
-    def decode_step(self, tok, pos, cache):
+    def decode_step(self, tok, pos, cache, live):
         """One decode step: feed token `tok` (B,) at target position
         `pos` (B,; unused — LSTM state carries position) and return
-        (next-token logits (B, V), updated cache)."""
+        (next-token logits (B, V), updated cache).  `live` (B,; which
+        slots hold a stream) is unused: a step costs the same for a slot
+        nobody sits in."""
         from .. import ndarray as F
         x = self.tgt_embed(tok.reshape((-1, 1)))            # (B, 1, E)
         x = x.transpose((1, 0, 2))                          # (1, B, E)

@@ -38,7 +38,7 @@ Serving contract (`serving.GenerationEngine`):
   ``start_pos`` = its position.  The first decode step reads that token
   again at that position and yields the first new one, so a stream is
   one token a step from the start, like any other model's.
-- ``decode_step(tok, pos, cache)`` writes row ``pos[slot]`` of every
+- ``decode_step(tok, pos, cache, live)`` writes row ``pos[slot]`` of every
   leaf by an indexed update, scores the slot's cached indexer keys,
   selects, and attends over the slot's rows under the selection
   (`ops.attention` says why under a mask and not by a gather).
@@ -351,9 +351,11 @@ class SparseDecoder(HybridBlock):
                "start_pos": last}
         return {name: NDArray(a) for name, a in out.items()}
 
-    def decode_step(self, tok, pos, cache):
+    def decode_step(self, tok, pos, cache, live):
         """Token `tok` (S,) at position `pos` (S,) against the cache:
-        (logits (S, V) float32, the cache with row `pos` written)."""
+        (logits (S, V) float32, the cache with row `pos` written).
+        `live` (S,; which slots hold a stream) is not used: the
+        attention streams every slot under the selection's mask."""
         import jax
         import jax.numpy as jnp
         tok, pos = tok._data, pos._data

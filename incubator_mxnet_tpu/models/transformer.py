@@ -573,7 +573,8 @@ def _nmt_init_cache(self, src, src_valid_len, max_len, mem_len=None):
         memory = F.concat(
             memory, F.zeros((B, int(mem_len) - int(Ts), self._units)),
             dim=1)
-    cache = {"src_len": src_valid_len.reshape((-1,))}
+    cache = {"src_len": src_valid_len.reshape((-1,)),
+             "counts": F.zeros((B, 1), dtype="int32")}
     zeros = F.zeros((B, G, int(max_len), self._units // G))
     for i, layer in enumerate(self.decoder.layers._children.values()):
         ca = layer.cross_attn
@@ -584,38 +585,36 @@ def _nmt_init_cache(self, src, src_valid_len, max_len, mem_len=None):
     return cache
 
 
-def _nmt_decode_step(self, tok, pos, cache):
+def _nmt_decode_step(self, tok, pos, cache, live):
     """One decode step: token `tok` (B,) at target position `pos`
-    (B,) against the cached K/V.  Returns (logits (B, V), updated
-    cache).  Each layer's new K/V row is written at the slot's own
-    position by an indexed update (a position outside the cache writes
-    nothing), then one batched contraction over (B, G) gives the
-    scores and one the context: no cache leaf is reshaped, transposed
-    or rewritten."""
-    import jax
+    (B,) against the cached K/V; `live` (B,) bool says which slots
+    hold a stream.  Returns (logits (B, V), updated cache).  Each
+    layer's new K/V row is written at the slot's own position by an
+    indexed update (a position outside the cache writes nothing), then
+    `ops.attention.decode_attention` reads the leaves as they lie, a
+    live slot's rows up to its position (self-attention: one query row
+    per slot, each at its OWN position — the continuous-batching point)
+    or its source length (cross-attention), and nothing of the others:
+    no cache leaf is reshaped, transposed or rewritten.  The logits of
+    a slot that is not live are finite and mean nothing.  The `counts`
+    leaf says how many cached rows the step covered for each slot in
+    one layer, self + memory (`step_counts`)."""
     import jax.numpy as jnp
-    from .. import ndarray as F
     from ..ndarray.ndarray import NDArray
+    from ..ops.attention import decode_attention, decode_rows_read
     H, U = self._num_heads, self._units
     d = U // H
     P = _lane_heads(H, d)
     B, G, L, W = cache["k0"].shape
-    M = cache["mem_k0"].shape[2]
     scale = 1.0 / math.sqrt(d)
     x = self.tgt_embed(tok.reshape((-1, 1))) * math.sqrt(U) \
         + self.pos_embed(pos.reshape((-1, 1)))              # (B, 1, U)
     x = self.dec_ln(x)
-    # additive masks: self-attention sees positions <= pos (one query
-    # row per slot, each at its OWN position — the continuous-batching
-    # point), cross-attention sees the real source positions
-    steps = F.arange(0, L).reshape((1, 1, 1, L))
-    self_mask = ((steps > pos.reshape((-1, 1, 1, 1))) * -1e9)._data
-    msteps = F.arange(0, M).reshape((1, 1, 1, M))
-    mem_mask = ((msteps >=
-                 cache["src_len"].reshape((-1, 1, 1, 1))) * -1e9)._data
+    live = live._data
+    self_len = jnp.where(live, pos._data + 1, 0).astype(jnp.int32)
+    mem_len = jnp.where(live, cache["src_len"]._data, 0).astype(jnp.int32)
     row = (jnp.arange(B)[:, None], jnp.arange(G)[None, :],
            pos._data.reshape((-1, 1)))
-    own = jnp.eye(P)[:, :, None]                            # (P, P, 1)
     new_cache = dict(cache)
 
     def _write(leaf, new):
@@ -623,35 +622,36 @@ def _nmt_decode_step(self, tok, pos, cache):
         return NDArray(leaf.at[row].set(new._data.reshape(B, G, W)
                                         .astype(leaf.dtype)))
 
-    def _attend(q, k, v, mask):
-        # head j of a group reads its own d of the row's W lanes: its
-        # query is zero on the others', and of the (P, W) context it
-        # keeps its own d
-        q = q._data.reshape(B, G, P, 1, d)
-        qh = (q * own.astype(q.dtype)).reshape(B, G, P, W)
-        sc = jnp.einsum("bgjw,bgtw->bgjt", qh, k._data) * scale + mask
-        at = jax.nn.softmax(sc, axis=-1)
-        ctx = jnp.einsum("bgjt,bgtw->bgjw", at, v._data)
-        ctx = jnp.einsum("bgjjd->bgjd", ctx.reshape(B, G, P, P, d))
+    def _attend(q, k, v, lengths):
+        # the P heads of a group lie side by side on the row's W lanes,
+        # in the query as in the leaves
+        ctx = decode_attention(q._data.reshape(B, G, W), k._data, v._data,
+                               lengths, heads=P, scale=scale)
         return NDArray(ctx.reshape(B, 1, U))
 
     for i, layer in enumerate(self.decoder.layers._children.values()):
         sa = layer.self_attn
         kc = new_cache["k%d" % i] = _write(cache["k%d" % i], sa.key(x))
         vc = new_cache["v%d" % i] = _write(cache["v%d" % i], sa.value(x))
-        x = layer.ln1(x + sa.proj(_attend(sa.query(x), kc, vc, self_mask)))
+        x = layer.ln1(x + sa.proj(_attend(sa.query(x), kc, vc, self_len)))
         ca = layer.cross_attn
         ctx = _attend(ca.query(x), cache["mem_k%d" % i],
-                      cache["mem_v%d" % i], mem_mask)
+                      cache["mem_v%d" % i], mem_len)
         x = layer.ln2(x + ca.proj(ctx))
         x = layer.ln3(x + layer.ffn(x))
     if self.out_proj is None:
         raise ValueError("decode_step needs the vocab projection "
                          "(build TransformerNMT without "
                          "output_hidden=True for generation)")
+    new_cache["counts"] = NDArray((
+        decode_rows_read(self_len, cache["k0"]._data)
+        + decode_rows_read(mem_len, cache["mem_k0"]._data))[:, None])
     return self.out_proj(x).reshape((0, -1)), new_cache
 
 
+# what `counts` counts, a column a name: the engine adds each, summed over
+# the live slots, to the counter of that name once a step
+TransformerNMT.step_counts = ("gen.attn_rows_read",)
 TransformerNMT.init_cache = _nmt_init_cache
 TransformerNMT.decode_step = _nmt_decode_step
 

@@ -36,18 +36,20 @@ from .registry import register
 
 __all__ = ["flash_attention", "naive_attention", "index_scores",
            "select_mask", "masked_decode_attention",
-           "blocked_select_attention"]
+           "blocked_select_attention", "decode_attention",
+           "ragged_decode_attention", "dense_decode_attention",
+           "decode_rows_read", "ragged_row_block"]
 
 _NEG_INF = -1e30
 
 
-def _largest_divisor(T, cap):
-    """Largest divisor of T that is ≤ cap and a multiple of 8 (TPU
-    sublane), or T itself if T ≤ cap."""
+def _largest_divisor(T, cap, tile=8):
+    """Largest divisor of T that is ≤ cap and a multiple of `tile` (8: the
+    TPU sublanes of float32), or T itself if T ≤ cap; 0 if there is none."""
     if T <= cap:
         return T
-    for b in range(cap, 7, -1):
-        if T % b == 0 and b % 8 == 0:
+    for b in range(cap, tile - 1, -1):
+        if T % b == 0 and b % tile == 0:
             return b
     return 0
 
@@ -686,3 +688,218 @@ def blocked_select_attention(q, k, v, qi, ki, w, top_k, scale, block=1024,
                           scale, chunk)                     # (G, h, bq, d)
         out.append(o.transpose(2, 0, 1, 3).reshape(bq, H, d))
     return jnp.concatenate(out, 0)
+
+
+# ---------------------------------------------------------------------------
+# ragged decode attention: one query row a slot, cost by the rows that are live
+# ---------------------------------------------------------------------------
+# A decode step of a slot-major cache attends one query row a slot over the
+# slot's own rows [0, length): `lengths` is data, 0 for a slot nobody sits
+# in.  The leaves lie (S, G, T, W): G groups of `heads` heads whose d-wide
+# rows share one W = heads*d wide row (models/transformer.py `_lane_heads`).
+#
+# - `dense_decode_attention`: two einsums over the whole leaves under an
+#   additive mask.  Reads every row of every slot; the reference, and what
+#   runs wherever the kernel does not.
+# - `ragged_decode_attention`: the Pallas kernel.  Grid (slot blocks, row
+#   blocks); the lengths are prefetched as scalars, and the index map of K
+#   and V repeats the block it fetched last wherever a step needs none, so a
+#   skipped step moves no byte and runs no arithmetic.  Inside a needed block
+#   a loop over its slots skips those that end before it.  The scores of the
+#   `heads` heads of a row come from one product k*q summed over each head's
+#   own lanes by a matmul with a 0/1 matrix (the product split into three
+#   bfloat16 parts, so the sum keeps float32), and stay broadcast over those
+#   lanes: softmax and the context are then plain elementwise work and
+#   reductions over rows, the flash recurrence in float32.
+# - `decode_attention` chooses between them where the step is LOWERED
+#   (`lax.platform_dependent`), so a compile for a described TPU sees the
+#   kernel while the CPU runs the einsums.
+
+_SLOT_BLOCK = 16        # slots a grid step
+_ROW_BLOCK = 64         # cached rows a grid step, at most
+
+
+def _tile_rows(dtype):
+    """Rows of one TPU tile of `dtype`: 8 of float32, 16 of bfloat16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def ragged_row_block(T, dtype=jnp.float32):
+    """Rows of one key block of the ragged kernel over leaves of T rows:
+    the largest divisor of T up to `_ROW_BLOCK` that is whole tiles of
+    `dtype`, or T where there is none (one block, every row read)."""
+    return _largest_divisor(T, _ROW_BLOCK, _tile_rows(dtype)) or T
+
+
+def _ragged_fits(k):
+    """Whether the ragged kernel tiles the leaf k (S, G, T, W) on a TPU:
+    full-lane rows, row blocks of whole tiles, a block that fits VMEM
+    twice over beside V's."""
+    S, G, T, W = k.shape
+    tb = ragged_row_block(T, k.dtype)
+    block = min(S, _SLOT_BLOCK) * G * tb * W * jnp.dtype(k.dtype).itemsize
+    return W % 128 == 0 and tb % _tile_rows(k.dtype) == 0 \
+        and block <= 4 << 20
+
+
+def decode_rows_read(lengths, k):
+    """Rows of the leaf k (S, G, T, W) that `decode_attention` covers for
+    each slot, (S,) int32: the slot's length rounded up to the kernel's
+    row block, or all T where the leaf does not tile."""
+    T = k.shape[2]
+    tb = ragged_row_block(T, k.dtype) if _ragged_fits(k) else T
+    return (-(-jnp.clip(lengths, 0, T) // tb) * tb).astype(jnp.int32)
+
+
+def decode_attention(q, k, v, lengths, heads=1, scale=1.0):
+    """`ragged_decode_attention` where the step is lowered for a TPU (and
+    wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself),
+    `dense_decode_attention` elsewhere and for leaves the kernel does not
+    tile.  The two agree on every slot with lengths > 0."""
+    ragged = functools.partial(ragged_decode_attention, heads=heads,
+                               scale=scale)
+    dense = functools.partial(dense_decode_attention, heads=heads,
+                              scale=scale)
+    if _interpret():
+        return ragged(q, k, v, lengths)
+    if not _ragged_fits(k):
+        return dense(q, k, v, lengths)
+    return jax.lax.platform_dependent(q, k, v, lengths, tpu=ragged,
+                                      default=dense)
+
+
+def _lane_owner(heads, W):
+    """(W, W) 0/1: lane i and lane j belong to the same head."""
+    own = jnp.arange(W) // (W // heads)
+    return own[:, None] == own[None, :]
+
+
+def dense_decode_attention(q, k, v, lengths, heads=1, scale=1.0):
+    """q (S, G, W) against k, v (S, G, T, W) under rows < lengths (S,):
+    (S, G, W) float32.  Head j of a group reads its own d = W/heads of the
+    row's lanes: its query is zero on the others', and of the (heads, W)
+    context it keeps its own d."""
+    S, G, T, W = k.shape
+    d = W // heads
+    own = jnp.eye(heads, dtype=q.dtype)[:, :, None]             # (P, P, 1)
+    qh = (q.reshape(S, G, 1, heads, d) * own).reshape(S, G, heads, W)
+    mask = (jnp.arange(T)[None, :] >= lengths[:, None]) * -1e9  # (S, T)
+    sc = jnp.einsum("bgjw,bgtw->bgjt", qh, k) * scale \
+        + mask[:, None, None, :]
+    at = jax.nn.softmax(sc, axis=-1)
+    ctx = jnp.einsum("bgjt,bgtw->bgjw", at, v)
+    ctx = jnp.einsum("bgjjd->bgjd", ctx.reshape(S, G, heads, heads, d))
+    return ctx.reshape(S, G, W)
+
+
+def _ragged_kernel(fetch_ref, need_ref, lo_ref, hi_ref, len_ref,
+                   q_ref, own_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
+                   scale, sb, tb):
+    i, j = pl.program_id(0), pl.program_id(1)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    G, W = k_ref.shape[1], k_ref.shape[3]
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, _NEG_INF, f32)
+        l_s[...] = jnp.zeros(l_s.shape, f32)
+        acc_s[...] = jnp.zeros(acc_s.shape, f32)
+
+    @pl.when(j < need_ref[i])
+    def _block():
+        own = own_ref[...]                                      # (W, W)
+        row = j * tb + jax.lax.broadcasted_iota(jnp.int32, (G, tb, W), 1)
+
+        def one_slot(s, carry):
+            n = len_ref[i * sb + s]
+
+            @pl.when(n > j * tb)
+            def _slot():
+                x = (k_ref[s].astype(f32) * q_ref[s]).reshape(G * tb, W)
+                # the sum over each head's lanes, left on those lanes
+                hi = x.astype(bf16)
+                x = x - hi.astype(f32)
+                mid = x.astype(bf16)
+                lo = (x - mid.astype(f32)).astype(bf16)
+                sc = sum(jnp.dot(part, own, preferred_element_type=f32)
+                         for part in (hi, mid, lo)).reshape(G, tb, W)
+                sc = jnp.where(row < n, sc * scale, _NEG_INF)
+                m_prev = m_s[s]                                 # (G, 1, W)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(sc, axis=1, keepdims=True))
+                p = jnp.exp(sc - m_new)
+                shrink = jnp.exp(m_prev - m_new)
+                l_s[s] = l_s[s] * shrink + jnp.sum(p, axis=1, keepdims=True)
+                acc_s[s] = acc_s[s] * shrink + jnp.sum(
+                    p * v_ref[s].astype(f32), axis=1, keepdims=True)
+                m_s[s] = m_new
+
+            return carry
+
+        jax.lax.fori_loop(0, sb, one_slot, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        total = l_s[...]
+        o_ref[...] = jnp.where(total > 0, acc_s[...] / total, 0.0)
+
+
+def _ragged_plan(lengths, S, sb, tb):
+    """What each grid row (a block of `sb` slots) does, as the scalars the
+    kernel prefetches: `fetch`, the slot block it keeps in VMEM; `need`, how
+    many row blocks of `tb` it computes; `lo`/`hi`, the row blocks it
+    fetches (step j fetches clip(j, lo, hi)).  A slot block that needs
+    nothing repeats what was fetched last before it (or what is fetched
+    first after it), so its steps move nothing."""
+    nB = -(-S // sb)
+    lens = jnp.pad(lengths.astype(jnp.int32), (0, nB * sb - S))
+    need = -(-jnp.max(lens.reshape(nB, sb), axis=1) // tb)
+    idx = jnp.arange(nB, dtype=jnp.int32)
+    live = need > 0
+    before = jax.lax.cummax(jnp.where(live, idx, -1))
+    fetch = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
+    still = jnp.where(before >= 0, need[fetch] - 1, 0)
+    lo = jnp.where(live, 0, still)
+    hi = jnp.where(live, need - 1, still)
+    return [a.astype(jnp.int32) for a in (fetch, need, lo, hi, lens)]
+
+
+def ragged_decode_attention(q, k, v, lengths, heads=1, scale=1.0):
+    """One query row a slot over the slot's rows [0, lengths[slot]) of
+    slot-major leaves: q (S, G, W), k and v (S, G, T, W) float32 or
+    bfloat16, lengths (S,) int32 in [0, T]; W = heads * d lanes hold
+    `heads` heads side by side.  Returns (S, G, W) float32.  Reads only
+    the key blocks below a slot's length and nothing of a slot of length
+    0, whose rows of the result are finite and unspecified."""
+    S, G, T, W = k.shape
+    sb = min(S, _SLOT_BLOCK)
+    tb = ragged_row_block(T, k.dtype)
+    nB = -(-S // sb)
+    plan = _ragged_plan(jnp.clip(lengths, 0, T), S, sb, tb)
+    one = lambda i, j, fetch, *_: (fetch[i], 0, 0, 0)
+    rows = lambda i, j, fetch, need, lo, hi, lens: (
+        fetch[i], 0, jnp.clip(j, lo[i], hi[i]), 0)
+    out = pl.pallas_call(
+        functools.partial(_ragged_kernel, scale=float(scale), sb=sb, tb=tb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(plan),
+            grid=(nB, T // tb),
+            in_specs=[
+                pl.BlockSpec((sb, G, 1, W), one),
+                pl.BlockSpec((W, W), lambda i, j, *_: (0, 0)),
+                pl.BlockSpec((sb, G, tb, W), rows),
+                pl.BlockSpec((sb, G, tb, W), rows),
+            ],
+            out_specs=pl.BlockSpec((sb, G, 1, W),
+                                   lambda i, j, *_: (i, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((sb, G, 1, W), jnp.float32)] * 3,
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, G, 1, W), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name="ragged_decode_attention",
+        interpret=_interpret(),
+    )(*plan, q.reshape(S, G, 1, W).astype(jnp.float32),
+      _lane_owner(heads, W).astype(jnp.bfloat16), k, v)
+    return out.reshape(S, G, W)

@@ -30,14 +30,28 @@ exactly like the one-shot engine):
    build, plus a runtime no-copy probe on the first step — a backend
    that silently copies warns with the executable label and counts
    ``gen.donation_copy``).  Per-sequence state (cur position, last
-   token, emitted tokens) lives in device arrays indexed by slot
-   INSIDE the donated cache.
+   token, emitted tokens, the tokens it may still emit) lives in
+   device arrays indexed by slot INSIDE the donated cache.
 3. ``join`` — admit one prefilled request into a free slot: an
    indexed in-place write of the slot's row into every leaf of the
    donated cache (`lax.dynamic_update_slice` at the slot, axis 0), so
-   an admission moves one slot's bytes whatever the slot count.  The
-   warm-up's join runs the same no-copy probe as the first decode
-   step.  Joins and retires never reshape anything.
+   an admission moves one slot's bytes whatever the slot count.  Slot
+   and token budget come as one int32[2].  The warm-up's join runs the
+   same no-copy probe as the first decode step.  Joins and retires
+   never reshape anything.
+
+**Who is live, on the device.**  The slot-major leaf ``left`` (S,) int32
+holds the tokens each slot may still emit: ``join`` writes the admitted
+request's budget, a step hands the model ``live = left > 0`` and leaves
+``left = 0`` where it emitted ``eos``, ``left - 1`` elsewhere.  No
+transfer a step keeps it: the host's rules stay the authority for
+retiring a stream (eos, budget, ``max_len``, deadline, cancel), and
+``left`` only has to cover them — every slot whose token the host takes
+from a step was live on the device in that step.  A stream shed by its
+deadline stays live on the device until its budget runs out or the slot
+is joined again: it costs its rows, never a token.  A model whose step
+reads only what is live (`TransformerNMT`) then pays for the slots that
+hold a stream, not for the slots the engine holds.
 
 **Continuous batching.**  The decode loop advances the fixed-slot
 batch step by step.  A sequence that finishes (EOS / token budget /
@@ -109,8 +123,11 @@ models:
   leaves, ``start_tok`` and ``start_pos`` (B,): a decoder-only model's
   prefill IS its prompt, so it starts at the prompt's last token and
   position.  ``join`` writes the start into the slot with the row.
-- ``decode_step(tok, pos, cache)`` → (next-token logits (B, V),
-  updated cache).  One token per slot per call; position is data.
+- ``decode_step(tok, pos, cache, live)`` → (next-token logits (B, V),
+  updated cache).  One token per slot per call; position is data, and
+  so is ``live`` (B,) bool: the slots that hold a stream.  A model may
+  skip the work of the others (their logits are then finite and mean
+  nothing; nobody reads them) or ignore ``live``.
   A leaf ``counts`` (B, k) int32, if the cache has one, is what the
   step did for each slot under the k names of the model's
   ``step_counts``: it comes back with the step's tokens and the engine
@@ -287,7 +304,9 @@ def project_generation_footprint(block, slots, max_len, buckets,
         try:
             step = _pure_method(block, "decode_step")
             tok = jax.ShapeDtypeStruct((1,), _np.int32)
-            logits, _ = jax.eval_shape(step, pvals, tok, vl, cache)
+            logits, _ = jax.eval_shape(
+                step, pvals, tok, vl, cache,
+                jax.ShapeDtypeStruct((1,), _np.bool_))
             vocab = int(logits.shape[-1])
         except Exception:       # noqa: BLE001 — degrade to KV-only
             pass
@@ -549,8 +568,9 @@ class GenerationEngine:
 
         def decode_step(params, cache):
             events.incr("serve.traces")
-            tok, pos = cache["tok"], cache["pos"]
-            logits, new_m = pure_step(params, tok, pos, cache["m"])
+            tok, pos, left = cache["tok"], cache["pos"], cache["left"]
+            logits, new_m = pure_step(params, tok, pos, cache["m"],
+                                      left > 0)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # the device-resident emitted-token record (ISSUE 14
             # contract: per-sequence state lives in device arrays
@@ -572,10 +592,17 @@ class GenerationEngine:
                 # LIVE slot never reaches the clamp (the host retires
                 # at max_new <= max_len)
                 "pos": jnp.minimum(pos + 1, L - 1).astype(jnp.int32),
+                # the tokens a slot may still emit: what the model is
+                # told is live.  The host's rules retire a stream (eos,
+                # budget, max_len, deadline, cancel); this only has to
+                # cover them: device-live ⊇ host-live at every step
+                "left": jnp.where(nxt == eos, 0,
+                                  jnp.maximum(left - 1, 0)).astype(jnp.int32),
                 "out": out}
 
-        def join(cache, row, slot):
+        def join(cache, row, seat):
             events.incr("serve.traces")
+            slot, budget = seat[0], seat[1]
 
             def put(c, r):
                 # every leaf is slot-major: the slot's row is one
@@ -588,6 +615,7 @@ class GenerationEngine:
                                                 row["m"]),
                     "tok": put(cache["tok"], row["tok"]),
                     "pos": put(cache["pos"], row["pos"]),
+                    "left": put(cache["left"], budget[None]),
                     "out": put(cache["out"],
                                jnp.full((1, L), eos, jnp.int32))}
 
@@ -637,6 +665,7 @@ class GenerationEngine:
             "tok": jax.device_put(
                 jnp.full((S,), self._eos, jnp.int32), dev),
             "pos": jax.device_put(jnp.zeros((S,), jnp.int32), dev),
+            "left": jax.device_put(jnp.zeros((S,), jnp.int32), dev),
             "out": jax.device_put(
                 jnp.full((S, L), self._eos, jnp.int32), dev)}
         self._slot_bytes = sum(
@@ -689,8 +718,11 @@ class GenerationEngine:
                     jax.tree_util.tree_leaves(row)[0])
                 per_bucket[b] = round(time.monotonic() - tb, 4)
             old_probe = self._probe_leaf()
-            self._cache = self._join(self._cache, row,
-                                     jax.device_put(_np.int32(0), dev))
+            # slot 0 with a budget of one token: the step below spends
+            # it, and the slot is dead again when traffic starts
+            self._cache = self._join(
+                self._cache, row,
+                jax.device_put(_np.array([0, 1], _np.int32), dev))
             self._donation_probe(old_probe, "join")
             nxt, self._cache = self._decode(self._params, self._cache)
             _np.asarray(nxt)            # sync
@@ -1006,7 +1038,8 @@ class GenerationEngine:
             with _tele.phase("gen.join", req.rid, req.tick) as join:
                 self._cache = self._join(
                     self._cache, row,
-                    jax.device_put(_np.int32(slot), dev))
+                    jax.device_put(_np.array([slot, req.max_new],
+                                             _np.int32), dev))
         except Exception as e:          # noqa: BLE001 — join DONATES
             events.incr("gen.failed")   # the cache: running slots lose
             self._resolve(req, exc=e)   # their state too — fail them,

@@ -8,7 +8,7 @@ and reads `time.monotonic()`; leaving it appends one tuple
 
     (name, t0, t1, ident, parent, n)
 
-to a process-wide ring of the newest 65536 rows.  No lock, no id minted,
+to a process-wide ring of the newest 262144 rows.  No lock, no id minted,
 no string built, no dict: whatever the caller passed is stored as it is.
 `phase_at(...)` appends an interval whose stamps the caller already
 holds (a request's phases cross threads); it is not mirrored to the
@@ -107,8 +107,12 @@ __all__ = ["SpanContext", "TraceContext", "enabled", "enable", "span",
 # -- the phase log ------------------------------------------------------
 # The newest rows (name, t0, t1, ident, parent, n), stamps from
 # time.monotonic().  deque.append is atomic and drops the oldest row
-# itself: writers take no lock.  The length is a constant, not a knob.
-_LOG = collections.deque(maxlen=65536)
+# itself: writers take no lock.  The length is a constant, not a knob:
+# room for a whole benchmark run of an engine that ticks every 1.3 ms (a
+# tick writes five rows, a request five more; at 65536 rows a run of
+# `nmt_base.online_steady` with 4.5 ms ticks lost its set-up's rows
+# before the readers came for them, PERF.md PR 34).
+_LOG = collections.deque(maxlen=1 << 18)
 _now = time.monotonic
 _ANNOTATION = None      # jax.profiler.TraceAnnotation, once jax is there
 

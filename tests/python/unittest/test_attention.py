@@ -208,3 +208,76 @@ def test_mha_mask_branch_matches_fused():
     causal = np.triu(np.full((8, 8), -1e9, np.float32), k=1)
     out_c = blk(x, nd.array(causal[None, None])).asnumpy()
     assert np.abs(out_c - fused).max() > 1e-3
+
+
+# -- ragged decode attention: one query row a slot, rows below a length ----
+
+_RAGGED_T = 128         # two row blocks of 64
+
+
+def _ragged_lengths(case, S):
+    tb = att.ragged_row_block(_RAGGED_T)
+    if case == "all_dead":
+        return np.zeros(S, np.int32)
+    if case == "one_live":                  # in the second slot block
+        lens = np.zeros(S, np.int32)
+        lens[S - 2] = tb + 1
+        return lens
+    edges = [0, 1, tb - 1, tb, tb + 1, _RAGGED_T]
+    return np.array((edges * S)[:S], np.int32)
+
+
+@pytest.mark.parametrize("case", ["edges", "all_dead", "one_live"])
+@pytest.mark.parametrize("heads", [2, 1])            # d 64, d 128
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_ragged_decode_attention_matches_masked_einsums(
+        pallas_interpret, dtype, heads, case):
+    """The kernel itself (interpret mode) against the masked einsums:
+    lengths 0, 1, a block edge and its neighbours, T; every slot dead; a
+    lone live slot behind dead blocks; float32 and bfloat16 leaves; two
+    heads a row and one; 20 slots in blocks of 16.  Rows of a slot of
+    length 0 are finite and compared with nothing."""
+    S, G, T, W = 20, 2, _RAGGED_T, 128
+    assert S % att._SLOT_BLOCK and T // att.ragged_row_block(T, dtype) == 2
+    rs = np.random.RandomState(11 * heads + len(case))
+    mk = lambda *shape: jnp.asarray(rs.randn(*shape).astype(np.float32))
+    q, k, v = mk(S, G, W), mk(S, G, T, W).astype(dtype), \
+        mk(S, G, T, W).astype(dtype)
+    lens = _ragged_lengths(case, S)
+    out = np.asarray(att.ragged_decode_attention(
+        q, k, v, jnp.asarray(lens), heads=heads, scale=0.11))
+    assert out.shape == (S, G, W) and out.dtype == np.float32
+    assert np.isfinite(out).all()
+    live = lens > 0
+    f32 = jnp.float32
+    exact = np.asarray(att.dense_decode_attention(
+        q, k.astype(f32), v.astype(f32), jnp.asarray(lens), heads=heads,
+        scale=0.11))
+    np.testing.assert_allclose(out[live], exact[live], rtol=_tol(),
+                               atol=_tol())
+    # and the reference on the leaves as they lie (bfloat16 leaves give
+    # it scores rounded to bfloat16)
+    as_is = np.asarray(att.dense_decode_attention(
+        q.astype(dtype), k, v, jnp.asarray(lens), heads=heads, scale=0.11))
+    tol = _tol() if dtype == "float32" else 0.05
+    np.testing.assert_allclose(out[live], as_is[live], rtol=tol, atol=tol)
+
+
+def test_decode_attention_lowers_the_kernel_for_a_tpu_only():
+    """`decode_attention` chooses where it is lowered: the CPU's text holds
+    the einsums and no kernel, leaves the kernel cannot tile take the
+    einsums everywhere, and the count of rows read follows the choice."""
+    S, G, T, W = 4, 2, 128, 128
+    q, k = jnp.zeros((S, G, W)), jnp.zeros((S, G, T, W))
+    lens = jnp.array([0, 1, 64, 65], jnp.int32)
+    text = jax.jit(att.decode_attention).lower(q, k, k, lens).as_text()
+    assert "dot_general" in text and "custom_call" not in text
+    out = att.decode_attention(q, k, k, lens)
+    ref = att.dense_decode_attention(q, k, k, lens)
+    assert np.asarray(out).tobytes() == np.asarray(ref).tobytes()
+    assert list(np.asarray(att.decode_rows_read(lens, k))) == \
+        [0, 64, 64, 128]
+    narrow = jnp.zeros((S, G, T, 64))           # half a lane row
+    assert not att._ragged_fits(narrow)
+    assert list(np.asarray(att.decode_rows_read(lens, narrow))) == \
+        [0, T, T, T]

@@ -321,14 +321,162 @@ def test_nmt_step_writes_one_row_like_the_one_hot_rewrite(case, dtype):
                     c[name] = nd.array(a, dtype=a.dtype)
         tok = nd.array(rs.randint(3, V, (S,)), dtype="int32")
         pos = nd.array(onp.array(pos), dtype="int32")
-        logits, cache = net.decode_step(tok, pos, cache)
+        logits, cache = net.decode_step(
+            tok, pos, cache, nd.array(onp.ones(S, bool), dtype="bool"))
         want, ref = _one_hot_step(net, tok, pos, ref)
         assert set(cache) == set(ref)
+        ref["counts"] = cache["counts"]     # the step's own report
         for name in sorted(ref):
             have, need = cache[name].asnumpy(), ref[name].asnumpy()
             assert have.dtype == need.dtype and have.shape == need.shape
             assert have.tobytes() == need.tobytes(), (name, n)
         assert logits.asnumpy().tobytes() == want.asnumpy().tobytes()
+
+
+# -- liveness on the device, and the kernel that reads it ----------------
+
+def _wide_transformer(seed):
+    """One head to a 128-lane row (d 128, two groups): leaves the ragged
+    kernel tiles, at a size the interpreter runs in seconds."""
+    from incubator_mxnet_tpu.models.transformer import TransformerNMT
+    mx.random.seed(seed)
+    net = TransformerNMT(V, V, units=256, hidden_size=64, num_layers=2,
+                         num_heads=2, max_length=32, dropout=0.0)
+    net.initialize(force_reinit=True)
+    return net
+
+
+def _greedy_margin(net, prompt, n, L, M):
+    """Smallest gap between a step's two best logits over `n` greedy
+    steps of `prompt`, by the model's own (dense) step, one slot."""
+    cache = net.init_cache(nd.array(prompt[None], dtype="int32"),
+                           nd.array([len(prompt)], dtype="int32"), L, M)
+    tok, gap = BOS, float("inf")
+    for pos in range(n):
+        logits, cache = net.decode_step(
+            nd.array([tok], dtype="int32"), nd.array([pos], dtype="int32"),
+            cache, nd.array([True], dtype="bool"))
+        top = onp.sort(logits.asnumpy()[0].astype(onp.float64))
+        gap = min(gap, top[-1] - top[-2])
+        tok = int(logits.asnumpy()[0].argmax())
+    return gap
+
+
+_CHURN_L, _CHURN_M, _CHURN_S = 16, 8, 3
+
+
+def _churn(net):
+    """A run the test steps by hand (no decode thread): six requests over
+    three slots, one cancelled in the queue, one that comes late and is
+    shed by its deadline after two tokens, the others ending by eos,
+    budget or max_len, and the freed slots taken again.  Returns each stream's tokens and how it
+    ended, and for every step the slots whose token the host pushed
+    beside the device's `left` as the step found it (a step dispatched
+    early runs before the host has read the one before it: the last entry
+    is the state after the run, nothing pushed)."""
+    rs = onp.random.RandomState(5)
+    prompts = [rs.randint(3, V, (n,)).astype(onp.int32)
+               for n in (3, 8, 5, 4, 6, 7)]
+    budgets = [_CHURN_L, 3, _CHURN_L, 5, 4, 6]
+    eng = _engine(net, slots=_CHURN_S, max_len=_CHURN_L, buckets=(4, 8))
+    eng._ensure_loop = lambda: None         # the test is the loop
+    found, pushed, decode, emit = [], [], eng._decode, eng._emit
+
+    def watched(params, cache):
+        found.append(onp.asarray(cache["left"]))
+        return decode(params, cache)
+
+    def emitting(seats, *rest):
+        pushed.append([i for i, sl in seats if eng._slots[i] is sl])
+        return emit(seats, *rest)
+
+    try:
+        eng.warmup()
+        eng._decode, eng._emit = watched, emitting
+        streams = {i: eng.submit(prompts[i], max_new_tokens=budgets[i])
+                   for i in (0, 1, 3, 4, 5)}
+        assert streams[4].future.cancel()           # still queued
+        shed = False
+        while len(streams) < 6 or not all(st.done()
+                                          for st in streams.values()):
+            assert eng._tick() == "ran"
+            if len(found) == 4:     # the queue is empty by now: the slot
+                # this one leaves stays idle, and live on the device
+                streams[2] = eng.submit(prompts[2], deadline=60.0,
+                                        max_new_tokens=budgets[2])
+            late = [sl for sl in eng._slots if sl is not None
+                    and sl.req.stream is streams.get(2)]
+            if late and late[0].emitted == 2 and not shed:
+                late[0].req.deadline = time.monotonic() - 1.0
+                shed = True
+            assert len(found) < 80
+        assert len(found) == len(pushed)    # every step was read
+        ticks = list(zip(pushed, found)) \
+            + [(eng._live(), onp.asarray(eng._cache["left"]))]
+        ends = []
+        streams = [streams[i] for i in range(6)]
+        for st in streams:
+            exc = None if st.future.cancelled() else st.future.exception()
+            ends.append("cancelled" if st.future.cancelled()
+                        else type(exc).__name__ if exc else "ok")
+        return prompts, budgets, [st.tokens() for st in streams], ends, ticks
+    finally:
+        eng.close(timeout=5.0)
+
+
+def test_kernel_serves_the_same_tokens_and_the_device_knows_who_is_live(
+        monkeypatch):
+    """(a) The ragged kernel (interpret mode) serves, token for token,
+    what the masked einsums serve, over a run with eos, budget, max_len,
+    deadline and cancel retirements and re-admissions into the same
+    slots; no step's two best logits lie within 1e-4.  (b) At every step
+    the device's `left` covers the host's live slots, and a slot whose
+    stream ended by eos or budget reads 0.  (c) `gen.attn_rows_read` is
+    the live slots' lengths, self + source, rounded up to the kernel's
+    row block, and stays under the rows held."""
+    from incubator_mxnet_tpu.ops import attention as att
+    monkeypatch.setattr(att, "_ROW_BLOCK", 8)       # two row blocks of 8
+    L, M, S = _CHURN_L, _CHURN_M, _CHURN_S
+    net = _wide_transformer(seed=34)
+    assert att._ragged_fits(onp.zeros((S, 2, L, 128), onp.float32))
+    rows0, steps0 = (events.get(n) or 0
+                     for n in ("gen.attn_rows_read", "gen.steps"))
+    prompts, budgets, want, ends, ticks = _churn(net)
+    rows = (events.get("gen.attn_rows_read") or 0) - rows0
+    steps = (events.get("gen.steps") or 0) - steps0
+    # the run holds every way a stream ends
+    assert ends == ["ok", "ok", "DeadlineExceeded", "ok", "cancelled", "ok"]
+    assert len(want[2]) == 3 and want[4] == []
+    assert len(want[1]) == budgets[1] and EOS not in want[1]    # budget
+    assert any(t[-1] == EOS and len(t) < b                      # eos
+               for t, b in zip(want, budgets) if t)
+    assert len(want[0]) == L and EOS not in want[0]     # the last row
+    for p, t in zip(prompts, want):
+        if t:
+            assert _greedy_margin(net, p, len(t), L, M) > 1e-4
+    # (b) every slot whose token the host took was live on the device
+    # in that step; a stream shed by its deadline stays live on the
+    # device, eos and budget leave 0 behind
+    assert len({tuple(live) for live, _ in ticks}) > 3          # churn
+    idle_left = set()
+    for live, left in ticks:
+        assert (left[live] > 0).all(), (live, left)
+        idle_left.update(int(left[i]) for i in range(S) if i not in live)
+    assert 0 in idle_left and max(idle_left) > 0
+    assert ticks[-1][0] == []
+    # (c) a stream of n tokens read rows 1..n of itself and its source
+    up = lambda n, b: -(-n // b) * b
+    expect = sum(up(t, 8) + up(len(p), 8)
+                 for p, toks in zip(prompts, want)
+                 for t in range(1, len(toks) + 1))
+    assert rows == expect
+    assert 0 < rows <= steps * S * (L + M)
+    # (a) the kernel itself
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    _, _, got, ends_k, ticks_k = _churn(net)
+    assert got == want and ends_k == ends
+    assert [live for live, _ in ticks_k] == [live for live, _ in ticks]
+    assert (events.get("gen.attn_rows_read") or 0) - rows0 == 2 * expect
 
 
 # -- join: indexed in-place write of one slot ---------------------------
@@ -366,8 +514,9 @@ def _join_operands(eng, seed, cast_leaf=None):
 def test_join_writes_one_slot_bit_exact(family, cast_leaf, where):
     """The join against a plain NumPy reference, bit for bit on every
     leaf: the slot's row holds the prefilled row (cast to the cache
-    leaf's dtype), the row's start token and position, a row of eos,
-    and every other slot keeps its bytes."""
+    leaf's dtype), the row's start token and position, the request's
+    budget of tokens, a row of eos, and every other slot keeps its
+    bytes."""
     import jax
     net = _seq2seq(seed=11) if family == "seq2seq" else _transformer(11)
     S = 5
@@ -377,11 +526,12 @@ def test_join_writes_one_slot_bit_exact(family, cast_leaf, where):
         ref, row, cache_d, row_d = _join_operands(eng, 17 + slot,
                                                   cast_leaf)
         got = eng._join(cache_d, row_d, jax.device_put(
-            onp.int32(slot), eng._ctx.jax_device))
+            onp.array([slot, 7], onp.int32), eng._ctx.jax_device))
         for k, r in row["m"].items():
             ref["m"][k][slot] = r[0].astype(ref["m"][k].dtype)
         ref["tok"][slot] = row["tok"][0]
         ref["pos"][slot] = row["pos"][0]
+        ref["left"][slot] = 7
         ref["out"][slot] = EOS
         tree = jax.tree_util
         assert tree.tree_structure(got) == tree.tree_structure(ref)
@@ -409,9 +559,10 @@ def test_join_lowers_to_indexed_update(family):
         _, _, cache_d, row_d = _join_operands(eng, 3)
         text = eng._join.lower(
             cache_d, row_d, jax.device_put(
-                onp.int32(1), eng._ctx.jax_device)).as_text()
+                onp.array([1, 7], onp.int32),
+                eng._ctx.jax_device)).as_text()
         n_leaves = len(jax.tree_util.tree_leaves(cache_d))
-        assert n_leaves == len(cache_d["m"]) + 3
+        assert n_leaves == len(cache_d["m"]) + 4
         assert text.count("stablehlo.dynamic_update_slice") == n_leaves
         # a select line ends ": <predicate type>, <result type>"
         whole = [ln for ln in text.splitlines()
@@ -651,11 +802,11 @@ def test_engine_projection_matches_live_cache():
     eng = _engine(net, slots=2, max_len=16, buckets=(4, 8))
     try:
         kv = eng.kv_cache_bytes()
-        # engine cache adds the tok/pos/out bookkeeping leaves on top
-        # of the model KV rows the projection counts
+        # engine cache adds the tok/pos/left/out bookkeeping leaves on
+        # top of the model KV rows the projection counts
         assert kv["per_slot"] >= detail["kv_bytes_per_slot"]
         assert kv["per_slot"] - detail["kv_bytes_per_slot"] <= \
-            4 * (2 + 16)                # tok+pos+out int32 rows
+            4 * (3 + 16)                # tok+pos+left+out int32 rows
     finally:
         eng.close()
 

@@ -73,11 +73,12 @@ def test_window_and_prefix_filters_and_totals(log):
 
 
 def test_ring_is_bounded_by_a_constant(log):
-    assert spans._LOG.maxlen == 65536
-    for k in range(65536 + 10):
+    n = spans._LOG.maxlen
+    assert n == 1 << 18
+    for k in range(n + 10):
         log.phase_at("t.fill", float(k), float(k))
     rows = log.phase_log()
-    assert len(rows) == 65536 and rows[0][1] == 10.0    # oldest dropped
+    assert len(rows) == n and rows[0][1] == 10.0        # oldest dropped
 
 
 def test_a_span_is_a_phase_too(log):
@@ -289,7 +290,8 @@ def test_engine_executables_are_named_by_role():
         "jit__traced_gen_prefill"
     row = eng._prefill(eng._params, src, vl)
     assert _module_name(eng._join, eng._cache, row,
-                        jax.device_put(onp.int32(0), dev)) == \
+                        jax.device_put(onp.array([0, 1], onp.int32),
+                                       dev)) == \
         "jit__traced_gen_join"
     assert _module_name(eng._decode, eng._params, eng._cache) == \
         "jit__traced_gen_decode"

@@ -102,7 +102,8 @@ def test_prefill_then_decode_logits_match_the_reference(tiny, n_prompt):
     for pos in range(n_prompt - 1, len(seq)):
         logits, cache = net.decode_step(
             nd.array([seq[pos]], dtype="int32"),
-            nd.array([pos], dtype="int32"), cache)
+            nd.array([pos], dtype="int32"), cache,
+            nd.array([True], dtype="bool"))
         assert onp.abs(logits.asnumpy()[0] - want[pos]).max() < 2e-4, pos
     counts = cache["counts"].asnumpy()[0]
     ctx = len(seq)
