@@ -371,6 +371,71 @@ def _ragged_case(run, dtype):
             "max_abs_err": round(worst, 8)}
 
 
+def _delta_case(run):
+    """The gated delta rule as the served model calls it (on the chip: the
+    Mosaic kernels `gated_delta_step` and `gated_delta_chunk`) against the
+    recurrence in NumPy float64: a prompt stopped at `valid_len`, then one
+    step from the state it handed over."""
+    import numpy as np
+    import jax
+    from incubator_mxnet_tpu.ops import linear_attention as la
+
+    T, n, H, dk, dv, chunk, layers = (29, 22, 2, 8, 128, 8, 2) if run.dry \
+        else (200, 150, 32, 128, 128, 64, 3)
+    rs = np.random.RandomState(T)
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    q, k = unit(rs.randn(T, H, dk)) * dk ** -0.5, unit(rs.randn(T, H, dk))
+    v = rs.randn(T, H, dv)
+    g = -np.exp(rs.randn(T, H) * 0.5 - 3.0)
+    beta = 1.0 / (1.0 + np.exp(-rs.randn(T, H)))
+    dev = run.ctx().jax_device
+    args = [jax.device_put(a.astype(np.float32), dev)
+            for a in (q, k, v, g, beta)]
+    run.on_device(args, "kernels input")
+    chunked = jax.jit(lambda *a: la.gated_delta_chunked(
+        *a, valid_len=n, chunk=chunk)).lower(*args).compile()
+    # a step over the slots' state leaf, at its middle layer: each slot gets
+    # the prompt's state and the token the prompt stopped before
+    step = jax.jit(lambda q, k, v, g, b, s: la.gated_delta_step(
+        q, k, v, g, b, s, layers // 2), donate_argnums=(5,))
+    calls = [chunked.as_text().count("tpu_custom_call")]
+    o, state = chunked(*args)
+    slots = 4
+    leaf = jax.numpy.zeros((slots, layers) + state.shape, state.dtype) \
+        .at[:, layers // 2].set(state)
+    at = [jax.numpy.broadcast_to(a[n - 1], (slots,) + a.shape[1:])
+          for a in args]
+    low = step.lower(*at, leaf).compile()
+    calls.append(low.as_text().count("tpu_custom_call"))
+    check(run.dry or calls == [1, 1],
+          "the delta rule compiled %s tpu_custom_call(s) on the chip, want "
+          "one kernel each for the prompt and the step" % calls)
+    o_step, leaf = low(*at, leaf)
+    run.on_device([o, state, leaf], "kernels output")
+    S = np.zeros((H, dk, dv))
+    want = np.zeros((n, H, dv))
+    for t in range(n):                  # position n - 1 is the step's
+        S = S * np.exp(g[t])[:, None, None]
+        u = beta[t][:, None] * (v[t] - np.einsum("hkv,hk->hv", S, k[t]))
+        S = S + k[t][:, :, None] * u[:, None, :]
+        want[t] = np.einsum("hkv,hk->hv", S, q[t])
+        if t == n - 2:
+            errs = {"prompt_out": np.abs(np.asarray(o)[:n - 1]
+                                         - want[:n - 1]).max(),
+                    "prompt_state": np.abs(np.asarray(state) - S).max()}
+    leaf = np.asarray(leaf)
+    errs["step_out"] = np.abs(np.asarray(o_step) - want[n - 1]).max()
+    errs["step_state"] = np.abs(leaf[:, layers // 2] - S).max()
+    check(not leaf[:, [i for i in range(layers) if i != layers // 2]].any(),
+          "the step wrote a layer of the state leaf it was not at")
+    for name, e in errs.items():
+        check(np.isfinite(e) and e < 2e-4, "gated delta rule: %s is %.3g "
+              "away from float64" % (name, e))
+    return {"positions": T, "valid_len": n, "heads": H, "chunk": chunk,
+            "tpu_custom_calls": calls,
+            "max_abs_err": {k_: round(float(e), 8) for k_, e in errs.items()}}
+
+
 def phase_kernels(run):
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import attention as att
@@ -384,7 +449,8 @@ def phase_kernels(run):
     return {"compile_s": round(sum(c["compile_s"] for c in cases), 2),
             "cases": cases,
             "ragged_decode": [_ragged_case(run, dt)
-                              for dt in (jnp.float32, jnp.bfloat16)]}
+                              for dt in (jnp.float32, jnp.bfloat16)],
+            "gated_delta": _delta_case(run)}
 
 
 # --------------------------------------------------------------------------

@@ -11,6 +11,7 @@ from .ssd import (SSD, ssd_300, ssd_512, ssd_512_vgg16, ssd_toy,
 from .seq2seq import Seq2Seq, GNMT, gnmt_large, gnmt_sym_gen
 from .sparse_decoder import (SparseDecoder, SelectAttention, HeldExperts,
                              RMSNorm)
+from .hybrid_decoder import HybridDecoder, GatedAttention, GatedDeltaNet
 from .faster_rcnn import (FasterRCNN, faster_rcnn_toy,
                           faster_rcnn_resnet50_v1b,
                           rcnn_training_targets, RCNNTrainLoss)
@@ -26,4 +27,5 @@ __all__ = ["transformer", "BERTModel", "TransformerEncoder", "bert_base",
            "rcnn_training_targets",
            "RCNNTrainLoss",
            "gnmt_sym_gen", "SparseDecoder", "SelectAttention",
-           "HeldExperts", "RMSNorm"]
+           "HeldExperts", "RMSNorm", "HybridDecoder", "GatedAttention",
+           "GatedDeltaNet"]

@@ -65,19 +65,24 @@ def _f32(x):
     return x.astype(jnp.float32)
 
 
-def _rms(x, g, eps):
-    """RMSNorm over the last axis, computed in float32."""
+def _rms(x, g, eps, offset=0.0):
+    """RMSNorm over the last axis, computed in float32; the scale is
+    `offset + g` (a zero-centred scale is stored as g with offset 1)."""
     import jax
     import jax.numpy as jnp
     xf = _f32(x)
     return xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps) \
-        * _f32(g)
+        * (offset + _f32(g) if offset else _f32(g))
 
 
-def rotary(x, pos, theta):
-    """Rotary positions, rotate-half over the whole last axis.
+def rotary(x, pos, theta, width=None):
+    """Rotary positions, rotate-half over the first `width` dims of the
+    last axis (all of it by default); the others pass.
     x (T, heads, d) float32 at positions pos (T,)."""
     import jax.numpy as jnp
+    if width is not None and width < x.shape[-1]:
+        return jnp.concatenate(
+            [rotary(x[..., :width], pos, theta), x[..., width:]], -1)
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
@@ -108,17 +113,18 @@ class _Stacked(HybridBlock):
 
 
 class RMSNorm(HybridBlock):
-    """x * rsqrt(mean(x^2) + eps) * gamma over the last axis."""
+    """x * rsqrt(mean(x^2) + eps) * (offset + gamma) over the last axis."""
 
-    def __init__(self, units, eps=1e-6, **kwargs):
+    def __init__(self, units, eps=1e-6, offset=0.0, **kwargs):
         super().__init__(**kwargs)
-        self._eps = float(eps)
+        self._eps, self._offset = float(eps), float(offset)
         self.gamma = self.params.get("gamma", shape=(int(units),),
-                                     init="ones")
+                                     init="zeros" if offset else "ones")
 
     def forward(self, x):
         g = self.gamma.data()._data
-        return NDArray(_rms(x._data, g, self._eps).astype(x._data.dtype))
+        return NDArray(_rms(x._data, g, self._eps, self._offset)
+                       .astype(x._data.dtype))
 
 
 class SelectAttention(_Stacked):
@@ -226,12 +232,16 @@ class HeldExperts(_Stacked):
     """The expert half of every layer: pre-norm, a router over ALL
     `num_experts`, the top `per_token` with renormalised gates, and the
     SwiGLU experts `first_held .. first_held + held - 1` that this
-    device holds.  Terms of experts held elsewhere are left out."""
+    device holds.  Terms of experts held elsewhere are left out.  With
+    `shared_hidden`, a shared SwiGLU expert of that width under a sigmoid
+    gate of its own is added for every token: every device holds it whole
+    and computes it alike.  `norm_offset` as `_rms`'s."""
 
     _names = ("ln", "router", "wg", "wu", "wd")
 
     def __init__(self, layers, units, hidden, num_experts, per_token,
-                 first_held=0, held=None, eps=1e-6, tile=256, **kwargs):
+                 first_held=0, held=None, eps=1e-6, tile=256,
+                 shared_hidden=0, norm_offset=0.0, **kwargs):
         super().__init__(**kwargs)
         held = num_experts - first_held if held is None else int(held)
         if not 0 <= first_held <= first_held + held <= num_experts:
@@ -240,29 +250,58 @@ class HeldExperts(_Stacked):
                                 num_experts))
         self._layers = int(layers)
         self._k, self._first, self._held = int(per_token), int(first_held), held
+        self._experts = int(num_experts)
         self._eps, self._tile = float(eps), int(tile)
+        self._offset = float(norm_offset)
         D, F = int(units), int(hidden)
-        self.ln = self._param("ln", (D,), "ones")
+        self.ln = self._param("ln", (D,), "zeros" if norm_offset else "ones")
         self.router = self._param("router", (num_experts, D))
         self.wg = self._param("wg", (held, F, D))
         self.wu = self._param("wu", (held, F, D))
         self.wd = self._param("wd", (held, D, F))
+        if shared_hidden:
+            Fs = int(shared_hidden)
+            self._names = self._names + ("sgate", "sg", "su", "sd")
+            self.sgate = self._param("sgate", (1, D))
+            self.sg = self._param("sg", (Fs, D))
+            self.su = self._param("su", (Fs, D))
+            self.sd = self._param("sd", (D, Fs))
 
-    def apply(self, p, h):
+    def _run_tile(self, tokens):
+        """Rows of a tile of a held expert's sorted run: three times the
+        expert's mean share of `tokens` tokens' picks, as a power of two
+        from 16 up to `tile`.  (On the v5e, 1024 tokens x 10 picks over 512
+        experts, 20 an expert: tiles of 64 rows 1.84 ms a layer, of 256
+        2.47, of 16 3.39; 8192 x 8 over 128 stays at `tile`.)"""
+        run = 16
+        while run < 3 * tokens * self._k / self._experts:
+            run *= 2
+        return min(run, self._tile)
+
+    def apply(self, p, h, layer=None):
         """One layer over tokens h (T, D): (h + the held experts' terms,
-        picks held (T,), picks at the fullest held expert (T,))."""
+        picks held (T,), picks at the fullest held expert (T,)).  `p` is the
+        layer's slice of `stacked()`; with `layer`, its expert weights
+        `wg`, `wu`, `wd` are the whole stacks and are read at that layer
+        (`moe.held_experts`)."""
         from ..parallel import moe
         import jax
         import jax.numpy as jnp
-        x = _rms(h, p["ln"], self._eps)
+        x = _rms(h, p["ln"], self._eps, self._offset)
         # the router reads the normed state unrounded, in true float32: two
         # experts nearly tied for the last place are common, and rounding
         # that swaps them puts another expert's whole term in the sum
         scores = jnp.einsum("td,ed->te", x, _f32(p["router"]),
                             precision=jax.lax.Precision.HIGHEST)
         gate, expert = moe.topk_route(scores, self._k)
-        y = moe.held_experts(x.astype(p["wg"].dtype), gate, expert, p["wg"],
-                             p["wu"], p["wd"], self._first, tile=self._tile)
+        xw = x.astype(p["wg"].dtype)
+        y = moe.held_experts(xw, gate, expert, p["wg"], p["wu"], p["wd"],
+                             self._first, tile=self._tile,
+                             run_tile=self._run_tile(h.shape[0]),
+                             layer=layer)
+        if "sgate" in p:
+            y = y + jax.nn.sigmoid(_dense(xw, p["sgate"])) \
+                * moe.swiglu(xw, p["sg"], p["su"], p["sd"])
         return (h + y,) + moe.held_load(expert, self._first, self._held)
 
 

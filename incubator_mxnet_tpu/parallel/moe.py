@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["switch_route", "moe_apply", "moe_ffn", "topk_route",
-           "held_experts", "held_load"]
+           "swiglu", "held_experts", "held_load"]
 
 
 def switch_route(router_logits, capacity):
@@ -163,7 +163,7 @@ def held_load(expert, first_held, n_held):
             jnp.sum(held & (local == fullest), axis=-1, dtype=jnp.int32))
 
 
-def _swiglu(x, wg, wu, wd):
+def swiglu(x, wg, wu, wd):
     """One expert: wd (silu(wg x) * wu x); weights are (out, in)."""
     f32 = jnp.float32
     a = jax.nn.silu(jnp.einsum("td,fd->tf", x, wg,
@@ -173,13 +173,22 @@ def _swiglu(x, wg, wu, wd):
                       preferred_element_type=f32)
 
 
-def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256):
+def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
+                 run_tile=None, layer=None):
     """sum over a token's picks e that this device holds of
     gate_e * expert_e(x): the device's share of a dropless top-k layer.
 
     x (T, D); gate, expert (T, k) from `topk_route`; wg, wu
     (n_held, F, D) and wd (n_held, D, F) the held experts' weights, expert
     `first_held + i` at index i.  Returns (T, D) float32.
+
+    With `layer` (a traced scalar), wg, wu, wd are the stacks of ALL layers,
+    (layers, n_held, ...), and an expert's weights are read at
+    [layer, expert] where they lie: a layer's slice taken first and an
+    expert's slice of that inside the loop over tiles is a copy of the
+    layer's experts (0.4 GB a layer at 64 experts of 3 x 512 x 2048).
+    `run_tile` is the rows of a tile of the many-token form where they
+    should differ from `tile`, the bound of the few-token form.
 
     Few tokens (T <= tile, a decode step): every held expert multiplies
     every token under its gate, zero where it was not picked; the weights
@@ -189,11 +198,17 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256):
     need and no more, so the work follows the picks, however uneven."""
     T, D = x.shape
     k = gate.shape[1]
-    n_held = wg.shape[0]
+    if layer is None:
+        of = lambda w, e: w[e]
+    else:
+        of = lambda w, e: w[layer, e]
+    n_held = wg.shape[0 if layer is None else 1]
     f32 = jnp.float32
     local = expert - first_held
     held = (local >= 0) & (local < n_held)
     if T <= tile:
+        if layer is not None:
+            wg, wu, wd = wg[layer], wu[layer], wd[layer]
         g = jnp.sum(jax.nn.one_hot(jnp.where(held, local, n_held), n_held,
                                    dtype=f32) * gate[..., None], axis=1)
         a = jax.nn.silu(jnp.einsum("td,efd->tef", x, wg,
@@ -202,6 +217,8 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256):
         return jnp.einsum("tef,edf->td", (a * g[..., None]).astype(x.dtype),
                           wd, preferred_element_type=f32)
 
+    if run_tile is not None:
+        tile = run_tile
     # picks in token order, flattened; held ones first after the sort,
     # grouped by expert
     key = jnp.where(held, local, n_held).reshape(-1)             # (T*k,)
@@ -220,7 +237,7 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256):
         e = jnp.sum(tile_end <= j).astype(jnp.int32)    # whose tile j is
         base = start[e] + (j - (tile_end[e] - tiles[e])) * tile
         picks = lax.dynamic_slice(order_pad, (base,), (tile,))
-        y = _swiglu(x[picks // k], wg[e], wu[e], wd[e])
+        y = swiglu(x[picks // k], of(wg, e), of(wu, e), of(wd, e))
         return lax.dynamic_update_slice(rows, y.astype(rows.dtype),
                                         (base, 0))
 

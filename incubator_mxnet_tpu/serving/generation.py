@@ -113,8 +113,8 @@ and per-lane TTFT SLO targets (`slo_targets()`) that
 `telemetry/slo.py`'s default generation rules alert on.
 
 Model contract (``models/seq2seq.py``, ``models/transformer.py``,
-``models/sparse_decoder.py``), one for encoder-decoder and decoder-only
-models:
+``models/sparse_decoder.py``, ``models/hybrid_decoder.py``), one for
+encoder-decoder and decoder-only models:
 
 - ``init_cache(prompt, valid_len, max_len=, mem_len=)`` → dict of
   NDArray leaves, ALL slot-major (axis 0 = request), shapes a pure
@@ -123,6 +123,17 @@ models:
   leaves, ``start_tok`` and ``start_pos`` (B,): a decoder-only model's
   prefill IS its prompt, so it starts at the prompt's last token and
   position.  ``join`` writes the start into the slot with the row.
+  A leaf need not have a time axis: a recurrent state a layer
+  (`HybridDecoder`'s ``s`` and ``c``) is a slot-major leaf like any
+  other, which a step rewrites whole.  Two clauses hold such a leaf to
+  what rows of K/V get for free.  (1) The first decode step reads
+  ``start_tok`` AGAIN: a row is rewritten with what it held, a
+  recurrence would apply the token twice, so a decoder-only prefill
+  hands over recurrent state as of BEFORE its prompt's last token.
+  (2) A prompt is padded to its bucket: padded rows are never read,
+  padding would run on through a recurrence, so the prompt's scans
+  stop at ``valid_len``.  ``join`` rewrites every leaf of the slot, so
+  a state ends with its stream.
 - ``decode_step(tok, pos, cache, live)`` → (next-token logits (B, V),
   updated cache).  One token per slot per call; position is data, and
   so is ``live`` (B,) bool: the slots that hold a stream.  A model may
@@ -165,7 +176,8 @@ __all__ = ["GenerationEngine", "GenerationStream",
 _END = object()          # stream sentinel: normal end
 
 # Bytes of prefilled rows admitted between two decode steps (at least one
-# row; two of the 356 MB rows of a 16-layer, 10 k-token slot).  Dispatch is
+# row; two of the 356 MB rows of a 16-layer, 10 k-token slot; 37 of the
+# 21 MB rows of a slot whose layers mostly hold a recurrent state).  Dispatch is
 # asynchronous and a dispatched prefill holds its row and its temporaries
 # from then on, so a tick that fills many free slots at once would hold them
 # all: with rows of a few MB that is nothing, with rows of hundreds of MB it
@@ -434,7 +446,8 @@ class GenerationEngine:
 
     block: a model implementing ``init_cache``/``decode_step`` (the
         explicit-cache contract — `models.Seq2Seq`,
-        `models.TransformerNMT`, `models.SparseDecoder`).  Parameters
+        `models.TransformerNMT`, `models.SparseDecoder`,
+        `models.HybridDecoder`).  Parameters
         must be initialized.
     bos / eos: special token ids (decode starts from bos unless the
         prefilled row names its own start; an emitted eos retires the

@@ -27,11 +27,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_for(eng, chip, slots, max_len, bucket, join=False):
-    """Compiles the engine's decode step (and its join) for the described
-    chip over `slots` rows of what a prefill of one `bucket`-long prompt
-    returns, JAX's persistent cache off.  Returns the abstract cache and
-    the compiled executables; the engine is closed."""
+def _compile_for(eng, chip, slots, max_len, bucket, join=False,
+                 prefill=False):
+    """Compiles the engine's decode step (and its join, and the prefill)
+    for the described chip over `slots` rows of what a prefill of one
+    `bucket`-long prompt returns, JAX's persistent cache off.  Returns the
+    abstract cache and the compiled executables (False for those not asked
+    for); the engine is closed."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -53,11 +55,13 @@ def _compile_for(eng, chip, slots, max_len, bucket, join=False):
         step = eng._decode._jit.lower(params, cache).compile()
         if join:
             join = eng._join._jit.lower(cache, row, ints(2)).compile()
+        if prefill:
+            prefill = pre.compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
         eng.close()
-    return cache, step, join
+    return cache, step, join, prefill
 
 
 def test_sparse_decoder_step_writes_its_cache_in_place(one_chip):
@@ -75,7 +79,7 @@ def test_sparse_decoder_step_writes_its_cache_in_place(one_chip):
     net.cast("bfloat16")
     eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
                            max_len=L, prompt_buckets=(2048,), queue_cap=4)
-    _, step, join = _compile_for(eng, one_chip, S, L, 2048, join=True)
+    _, step, join, _ = _compile_for(eng, one_chip, S, L, 2048, join=True)
     step, join = step.memory_analysis(), join.memory_analysis()
     leaf = S * layers * 4 * L * 128 * 2              # k, or v: 33.5 MB
     total = 2 * leaf + S * layers * L * 64 * 2
@@ -83,6 +87,49 @@ def test_sparse_decoder_step_writes_its_cache_in_place(one_chip):
     assert step.temp_size_in_bytes < leaf // 4, step.temp_size_in_bytes
     assert join.alias_size_in_bytes >= total
     assert join.temp_size_in_bytes < leaf // 4, join.temp_size_in_bytes
+
+
+def test_hybrid_decoder_step_and_prefill_fit_the_chip(one_chip):
+    """`HybridDecoder` at the published widths of the benchmark's
+    configuration and at its serving size (8 layers, 256 slots of 2048 rows,
+    a 1024-token prefill), two of the 64 experts held so that the host's
+    copy of the weights stays small: the donated cache comes back aliased,
+    recurrent state and all; the state leaf passes through the three
+    `gated_delta_step` kernel calls of the period's body and no temporary is
+    a tenth of it; and with the experts and vocabulary rows left out here
+    added back, the step and the prefill stay under 16 GB."""
+    from incubator_mxnet_tpu.models.hybrid_decoder import HybridDecoder
+
+    S, L, V, held = 256, 2048, 1024, 2
+    net = HybridDecoder(V, 2048, 8, 4, 16, 2, 256, 64, 16, 32, 128, 128, 4,
+                        512, 512, 10, shared_hidden=512, first_held=0,
+                        experts_held=held)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    net.cast("bfloat16")
+    eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
+                           max_len=L, prompt_buckets=(1024,), queue_cap=4)
+    cache, step, _, prefill = _compile_for(eng, one_chip, S, L, 1024,
+                                           prefill=True)
+    m = cache["m"]
+    assert m["s"].shape == (S, 6, 32, 128, 128) and m["s"].dtype == "float32"
+    assert m["c"].shape == (S, 6, 3, 8192)
+    assert m["k"].shape == (S, 2, 2, L, 256)
+    state = S * 6 * 32 * 128 * 128 * 4                   # 3.22 GB
+    total = state + S * 6 * 3 * 8192 * 2 + 2 * S * 2 * 2 * L * 256 * 2
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= total
+    assert mem.temp_size_in_bytes < state // 10, mem.temp_size_in_bytes
+    assert step.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    # what this test left off the chip: 62 experts a layer, 17 968 rows of
+    # the embedding and of the head
+    absent = 8 * (64 - held) * 3 * 512 * 2048 * 2 + 2 * (18992 - V) * 2048 * 2
+    size = lambda a: a.argument_size_in_bytes + a.output_size_in_bytes \
+        - a.alias_size_in_bytes + a.temp_size_in_bytes
+    assert size(mem) + absent < 16e9, size(mem) + absent
+    # a prefill runs beside the resident cache
+    pre = prefill.memory_analysis()
+    assert size(pre) + total + absent < 16e9, size(pre) + total + absent
 
 
 def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
@@ -109,7 +156,7 @@ def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
     net.cast("bfloat16")
     eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
                            max_len=L, prompt_buckets=(L,), queue_cap=4)
-    cache, step, _ = _compile_for(eng, one_chip, S, L, L)
+    cache, step, _, _ = _compile_for(eng, one_chip, S, L, L)
     m = cache["m"]
     assert m["k0"].dtype == jnp.float32 and m["mem_k0"].dtype == jnp.bfloat16
     assert m["counts"].shape == (S, 1) and cache["left"].shape == (S,)
