@@ -436,6 +436,66 @@ def _delta_case(run):
             "max_abs_err": {k_: round(float(e), 8) for k_, e in errs.items()}}
 
 
+def _grouped_case(run):
+    """`held_experts`' many-token form as the served models call it (on the
+    chip: the Mosaic kernel `held_experts_grouped`, stacked weights read at
+    a layer) against the same sum in NumPy float64: uneven runs, one held
+    expert that no token picks."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.parallel import moe
+
+    T, D, F, E, k, held, tile, layers = (40, 128, 128, 8, 2, 4, 8, 2) \
+        if run.dry else (600, 512, 256, 32, 4, 8, 64, 3)
+    rs = np.random.RandomState(T)
+    bf = jnp.bfloat16
+    dev = run.ctx().jax_device
+    put = lambda a, s: jax.device_put((a * s).astype(np.float32), dev) \
+        .astype(bf)
+    x = put(rs.randn(T, D), 1.0)
+    wg, wu = (put(rs.randn(layers, held, F, D), D ** -0.5) for _ in range(2))
+    wd = put(rs.randn(layers, held, D, F), F ** -0.5)
+    scores = rs.randn(T, E)
+    scores[:, :held] += np.linspace(1.0, -1.0, held)    # uneven runs
+    scores[:, 3] = -9.0                                 # nobody picks 3
+    gate, expert = moe.topk_route(jax.device_put(
+        scores.astype(np.float32), dev), k)
+    layer = layers - 1
+    args = (x, gate, expert, wg, wu, wd)
+    run.on_device(list(args), "kernels input")
+    compiled = jax.jit(lambda *a: moe.held_experts(
+        *a, 0, tile=tile, layer=jnp.int32(layer))).lower(*args).compile()
+    n_calls = compiled.as_text().count("tpu_custom_call")
+    check(run.dry or n_calls == 1,
+          "held_experts compiled %d tpu_custom_call(s) on the chip, want "
+          "the grouped kernel" % n_calls)
+    out = compiled(*args)
+    run.on_device([out], "kernels output")
+    out = np.asarray(out)
+    check(np.isfinite(out).all(), "non-finite grouped expert output")
+    f64 = lambda a: np.asarray(a.astype(jnp.float32)).astype(np.float64)
+    x64, g64, e64 = f64(x), f64(gate), np.asarray(expert)
+    want = np.zeros((T, D))
+    runs = []
+    for e in range(held):
+        rows, col = np.nonzero(e64 == e)
+        runs.append(len(rows))
+        h = x64[rows] @ f64(wg[layer, e]).T
+        a = h / (1.0 + np.exp(-h)) * (x64[rows] @ f64(wu[layer, e]).T)
+        # the kernel rounds a to bfloat16 before the down projection and
+        # its rows to bfloat16 after it: 2^-8 of each
+        np.add.at(want, rows, g64[rows, col][:, None]
+                  * (a @ f64(wd[layer, e]).T))
+    check(runs[3] == 0 and max(runs) > 2 * min(r for r in runs if r),
+          "the case's runs %s are not uneven with one empty" % runs)
+    err = float(np.abs(out - want).max() / np.abs(want).max())
+    check(err < 2e-2, "the grouped experts are %.3g of max|ref| away from "
+          "float64" % err)
+    return {"tokens": T, "held": held, "tile": tile, "runs": runs,
+            "tpu_custom_calls": n_calls, "rel_err": round(err, 6)}
+
+
 def phase_kernels(run):
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import attention as att
@@ -450,7 +510,8 @@ def phase_kernels(run):
             "cases": cases,
             "ragged_decode": [_ragged_case(run, dt)
                               for dt in (jnp.float32, jnp.bfloat16)],
-            "gated_delta": _delta_case(run)}
+            "gated_delta": _delta_case(run),
+            "grouped_experts": _grouped_case(run)}
 
 
 # --------------------------------------------------------------------------

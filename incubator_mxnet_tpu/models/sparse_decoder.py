@@ -271,8 +271,10 @@ class HeldExperts(_Stacked):
         """Rows of a tile of a held expert's sorted run: three times the
         expert's mean share of `tokens` tokens' picks, as a power of two
         from 16 up to `tile`.  (On the v5e, 1024 tokens x 10 picks over 512
-        experts, 20 an expert: tiles of 64 rows 1.84 ms a layer, of 256
-        2.47, of 16 3.39; 8192 x 8 over 128 stays at `tile`.)"""
+        experts, 20 an expert, a layer by the kernel `held_experts_grouped`:
+        tiles of 64 rows 0.56 ms, of 128 0.61, of 32 and of 256 more, where
+        the loop over tiles took 1.28 at 64; 8192 x 8 over 128 stays at
+        `tile`: 0.91 ms, the same at 128, more at 512.)"""
         run = 16
         while run < 3 * tokens * self._k / self._experts:
             run *= 2
@@ -351,14 +353,22 @@ class SparseDecoder(HybridBlock):
     def _run_prompt(self, tokens):
         """tokens (T,) -> (h (T, D), k, v, ki each (layers, T, .))."""
         import jax
+        import jax.numpy as jnp
+        stacks = self._stacks()
+        # the expert weights go down whole with the layer's index: the
+        # many-token form reads one expert at a time, at [layer, expert],
+        # and a layer's slice handed to its kernel would be a copy
+        whole = {n: stacks["experts"].pop(n) for n in ("wg", "wu", "wd")}
 
-        def layer(h, p):
+        def layer(h, xs):
+            p, i = xs
             h, k, v, ki = self.attn.prompt(p["attn"], h, self._block,
                                            self._chunk)
-            h = self.experts.apply(p["experts"], h)[0]
+            h = self.experts.apply(dict(p["experts"], **whole), h, i)[0]
             return h, (k, v, ki)
 
-        return jax.lax.scan(layer, self._embed(tokens), self._stacks())
+        return jax.lax.scan(layer, self._embed(tokens),
+                            (stacks, jnp.arange(self._layers)))
 
     def forward(self, tokens):
         """Logits (B, T, V) of `tokens` (B, T), every position attending

@@ -25,12 +25,19 @@ exchange.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..monitor import events
+from ..ops.attention import _interpret
 
 __all__ = ["switch_route", "moe_apply", "moe_ffn", "topk_route",
-           "swiglu", "held_experts", "held_load"]
+           "swiglu", "held_experts", "held_experts_grouped", "held_load"]
 
 
 def switch_route(router_logits, capacity):
@@ -173,6 +180,216 @@ def swiglu(x, wg, wu, wd):
                       preferred_element_type=f32)
 
 
+# the weight blocks of one grid step, both pipeline buffers of all three,
+# that `held_experts_grouped` may keep in VMEM, beside its row tiles, its
+# result tile and the products' float32 temporaries (`_grouped_vmem`)
+_GROUPED_WEIGHT_BYTES = 40 << 20
+
+
+def _grouped_hidden_block(F, D, itemsize):
+    """Columns of an expert's hidden width F that one grid step of
+    `held_experts_grouped` multiplies: all of F where the three (F, D)
+    blocks fit in VMEM twice over, else F's largest divisor that is a
+    multiple of 128 lanes and does."""
+    fits = lambda fb: 2 * 3 * fb * D * itemsize <= _GROUPED_WEIGHT_BYTES
+    if fits(F):
+        return F
+    for n in range(2, F // 128 + 1):
+        if F % (128 * n) == 0 and fits(F // n):
+            return F // n
+    return 128 if F % 128 == 0 else F
+
+
+def _grouped_vmem(tile, fb, D, itemsize):
+    """Bytes of VMEM `held_experts_grouped` asks for: its weight blocks and
+    result tile twice over, the two float32 row buffers, one tile's
+    operands and products, and an eighth more; no less than the 16 MB a
+    fusion gets anyway.  What a kernel reserves, XLA cannot use to keep the
+    operands of the OTHER ops of the executable near: under a flat 64 MB
+    `keye_vl2_30b_a3b`'s prefill lost 0.7 ms a layer in its attention
+    loops."""
+    need = 2 * 3 * fb * D * itemsize + 2 * tile * D * (4 + itemsize) \
+        + tile * (D * (4 + itemsize) + fb * (8 + itemsize))
+    return max(16 << 20, need + need // 8)
+
+
+def _tile_plan(count, start, tile, steps):
+    """The tiles of the held experts' sorted runs, run after run, each run
+    from a tile's first row on: (used, the tiles in all; expert, first,
+    valid (steps,): whose tile j is, its first row among the sorted picks
+    and how many of its rows belong to the run, 0 past the last tile; at
+    (n_held,): the first row of e's run among the tiles' rows)."""
+    count, start = count.astype(jnp.int32), start.astype(jnp.int32)
+    tiles = -(-count // tile)
+    tile_end = jnp.cumsum(tiles)
+    at = (tile_end - tiles) * tile
+    j = jnp.arange(steps, dtype=jnp.int32)
+    e = jnp.minimum(jnp.sum(tile_end[None, :] <= j[:, None], axis=1),
+                    count.shape[0] - 1).astype(jnp.int32)
+    off = j * tile - at[e]
+    return (tile_end[-1:], e, start[e] + off,
+            jnp.clip(count[e] - off, 0, tile), at)
+
+
+def _grouped_kernel(layer_ref, used_ref, exp_ref, first_ref, valid_ref,
+                    tok_ref, x_hbm, wg_ref, wu_ref, wd_ref, o_ref, xbuf, sem,
+                    *acc, nf):
+    del layer_ref, exp_ref              # read by the weights' index maps
+    j, f = pl.program_id(0), pl.program_id(1)
+    used = used_ref[0]
+    f32 = jnp.float32
+
+    def rows_of(t, wait):
+        """Tile t's rows of x, one DMA a valid row, into buffer t % 2."""
+        slot, base = t % 2, first_ref[t]
+
+        def one(r, carry):
+            cp = pltpu.make_async_copy(
+                x_hbm.at[pl.ds(tok_ref[base + r], 1)],
+                xbuf.at[slot, pl.ds(r, 1)], sem.at[slot])
+            cp.wait() if wait else cp.start()
+            return carry
+
+        lax.fori_loop(0, valid_ref[t], one, 0)
+
+    @pl.when((f == 0) & (j < used))
+    def _rows():
+        @pl.when(j == 0)
+        def _first():
+            rows_of(0, False)
+
+        @pl.when(j + 1 < used)          # the next tile's, under this one's
+        def _ahead():                   # products
+            rows_of(j + 1, False)
+
+        rows_of(j, True)
+
+    def store(y):
+        row = lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        o_ref[...] = jnp.where(row < valid_ref[j], y, 0.0).astype(o_ref.dtype)
+
+    @pl.when(j < used)
+    def _tile():
+        xt = xbuf[j % 2].reshape(o_ref.shape).astype(o_ref.dtype)
+        nt = (((1,), (1,)), ((), ()))   # both operands contract their last
+        g = lax.dot_general(xt, wg_ref[...], nt, preferred_element_type=f32)
+        u = lax.dot_general(xt, wu_ref[...], nt, preferred_element_type=f32)
+        y = lax.dot_general((jax.nn.silu(g) * u).astype(xt.dtype),
+                            wd_ref[...], nt, preferred_element_type=f32)
+        if nf == 1:
+            store(y)
+            return
+        total, = acc
+
+        @pl.when(f == 0)
+        def _start():
+            total[...] = y
+
+        @pl.when(f > 0)
+        def _more():
+            total[...] += y
+
+        @pl.when(f == nf - 1)
+        def _done():
+            store(total[...])
+
+    @pl.when((used == 0) & (j == 0) & (f == 0))
+    def _none():                        # no held pick: row 0 is read, as 0
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def held_experts_grouped(x, token, plan, wg, wu, wd, layer, *, tile):
+    """The held experts' sorted runs as ONE grouped matmul (Pallas, TPU).
+    x (T, D); pick i of the sorted picks is token `token[i]`'s (T * k,);
+    `plan` the first four of `_tile_plan` over len(plan[1]) tiles of `tile`
+    rows; wg, wu (layers, n_held, F, D), wd (layers, n_held, D, F) the
+    stacks of all layers, read at [layer, expert] where they lie.  Returns
+    the tiles' rows (tiles * tile, D) in x's type: rows of a tile past its
+    run's end are 0, tiles past the last used one are not written (nothing
+    reads them).
+
+    Grid (tiles, blocks of F).  Whose tile a step multiplies, where its
+    rows start and how many are valid are prefetched scalars; the weights'
+    index maps read [layer, expert], so consecutive tiles of one expert
+    keep its weights in VMEM and the next expert's come in under this
+    one's products (the pipeline's two buffers).  The rows of x are
+    gathered by the kernel itself, a DMA a valid row from x in HBM, the
+    next tile's under this tile's products; Mosaic moves a single row only
+    of a 32-bit array whose rows are its leading axis, so x goes in as
+    float32 (T, 1, D), which holds its values exactly.  A step past the
+    last used tile repeats the last block indices and does nothing, so the
+    cost follows the held picks.  Rounding as `swiglu`'s: operands in x's type,
+    float32 accumulation, silu(g) * u in float32, cast to x's type before
+    the down projection, float32 until the result is stored."""
+    D, F = x.shape[1], wg.shape[-2]
+    steps = plan[1].shape[0]
+    fb = _grouped_hidden_block(F, D, jnp.dtype(wg.dtype).itemsize)
+    nf = F // fb
+    scalars = [jnp.asarray(layer, jnp.int32).reshape(1)] \
+        + [a.astype(jnp.int32) for a in plan + (token,)]
+
+    def tile_of(j, used):               # a step past the last repeats it
+        return jnp.minimum(j, jnp.maximum(used[0] - 1, 0))
+
+    up = pl.BlockSpec(
+        (None, None, fb, D),
+        lambda j, f, l, used, exp, *_: (l[0], exp[tile_of(j, used)], f, 0))
+    down = pl.BlockSpec(
+        (None, None, D, fb),
+        lambda j, f, l, used, exp, *_: (l[0], exp[tile_of(j, used)], 0, f))
+    scratch = [pltpu.VMEM((2, tile, 1, D), jnp.float32),
+               pltpu.SemaphoreType.DMA((2,))]
+    if nf > 1:                          # the down projection's running sum
+        scratch.append(pltpu.VMEM((tile, D), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, nf=nf),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(steps, nf),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), up, up, down],
+            out_specs=pl.BlockSpec(
+                (tile, D), lambda j, f, l, used, *_: (tile_of(j, used), 0)),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct((steps * tile, D), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_grouped_vmem(
+                tile, fb, D, jnp.dtype(wg.dtype).itemsize)),
+        interpret=_interpret(),
+        name="held_experts_grouped",
+    )(*scalars, x.astype(jnp.float32)[:, None, :], wg, wu, wd)
+
+
+def _held_experts_loop(x, token, plan, wg, wu, wd, layer, *, tile):
+    """`held_experts_grouped`'s rows by a loop over the used tiles, each a
+    slice of the sorted picks, a gather of its rows of x, `swiglu` against
+    one expert's weights read at [layer, expert], and an update of the
+    result: the CPU's form and the tests' oracle.  (Rows of a tile past its
+    run's end are the next picks' under this expert: nothing reads them.)"""
+    used, expert, first, _ = plan
+    token = jnp.concatenate([token, jnp.zeros((tile,), jnp.int32)])
+
+    def one_tile(j, rows):
+        e = expert[j]
+        picks = lax.dynamic_slice(token, (first[j],), (tile,))
+        y = swiglu(x[picks], wg[layer, e], wu[layer, e], wd[layer, e])
+        return lax.dynamic_update_slice(rows, y.astype(rows.dtype),
+                                        (j * tile, 0))
+
+    return lax.fori_loop(
+        0, used[0], one_tile,
+        jnp.zeros((expert.shape[0] * tile, x.shape[1]), x.dtype))
+
+
+def _grouped_fits(x, wg, tile):
+    """Whether the kernel takes these shapes: lanes and sublanes whole."""
+    D, F = x.shape[1], wg.shape[-2]
+    sub = 32 // jnp.dtype(x.dtype).itemsize
+    return D % 128 == 0 and F % 128 == 0 and tile % sub == 0 \
+        and x.dtype == wg.dtype
+
+
 def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
                  run_tile=None, layer=None):
     """sum over a token's picks e that this device holds of
@@ -195,13 +412,13 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
     stream once either way and there is nothing to sort.  Many tokens (a
     prefill): the held picks are sorted by expert and each expert's run
     is multiplied in tiles of `tile` rows, as many tiles as its tokens
-    need and no more, so the work follows the picks, however uneven."""
+    need and no more, so the work follows the picks, however uneven:
+    `held_experts_grouped` where the layer is lowered for a TPU (and
+    wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself),
+    `_held_experts_loop` elsewhere and for shapes the kernel does not
+    tile."""
     T, D = x.shape
     k = gate.shape[1]
-    if layer is None:
-        of = lambda w, e: w[e]
-    else:
-        of = lambda w, e: w[layer, e]
     n_held = wg.shape[0 if layer is None else 1]
     f32 = jnp.float32
     local = expert - first_held
@@ -226,22 +443,31 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
     rank = jnp.argsort(order).astype(jnp.int32)         # where a pick went
     count = jnp.sum(jax.nn.one_hot(key, n_held, dtype=jnp.int32), axis=0)
     start = jnp.cumsum(count) - count                   # first row of e
-    tiles = (count + tile - 1) // tile
-    tile_end = jnp.cumsum(tiles)                        # tiles up to e
-    order_pad = jnp.concatenate([order, jnp.zeros((tile,), jnp.int32)])
-    # the results, one row a held pick in sorted order (a run's last tile
-    # overhangs into the next run, whose own tile then overwrites it)
-    rows = jnp.zeros((T * k + tile, D), x.dtype)
-
-    def one_tile(j, rows):
-        e = jnp.sum(tile_end <= j).astype(jnp.int32)    # whose tile j is
-        base = start[e] + (j - (tile_end[e] - tiles[e])) * tile
-        picks = lax.dynamic_slice(order_pad, (base,), (tile,))
-        y = swiglu(x[picks // k], of(wg, e), of(wu, e), of(wd, e))
-        return lax.dynamic_update_slice(rows, y.astype(rows.dtype),
-                                        (base, 0))
-
-    rows = lax.fori_loop(0, tile_end[-1], one_tile, rows)
-    mine = rows[jnp.where(held, rank.reshape(T, k), 0)]          # (T, k, D)
-    return jnp.einsum("tkd,tk->td", mine, jnp.where(held, gate, 0.0),
-                      preferred_element_type=f32)
+    plan = _tile_plan(count, start, tile, T * k // tile + n_held)
+    if layer is None:                   # one layer's weights: a stack of one
+        wg, wu, wd, layer = wg[None], wu[None], wd[None], 0
+    grouped = functools.partial(held_experts_grouped, tile=tile)
+    loop = functools.partial(_held_experts_loop, tile=tile)
+    args = (x, order // k, plan[:4], wg, wu, wd, jnp.asarray(layer, jnp.int32))
+    fits = _grouped_fits(x, wg, tile)
+    if _interpret():
+        rows = grouped(*args)
+    elif not fits:
+        rows = loop(*args)
+    else:
+        rows = lax.platform_dependent(*args, tpu=grouped, default=loop)
+    if _interpret() or (fits and jax.default_backend() == "tpu"):
+        # trace-time side effect only, as `serve.traces` is: one for each
+        # layer body that is lowered with the kernel
+        events.incr("moe.grouped_traces")
+    # a held pick's row: its place in its expert's run, from the run's
+    # first row among the tiles' rows.  One gather of T rows a pick, which
+    # XLA fuses with its multiply-add: gathered whole, (T, k, D) pads k to a
+    # sublane tile and is copied, (k, T, D) is widened to float32 in a pass
+    # of its own (v5e, 8192 tokens x 8: 4.2 and 5.5 ms a layer against 3.8)
+    where = (plan[4] - start)[jnp.where(held, local, 0)] + rank.reshape(T, k)
+    where, gate = jnp.where(held, where, 0), jnp.where(held, gate, 0.0)
+    out = jnp.zeros((T, D), f32)
+    for i in range(k):
+        out = out + rows[where[:, i]].astype(f32) * gate[:, i:i + 1]
+    return out

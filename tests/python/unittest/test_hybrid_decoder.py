@@ -162,9 +162,16 @@ def test_step_is_one_position_of_the_recurrence_and_touches_one_layer():
     assert others.all()
 
 
-def test_the_kernels_in_interpret_mode_are_the_jax_forms(monkeypatch):
-    import jax.numpy as jnp
+def _interpret_kernels(monkeypatch):
     from incubator_mxnet_tpu import config
+    from incubator_mxnet_tpu.ops import linear_attention as la
+    monkeypatch.setattr(config, "_OVERRIDES",
+                        dict(config._OVERRIDES, MXNET_PALLAS_INTERPRET=True))
+    assert la._interpret()
+
+
+def _delta_rule_forms(monkeypatch):
+    import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import linear_attention as la
     q, k, v, g, beta = _rule_inputs(29, H=2, dk=8, dv=128, seed=3)
     want_o, want_s = la.gated_delta_chunked(q, k, v, g, beta, 25, chunk=8)
@@ -172,9 +179,7 @@ def test_the_kernels_in_interpret_mode_are_the_jax_forms(monkeypatch):
                          jnp.float32)
     want = la.gated_delta_step(q[:3], k[:3], v[:3], g[:3], beta[:3], states,
                                jnp.int32(1))
-    monkeypatch.setattr(config, "_OVERRIDES",
-                        dict(config._OVERRIDES, MXNET_PALLAS_INTERPRET=True))
-    assert la._interpret()
+    _interpret_kernels(monkeypatch)
     o, s = la.gated_delta_chunked(q, k, v, g, beta, 25, chunk=8)
     assert onp.abs(onp.asarray(o - want_o)).max() < 1e-5
     assert onp.abs(onp.asarray(s - want_s)).max() < 1e-5
@@ -182,6 +187,90 @@ def test_the_kernels_in_interpret_mode_are_the_jax_forms(monkeypatch):
                               jnp.int32(1))
     assert onp.abs(onp.asarray(got[0] - want[0])).max() < 1e-5
     assert onp.abs(onp.asarray(got[1] - want[1])).max() < 1e-5
+
+
+# the grouped kernel's cases: T tokens of width D, top k of E experts, `held`
+# of them held from `first` on, width F, tiles of `tile` rows; `route` says
+# how the picks fall, `layer` of `layers` reads stacked weights
+_GROUPED = {
+    # held expert 1 gets no token at all
+    "an_empty_expert": dict(T=40, E=8, k=2, held=4, tile=8, route="skip_1"),
+    # a run of 80 picks and three empty experts: ten whole tiles
+    "every_pick_on_one": dict(T=40, E=8, k=2, held=4, tile=8, route="all_2"),
+    # 37 tokens x 3: runs of any length against tiles of 16
+    "ragged_runs": dict(T=37, E=16, k=3, held=8, tile=16, route="random"),
+    # the held experts are 12..15 and nobody picks them: all zeros
+    "no_held_pick": dict(T=40, E=16, k=2, held=4, first=12, tile=8,
+                         route="low"),
+    # 25 x 3 = 75 picks, no multiple of 8
+    "picks_no_multiple_of_the_tile": dict(T=25, E=8, k=3, held=5, tile=8,
+                                          route="random"),
+    "stacked_layer_0": dict(T=40, E=8, k=2, held=4, tile=8, route="random",
+                            layers=3, layer=0),
+    "stacked_last_layer": dict(T=40, E=8, k=2, held=4, tile=8,
+                               route="random", layers=3, layer=2),
+    # the tiny presets of the two served configurations
+    "keye_tiny": dict(T=48, D=64, F=32, E=8, k=2, held=4, tile=8,
+                      route="random"),
+    "qwen3_next_tiny": dict(T=32, D=64, F=32, E=16, k=3, held=8, tile=8,
+                            route="random", layers=4, layer=3),
+    # a hidden width in two blocks: the down projection summed over them
+    "hidden_in_blocks": dict(T=40, D=128, F=256, E=8, k=2, held=4, tile=8,
+                             route="random",
+                             weight_bytes=2 * 3 * 128 * 128 * 4),
+}
+
+
+def _grouped_is_the_loop(monkeypatch, T, E, k, held, tile, route, D=32, F=16,
+                         first=0, layers=None, layer=None, weight_bytes=None):
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.parallel import moe
+    rs = onp.random.RandomState(T + E)
+    rand = lambda *s: jnp.asarray(rs.randn(*s).astype(onp.float32))
+    lead = () if layers is None else (layers,)
+    x = rand(T, D)
+    wg, wu = rand(*lead, held, F, D) / 4, rand(*lead, held, F, D) / 4
+    wd = rand(*lead, held, D, F) / 3
+    scores = rs.randn(T, E).astype(onp.float32)
+    if route == "skip_1":
+        scores[:, first + 1] = -9.0
+    elif route == "all_2":
+        scores[:, first + 2] = 9.0
+    elif route == "low":
+        scores[:, first:first + held] = -9.0
+    gate, expert = moe.topk_route(jnp.asarray(scores), k)
+    run = lambda: onp.asarray(moe.held_experts(
+        x, gate, expert, wg, wu, wd, first, tile=tile,
+        layer=None if layer is None else jnp.int32(layer)))
+    want = run()
+    local = onp.asarray(expert) - first
+    n_held = int(((local >= 0) & (local < held)).sum())
+    assert (n_held == 0) == (route == "low")
+    assert (onp.abs(want).max() > 0.05) == (n_held > 0)
+    if weight_bytes:
+        monkeypatch.setattr(moe, "_GROUPED_WEIGHT_BYTES", weight_bytes)
+        assert moe._grouped_hidden_block(F, D, 4) == F // 2
+    traced = events.get("moe.grouped_traces") or 0
+    _interpret_kernels(monkeypatch)
+    got = run()
+    # the counter that says the kernel engages: this trace, and not the
+    # CPU's of the loop above
+    assert (events.get("moe.grouped_traces") or 0) == traced + 1
+    assert onp.isfinite(got).all()
+    # float32 rounding: the blocks of F are summed in another order
+    assert onp.abs(got - want).max() < 2e-6 * max(1.0, onp.abs(want).max())
+
+
+@pytest.mark.parametrize("case", ["delta_rule"] + sorted(_GROUPED))
+def test_the_kernels_in_interpret_mode_are_the_jax_forms(monkeypatch, case):
+    """Each Pallas kernel, run itself in interpret mode, against the
+    `jax.numpy` form the CPU runs: the delta rule's two, and
+    `moe.held_experts_grouped` against the loop over tiles in each of
+    `_GROUPED`'s cases, to float32 rounding."""
+    if case == "delta_rule":
+        _delta_rule_forms(monkeypatch)
+    else:
+        _grouped_is_the_loop(monkeypatch, **_GROUPED[case])
 
 
 def test_causal_conv_rows_are_those_before_the_last_token():
@@ -308,15 +397,19 @@ def test_counters_and_prefill_rows(tiny):
 
 # ---- the expert layer: shares and the shared expert ------------------------
 
-@pytest.mark.parametrize("tile", [256, 8])
-def test_the_shares_add_up_with_the_shared_expert_counted_once(tiny, tile):
+@pytest.mark.parametrize("tile,kernel", [(256, False), (8, False), (8, True)])
+def test_the_shares_add_up_with_the_shared_expert_counted_once(
+        tiny, tile, kernel, monkeypatch):
     """Held 0-3, 4-7, 8-11 and 12-15, each with the shared expert that every
     chip computes alike: their sum less three shared terms is the reference's
-    uncut layer, through both forms of `held_experts`."""
+    uncut layer, through the forms of `held_experts`: every expert over every
+    token, the loop over tiles, and the grouped kernel in interpret mode."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.models.sparse_decoder import HeldExperts
     cfg, ref, w, _ = tiny
+    if kernel:
+        _interpret_kernels(monkeypatch)
     z = dict(ref.sizes(cfg), EH=16, E0=0)
     rs = onp.random.RandomState(2)
     D, F, E = 64, 32, 16

@@ -194,14 +194,27 @@ def test_select_mask_equals_a_sort(case):
 
 # ---- the expert layer ----------------------------------------------------
 
-@pytest.mark.parametrize("tile", [256, 8])
-def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(tiny, tile):
+def _interpret_kernels(monkeypatch):
+    from incubator_mxnet_tpu import config
+    from incubator_mxnet_tpu.parallel import moe
+    monkeypatch.setattr(config, "_OVERRIDES",
+                        dict(config._OVERRIDES, MXNET_PALLAS_INTERPRET=True))
+    assert moe._interpret()
+
+
+@pytest.mark.parametrize("tile,kernel", [(256, False), (8, False), (8, True),
+                                         (16, True)])
+def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(
+        tiny, tile, kernel, monkeypatch):
     """Held 0-3 plus held 4-7 is what the reference gives for all 8, through
-    both forms of the layer: every expert over every token (few tokens) and
-    sorted runs in tiles (many)."""
+    the forms of the layer: every expert over every token (few tokens),
+    sorted runs in tiles by the loop (many), and by the grouped kernel
+    itself in interpret mode."""
     import jax.numpy as jnp
     from incubator_mxnet_tpu.parallel import moe
     cfg, ref, w, _ = tiny
+    if kernel:
+        _interpret_kernels(monkeypatch)
     rs = onp.random.RandomState(2)
     D, F, E, k = 64, 32, 8, 2
     x = jnp.asarray(rs.randn(40, D).astype(onp.float32))
@@ -225,12 +238,16 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(tiny, tile):
     assert int(fullest.sum()) == max((local == e).sum() for e in range(4))
 
 
-def test_every_pick_on_one_held_expert_is_not_dropped():
+@pytest.mark.parametrize("kernel", [False, True])
+def test_every_pick_on_one_held_expert_is_not_dropped(kernel, monkeypatch):
     """Dropless: all tokens routed to the same two experts, far past any
-    even share, still get their full terms."""
+    even share, still get their full terms, from the loop over tiles and
+    from the grouped kernel (in interpret mode) alike."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.parallel import moe
+    if kernel:
+        _interpret_kernels(monkeypatch)
     rs = onp.random.RandomState(4)
     T, D, F = 50, 16, 8
     x = jnp.asarray(rs.randn(T, D).astype(onp.float32))

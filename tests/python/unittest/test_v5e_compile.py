@@ -13,6 +13,20 @@ from incubator_mxnet_tpu.serving import GenerationEngine
 pytestmark = pytest.mark.gen
 
 
+@pytest.fixture(autouse=True)
+def _kernels_not_their_interpreters(monkeypatch):
+    """`tests/benchmark` rehearses `benchmark/run.py --rehearse` in the
+    test process, which sets MXNET_PALLAS_INTERPRET in `os.environ` for good
+    (`setdefault`): a worker that ran such a test first would lower every
+    kernel here as its interpreter, with cache-sized temporaries and no
+    custom call.  A compile for the chip takes the kernels themselves."""
+    from incubator_mxnet_tpu import config
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(config, "_OVERRIDES", {
+        k: v for k, v in config._OVERRIDES.items()
+        if k != "MXNET_PALLAS_INTERPRET"})
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     import os
@@ -132,6 +146,70 @@ def test_hybrid_decoder_step_and_prefill_fit_the_chip(one_chip):
     assert size(pre) + total + absent < 16e9, size(pre) + total + absent
 
 
+def _results(text):
+    """(op, name, dims) of every instruction of an optimized program that
+    has one array as its result: `%name = type[dims]{layout} op(`."""
+    import re
+    for name, dims, op in re.findall(
+            r"%?([\w.\-]+) = \(?\w+\[([\d,]+)\]\S* ([\w\-]+)\(", text):
+        yield op, name, [int(n) for n in dims.split(",")]
+
+
+def _expert_sized(text, held, F, D):
+    """Instructions whose result is one layer's held experts, (held, F, D)
+    or (held, D, F) under any leading 1s: a layer's slice of an expert
+    stack, copied out."""
+    found = []
+    for op, name, dims in _results(text):
+        while dims[:1] == [1]:
+            dims = dims[1:]
+        if dims in ([held, F, D], [held, D, F]):
+            found.append((op, name))
+    return found
+
+
+@pytest.mark.parametrize("model", ["hybrid", "sparse"])
+def test_a_prefill_multiplies_its_held_experts_by_the_grouped_kernel(
+        one_chip, model):
+    """The prefill of each decoder-only model at the published widths of its
+    configuration (`HybridDecoder`: one period of four layers, a 1024-token
+    bucket; `SparseDecoder`: two layers, a 4096-token bucket; three experts
+    held so that the host's copy of the weights stays small), compiled for
+    the described v5e: the expert half of every layer is the kernel
+    `held_experts_grouped`, which reads the whole expert stacks at
+    [layer, expert]: no instruction's result is a layer's held experts (at
+    64 held experts of 3 x 512 x 2048 that copy would be 0.4 GB a layer),
+    and the decode step, in its few-token form, holds no such kernel."""
+    held = 3
+    if model == "hybrid":
+        from incubator_mxnet_tpu.models.hybrid_decoder import HybridDecoder
+        # one period, unrolled: the last layer's experts feed nothing that
+        # a prefill returns and are not compiled at all
+        F, bucket, calls = 512, 1024, 3
+        net = HybridDecoder(1024, 2048, 4, 4, 16, 2, 256, 64, 16, 32, 128,
+                            128, 4, F, 512, 10, shared_hidden=512,
+                            first_held=0, experts_held=held)
+    else:
+        from incubator_mxnet_tpu.models.sparse_decoder import SparseDecoder
+        F, bucket, calls = 768, 4096, 1
+        net = SparseDecoder(1024, 2048, 2, 32, 4, 128, F, 128, 8, 16, 64,
+                            2048, first_held=0, experts_held=held)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    net.cast("bfloat16")
+    eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=4,
+                           max_len=bucket, prompt_buckets=(bucket,),
+                           queue_cap=4)
+    _, step, _, prefill = _compile_for(eng, one_chip, 4, bucket, bucket,
+                                       prefill=True)
+    text = prefill.as_text()
+    assert len([l for l in text.splitlines()
+                if " custom-call(" in l and "held_experts_grouped" in l
+                and "tpu_custom_call" in l]) == calls
+    assert not _expert_sized(text, held, F, 2048)
+    assert "held_experts_grouped" not in step.as_text()
+
+
 def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
     """The decode step of `transformer_nmt_base` at the benchmark cell's
     own size (6 layers, 512 units, 8 heads; 512 slots of 256 rows; at a
@@ -142,7 +220,6 @@ def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
     transpose or select in the optimized program is as large as a cache
     leaf: each row is written where it lies and attention reads the
     leaves as they lie."""
-    import re
     import jax.numpy as jnp
     from incubator_mxnet_tpu import nd
     from incubator_mxnet_tpu.models.transformer import transformer_nmt_base
@@ -167,11 +244,8 @@ def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
     assert mem.temp_size_in_bytes < leaf * 4 // 4, mem.temp_size_in_bytes
     text = step.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 12
-    # `%name = type[dims]{layout} op(`: results as large as a memory leaf
-    moved = []
-    for name, dims, op in re.findall(
-            r"%?([\w.\-]+) = \(?\w+\[([\d,]+)\]\S* ([\w\-]+)\(", text):
-        if op in ("copy", "copy-start", "transpose", "select") and \
-                onp.prod([int(n) for n in dims.split(",")]) >= leaf:
-            moved.append((op, name, dims))
+    # results as large as a memory leaf
+    moved = [(op, name, dims) for op, name, dims in _results(text)
+             if op in ("copy", "copy-start", "transpose", "select")
+             and onp.prod(dims) >= leaf]
     assert not moved, moved
