@@ -12,6 +12,7 @@ from .seq2seq import Seq2Seq, GNMT, gnmt_large, gnmt_sym_gen
 from .sparse_decoder import (SparseDecoder, SelectAttention, HeldExperts,
                              RMSNorm)
 from .hybrid_decoder import HybridDecoder, GatedAttention, GatedDeltaNet
+from .latent_decoder import LatentDecoder, LatentAttention, DenseSwiGLU
 from .faster_rcnn import (FasterRCNN, faster_rcnn_toy,
                           faster_rcnn_resnet50_v1b,
                           rcnn_training_targets, RCNNTrainLoss)
@@ -28,4 +29,5 @@ __all__ = ["transformer", "BERTModel", "TransformerEncoder", "bert_base",
            "RCNNTrainLoss",
            "gnmt_sym_gen", "SparseDecoder", "SelectAttention",
            "HeldExperts", "RMSNorm", "HybridDecoder", "GatedAttention",
-           "GatedDeltaNet"]
+           "GatedDeltaNet", "LatentDecoder", "LatentAttention",
+           "DenseSwiGLU"]

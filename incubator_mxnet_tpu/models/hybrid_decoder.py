@@ -52,8 +52,8 @@ import math
 
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import NDArray
-from .sparse_decoder import (HeldExperts, RMSNorm, _Stacked, _dense, _f32,
-                             _rms, rotary)
+from .sparse_decoder import (HeldExperts, RMSNorm, _Stacked, _at, _dense,
+                             _f32, _rms, rotary)
 
 __all__ = ["GatedAttention", "GatedDeltaNet", "HybridDecoder"]
 
@@ -283,25 +283,12 @@ class HybridDecoder(HybridBlock):
         return {"attn": self.attn.stacked(), "gdn": self.gdn.stacked(),
                 "experts": self.experts.stacked()}
 
-    @staticmethod
-    def _at(tree, layer, whole=()):
-        """One layer's slice of a block's stacked parameters, the leaves
-        named in `whole` left as they are.  Each leaf is sliced on its
-        leading axis where it is used, at the layer's own index: the one
-        form of slice that XLA reads in place (a period's slice of several
-        layers, sliced again, is copied: 3.2 GB of expert weights a
-        step)."""
-        import jax
-        return {k: a if k in whole else
-                jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-                for k, a in tree.items()}
-
     def _experts(self, p, h, layer):
         """Layer `layer`'s expert half over h.  The expert weights go down
         whole with the layer's index: the many-token form reads one expert
         at a time, at [layer, expert]."""
         return self.experts.apply(
-            self._at(p["experts"], layer, ("wg", "wu", "wd")), h, layer)
+            _at(p["experts"], layer, ("wg", "wu", "wd")), h, layer)
 
     def _embed(self, tokens):
         return _f32(self.embed.data()._data[tokens])
@@ -324,11 +311,11 @@ class HybridDecoder(HybridBlock):
             states, rows = [], []
             for j in range(P - 1):
                 h, s, c = self.gdn.prompt(
-                    self._at(p["gdn"], i * (P - 1) + j), h, valid_len)
+                    _at(p["gdn"], i * (P - 1) + j), h, valid_len)
                 h = self._experts(p, h, i * P + j)[0]
                 states.append(s)
                 rows.append(c)
-            h, k, v = self.attn.prompt(self._at(p["attn"], i), h)
+            h, k, v = self.attn.prompt(_at(p["attn"], i), h)
             h = self._experts(p, h, i * P + P - 1)[0]
             return h, (k, v, jnp.stack(states), jnp.stack(rows))
 
@@ -383,11 +370,11 @@ class HybridDecoder(HybridBlock):
             h, leaves, held, full = carry
             for j in range(P - 1):
                 n = i * (P - 1) + j
-                h, leaves = self.gdn.step(self._at(p["gdn"], n), h, n,
+                h, leaves = self.gdn.step(_at(p["gdn"], n), h, n,
                                           leaves)
                 h, n_held, n_full = self._experts(p, h, i * P + j)
                 held, full = held + n_held, full + n_full
-            h, leaves = self.attn.step(self._at(p["attn"], i), h, pos, i,
+            h, leaves = self.attn.step(_at(p["attn"], i), h, pos, i,
                                        leaves)
             h, n_held, n_full = self._experts(p, h, i * P + P - 1)
             return (h, leaves, held + n_held, full + n_full), None

@@ -99,6 +99,18 @@ def _dense(x, w):
                       preferred_element_type=jnp.float32)
 
 
+def _at(tree, layer, whole=()):
+    """One layer's slice of a block's stacked parameters, the leaves named
+    in `whole` left as they are.  Each leaf is sliced on its leading axis
+    where it is used, at the layer's own index: the one form of slice that
+    XLA reads in place (a period's slice of several layers, sliced again,
+    is copied: 3.2 GB of expert weights a step)."""
+    import jax
+    return {k: a if k in whole else
+            jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for k, a in tree.items()}
+
+
 class _Stacked(HybridBlock):
     """A block whose parameters carry a leading axis of layers."""
 
@@ -233,15 +245,18 @@ class HeldExperts(_Stacked):
     `num_experts`, the top `per_token` with renormalised gates, and the
     SwiGLU experts `first_held .. first_held + held - 1` that this
     device holds.  Terms of experts held elsewhere are left out.  With
-    `shared_hidden`, a shared SwiGLU expert of that width under a sigmoid
-    gate of its own is added for every token: every device holds it whole
-    and computes it alike.  `norm_offset` as `_rms`'s."""
+    `shared_hidden`, a shared SwiGLU expert of that width is added for
+    every token, under a sigmoid gate of its own unless `shared_gate` is
+    false: every device holds it whole and computes it alike.  `route`
+    takes the place of `moe.topk_route`: (scores (T, E), per_token) ->
+    (gate, expert) in its form.  `norm_offset` as `_rms`'s."""
 
     _names = ("ln", "router", "wg", "wu", "wd")
 
     def __init__(self, layers, units, hidden, num_experts, per_token,
                  first_held=0, held=None, eps=1e-6, tile=256,
-                 shared_hidden=0, norm_offset=0.0, **kwargs):
+                 shared_hidden=0, norm_offset=0.0, route=None,
+                 shared_gate=True, **kwargs):
         super().__init__(**kwargs)
         held = num_experts - first_held if held is None else int(held)
         if not 0 <= first_held <= first_held + held <= num_experts:
@@ -253,6 +268,7 @@ class HeldExperts(_Stacked):
         self._experts = int(num_experts)
         self._eps, self._tile = float(eps), int(tile)
         self._offset = float(norm_offset)
+        self._route = route
         D, F = int(units), int(hidden)
         self.ln = self._param("ln", (D,), "zeros" if norm_offset else "ones")
         self.router = self._param("router", (num_experts, D))
@@ -261,8 +277,10 @@ class HeldExperts(_Stacked):
         self.wd = self._param("wd", (held, D, F))
         if shared_hidden:
             Fs = int(shared_hidden)
-            self._names = self._names + ("sgate", "sg", "su", "sd")
-            self.sgate = self._param("sgate", (1, D))
+            if shared_gate:
+                self._names = self._names + ("sgate",)
+                self.sgate = self._param("sgate", (1, D))
+            self._names = self._names + ("sg", "su", "sd")
             self.sg = self._param("sg", (Fs, D))
             self.su = self._param("su", (Fs, D))
             self.sd = self._param("sd", (D, Fs))
@@ -295,7 +313,7 @@ class HeldExperts(_Stacked):
         # that swaps them puts another expert's whole term in the sum
         scores = jnp.einsum("td,ed->te", x, _f32(p["router"]),
                             precision=jax.lax.Precision.HIGHEST)
-        gate, expert = moe.topk_route(scores, self._k)
+        gate, expert = (self._route or moe.topk_route)(scores, self._k)
         xw = x.astype(p["wg"].dtype)
         y = moe.held_experts(xw, gate, expert, p["wg"], p["wu"], p["wd"],
                              self._first, tile=self._tile,
@@ -304,6 +322,8 @@ class HeldExperts(_Stacked):
         if "sgate" in p:
             y = y + jax.nn.sigmoid(_dense(xw, p["sgate"])) \
                 * moe.swiglu(xw, p["sg"], p["su"], p["sd"])
+        elif "sg" in p:
+            y = y + moe.swiglu(xw, p["sg"], p["su"], p["sd"])
         return (h + y,) + moe.held_load(expert, self._first, self._held)
 
 

@@ -36,7 +36,8 @@ from .registry import register
 
 __all__ = ["flash_attention", "naive_attention", "index_scores",
            "select_mask", "masked_decode_attention",
-           "blocked_select_attention", "decode_attention",
+           "blocked_select_attention", "blocked_causal_attention",
+           "latent_decode_attention", "latent_rows_read", "decode_attention",
            "ragged_decode_attention", "dense_decode_attention",
            "decode_rows_read", "ragged_row_block"]
 
@@ -628,14 +629,15 @@ def masked_decode_attention(q, k_rows, v_rows, mask, scale):
 
 
 def _masked_block(qg, k, v, mask, scale, chunk):
-    """qg (G, h, bq, d) against k, v (G, Tk, d) under mask (bq, Tk), the
-    keys in chunks of `chunk` with a running maximum and sum (the flash
-    recurrence), so that the float32 scores alive are (G, h, bq, chunk).
+    """qg (G, h, bq, d) against k (G, Tk, d), v (G, Tk, dv) under mask
+    (bq, Tk), the keys in chunks of `chunk` with a running maximum and sum
+    (the flash recurrence), so that the float32 scores alive are
+    (G, h, bq, chunk).  Returns (G, h, bq, dv).
     Measured on a v5e (PERF.md, PR 29): a softmax over whole rows of 8192
     keys takes XLA fifty times as long as these chunks of 512."""
-    G, h, bq, d = qg.shape
+    G, h, bq, _ = qg.shape
     n = k.shape[1] // chunk
-    split = lambda t: t.reshape(G, n, chunk, d).transpose(1, 0, 2, 3)
+    split = lambda t: t.reshape(G, n, chunk, -1).transpose(1, 0, 2, 3)
     masks = mask.reshape(bq, n, chunk).transpose(1, 0, 2)
 
     def one(carry, xs):
@@ -655,9 +657,9 @@ def _masked_block(qg, k, v, mask, scale, chunk):
 
     init = (jnp.full((G, h, bq), _NEG_INF, jnp.float32),
             jnp.zeros((G, h, bq), jnp.float32),
-            jnp.zeros((G, h, bq, d), jnp.float32))
+            jnp.zeros((G, h, bq, v.shape[-1]), jnp.float32))
     (_, total, acc), _ = jax.lax.scan(one, init, (split(k), split(v), masks))
-    return acc / total[..., None]                       # (G, h, bq, d)
+    return acc / total[..., None]                       # (G, h, bq, dv)
 
 
 def blocked_select_attention(q, k, v, qi, ki, w, top_k, scale, block=1024,
@@ -688,6 +690,68 @@ def blocked_select_attention(q, k, v, qi, ki, w, top_k, scale, block=1024,
                           scale, chunk)                     # (G, h, bq, d)
         out.append(o.transpose(2, 0, 1, 3).reshape(bq, H, d))
     return jnp.concatenate(out, 0)
+
+
+def blocked_causal_attention(q, k, v, scale, block=512, chunk=512):
+    """Causal multi-head attention over a whole prompt, keys and values of
+    different widths: q, k (T, H, d), v (T, H, dv).  Query block b sees
+    keys [0, end of b) in chunks of `chunk` (`_masked_block`), the blocks
+    unrolled with static shapes, so no (T, T) matrix of a head is ever
+    whole and what lies after a block is not read.  Returns (T, H, dv)
+    float32."""
+    T, H, _ = q.shape
+    bq = min(int(block), T)
+    chunk = min(int(chunk), bq)
+    if T % bq or bq % chunk:
+        raise ValueError("%d positions are no whole number of query "
+                         "blocks of %d in key chunks of %d" % (T, bq, chunk))
+    qg = q.transpose(1, 0, 2)[:, None]                      # (H, 1, T, d)
+    kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)     # (H, T, .)
+    out = []
+    for q0 in range(0, T, bq):
+        end = q0 + bq
+        mask = jnp.arange(end)[None, :] <= q0 + jnp.arange(bq)[:, None]
+        o = _masked_block(qg[:, :, q0:end], kg[:, :end], vg[:, :end], mask,
+                          scale, chunk)                     # (H, 1, bq, dv)
+        out.append(o[:, 0].transpose(1, 0, 2))
+    return jnp.concatenate(out, 0)
+
+
+# ---------------------------------------------------------------------------
+# latent decode attention: every head reads ONE compressed row a position
+# ---------------------------------------------------------------------------
+# A latent cache holds, a position, one row c of `rank` values that every
+# head's key and value are linear maps of, and one rotary key kr that all
+# heads share.  With the key's map absorbed into the query (q_abs = Wk^T q,
+# a head) the scores are q_abs . c + q_rope . kr, and the context is the
+# softmax-weighted sum of the rows c themselves, (H, rank) a slot; the
+# value's map is applied to that afterwards.  No head's keys or values are
+# ever formed from cached rows.  The work a row: H (2 rank + rope) x 2
+# operations for (rank + rope) values read, so at H = 128 the read sits on
+# the v5e's ridge and not under it, unlike every other decode attention here.
+#
+# `latent_decode_attention` is two einsums over ALL rows of every slot under
+# a mask of the slots' lengths: it reads what `latent_rows_read` says.
+
+def latent_rows_read(lengths, T):
+    """Rows of a leaf of T rows a slot that `latent_decode_attention` reads
+    for each slot, (S,) int32: all T, whatever the slot's length."""
+    return jnp.full(lengths.shape, T, jnp.int32)
+
+
+def latent_decode_attention(q_abs, q_rope, ckv, kr, lengths, scale):
+    """One query a slot over the slot's latent rows [0, lengths[slot]).
+    q_abs (S, H, rank) the queries with the keys' map absorbed, q_rope
+    (S, H, rope); ckv (S, T, rank), kr (S, T, rope) the cached rows;
+    lengths (S,) int32 in [1, T].  Softmax in float32.  Returns the context
+    in latent space, (S, H, rank) float32."""
+    f32 = jnp.float32
+    s = jnp.einsum("shc,stc->sht", q_abs, ckv, preferred_element_type=f32) \
+        + jnp.einsum("shr,str->sht", q_rope, kr, preferred_element_type=f32)
+    live = jnp.arange(ckv.shape[1])[None, :] < lengths[:, None]
+    p = jax.nn.softmax(jnp.where(live[:, None, :], s * scale, _NEG_INF), -1)
+    return jnp.einsum("sht,stc->shc", p.astype(ckv.dtype), ckv,
+                      preferred_element_type=f32)
 
 
 # ---------------------------------------------------------------------------

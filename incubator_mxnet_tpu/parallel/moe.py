@@ -21,7 +21,9 @@ terms of the experts it HOLDS (`first_held`, and as many as its weight
 stacks carry) for the tokens routed to them.  Nothing is dropped and
 nothing stands in for the experts held elsewhere: their devices add
 their terms.  On one device the layer runs as it is, without an
-exchange.
+exchange.  `group_limited_route` is the routing of models that keep a
+token's experts inside a few groups and do not renormalise the gates; it
+returns what `topk_route` returns, and `held_experts` takes either.
 """
 from __future__ import annotations
 
@@ -37,7 +39,8 @@ from ..monitor import events
 from ..ops.attention import _interpret
 
 __all__ = ["switch_route", "moe_apply", "moe_ffn", "topk_route",
-           "swiglu", "held_experts", "held_experts_grouped", "held_load"]
+           "group_limited_route", "swiglu", "held_experts",
+           "held_experts_grouped", "held_load"]
 
 
 def switch_route(router_logits, capacity):
@@ -154,6 +157,25 @@ def topk_route(router_logits, k):
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
     top, expert = lax.top_k(probs, k)
     return top / jnp.sum(top, axis=-1, keepdims=True), expert
+
+
+def group_limited_route(router_logits, k, n_group, topk_group, scale=1.0):
+    """Softmax over all experts; the experts lie in `n_group` equal groups,
+    a group scores what its best expert scores, the `topk_group` best groups
+    are kept and the k largest experts inside them; the gates are the
+    softmax's own values times `scale`, NOT renormalised.  router_logits
+    (T, E) -> (gate (T, k) float32, expert (T, k) int32) in `topk_route`'s
+    form, best first, ties to the lower group and to the lower expert."""
+    T, E = router_logits.shape
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    best = jnp.max(probs.reshape(T, n_group, E // n_group), axis=-1)
+    _, groups = lax.top_k(best, topk_group)
+    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)                                      # (T, n_group)
+    # a softmax's values are positive, so -1 is below every kept expert
+    inside = jnp.where(jnp.repeat(kept, E // n_group, axis=1), probs, -1.0)
+    top, expert = lax.top_k(inside, k)
+    return top * scale, expert
 
 
 def held_load(expert, first_held, n_held):

@@ -113,8 +113,9 @@ and per-lane TTFT SLO targets (`slo_targets()`) that
 `telemetry/slo.py`'s default generation rules alert on.
 
 Model contract (``models/seq2seq.py``, ``models/transformer.py``,
-``models/sparse_decoder.py``, ``models/hybrid_decoder.py``), one for
-encoder-decoder and decoder-only models:
+``models/sparse_decoder.py``, ``models/hybrid_decoder.py``,
+``models/latent_decoder.py``), one for encoder-decoder and decoder-only
+models:
 
 - ``init_cache(prompt, valid_len, max_len=, mem_len=)`` → dict of
   NDArray leaves, ALL slot-major (axis 0 = request), shapes a pure

@@ -146,6 +146,64 @@ def test_hybrid_decoder_step_and_prefill_fit_the_chip(one_chip):
     assert size(pre) + total + absent < 16e9, size(pre) + total + absent
 
 
+def test_latent_decoder_step_and_prefill_fit_the_chip(one_chip):
+    """`LatentDecoder` at the published widths of the benchmark's
+    configuration and at its serving size (256 slots of 3072 rows, a
+    2048-token prefill), with the dense layer and TWO of the four sparse
+    layers (the last layer's experts feed nothing that a prefill returns:
+    with one there would be none to compile), two of the ten experts held
+    and 1024 vocabulary rows, so that
+    the host's copy of the weights stays small: the cache leaves have 512
+    and 64 values a row and no head axis, and come back aliased; the
+    prefill's experts of 1536 x 5120 run in `held_experts_grouped` (three
+    blocks of the hidden width a tile); and with the layers, experts and
+    vocabulary rows left out here added back, the step and the prefill stay
+    under 16 GB."""
+    from incubator_mxnet_tpu.models.latent_decoder import LatentDecoder
+
+    S, L, V, held, layers = 256, 3072, 1024, 2, 3
+    yarn = {"factor": 40, "original_max_position_embeddings": 4096,
+            "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
+            "mscale_all_dim": 0.707}
+    net = LatentDecoder(V, 5120, layers, 1, 128, 1536, 512, 128, 64, 128,
+                        12288, 1536, 160, 6, 8, 3, routed_scale=16.0,
+                        shared_hidden=3072, first_held=0, experts_held=held,
+                        rope_scaling=yarn)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    net.cast("bfloat16")
+    eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
+                           max_len=L, prompt_buckets=(2048,), queue_cap=4)
+    cache, step, _, prefill = _compile_for(eng, one_chip, S, L, 2048,
+                                           prefill=True)
+    m = cache["m"]
+    assert m["ckv"].shape == (S, layers, L, 512) and m["ckv"].dtype == "bfloat16"
+    assert m["kr"].shape == (S, layers, L, 64) and m["kr"].dtype == "bfloat16"
+    assert sorted(m) == ["ckv", "counts", "kr"]
+    total = S * layers * L * 576 * 2
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= total
+    text = prefill.as_text()
+    assert len([l for l in text.splitlines()
+                if " custom-call(" in l and "held_experts_grouped" in l
+                and "tpu_custom_call" in l]) == 1
+    assert "held_experts_grouped" not in step.as_text()
+    # what this test left off the chip: two sparse layers (attention
+    # 149.23 M, router and shared experts 48.0 M, ten experts of 23.59 M),
+    # eight experts of each one here, 11 776 rows of the embedding and of
+    # the head; and the two layers' rows of the cache
+    absent = 2 * (2 * (149.23e6 + 48.0e6 + 10 * 23.59e6) + 2 * 8 * 23.59e6
+                  + 2 * (12800 - V) * 5120)
+    rows = S * 2 * L * 576 * 2
+    size = lambda a: a.argument_size_in_bytes + a.output_size_in_bytes \
+        - a.alias_size_in_bytes + a.temp_size_in_bytes
+    assert size(mem) + absent + rows < 16e9, size(mem) + absent + rows
+    # a prefill runs beside the resident cache
+    pre = prefill.memory_analysis()
+    assert size(pre) + total + absent + rows < 16e9, \
+        size(pre) + total + absent + rows
+
+
 def _results(text):
     """(op, name, dims) of every instruction of an optimized program that
     has one array as its result: `%name = type[dims]{layout} op(`."""
