@@ -496,6 +496,59 @@ def _grouped_case(run):
             "tpu_custom_calls": n_calls, "rel_err": round(err, 6)}
 
 
+def _latent_case(run):
+    """`latent_decode_attention` as `LatentAttention.step` calls it, over
+    whole bfloat16 leaves at a layer that is not 0 (on the chip: the Mosaic
+    kernel at the published widths, 128 heads of rank 512 + rope 64)
+    against the same attention in NumPy float64."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import attention as att
+
+    S, H, T, R, dr, layers = (5, 8, 1024, 128, 64, 2) if run.dry \
+        else (12, 128, 3072, 512, 64, 3)
+    rs = np.random.RandomState(S)
+    dev = run.ctx().jax_device
+    put = lambda *shape: jax.device_put(
+        rs.randn(*shape).astype(np.float32), dev).astype(jnp.bfloat16)
+    qa, qr, ckv, kr = put(S, H, R), put(S, H, dr), put(S, layers, T, R), \
+        put(S, layers, T, dr)
+    tb = att.latent_row_block(T, jnp.bfloat16)
+    lens = rs.randint(1, T + 1, (S,)).astype(np.int32)
+    lens[:5] = [1, tb, tb + 1, T, 37]
+    run.on_device([qa, qr, ckv, kr], "kernels input")
+    args = (qa, qr, ckv, kr, jnp.int32(1), jax.device_put(lens, dev))
+    compiled = jax.jit(lambda *a: att.latent_decode_attention(
+        *a, 0.1147)).lower(*args).compile()
+    n_calls = compiled.as_text().count("tpu_custom_call")
+    check(run.dry or n_calls == 1,
+          "latent_decode_attention compiled %d tpu_custom_call(s) on the "
+          "chip, want the kernel" % n_calls)
+    out = compiled(*args)
+    run.on_device([out], "kernels output")
+    out = np.asarray(out)
+    check(np.isfinite(out).all(), "non-finite latent decode output")
+    f64 = lambda a: np.asarray(a.astype(jnp.float32)).astype(np.float64)
+    qa, qr, ckv, kr = f64(qa), f64(qr), f64(ckv[:, 1]), f64(kr[:, 1])
+    worst = 0.0
+    for s, n in enumerate(lens):
+        sc = (qa[s] @ ckv[s, :n].T + qr[s] @ kr[s, :n].T) * 0.1147
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        ref = (p / p.sum(-1, keepdims=True)) @ ckv[s, :n]
+        worst = max(worst, float(np.abs(out[s] - ref).max()
+                                 / np.abs(ref).max()))
+    # the probabilities are rounded to bfloat16 for the context
+    check(worst < 1e-2, "latent decode attention is %.3g (relative) away "
+          "from float64" % worst)
+    return {"slots": S, "rows": T, "row_block": tb,
+            "rows_read": int(np.asarray(att.latent_rows_read(
+                jnp.asarray(lens), jax.ShapeDtypeStruct(
+                    (S, layers, T, R), jnp.bfloat16))).sum()),
+            "rows_needed": int(lens.sum()),
+            "tpu_custom_calls": n_calls, "rel_err": round(worst, 6)}
+
+
 def phase_kernels(run):
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import attention as att
@@ -511,7 +564,8 @@ def phase_kernels(run):
             "ragged_decode": [_ragged_case(run, dt)
                               for dt in (jnp.float32, jnp.bfloat16)],
             "gated_delta": _delta_case(run),
-            "grouped_experts": _grouped_case(run)}
+            "grouped_experts": _grouped_case(run),
+            "latent_decode": _latent_case(run)}
 
 
 # --------------------------------------------------------------------------

@@ -23,8 +23,10 @@ nothing else.  The two forms of the same attention:
   (`ops.attention.blocked_causal_attention`).
 - **absorbed** (`step`): q~ = Wkn^T q_n a head, scores q~ . c + q_r . k_r
   over the cached rows, u = sum p c in the latent space, o = Wv u
-  (`ops.attention.latent_decode_attention`).  Equal to the expanded form
-  by associativity; no head's keys or values are formed from cached rows.
+  (`ops.attention.latent_decode_attention`: a Pallas kernel over the
+  leaves as they lie where the step is lowered for a TPU, two einsums
+  elsewhere).  Equal to the expanded form by associativity; no head's keys
+  or values are formed from cached rows.
 
 Types as in `sparse_decoder`: the residual stream float32, every block
 rounding its normed input to the weights' type for its matrix products,
@@ -183,7 +185,8 @@ class LatentAttention(_Stacked):
     def step(self, p, h, pos, layer, cache):
         """One layer, one token a slot, absorbed: h (S, D) at pos (S,).
         Writes row pos of the layer's latent rows and attends over rows
-        <= pos."""
+        <= pos.  The attention takes the leaves whole with the layer's
+        index: a layer's slice in front of a kernel would be copied."""
         import jax.numpy as jnp
         from ..ops.attention import latent_decode_attention
         f32 = jnp.float32
@@ -193,9 +196,8 @@ class LatentAttention(_Stacked):
                      kr=cache["kr"].at[slots, layer, pos].set(kr))
         q_abs = jnp.einsum("shn,hnc->shc", qn, p["wkn"],
                            preferred_element_type=f32).astype(c.dtype)
-        u = latent_decode_attention(
-            q_abs, qr, jnp.take(cache["ckv"], layer, axis=1),
-            jnp.take(cache["kr"], layer, axis=1), pos + 1, self.scale)
+        u = latent_decode_attention(q_abs, qr, cache["ckv"], cache["kr"],
+                                    layer, pos + 1, self.scale)
         o = jnp.einsum("shc,hvc->shv", u.astype(c.dtype), p["wv"],
                        preferred_element_type=f32)
         return self._out(p, h, o), cache
@@ -349,7 +351,7 @@ class LatentDecoder(HybridBlock):
         (logits (S, V) float32, the cache with row `pos` written), in the
         absorbed form.  `live` (S,; which slots hold a stream) only shares
         the weights' bytes out among `counts`: the attention reads every
-        slot under its mask."""
+        slot up to its position."""
         import jax
         import jax.numpy as jnp
         from ..ops.attention import latent_rows_read
@@ -382,7 +384,7 @@ class LatentDecoder(HybridBlock):
             // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
         counts = jnp.stack(
             [self._layers * (pos + 1),
-             self._layers * latent_rows_read(pos + 1, ckv.shape[2]),
+             self._layers * latent_rows_read(pos + 1, ckv),
              cache_kib, cache_kib + jnp.where(live, share, 0),
              jnp.full((S,), self._sparse * self._per_token, jnp.int32),
              held, full], axis=1).astype(jnp.int32)

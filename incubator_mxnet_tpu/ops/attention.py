@@ -32,12 +32,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..base import MXNetError
+from ..monitor import events
 from .registry import register
 
 __all__ = ["flash_attention", "naive_attention", "index_scores",
            "select_mask", "masked_decode_attention",
            "blocked_select_attention", "blocked_causal_attention",
-           "latent_decode_attention", "latent_rows_read", "decode_attention",
+           "latent_decode_attention", "latent_rows_read", "latent_row_block",
+           "decode_attention",
            "ragged_decode_attention", "dense_decode_attention",
            "decode_rows_read", "ragged_row_block"]
 
@@ -730,28 +732,181 @@ def blocked_causal_attention(q, k, v, scale, block=512, chunk=512):
 # operations for (rank + rope) values read, so at H = 128 the read sits on
 # the v5e's ridge and not under it, unlike every other decode attention here.
 #
-# `latent_decode_attention` is two einsums over ALL rows of every slot under
-# a mask of the slots' lengths: it reads what `latent_rows_read` says.
+# - `_latent_einsums`: two einsums over ALL rows of every slot of the layer's
+#   slice under a mask of the slots' lengths, the float32 scores (S, H, T)
+#   in memory between them.  The reference, what the CPU runs, and what runs
+#   for leaves the kernel does not tile.
+# - `_latent_pallas`: the kernel `latent_decode_attention`.  Grid (slots,
+#   row blocks); the layer, each slot's count of needed blocks and its
+#   length are prefetched as scalars.  The leaves come WHOLE: the layer only
+#   enters their index maps, so no layer's rows are copied out.  A step past
+#   a slot's last needed block computes nothing and fetches nothing new: its
+#   index map already names the NEXT slot's first block, so that block
+#   arrives under the last needed block's arithmetic and not after it.  A
+#   block's scores, its probabilities and the flash recurrence (float32)
+#   stay in VMEM; the context is summed from the same block's latent rows,
+#   which is why a row is read once.
+# - `latent_decode_attention` chooses between them where the step is LOWERED
+#   (`lax.platform_dependent`), as `decode_attention` does.
+#
+# The rotary keys' leaf kr (S, layers, T, rope) has rope < 128: XLA lays
+# such a leaf on the TPU with T on the lanes (minor-to-major {2,3,1,0}), so
+# the kernel takes it as (S, layers, rope, T), which is the same bytes (the
+# `swapaxes` lowers to a bitcast) and gives blocks (rope, rows) of whole
+# tiles whose product with the queries needs no transpose.
 
-def latent_rows_read(lengths, T):
-    """Rows of a leaf of T rows a slot that `latent_decode_attention` reads
-    for each slot, (S,) int32: all T, whatever the slot's length."""
-    return jnp.full(lengths.shape, T, jnp.int32)
+_LATENT_ROW_BLOCK = 512     # cached bfloat16 rows a grid step, at most
 
 
-def latent_decode_attention(q_abs, q_rope, ckv, kr, lengths, scale):
-    """One query a slot over the slot's latent rows [0, lengths[slot]).
-    q_abs (S, H, rank) the queries with the keys' map absorbed, q_rope
-    (S, H, rope); ckv (S, T, rank), kr (S, T, rope) the cached rows;
-    lengths (S,) int32 in [1, T].  Softmax in float32.  Returns the context
-    in latent space, (S, H, rank) float32."""
+def latent_row_block(T, dtype=jnp.bfloat16):
+    """Rows of one block of the latent kernel over leaves of T rows of
+    `dtype`: the largest divisor of T that is whole lane tiles (a block's
+    scores lie with the rows on the lanes) up to `_LATENT_ROW_BLOCK` rows of
+    bfloat16, half as many of float32 (the same bytes); 0 if there is
+    none."""
+    cap = _LATENT_ROW_BLOCK * 2 // jnp.dtype(dtype).itemsize
+    tb = _largest_divisor(T, cap, 128)
+    return tb if tb % 128 == 0 else 0
+
+
+def _latent_fits(ckv):
+    """Whether the kernel tiles the leaf ckv (S, layers, T, rank): full-lane
+    latent rows and a whole number of row blocks."""
+    return ckv.shape[3] % 128 == 0 \
+        and latent_row_block(ckv.shape[2], ckv.dtype) > 0
+
+
+def latent_rows_read(lengths, ckv):
+    """Rows of the leaf ckv (S, layers, T, rank) that
+    `latent_decode_attention` covers for each slot, (S,) int32: the slot's
+    length rounded up to the kernel's row block, or all T where the leaf
+    does not tile."""
+    T = ckv.shape[2]
+    tb = latent_row_block(T, ckv.dtype) if _latent_fits(ckv) else T
+    return (-(-jnp.clip(lengths, 1, T) // tb) * tb).astype(jnp.int32)
+
+
+def latent_decode_attention(q_abs, q_rope, ckv, kr, layer, lengths, scale):
+    """One query a slot over the slot's latent rows [0, lengths[slot]) of
+    one layer.  q_abs (S, H, rank) the queries with the keys' map absorbed,
+    q_rope (S, H, rope); ckv (S, layers, T, rank) and kr (S, layers, T,
+    rope) the cache's leaves, whole; `layer` (a traced scalar) the layer
+    whose rows are read; lengths (S,) int32 in [1, T].  Products of
+    operands in the leaves' type summed in float32, softmax in float32,
+    the probabilities rounded to the leaves' type for the context.  Returns
+    the context in latent space, (S, H, rank) float32.
+
+    The kernel where the step is lowered for a TPU (and wherever
+    `MXNET_PALLAS_INTERPRET` runs the kernel itself), the einsums elsewhere
+    and for leaves the kernel does not tile."""
+    args = (q_abs, q_rope, ckv, kr, jnp.asarray(layer, jnp.int32).reshape(1),
+            lengths)
+    kernel = functools.partial(_latent_pallas, scale=scale)
+    einsums = functools.partial(_latent_einsums, scale=scale)
+    if not _latent_fits(ckv):
+        return einsums(*args)
+    if _interpret() or jax.default_backend() == "tpu":
+        # trace-time side effect only, as `serve.traces` is: one for each
+        # layer body that is lowered with the kernel
+        events.incr("mla.kernel_traces")
+    if _interpret():
+        return kernel(*args)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=einsums)
+
+
+def _latent_einsums(q_abs, q_rope, ckv, kr, layer, lengths, scale):
     f32 = jnp.float32
-    s = jnp.einsum("shc,stc->sht", q_abs, ckv, preferred_element_type=f32) \
-        + jnp.einsum("shr,str->sht", q_rope, kr, preferred_element_type=f32)
-    live = jnp.arange(ckv.shape[1])[None, :] < lengths[:, None]
+    c, r = jnp.take(ckv, layer[0], axis=1), jnp.take(kr, layer[0], axis=1)
+    s = jnp.einsum("shc,stc->sht", q_abs, c, preferred_element_type=f32) \
+        + jnp.einsum("shr,str->sht", q_rope, r, preferred_element_type=f32)
+    live = jnp.arange(c.shape[1])[None, :] < lengths[:, None]
     p = jax.nn.softmax(jnp.where(live[:, None, :], s * scale, _NEG_INF), -1)
-    return jnp.einsum("sht,stc->shc", p.astype(ckv.dtype), ckv,
+    return jnp.einsum("sht,stc->shc", p.astype(c.dtype), c,
                       preferred_element_type=f32)
+
+
+def _latent_kernel(layer_ref, need_ref, len_ref, qa_ref, qr_ref, c_ref, r_ref,
+                   o_ref, m_s, l_s, acc_s, *, scale, tb):
+    del layer_ref                       # read by the index maps
+    s, j = pl.program_id(0), pl.program_id(1)
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, _NEG_INF, f32)
+        l_s[...] = jnp.zeros(l_s.shape, f32)
+        acc_s[...] = jnp.zeros(acc_s.shape, f32)
+
+    @pl.when(j < need_ref[s])
+    def _block():
+        c = c_ref[0, 0]                                         # (tb, rank)
+        sc = jax.lax.dot_general(qa_ref[0], c, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=f32) \
+            + jnp.dot(qr_ref[0], r_ref[0, 0], preferred_element_type=f32)
+        row = j * tb + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(row < len_ref[s], sc * scale, _NEG_INF)  # (H, tb)
+        m_prev = m_s[...]                                       # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        shrink = jnp.exp(m_prev - m_new)
+        l_s[...] = l_s[...] * shrink + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * shrink + jnp.dot(
+            p.astype(c.dtype), c, preferred_element_type=f32)
+        m_s[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        o_ref[0] = acc_s[...] / l_s[...]
+
+
+def _latent_pallas(q_abs, q_rope, ckv, kr, layer, lengths, scale):
+    S, H, rank = q_abs.shape
+    T, rope = kr.shape[2:]
+    tb = latent_row_block(T, ckv.dtype)
+    lens = jnp.clip(lengths.astype(jnp.int32), 1, T)
+    need = -(-lens // tb)                   # row blocks a slot computes
+
+    def at(s, j, need):
+        """(slot, row block) that step (s, j) holds: its own while the slot
+        needs block j, then the next slot's first (the last slot keeps its
+        last)."""
+        done, last = j >= need[s], s == S - 1
+        return (jnp.where(done & ~last, s + 1, s),
+                jnp.where(done, jnp.where(last, need[s] - 1, 0), j))
+
+    def query(s, j, layer, need, lens):
+        return (at(s, j, need)[0], 0, 0)
+
+    def rows(s, j, layer, need, lens):
+        slot, block = at(s, j, need)
+        return (slot, layer[0], block, 0)
+
+    def rows_t(s, j, layer, need, lens):
+        slot, block = at(s, j, need)
+        return (slot, layer[0], 0, block)
+
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=float(scale), tb=tb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, T // tb),
+            in_specs=[
+                pl.BlockSpec((1, H, rank), query),
+                pl.BlockSpec((1, H, rope), query),
+                pl.BlockSpec((1, 1, tb, rank), rows),
+                pl.BlockSpec((1, 1, rope, tb), rows_t),
+            ],
+            out_specs=pl.BlockSpec((1, H, rank), lambda s, j, *_: (s, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, rank), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, H, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="latent_decode_attention",
+        interpret=_interpret(),
+    )(layer, need, lens, q_abs, q_rope, ckv, jnp.swapaxes(kr, 2, 3))
 
 
 # ---------------------------------------------------------------------------

@@ -78,6 +78,12 @@ def _compile_for(eng, chip, slots, max_len, bucket, join=False,
     return cache, step, join, prefill
 
 
+def _kernel_calls(text, name):
+    """How many Mosaic custom calls of an optimized program bear `name`."""
+    return len([l for l in text.splitlines() if " custom-call(" in l
+                and name in l and "tpu_custom_call" in l])
+
+
 def test_sparse_decoder_step_writes_its_cache_in_place(one_chip):
     """The decode step and the join of a SparseDecoder at the published
     widths (2 layers, 4 slots of 4096 rows): the donated cache comes back
@@ -156,9 +162,13 @@ def test_latent_decoder_step_and_prefill_fit_the_chip(one_chip):
     the host's copy of the weights stays small: the cache leaves have 512
     and 64 values a row and no head axis, and come back aliased; the
     prefill's experts of 1536 x 5120 run in `held_experts_grouped` (three
-    blocks of the hidden width a tile); and with the layers, experts and
-    vocabulary rows left out here added back, the step and the prefill stay
-    under 16 GB."""
+    blocks of the hidden width a tile); the step attends through the kernel
+    `latent_decode_attention`, once for the dense layer and once in the
+    scan's body, over the leaves as they lie: no float32 scores (S, 128,
+    3072) and no copy of a layer's rows are among its temporaries, 69.1 MB
+    in all (815.7 MB with the einsums, before the kernel); the prefill
+    holds no such kernel; and with the layers, experts and vocabulary rows
+    left out here added back, the step and the prefill stay under 16 GB."""
     from incubator_mxnet_tpu.models.latent_decoder import LatentDecoder
 
     S, L, V, held, layers = 256, 3072, 1024, 2, 3
@@ -183,11 +193,18 @@ def test_latent_decoder_step_and_prefill_fit_the_chip(one_chip):
     total = S * layers * L * 576 * 2
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= total
+    assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes
+    text = step.as_text()
+    assert _kernel_calls(text, "latent_decode_attention") == 2
+    assert _kernel_calls(text, "held_experts_grouped") == 0
+    # the scores of every row, or a layer's rows out of a leaf
+    moved = [(op, name, dims) for op, name, dims in _results(text)
+             if sorted(dims) in (sorted([S, 128, L]), sorted([S, L, 512]),
+                                 sorted([S, L, 64]))]
+    assert not moved, moved
     text = prefill.as_text()
-    assert len([l for l in text.splitlines()
-                if " custom-call(" in l and "held_experts_grouped" in l
-                and "tpu_custom_call" in l]) == 1
-    assert "held_experts_grouped" not in step.as_text()
+    assert _kernel_calls(text, "held_experts_grouped") == 1
+    assert _kernel_calls(text, "latent_decode_attention") == 0
     # what this test left off the chip: two sparse layers (attention
     # 149.23 M, router and shared experts 48.0 M, ten experts of 23.59 M),
     # eight experts of each one here, 11 776 rows of the embedding and of
@@ -261,9 +278,7 @@ def test_a_prefill_multiplies_its_held_experts_by_the_grouped_kernel(
     _, step, _, prefill = _compile_for(eng, one_chip, 4, bucket, bucket,
                                        prefill=True)
     text = prefill.as_text()
-    assert len([l for l in text.splitlines()
-                if " custom-call(" in l and "held_experts_grouped" in l
-                and "tpu_custom_call" in l]) == calls
+    assert _kernel_calls(text, "held_experts_grouped") == calls
     assert not _expert_sized(text, held, F, 2048)
     assert "held_experts_grouped" not in step.as_text()
 

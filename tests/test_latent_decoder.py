@@ -285,26 +285,94 @@ def test_absorbed_is_expanded_on_the_same_rows():
     assert (onp.asarray(new["ckv"][:, 0]) == onp.asarray(noise[:, 0])).all()
 
 
-def test_latent_decode_attention_is_attention_over_the_live_rows():
-    """Against a loop over slots in NumPy float64: rows past a slot's
-    length, whatever they hold, do not enter."""
+def _attend_by_hand(qa, qr, ckv, kr, layer, lengths, scale):
+    """A loop over slots in NumPy float64 over rows [0, length) of `layer`."""
+    f64 = lambda a: onp.asarray(a, onp.float64)
+    qa, qr, ckv, kr = f64(qa), f64(qr), f64(ckv), f64(kr)
+    out = []
+    for s, n in enumerate(lengths):
+        c = ckv[s, layer, :n]
+        sc = (qa[s] @ c.T + qr[s] @ kr[s, layer, :n].T) * scale
+        p = onp.exp(sc - sc.max(-1, keepdims=True))
+        out.append((p / p.sum(-1, keepdims=True)) @ c)
+    return onp.stack(out)
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_latent_decode_attention_is_attention_over_the_live_rows(
+        monkeypatch, interpret):
+    """The einsums (leaves the kernel does not tile: rank 8, 16 rows), in
+    interpret mode too, against a loop over slots in NumPy float64: rows
+    past a slot's length and the other layer's rows, whatever they hold,
+    do not enter; every row of the slot is read."""
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import attention as A
     S, H, T, R, dr = 4, 3, 16, 8, 4
     rs = onp.random.RandomState(3)
-    qa, qr = rs.randn(S, H, R), rs.randn(S, H, dr)
-    ckv, kr = rs.randn(S, T, R), rs.randn(S, T, dr)
+    f32 = lambda *s: jnp.asarray(rs.randn(*s).astype(onp.float32))
+    qa, qr, ckv, kr = f32(S, H, R), f32(S, H, dr), f32(S, 2, T, R), \
+        f32(S, 2, T, dr)
     lengths = onp.array([16, 1, 7, 12])
+    if interpret:
+        _interpret_kernels(monkeypatch)
+    traced = events.get("mla.kernel_traces") or 0
     got = onp.asarray(A.latent_decode_attention(
-        *[jnp.asarray(a.astype(onp.float32)) for a in (qa, qr, ckv, kr)],
-        jnp.asarray(lengths), 0.3))
-    for s, n in enumerate(lengths):
-        sc = (qa[s] @ ckv[s, :n].T + qr[s] @ kr[s, :n].T) * 0.3
-        p = onp.exp(sc - sc.max(-1, keepdims=True))
-        want = (p / p.sum(-1, keepdims=True)) @ ckv[s, :n]
-        assert onp.abs(got[s] - want).max() < 1e-5
-    assert list(onp.asarray(A.latent_rows_read(jnp.asarray(lengths), T))) \
+        qa, qr, ckv, kr, jnp.int32(1), jnp.asarray(lengths), 0.3))
+    want = _attend_by_hand(qa, qr, ckv, kr, 1, lengths, 0.3)
+    assert onp.abs(got - want).max() < 1e-5
+    assert list(onp.asarray(A.latent_rows_read(jnp.asarray(lengths), ckv))) \
         == [T] * S
+    assert (events.get("mla.kernel_traces") or 0) == traced
+
+
+@pytest.mark.parametrize("dtype,lengths", [
+    ("bfloat16", [1, 1536, 512, 513, 1400, 37]),
+    ("bfloat16", [1300, 40, 1024, 1025, 1536, 1536]),
+    ("float32", [1, 1536, 256, 257, 1400, 37]),
+    ("float32", [1300, 40, 768, 769, 1536, 1536]),
+])
+def test_latent_kernel_reads_each_slots_rows_up_to_its_length(
+        monkeypatch, dtype, lengths):
+    """The kernel in interpret mode over whole leaves (S, 3 layers, 1536,
+    128 | 64) at layer 1, against the loop in float64: lengths 1, T, a
+    block border, a border + 1, a slot far shorter than its neighbours (its
+    steps past its rows hold the next slot's first block), the last slot
+    short and full.  Rows past a slot's length hold huge values, blocks
+    past its last needed one and both other layers NaN: none may enter.
+    `latent_rows_read` is the length rounded up to the row block, and
+    `mla.kernel_traces` counts the one layer body traced."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import attention as A
+    S, H, T, R, dr, layers = len(lengths), 8, 1536, 128, 64, 3
+    dt = jnp.dtype(dtype)
+    tb = A.latent_row_block(T, dt)
+    assert tb == (512 if dtype == "bfloat16" else 256)
+    rs = onp.random.RandomState(len(dtype) + lengths[0])
+    rnd = lambda *s: onp.asarray(jnp.asarray(
+        rs.randn(*s).astype(onp.float32), dt).astype(jnp.float32))
+    qa, qr = rnd(S, H, R), rnd(S, H, dr)
+    ckv = onp.full((S, layers, T, R), onp.nan, onp.float32)
+    kr = onp.full((S, layers, T, dr), onp.nan, onp.float32)
+    for s, n in enumerate(lengths):
+        end = -(-n // tb) * tb
+        ckv[s, 1, :end], kr[s, 1, :end] = 1e30, -1e30
+        ckv[s, 1, :n], kr[s, 1, :n] = rnd(n, R), rnd(n, dr)
+    want = _attend_by_hand(qa, qr, ckv, kr, 1, lengths, 0.11)
+    _interpret_kernels(monkeypatch)
+    traced = events.get("mla.kernel_traces") or 0
+    run = jax.jit(lambda *a: A.latent_decode_attention(*a, 0.11))
+    got = onp.asarray(run(*[jnp.asarray(a, dt) for a in (qa, qr, ckv, kr)],
+                          jnp.int32(1), jnp.asarray(lengths, jnp.int32)))
+    assert (events.get("mla.kernel_traces") or 0) == traced + 1
+    assert got.shape == (S, H, R) and got.dtype == onp.float32
+    assert onp.isfinite(got).all()
+    # bfloat16: the probabilities are rounded to 8 bits for the context
+    tol = 1e-2 if dtype == "bfloat16" else 2e-5
+    assert onp.abs(got - want).max() < tol * onp.abs(want).max()
+    assert list(onp.asarray(A.latent_rows_read(
+        jnp.asarray(lengths), jnp.zeros((S, layers, T, R), dt)))) \
+        == [-(-n // tb) * tb for n in lengths]
 
 
 def test_blocked_causal_attention_takes_values_narrower_than_keys():
