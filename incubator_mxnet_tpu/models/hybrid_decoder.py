@@ -52,6 +52,7 @@ import math
 
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import NDArray
+from ..telemetry import costs as _costs
 from .sparse_decoder import (HeldExperts, RMSNorm, _Stacked, _at, _dense,
                              _f32, _rms, rotary)
 
@@ -102,8 +103,9 @@ class GatedAttention(_Stacked):
 
     def _out(self, p, h, o, gate):
         import jax
-        o = o.reshape(h.shape[0], -1) * jax.nn.sigmoid(gate)
-        return h + _dense(o.astype(p["wo"].dtype), p["wo"])
+        with _costs.part("proj"):
+            o = o.reshape(h.shape[0], -1) * jax.nn.sigmoid(gate)
+            return h + _dense(o.astype(p["wo"].dtype), p["wo"])
 
     def prompt(self, p, h):
         """One layer over a whole prompt h (T, D): (h + attention, the
@@ -111,17 +113,20 @@ class GatedAttention(_Stacked):
         import jax
         import jax.numpy as jnp
         T = h.shape[0]
-        q, k, v, gate = self.project(p, h, jnp.arange(T))
-        qg = q.reshape(T, self._G, self._H // self._G, self._d)
-        s = jnp.einsum("qghd,kgd->ghqk", qg, k,
-                       preferred_element_type=jnp.float32) \
-            / math.sqrt(self._d)
-        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
-        o = jnp.einsum("ghqk,kgd->qghd",
-                       jax.nn.softmax(s, -1).astype(v.dtype), v,
-                       preferred_element_type=jnp.float32)
-        return self._out(p, h, o, gate), k.transpose(1, 0, 2), \
-            v.transpose(1, 0, 2)
+        with _costs.part("proj"):
+            q, k, v, gate = self.project(p, h, jnp.arange(T))
+        with _costs.part("attn"):
+            qg = q.reshape(T, self._G, self._H // self._G, self._d)
+            s = jnp.einsum("qghd,kgd->ghqk", qg, k,
+                           preferred_element_type=jnp.float32) \
+                / math.sqrt(self._d)
+            s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+            o = jnp.einsum("ghqk,kgd->qghd",
+                           jax.nn.softmax(s, -1).astype(v.dtype), v,
+                           preferred_element_type=jnp.float32)
+        h = self._out(p, h, o, gate)
+        with _costs.part("cache"):
+            return h, k.transpose(1, 0, 2), v.transpose(1, 0, 2)
 
     def step(self, p, h, pos, layer, cache):
         """One layer, one token a slot: h (S, D) at pos (S,).  Writes row
@@ -129,16 +134,20 @@ class GatedAttention(_Stacked):
         import jax.numpy as jnp
         from ..ops.attention import masked_decode_attention
         S = h.shape[0]
-        q, k, v, gate = self.project(p, h, pos)
-        at = (jnp.arange(S)[:, None], layer, jnp.arange(self._G)[None, :],
-              pos[:, None])
-        cache = dict(cache, k=cache["k"].at[at].set(k),
-                     v=cache["v"].at[at].set(v))
-        L = cache["k"].shape[3]
-        o = masked_decode_attention(
-            q, jnp.take(cache["k"], layer, axis=1),
-            jnp.take(cache["v"], layer, axis=1),
-            jnp.arange(L)[None, :] <= pos[:, None], 1.0 / math.sqrt(self._d))
+        with _costs.part("proj"):
+            q, k, v, gate = self.project(p, h, pos)
+        with _costs.part("cache"):
+            at = (jnp.arange(S)[:, None], layer,
+                  jnp.arange(self._G)[None, :], pos[:, None])
+            cache = dict(cache, k=cache["k"].at[at].set(k),
+                         v=cache["v"].at[at].set(v))
+        with _costs.part("attn"):
+            L = cache["k"].shape[3]
+            o = masked_decode_attention(
+                q, jnp.take(cache["k"], layer, axis=1),
+                jnp.take(cache["v"], layer, axis=1),
+                jnp.arange(L)[None, :] <= pos[:, None],
+                1.0 / math.sqrt(self._d))
         return self._out(p, h, o, gate), cache
 
 
@@ -205,9 +214,10 @@ class GatedDeltaNet(_Stacked):
 
     def _out(self, p, h, o, z):
         import jax
-        o = _rms(o, p["gn"], self._eps) * jax.nn.silu(z)
-        return h + _dense(o.reshape(h.shape[0], -1).astype(p["wo"].dtype),
-                          p["wo"])
+        with _costs.part("proj"):
+            o = _rms(o, p["gn"], self._eps) * jax.nn.silu(z)
+            return h + _dense(
+                o.reshape(h.shape[0], -1).astype(p["wo"].dtype), p["wo"])
 
     def prompt(self, p, h, valid_len=None):
         """One layer over a whole prompt h (T, D): (h + the mixer, the
@@ -215,11 +225,13 @@ class GatedDeltaNet(_Stacked):
         of BEFORE position valid_len - 1; after the last position without
         `valid_len`)."""
         from ..ops import linear_attention as la
-        qkv, z, g, beta = self._project(p, h)
-        y, rows = la.causal_conv(qkv, p["conv"], valid_len)
-        q, k, v = self._heads(y)
-        o, state = la.gated_delta_chunked(q, k, v, g, beta, valid_len,
-                                          self._chunk)
+        with _costs.part("proj"):
+            qkv, z, g, beta = self._project(p, h)
+        with _costs.part("state"):
+            y, rows = la.causal_conv(qkv, p["conv"], valid_len)
+            q, k, v = self._heads(y)
+            o, state = la.gated_delta_chunked(q, k, v, g, beta, valid_len,
+                                              self._chunk)
         return self._out(p, h, o, z), state, rows
 
     def step(self, p, h, layer, cache):
@@ -228,14 +240,18 @@ class GatedDeltaNet(_Stacked):
         import jax
         import jax.numpy as jnp
         from ..ops import linear_attention as la
-        qkv, z, g, beta = self._project(p, h)
-        y, rows = la.causal_conv_step(qkv, jnp.take(cache["c"], layer, 1),
-                                      p["conv"])
-        q, k, v = self._heads(y)
-        o, states = la.gated_delta_step(q, k, v, g, beta, cache["s"], layer)
-        cache = dict(cache, s=states,
-                     c=jax.lax.dynamic_update_slice_in_dim(
-                         cache["c"], rows[:, None], layer, axis=1))
+        with _costs.part("proj"):
+            qkv, z, g, beta = self._project(p, h)
+        with _costs.part("state"):
+            y, rows = la.causal_conv_step(
+                qkv, jnp.take(cache["c"], layer, 1), p["conv"])
+            q, k, v = self._heads(y)
+            o, states = la.gated_delta_step(q, k, v, g, beta, cache["s"],
+                                            layer)
+        with _costs.part("cache"):
+            cache = dict(cache, s=states,
+                         c=jax.lax.dynamic_update_slice_in_dim(
+                             cache["c"], rows[:, None], layer, axis=1))
         return self._out(p, h, o, z), cache
 
 
@@ -291,11 +307,13 @@ class HybridDecoder(HybridBlock):
             _at(p["experts"], layer, ("wg", "wu", "wd")), h, layer)
 
     def _embed(self, tokens):
-        return _f32(self.embed.data()._data[tokens])
+        with _costs.part("embed"):
+            return _f32(self.embed.data()._data[tokens])
 
     def _logits(self, h):
         g, w = self.norm.gamma.data()._data, self.head.data()._data
-        return _dense(_rms(h, g, self.norm._eps, 1.0).astype(w.dtype), w)
+        with _costs.part("head"):
+            return _dense(_rms(h, g, self.norm._eps, 1.0).astype(w.dtype), w)
 
     def _run_prompt(self, tokens, valid_len=None):
         """tokens (T,) -> (h (T, D), k, v (full layers, G, T, d), s
@@ -345,11 +363,13 @@ class HybridDecoder(HybridBlock):
         pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 2)
                                 + [(0, int(max_len) - T), (0, 0)])
         last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        out = {"k": pad(k), "v": pad(v), "s": s, "c": c,
-               "counts": jnp.zeros((B, len(self.step_counts)), jnp.int32),
-               "start_tok": jnp.take_along_axis(
-                   tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-               "start_pos": last}
+        with _costs.part("cache"):
+            out = {"k": pad(k), "v": pad(v), "s": s, "c": c,
+                   "counts": jnp.zeros((B, len(self.step_counts)),
+                                       jnp.int32),
+                   "start_tok": jnp.take_along_axis(
+                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
+                   "start_pos": last}
         return {name: NDArray(a) for name, a in out.items()}
 
     def decode_step(self, tok, pos, cache, live):
@@ -387,12 +407,13 @@ class HybridDecoder(HybridBlock):
         # K/V rows 0..pos and writes row pos, in every layer of their kind
         state = 2 * (size(leaves["s"]) + size(leaves["c"]))
         row = 2 * size(leaves["k"]) // leaves["k"].shape[3]
-        counts = jnp.stack(
-            [self._periods * (pos + 1),
-             jnp.full((S,), state // 1024, jnp.int32),
-             (state + row * (pos + 2)) // 1024,
-             jnp.full((S,), self._layers * self._per_token, jnp.int32),
-             held, full], axis=1).astype(jnp.int32)
+        with _costs.part("cache"):
+            counts = jnp.stack(
+                [self._periods * (pos + 1),
+                 jnp.full((S,), state // 1024, jnp.int32),
+                 (state + row * (pos + 2)) // 1024,
+                 jnp.full((S,), self._layers * self._per_token, jnp.int32),
+                 held, full], axis=1).astype(jnp.int32)
         new = dict(cache)
         new.update({n: NDArray(a) for n, a in leaves.items()})
         new["counts"] = NDArray(counts)

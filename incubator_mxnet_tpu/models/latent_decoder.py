@@ -55,6 +55,7 @@ import numpy as np
 from ..gluon.block import HybridBlock
 from ..monitor import events
 from ..ndarray.ndarray import NDArray
+from ..telemetry import costs as _costs
 from .sparse_decoder import (HeldExperts, RMSNorm, _Stacked, _at, _dense,
                              _f32, _rms)
 
@@ -160,8 +161,9 @@ class LatentAttention(_Stacked):
                 rot(_dense(x, p["wkr"])).astype(dt))
 
     def _out(self, p, h, o):
-        return h + _dense(o.reshape(h.shape[0], -1).astype(p["wo"].dtype),
-                          p["wo"])
+        with _costs.part("proj"):
+            return h + _dense(
+                o.reshape(h.shape[0], -1).astype(p["wo"].dtype), p["wo"])
 
     def prompt(self, p, h, block, chunk):
         """One layer over a whole prompt h (T, D), expanded: (h +
@@ -170,16 +172,17 @@ class LatentAttention(_Stacked):
         import jax.numpy as jnp
         from ..ops.attention import blocked_causal_attention
         T, f32 = h.shape[0], jnp.float32
-        qn, qr, c, kr = self.project(p, h, jnp.arange(T))
-        kn = jnp.einsum("tc,hnc->thn", c, p["wkn"],
-                        preferred_element_type=f32).astype(c.dtype)
-        v = jnp.einsum("tc,hvc->thv", c, p["wv"],
-                       preferred_element_type=f32).astype(c.dtype)
-        k = jnp.concatenate(
-            [kn, jnp.broadcast_to(kr[:, None, :], (T, self._H, self._dr))],
-            -1)
-        o = blocked_causal_attention(jnp.concatenate([qn, qr], -1), k, v,
-                                     self.scale, block, chunk)
+        with _costs.part("proj"):
+            qn, qr, c, kr = self.project(p, h, jnp.arange(T))
+            kn = jnp.einsum("tc,hnc->thn", c, p["wkn"],
+                            preferred_element_type=f32).astype(c.dtype)
+            v = jnp.einsum("tc,hvc->thv", c, p["wv"],
+                           preferred_element_type=f32).astype(c.dtype)
+            k = jnp.concatenate(
+                [kn, jnp.broadcast_to(kr[:, None, :],
+                                      (T, self._H, self._dr))], -1)
+            q = jnp.concatenate([qn, qr], -1)
+        o = blocked_causal_attention(q, k, v, self.scale, block, chunk)
         return self._out(p, h, o), c, kr
 
     def step(self, p, h, pos, layer, cache):
@@ -190,16 +193,21 @@ class LatentAttention(_Stacked):
         import jax.numpy as jnp
         from ..ops.attention import latent_decode_attention
         f32 = jnp.float32
-        qn, qr, c, kr = self.project(p, h, pos)
-        slots = jnp.arange(h.shape[0])
-        cache = dict(cache, ckv=cache["ckv"].at[slots, layer, pos].set(c),
-                     kr=cache["kr"].at[slots, layer, pos].set(kr))
-        q_abs = jnp.einsum("shn,hnc->shc", qn, p["wkn"],
-                           preferred_element_type=f32).astype(c.dtype)
+        with _costs.part("proj"):
+            qn, qr, c, kr = self.project(p, h, pos)
+        with _costs.part("cache"):
+            slots = jnp.arange(h.shape[0])
+            cache = dict(cache,
+                         ckv=cache["ckv"].at[slots, layer, pos].set(c),
+                         kr=cache["kr"].at[slots, layer, pos].set(kr))
+        with _costs.part("proj"):
+            q_abs = jnp.einsum("shn,hnc->shc", qn, p["wkn"],
+                               preferred_element_type=f32).astype(c.dtype)
         u = latent_decode_attention(q_abs, qr, cache["ckv"], cache["kr"],
                                     layer, pos + 1, self.scale)
-        o = jnp.einsum("shc,hvc->shv", u.astype(c.dtype), p["wv"],
-                       preferred_element_type=f32)
+        with _costs.part("proj"):
+            o = jnp.einsum("shc,hvc->shv", u.astype(c.dtype), p["wv"],
+                           preferred_element_type=f32)
         return self._out(p, h, o), cache
 
 
@@ -220,8 +228,9 @@ class DenseSwiGLU(_Stacked):
 
     def apply(self, p, h):
         from ..parallel import moe
-        x = _rms(h, p["ln"], self._eps).astype(p["wg"].dtype)
-        return h + moe.swiglu(x, p["wg"], p["wu"], p["wd"])
+        with _costs.part("ffn"):
+            x = _rms(h, p["ln"], self._eps).astype(p["wg"].dtype)
+            return h + moe.swiglu(x, p["wg"], p["wu"], p["wd"])
 
 
 class LatentDecoder(HybridBlock):
@@ -283,11 +292,13 @@ class LatentDecoder(HybridBlock):
             _at(p["experts"], i, ("wg", "wu", "wd")), h, i)
 
     def _embed(self, tokens):
-        return _f32(self.embed.data()._data[tokens])
+        with _costs.part("embed"):
+            return _f32(self.embed.data()._data[tokens])
 
     def _logits(self, h):
         g, w = self.norm.gamma.data()._data, self.head.data()._data
-        return _dense(_rms(h, g, self.norm._eps).astype(w.dtype), w)
+        with _costs.part("head"):
+            return _dense(_rms(h, g, self.norm._eps).astype(w.dtype), w)
 
     def _run_prompt(self, tokens):
         """tokens (T,) -> (h (T, D), c (layers, T, kv_rank), k_r (layers,
@@ -332,11 +343,13 @@ class LatentDecoder(HybridBlock):
         pad = lambda a: jnp.pad(a, [(0, 0), (0, 0), (0, int(max_len) - T),
                                     (0, 0)])
         last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        out = {"ckv": pad(c), "kr": pad(kr),
-               "counts": jnp.zeros((B, len(self.step_counts)), jnp.int32),
-               "start_tok": jnp.take_along_axis(
-                   tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-               "start_pos": last}
+        with _costs.part("cache"):
+            out = {"ckv": pad(c), "kr": pad(kr),
+                   "counts": jnp.zeros((B, len(self.step_counts)),
+                                       jnp.int32),
+                   "start_tok": jnp.take_along_axis(
+                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
+                   "start_pos": last}
         return {name: NDArray(a) for name, a in out.items()}
 
     def step_weight_bytes(self):
@@ -379,15 +392,16 @@ class LatentDecoder(HybridBlock):
         # a step reads rows 0..pos of both leaves and writes row pos, in
         # every layer
         row = (ckv.shape[-1] + kr.shape[-1]) * ckv.dtype.itemsize
-        cache_kib = self._layers * row * (pos + 2) // 1024
-        share = (self.step_weight_bytes() // 1024) \
-            // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
-        counts = jnp.stack(
-            [self._layers * (pos + 1),
-             self._layers * latent_rows_read(pos + 1, ckv),
-             cache_kib, cache_kib + jnp.where(live, share, 0),
-             jnp.full((S,), self._sparse * self._per_token, jnp.int32),
-             held, full], axis=1).astype(jnp.int32)
+        with _costs.part("cache"):
+            cache_kib = self._layers * row * (pos + 2) // 1024
+            share = (self.step_weight_bytes() // 1024) \
+                // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
+            counts = jnp.stack(
+                [self._layers * (pos + 1),
+                 self._layers * latent_rows_read(pos + 1, ckv),
+                 cache_kib, cache_kib + jnp.where(live, share, 0),
+                 jnp.full((S,), self._sparse * self._per_token, jnp.int32),
+                 held, full], axis=1).astype(jnp.int32)
         new = dict(cache)
         new.update({n: NDArray(a) for n, a in leaves.items()})
         new["counts"] = NDArray(counts)

@@ -55,6 +55,7 @@ import math
 
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import NDArray
+from ..telemetry import costs as _costs
 
 __all__ = ["RMSNorm", "SelectAttention", "HeldExperts", "SparseDecoder",
            "rotary"]
@@ -205,11 +206,14 @@ class SelectAttention(_Stacked):
         import jax.numpy as jnp
         from ..ops.attention import blocked_select_attention
         T = h.shape[0]
-        q, k, v, qi, ki, w = self.project(p, h, jnp.arange(T))
+        with _costs.part("proj"):
+            q, k, v, qi, ki, w = self.project(p, h, jnp.arange(T))
         o = blocked_select_attention(q, k, v, qi, ki, w, self._topk,
                                      1.0 / math.sqrt(self._d), block, chunk)
-        h = h + _dense(o.reshape(T, -1).astype(k.dtype), p["wo"])
-        return h, k.transpose(1, 0, 2), v.transpose(1, 0, 2), ki
+        with _costs.part("proj"):
+            h = h + _dense(o.reshape(T, -1).astype(k.dtype), p["wo"])
+        with _costs.part("cache"):
+            return h, k.transpose(1, 0, 2), v.transpose(1, 0, 2), ki
 
     def step(self, p, h, pos, layer, cache):
         """One layer, one token a slot: h (S, D) at pos (S,).  Writes row
@@ -218,26 +222,32 @@ class SelectAttention(_Stacked):
         import jax.numpy as jnp
         from ..ops import attention as A
         S = h.shape[0]
-        q, k, v, qi, ki, w = self.project(p, h, pos)
+        with _costs.part("proj"):
+            q, k, v, qi, ki, w = self.project(p, h, pos)
         slots = jnp.arange(S)
-        at = (slots[:, None], layer, jnp.arange(self._G)[None, :],
-              pos[:, None])
-        cache = dict(cache, k=cache["k"].at[at].set(k),
-                     v=cache["v"].at[at].set(v),
-                     ki=cache["ki"].at[slots, layer, pos].set(ki))
-        L = cache["ki"].shape[2]
-        keys = jnp.take(cache["ki"], layer, axis=1)              # (S, L, di)
-        s = jnp.einsum("sjd,sld->sjl", qi, keys,
-                       preferred_element_type=jnp.float32)
-        score = jnp.einsum("sjl,sj->sl", jnp.maximum(s, 0.0), w)
-        causal = jnp.arange(L)[None, :] <= pos[:, None]
-        mask = A.select_mask(score, causal, self._topk)
-        o = A.masked_decode_attention(
-            q, jnp.take(cache["k"], layer, axis=1),
-            jnp.take(cache["v"], layer, axis=1), mask,
-            1.0 / math.sqrt(self._d))
-        h = h + _dense(o.reshape(S, -1).astype(k.dtype), p["wo"])
-        return h, cache, jnp.sum(mask, -1, dtype=jnp.int32)
+        with _costs.part("cache"):
+            at = (slots[:, None], layer, jnp.arange(self._G)[None, :],
+                  pos[:, None])
+            cache = dict(cache, k=cache["k"].at[at].set(k),
+                         v=cache["v"].at[at].set(v),
+                         ki=cache["ki"].at[slots, layer, pos].set(ki))
+        with _costs.part("index"):
+            L = cache["ki"].shape[2]
+            keys = jnp.take(cache["ki"], layer, axis=1)          # (S, L, di)
+            s = jnp.einsum("sjd,sld->sjl", qi, keys,
+                           preferred_element_type=jnp.float32)
+            score = jnp.einsum("sjl,sj->sl", jnp.maximum(s, 0.0), w)
+            causal = jnp.arange(L)[None, :] <= pos[:, None]
+            mask = A.select_mask(score, causal, self._topk)
+        with _costs.part("attn"):
+            o = A.masked_decode_attention(
+                q, jnp.take(cache["k"], layer, axis=1),
+                jnp.take(cache["v"], layer, axis=1), mask,
+                1.0 / math.sqrt(self._d))
+        with _costs.part("proj"):
+            h = h + _dense(o.reshape(S, -1).astype(k.dtype), p["wo"])
+        with _costs.part("index"):
+            return h, cache, jnp.sum(mask, -1, dtype=jnp.int32)
 
 
 class HeldExperts(_Stacked):
@@ -304,6 +314,10 @@ class HeldExperts(_Stacked):
         layer's slice of `stacked()`; with `layer`, its expert weights
         `wg`, `wu`, `wd` are the whole stacks and are read at that layer
         (`moe.held_experts`)."""
+        with _costs.part("experts"):
+            return self._apply(p, h, layer)
+
+    def _apply(self, p, h, layer):
         from ..parallel import moe
         import jax
         import jax.numpy as jnp
@@ -364,11 +378,13 @@ class SparseDecoder(HybridBlock):
         """The residual stream's start.  The stream is float32 from here to
         the logits: each block rounds its normed input to the weights' type
         for its matrix products and adds their float32 results to it."""
-        return _f32(self.embed.data()._data[tokens])
+        with _costs.part("embed"):
+            return _f32(self.embed.data()._data[tokens])
 
     def _logits(self, h):
         g, w = self.norm.gamma.data()._data, self.head.data()._data
-        return _dense(_rms(h, g, self.norm._eps).astype(w.dtype), w)
+        with _costs.part("head"):
+            return _dense(_rms(h, g, self.norm._eps).astype(w.dtype), w)
 
     def _run_prompt(self, tokens):
         """tokens (T,) -> (h (T, D), k, v, ki each (layers, T, .))."""
@@ -413,11 +429,13 @@ class SparseDecoder(HybridBlock):
         pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 2)
                                 + [(0, int(max_len) - T), (0, 0)])
         last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        out = {"k": pad(k), "v": pad(v), "ki": pad(ki),
-               "counts": jnp.zeros((B, len(self.step_counts)), jnp.int32),
-               "start_tok": jnp.take_along_axis(
-                   tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-               "start_pos": last}
+        with _costs.part("cache"):
+            out = {"k": pad(k), "v": pad(v), "ki": pad(ki),
+                   "counts": jnp.zeros((B, len(self.step_counts)),
+                                       jnp.int32),
+                   "start_tok": jnp.take_along_axis(
+                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
+                   "start_pos": last}
         return {name: NDArray(a) for name, a in out.items()}
 
     def decode_step(self, tok, pos, cache, live):
@@ -442,10 +460,11 @@ class SparseDecoder(HybridBlock):
         (h, leaves, sel, held, full), _ = jax.lax.scan(
             layer, (self._embed(tok), leaves, zero, zero, zero),
             (self._stacks(), jnp.arange(self._layers)))
-        counts = jnp.stack(
-            [self._layers * (pos + 1), sel,
-             jnp.full((S,), self._layers * self._per_token, jnp.int32),
-             held, full], axis=1).astype(jnp.int32)
+        with _costs.part("cache"):
+            counts = jnp.stack(
+                [self._layers * (pos + 1), sel,
+                 jnp.full((S,), self._layers * self._per_token, jnp.int32),
+                 held, full], axis=1).astype(jnp.int32)
         new = dict(cache)
         new.update({n: NDArray(a) for n, a in leaves.items()})
         new["counts"] = NDArray(counts)

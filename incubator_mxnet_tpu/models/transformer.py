@@ -15,6 +15,7 @@ import math
 
 from ..gluon.block import HybridBlock
 from ..gluon import nn
+from ..telemetry import costs as _costs
 
 __all__ = ["MultiHeadAttention", "CrossAttention", "PositionwiseFFN",
            "TransformerEncoderLayer", "TransformerEncoder",
@@ -126,26 +127,22 @@ class MultiHeadAttention(HybridBlock):
         # never hits HBM.  Attention-prob dropout is only live while
         # training, so inference fuses regardless of the dropout config.
         # Shape-free on purpose: keeps the block symbol-traceable.
-        if mask is None and (self.dropout is None
-                             or not autograd.is_training()):
-            ctx = F._contrib_flash_attention(
-                self.query(x), self.key(x), self.value(x), num_heads=H)
-            return self.proj(ctx)
-        q = _split_heads(F, self.query(x), H)
-        k = _split_heads(F, self.key(x), H)
-        v = _split_heads(F, self.value(x), H)
-        scale = 1.0 / math.sqrt(self._units // H)
-        if mask is None:
-            ctx = _scaled_dot_attention(F, q, k, v, scale, self.dropout)
-        else:
-            scores = F.batch_dot(q, k, transpose_b=True) * scale
-            # additive mask broadcasts over (B, H, T, T)
-            scores = F.reshape(scores, (-4, -1, H, 0, 0)) + mask
-            attn = F.reshape(F.softmax(scores, axis=-1), (-3, 0, 0))
-            if self.dropout is not None:
-                attn = self.dropout(attn)
-            ctx = F.batch_dot(attn, v)
-        return self.proj(_merge_heads(F, ctx, H))
+        # The part is `proj` but for the attention itself (the inner scope
+        # names an op)
+        with _costs.part("proj"):
+            if mask is None and (self.dropout is None
+                                 or not autograd.is_training()):
+                q, k, v = self.query(x), self.key(x), self.value(x)
+                with _costs.part("attn"):
+                    ctx = F._contrib_flash_attention(q, k, v, num_heads=H)
+                return self.proj(ctx)
+            q = _split_heads(F, self.query(x), H)
+            k = _split_heads(F, self.key(x), H)
+            v = _split_heads(F, self.value(x), H)
+            scale = 1.0 / math.sqrt(self._units // H)
+            ctx = _masked_attention(F, q, k, v, scale, H, mask,
+                                    self.dropout)
+            return self.proj(_merge_heads(F, ctx, H))
 
 
 class PositionwiseFFN(HybridBlock):
@@ -157,10 +154,11 @@ class PositionwiseFFN(HybridBlock):
 
     def forward(self, x):
         from .. import ndarray as F
-        h = F.LeakyReLU(self.ffn1(x), act_type="gelu")
-        if self.dropout is not None:
-            h = self.dropout(h)
-        return self.ffn2(h)
+        with _costs.part("ffn"):
+            h = F.LeakyReLU(self.ffn1(x), act_type="gelu")
+            if self.dropout is not None:
+                h = self.dropout(h)
+            return self.ffn2(h)
 
 
 class TransformerEncoderLayer(HybridBlock):
@@ -178,11 +176,13 @@ class TransformerEncoderLayer(HybridBlock):
         h = self.attn(x, mask)
         if self.dropout is not None:
             h = self.dropout(h)
-        x = self.ln1(x + h)
+        with _costs.part("proj"):
+            x = self.ln1(x + h)
         h = self.ffn(x)
         if self.dropout is not None:
             h = self.dropout(h)
-        return self.ln2(x + h)
+        with _costs.part("ffn"):
+            return self.ln2(x + h)
 
 
 class TransformerEncoder(HybridBlock):
@@ -229,15 +229,17 @@ class BERTModel(HybridBlock):
     def forward(self, tokens):
         from .. import ndarray as F
         _check_max_length(tokens, self._max_length, "BERT")
-        pos = _position_ids(F, tokens)
-        x = self.word_embed(tokens) + self.pos_embed(pos)
-        x = self.ln(x)
-        if self.dropout is not None:
-            x = self.dropout(x)
+        with _costs.part("embed"):
+            pos = _position_ids(F, tokens)
+            x = self.word_embed(tokens) + self.pos_embed(pos)
+            x = self.ln(x)
+            if self.dropout is not None:
+                x = self.dropout(x)
         x = self.encoder(x)
-        h = F.LeakyReLU(self.mlm_dense(x), act_type="gelu")
-        h = self.mlm_ln(h)
-        return h if self.decoder is None else self.decoder(h)
+        with _costs.part("head"):
+            h = F.LeakyReLU(self.mlm_dense(x), act_type="gelu")
+            h = self.mlm_ln(h)
+            return h if self.decoder is None else self.decoder(h)
 
 
 class FusedMLMCELoss(HybridBlock):
@@ -268,10 +270,12 @@ class FusedMLMCELoss(HybridBlock):
         # keeps the rest (ref reshape special codes).  Symbols carry no
         # shape, so the symbolic trace assumes the 3-D (B, T, D) form;
         # already-flat (N, D) arrays pass through on the ndarray path.
-        h2 = h if getattr(h, "ndim", 3) == 2 else F.reshape(h, (-3, -2))
-        l1 = F.reshape(label, (-1,))
-        return F._fused_linear_softmax_ce(h2, weight, bias, l1,
-                                          num_chunks=self._nchunk)
+        with _costs.part("head"):
+            h2 = h if getattr(h, "ndim", 3) == 2 \
+                else F.reshape(h, (-3, -2))
+            l1 = F.reshape(label, (-1,))
+            return F._fused_linear_softmax_ce(h2, weight, bias, l1,
+                                              num_chunks=self._nchunk)
 
 
 def bert_base(vocab_size=30522, **kwargs):
@@ -323,11 +327,26 @@ def _merge_heads(F, t, num_heads):
 def _scaled_dot_attention(F, q, k, v, scale, dropout=None):
     """The ONE unfused attention body shared by MultiHeadAttention's
     fallback and CrossAttention: softmax(q kᵀ · scale) v."""
-    scores = F.batch_dot(q, k, transpose_b=True) * scale
-    attn = F.softmax(scores, axis=-1)
-    if dropout is not None:
-        attn = dropout(attn)
-    return F.batch_dot(attn, v)
+    with _costs.part("attn"):
+        scores = F.batch_dot(q, k, transpose_b=True) * scale
+        attn = F.softmax(scores, axis=-1)
+        if dropout is not None:
+            attn = dropout(attn)
+        return F.batch_dot(attn, v)
+
+
+def _masked_attention(F, q, k, v, scale, num_heads, mask, dropout=None):
+    """`_scaled_dot_attention` under an additive `mask` that broadcasts
+    over (B, H, Tq, Tk); without one, that function itself."""
+    if mask is None:
+        return _scaled_dot_attention(F, q, k, v, scale, dropout)
+    with _costs.part("attn"):
+        scores = F.batch_dot(q, k, transpose_b=True) * scale
+        scores = F.reshape(scores, (-4, -1, num_heads, 0, 0)) + mask
+        attn = F.reshape(F.softmax(scores, axis=-1), (-3, 0, 0))
+        if dropout is not None:
+            attn = dropout(attn)
+        return F.batch_dot(attn, v)
 
 
 class CrossAttention(HybridBlock):
@@ -354,20 +373,13 @@ class CrossAttention(HybridBlock):
         large-negative for source padding."""
         from .. import ndarray as F
         H = self._num_heads
-        q = _split_heads(F, self.query(x), H)
-        k = _split_heads(F, self.key(memory), H)
-        v = _split_heads(F, self.value(memory), H)
-        if mem_mask is None:
-            ctx = _scaled_dot_attention(F, q, k, v, self._scale,
-                                        self.dropout)
-        else:
-            scores = F.batch_dot(q, k, transpose_b=True) * self._scale
-            scores = F.reshape(scores, (-4, -1, H, 0, 0)) + mem_mask
-            attn = F.reshape(F.softmax(scores, axis=-1), (-3, 0, 0))
-            if self.dropout is not None:
-                attn = self.dropout(attn)
-            ctx = F.batch_dot(attn, v)
-        return self.proj(_merge_heads(F, ctx, H))
+        with _costs.part("proj"):
+            q = _split_heads(F, self.query(x), H)
+            k = _split_heads(F, self.key(memory), H)
+            v = _split_heads(F, self.value(memory), H)
+            ctx = _masked_attention(F, q, k, v, self._scale, H, mem_mask,
+                                    self.dropout)
+            return self.proj(_merge_heads(F, ctx, H))
 
 
 class _CausalSelfAttention(MultiHeadAttention):
@@ -392,10 +404,12 @@ class _CausalSelfAttention(MultiHeadAttention):
             raise ValueError("_CausalSelfAttention builds its causal "
                              "mask inside the fused kernel; mask= is "
                              "not supported")
-        ctx = F._contrib_flash_attention(
-            self.query(x), self.key(x), self.value(x),
-            num_heads=self._num_heads, causal=True)
-        return self.proj(ctx)
+        with _costs.part("proj"):
+            q, k, v = self.query(x), self.key(x), self.value(x)
+            with _costs.part("attn"):
+                ctx = F._contrib_flash_attention(
+                    q, k, v, num_heads=self._num_heads, causal=True)
+            return self.proj(ctx)
 
 
 class TransformerDecoderLayer(HybridBlock):
@@ -414,15 +428,18 @@ class TransformerDecoderLayer(HybridBlock):
         h = self.self_attn(x)
         if self.dropout is not None:
             h = self.dropout(h)
-        x = self.ln1(x + h)
+        with _costs.part("proj"):
+            x = self.ln1(x + h)
         h = self.cross_attn(x, memory, mem_mask)
         if self.dropout is not None:
             h = self.dropout(h)
-        x = self.ln2(x + h)
+        with _costs.part("proj"):
+            x = self.ln2(x + h)
         h = self.ffn(x)
         if self.dropout is not None:
             h = self.dropout(h)
-        return self.ln3(x + h)
+        with _costs.part("ffn"):
+            return self.ln3(x + h)
 
 
 class TransformerDecoder(HybridBlock):
@@ -478,12 +495,13 @@ class TransformerNMT(HybridBlock):
     def _embed(self, embed, ln, tokens):
         from .. import ndarray as F
         _check_max_length(tokens, self._max_length, "NMT")
-        x = embed(tokens) * math.sqrt(self._units) + \
-            self.pos_embed(_position_ids(F, tokens))
-        x = ln(x)
-        if self.dropout is not None:
-            x = self.dropout(x)
-        return x
+        with _costs.part("embed"):
+            x = embed(tokens) * math.sqrt(self._units) + \
+                self.pos_embed(_position_ids(F, tokens))
+            x = ln(x)
+            if self.dropout is not None:
+                x = self.dropout(x)
+            return x
 
     def forward(self, src, tgt, src_valid_length=None):
         """src_valid_length: optional (B,) source lengths — padding
@@ -505,7 +523,8 @@ class TransformerNMT(HybridBlock):
                                           src), mask=mem_mask)
         h = self.decoder(self._embed(self.tgt_embed, self.dec_ln, tgt),
                          memory, mem_mask)
-        return h if self.out_proj is None else self.out_proj(h)
+        with _costs.part("head"):
+            return h if self.out_proj is None else self.out_proj(h)
 
 
 def _mem_mask_for(F, src, src_valid_len):
@@ -551,7 +570,9 @@ def _lane_heads(num_heads, head_dim):
 
 def _cache_rows(F, t, groups):
     """(B, T, U) → (B, G, T, U/G): the cache's layout."""
-    return F.transpose(F.reshape(t, (0, 0, groups, -1)), axes=(0, 2, 1, 3))
+    with _costs.part("cache"):
+        return F.transpose(F.reshape(t, (0, 0, groups, -1)),
+                           axes=(0, 2, 1, 3))
 
 
 def _nmt_init_cache(self, src, src_valid_len, max_len, mem_len=None):
@@ -573,13 +594,15 @@ def _nmt_init_cache(self, src, src_valid_len, max_len, mem_len=None):
         memory = F.concat(
             memory, F.zeros((B, int(mem_len) - int(Ts), self._units)),
             dim=1)
-    cache = {"src_len": src_valid_len.reshape((-1,)),
-             "counts": F.zeros((B, 1), dtype="int32")}
-    zeros = F.zeros((B, G, int(max_len), self._units // G))
+    with _costs.part("cache"):
+        cache = {"src_len": src_valid_len.reshape((-1,)),
+                 "counts": F.zeros((B, 1), dtype="int32")}
+        zeros = F.zeros((B, G, int(max_len), self._units // G))
     for i, layer in enumerate(self.decoder.layers._children.values()):
         ca = layer.cross_attn
-        cache["mem_k%d" % i] = _cache_rows(F, ca.key(memory), G)
-        cache["mem_v%d" % i] = _cache_rows(F, ca.value(memory), G)
+        with _costs.part("proj"):       # `_cache_rows` is `cache`
+            cache["mem_k%d" % i] = _cache_rows(F, ca.key(memory), G)
+            cache["mem_v%d" % i] = _cache_rows(F, ca.value(memory), G)
         cache["k%d" % i] = zeros                            # (B, G, L, W)
         cache["v%d" % i] = zeros
     return cache
@@ -607,9 +630,10 @@ def _nmt_decode_step(self, tok, pos, cache, live):
     P = _lane_heads(H, d)
     B, G, L, W = cache["k0"].shape
     scale = 1.0 / math.sqrt(d)
-    x = self.tgt_embed(tok.reshape((-1, 1))) * math.sqrt(U) \
-        + self.pos_embed(pos.reshape((-1, 1)))              # (B, 1, U)
-    x = self.dec_ln(x)
+    with _costs.part("embed"):
+        x = self.tgt_embed(tok.reshape((-1, 1))) * math.sqrt(U) \
+            + self.pos_embed(pos.reshape((-1, 1)))          # (B, 1, U)
+        x = self.dec_ln(x)
     live = live._data
     self_len = jnp.where(live, pos._data + 1, 0).astype(jnp.int32)
     mem_len = jnp.where(live, cache["src_len"]._data, 0).astype(jnp.int32)
@@ -619,34 +643,44 @@ def _nmt_decode_step(self, tok, pos, cache, live):
 
     def _write(leaf, new):
         leaf = leaf._data
-        return NDArray(leaf.at[row].set(new._data.reshape(B, G, W)
-                                        .astype(leaf.dtype)))
+        with _costs.part("cache"):
+            return NDArray(leaf.at[row].set(new._data.reshape(B, G, W)
+                                            .astype(leaf.dtype)))
 
     def _attend(q, k, v, lengths):
         # the P heads of a group lie side by side on the row's W lanes,
         # in the query as in the leaves
-        ctx = decode_attention(q._data.reshape(B, G, W), k._data, v._data,
-                               lengths, heads=P, scale=scale)
-        return NDArray(ctx.reshape(B, 1, U))
+        with _costs.part("attn"):
+            ctx = decode_attention(q._data.reshape(B, G, W), k._data,
+                                   v._data, lengths, heads=P, scale=scale)
+            return NDArray(ctx.reshape(B, 1, U))
 
     for i, layer in enumerate(self.decoder.layers._children.values()):
-        sa = layer.self_attn
-        kc = new_cache["k%d" % i] = _write(cache["k%d" % i], sa.key(x))
-        vc = new_cache["v%d" % i] = _write(cache["v%d" % i], sa.value(x))
-        x = layer.ln1(x + sa.proj(_attend(sa.query(x), kc, vc, self_len)))
-        ca = layer.cross_attn
-        ctx = _attend(ca.query(x), cache["mem_k%d" % i],
-                      cache["mem_v%d" % i], mem_len)
-        x = layer.ln2(x + ca.proj(ctx))
-        x = layer.ln3(x + layer.ffn(x))
+        # a layer's two mixers are `proj` but for what `_write` (`cache`)
+        # and `_attend` (`attn`) name themselves
+        with _costs.part("proj"):
+            sa = layer.self_attn
+            kc = new_cache["k%d" % i] = _write(cache["k%d" % i], sa.key(x))
+            vc = new_cache["v%d" % i] = _write(cache["v%d" % i],
+                                               sa.value(x))
+            x = layer.ln1(x + sa.proj(_attend(sa.query(x), kc, vc,
+                                              self_len)))
+            ca = layer.cross_attn
+            ctx = _attend(ca.query(x), cache["mem_k%d" % i],
+                          cache["mem_v%d" % i], mem_len)
+            x = layer.ln2(x + ca.proj(ctx))
+        with _costs.part("ffn"):
+            x = layer.ln3(x + layer.ffn(x))
     if self.out_proj is None:
         raise ValueError("decode_step needs the vocab projection "
                          "(build TransformerNMT without "
                          "output_hidden=True for generation)")
-    new_cache["counts"] = NDArray((
-        decode_rows_read(self_len, cache["k0"]._data)
-        + decode_rows_read(mem_len, cache["mem_k0"]._data))[:, None])
-    return self.out_proj(x).reshape((0, -1)), new_cache
+    with _costs.part("cache"):
+        new_cache["counts"] = NDArray((
+            decode_rows_read(self_len, cache["k0"]._data)
+            + decode_rows_read(mem_len, cache["mem_k0"]._data))[:, None])
+    with _costs.part("head"):
+        return self.out_proj(x).reshape((0, -1)), new_cache
 
 
 # what `counts` counts, a column a name: the engine adds each, summed over

@@ -33,6 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..base import MXNetError
 from ..monitor import events
+from ..telemetry import costs as _costs
 from .registry import register
 
 __all__ = ["flash_attention", "naive_attention", "index_scores",
@@ -489,19 +490,20 @@ _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 def flash_attention(q, k, v, scale=None, causal=False, bias=None):
     """Fused attention over (B, H, T, d) operands (any leading batch dims
     folded by the caller).  Returns (B, H, T, d)."""
-    *lead, T, d = q.shape
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
-    BH = 1
-    for n in lead:
-        BH *= n
-    if bias is None and _pallas_enabled(BH, T, d):
-        q3 = q.reshape(BH, T, d)
-        k3 = k.reshape(BH, T, d)
-        v3 = v.reshape(BH, T, d)
-        out = _flash_attention(q3, k3, v3, float(scale), bool(causal))
-        return out.reshape(*lead, T, d)
-    return naive_attention(q, k, v, scale, causal=causal, bias=bias)
+    with _costs.part("attn"):
+        *lead, T, d = q.shape
+        if scale is None:
+            scale = 1.0 / math.sqrt(d)
+        BH = 1
+        for n in lead:
+            BH *= n
+        if bias is None and _pallas_enabled(BH, T, d):
+            q3 = q.reshape(BH, T, d)
+            k3 = k.reshape(BH, T, d)
+            v3 = v.reshape(BH, T, d)
+            out = _flash_attention(q3, k3, v3, float(scale), bool(causal))
+            return out.reshape(*lead, T, d)
+        return naive_attention(q, k, v, scale, causal=causal, bias=bias)
 
 
 # ---------------------------------------------------------------------------
@@ -556,9 +558,11 @@ def _contrib_flash_attention(query, key, value, num_heads=1, scale=None,
 def index_scores(qi, ki, w):
     """I (Tq, Tk) float32 from indexer queries qi (Tq, J, d), keys ki
     (Tk, d) and head weights w (Tq, J)."""
-    s = jnp.einsum("qjd,kd->qjk", qi, ki,
-                   preferred_element_type=jnp.float32)
-    return jnp.einsum("qjk,qj->qk", jax.nn.relu(s), w.astype(jnp.float32))
+    with _costs.part("index"):
+        s = jnp.einsum("qjd,kd->qjk", qi, ki,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("qjk,qj->qk", jax.nn.relu(s),
+                          w.astype(jnp.float32))
 
 
 def _ordered_bits(x):
@@ -597,21 +601,23 @@ def _kth_largest_bits(bits, k):
 def select_mask(scores, valid, k):
     """Mask (..., N) of the min(k, valid count) valid entries of each row
     with the largest score, ties to the lower index."""
-    bits = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
-    kth = _kth_largest_bits(bits, k)[..., None]
-    above = bits > kth
-    room = k - jnp.sum(above, -1, dtype=jnp.int32, keepdims=True)
-    equal = bits == kth
+    with _costs.part("index"):
+        bits = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
+        kth = _kth_largest_bits(bits, k)[..., None]
+        above = bits > kth
+        room = k - jnp.sum(above, -1, dtype=jnp.int32, keepdims=True)
+        equal = bits == kth
 
-    def first_of_equal(_):
-        return above | (equal & (jnp.cumsum(equal, -1, dtype=jnp.int32)
-                                 <= room))
+        def first_of_equal(_):
+            return above | (equal & (jnp.cumsum(equal, -1, dtype=jnp.int32)
+                                     <= room))
 
-    # more entries equal the k-th than there is room for: rare, and the
-    # running count that settles it costs a pass of its own
-    tied = jnp.any(jnp.sum(equal, -1, dtype=jnp.int32, keepdims=True) > room)
-    return valid & jax.lax.cond(tied, first_of_equal,
-                                lambda _: above | equal, None)
+        # more entries equal the k-th than there is room for: rare, and the
+        # running count that settles it costs a pass of its own
+        tied = jnp.any(
+            jnp.sum(equal, -1, dtype=jnp.int32, keepdims=True) > room)
+        return valid & jax.lax.cond(tied, first_of_equal,
+                                    lambda _: above | equal, None)
 
 
 def masked_decode_attention(q, k_rows, v_rows, mask, scale):
@@ -619,15 +625,16 @@ def masked_decode_attention(q, k_rows, v_rows, mask, scale):
     q (S, H, d); k_rows, v_rows (S, G, L, d), head-major, H a multiple of
     the G key/value heads (query head i reads head i // (H/G)); mask
     (S, L).  Returns (S, H, d) float32."""
-    S, H, d = q.shape
-    G = k_rows.shape[1]
-    qg = q.reshape(S, G, H // G, d)
-    s = jnp.einsum("sghd,sgld->sghl", qg, k_rows,
-                   preferred_element_type=jnp.float32) * scale
-    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, _NEG_INF), -1)
-    o = jnp.einsum("sghl,sgld->sghd", p.astype(v_rows.dtype), v_rows,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(S, H, d)
+    with _costs.part("attn"):
+        S, H, d = q.shape
+        G = k_rows.shape[1]
+        qg = q.reshape(S, G, H // G, d)
+        s = jnp.einsum("sghd,sgld->sghl", qg, k_rows,
+                       preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, _NEG_INF), -1)
+        o = jnp.einsum("sghl,sgld->sghd", p.astype(v_rows.dtype), v_rows,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(S, H, d)
 
 
 def _masked_block(qg, k, v, mask, scale, chunk):
@@ -671,27 +678,30 @@ def blocked_select_attention(q, k, v, qi, ki, w, top_k, scale, block=1024,
     b sees keys [0, end of b), in chunks of `chunk`: the blocks are
     unrolled with static shapes, so what lies after a block is neither
     scored nor read.  Returns (T, H, d) float32."""
-    T, H, d = q.shape
-    G = k.shape[1]
-    bq = min(int(block), T)
-    chunk = min(int(chunk), bq)
-    if T % bq or bq % chunk:
-        raise ValueError("%d positions are no whole number of query "
-                         "blocks of %d in key chunks of %d" % (T, bq, chunk))
-    qg = q.reshape(T, G, H // G, d).transpose(1, 2, 0, 3)   # (G, h, T, d)
-    kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)     # (G, T, d)
-    out = []
-    for q0 in range(0, T, bq):
-        end = q0 + bq
-        pos_q = q0 + jnp.arange(bq)
-        mask = jnp.arange(end)[None, :] <= pos_q[:, None]   # causal
-        if end > top_k:         # else every causal key is selected
-            mask = select_mask(
-                index_scores(qi[q0:end], ki[:end], w[q0:end]), mask, top_k)
-        o = _masked_block(qg[:, :, q0:end], kg[:, :end], vg[:, :end], mask,
-                          scale, chunk)                     # (G, h, bq, d)
-        out.append(o.transpose(2, 0, 1, 3).reshape(bq, H, d))
-    return jnp.concatenate(out, 0)
+    with _costs.part("attn"):
+        T, H, d = q.shape
+        G = k.shape[1]
+        bq = min(int(block), T)
+        chunk = min(int(chunk), bq)
+        if T % bq or bq % chunk:
+            raise ValueError("%d positions are no whole number of query "
+                             "blocks of %d in key chunks of %d"
+                             % (T, bq, chunk))
+        qg = q.reshape(T, G, H // G, d).transpose(1, 2, 0, 3)   # (G, h, T, d)
+        kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)     # (G, T, d)
+        out = []
+        for q0 in range(0, T, bq):
+            end = q0 + bq
+            pos_q = q0 + jnp.arange(bq)
+            mask = jnp.arange(end)[None, :] <= pos_q[:, None]   # causal
+            if end > top_k:         # else every causal key is selected
+                mask = select_mask(
+                    index_scores(qi[q0:end], ki[:end], w[q0:end]), mask,
+                    top_k)
+            o = _masked_block(qg[:, :, q0:end], kg[:, :end], vg[:, :end],
+                              mask, scale, chunk)               # (G, h, bq, d)
+            out.append(o.transpose(2, 0, 1, 3).reshape(bq, H, d))
+        return jnp.concatenate(out, 0)
 
 
 def blocked_causal_attention(q, k, v, scale, block=512, chunk=512):
@@ -701,22 +711,24 @@ def blocked_causal_attention(q, k, v, scale, block=512, chunk=512):
     unrolled with static shapes, so no (T, T) matrix of a head is ever
     whole and what lies after a block is not read.  Returns (T, H, dv)
     float32."""
-    T, H, _ = q.shape
-    bq = min(int(block), T)
-    chunk = min(int(chunk), bq)
-    if T % bq or bq % chunk:
-        raise ValueError("%d positions are no whole number of query "
-                         "blocks of %d in key chunks of %d" % (T, bq, chunk))
-    qg = q.transpose(1, 0, 2)[:, None]                      # (H, 1, T, d)
-    kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)     # (H, T, .)
-    out = []
-    for q0 in range(0, T, bq):
-        end = q0 + bq
-        mask = jnp.arange(end)[None, :] <= q0 + jnp.arange(bq)[:, None]
-        o = _masked_block(qg[:, :, q0:end], kg[:, :end], vg[:, :end], mask,
-                          scale, chunk)                     # (H, 1, bq, dv)
-        out.append(o[:, 0].transpose(1, 0, 2))
-    return jnp.concatenate(out, 0)
+    with _costs.part("attn"):
+        T, H, _ = q.shape
+        bq = min(int(block), T)
+        chunk = min(int(chunk), bq)
+        if T % bq or bq % chunk:
+            raise ValueError("%d positions are no whole number of query "
+                             "blocks of %d in key chunks of %d"
+                             % (T, bq, chunk))
+        qg = q.transpose(1, 0, 2)[:, None]                      # (H, 1, T, d)
+        kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)     # (H, T, .)
+        out = []
+        for q0 in range(0, T, bq):
+            end = q0 + bq
+            mask = jnp.arange(end)[None, :] <= q0 + jnp.arange(bq)[:, None]
+            o = _masked_block(qg[:, :, q0:end], kg[:, :end], vg[:, :end],
+                              mask, scale, chunk)           # (H, 1, bq, dv)
+            out.append(o[:, 0].transpose(1, 0, 2))
+        return jnp.concatenate(out, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -799,19 +811,20 @@ def latent_decode_attention(q_abs, q_rope, ckv, kr, layer, lengths, scale):
     The kernel where the step is lowered for a TPU (and wherever
     `MXNET_PALLAS_INTERPRET` runs the kernel itself), the einsums elsewhere
     and for leaves the kernel does not tile."""
-    args = (q_abs, q_rope, ckv, kr, jnp.asarray(layer, jnp.int32).reshape(1),
-            lengths)
-    kernel = functools.partial(_latent_pallas, scale=scale)
-    einsums = functools.partial(_latent_einsums, scale=scale)
-    if not _latent_fits(ckv):
-        return einsums(*args)
-    if _interpret() or jax.default_backend() == "tpu":
-        # trace-time side effect only, as `serve.traces` is: one for each
-        # layer body that is lowered with the kernel
-        events.incr("mla.kernel_traces")
-    if _interpret():
-        return kernel(*args)
-    return jax.lax.platform_dependent(*args, tpu=kernel, default=einsums)
+    with _costs.part("attn"):
+        args = (q_abs, q_rope, ckv, kr,
+                jnp.asarray(layer, jnp.int32).reshape(1), lengths)
+        kernel = functools.partial(_latent_pallas, scale=scale)
+        einsums = functools.partial(_latent_einsums, scale=scale)
+        if not _latent_fits(ckv):
+            return einsums(*args)
+        if _interpret() or jax.default_backend() == "tpu":
+            # trace-time side effect only, as `serve.traces` is: one for each
+            # layer body that is lowered with the kernel
+            events.incr("mla.kernel_traces")
+        if _interpret():
+            return kernel(*args)
+        return jax.lax.platform_dependent(*args, tpu=kernel, default=einsums)
 
 
 def _latent_einsums(q_abs, q_rope, ckv, kr, layer, lengths, scale):
@@ -975,16 +988,17 @@ def decode_attention(q, k, v, lengths, heads=1, scale=1.0):
     wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself),
     `dense_decode_attention` elsewhere and for leaves the kernel does not
     tile.  The two agree on every slot with lengths > 0."""
-    ragged = functools.partial(ragged_decode_attention, heads=heads,
-                               scale=scale)
-    dense = functools.partial(dense_decode_attention, heads=heads,
-                              scale=scale)
-    if _interpret():
-        return ragged(q, k, v, lengths)
-    if not _ragged_fits(k):
-        return dense(q, k, v, lengths)
-    return jax.lax.platform_dependent(q, k, v, lengths, tpu=ragged,
-                                      default=dense)
+    with _costs.part("attn"):
+        ragged = functools.partial(ragged_decode_attention, heads=heads,
+                                   scale=scale)
+        dense = functools.partial(dense_decode_attention, heads=heads,
+                                  scale=scale)
+        if _interpret():
+            return ragged(q, k, v, lengths)
+        if not _ragged_fits(k):
+            return dense(q, k, v, lengths)
+        return jax.lax.platform_dependent(q, k, v, lengths, tpu=ragged,
+                                          default=dense)
 
 
 def _lane_owner(heads, W):

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..telemetry import costs as _costs
 from .attention import _interpret
 
 __all__ = ["gated_delta_scan", "gated_delta_chunked", "gated_delta_step",
@@ -195,46 +196,47 @@ def gated_delta_chunked(q, k, v, g, beta, valid_len=None, chunk=64):
     it is, so the state returned is the one after valid_len - 1 tokens;
     their outputs read that state and mean nothing.  Returns (o (T, H, dv),
     state (H, dk, dv)) float32."""
-    f32 = jnp.float32
-    T, H, dk = q.shape
-    dv = v.shape[2]
-    C = int(chunk)
-    if C & (C - 1):
-        raise ValueError("a chunk of %d positions is no power of two" % C)
-    N = -(-T // C)
-    g, beta = g.astype(f32), beta.astype(f32)
-    if valid_len is not None:
-        on = (jnp.arange(T) < valid_len - 1)[:, None]
-        g, beta = jnp.where(on, g, 0.0), jnp.where(on, beta, 0.0)
-    pad = N * C - T                     # padded positions: g = 0, beta = 0
+    with _costs.part("state"):
+        f32 = jnp.float32
+        T, H, dk = q.shape
+        dv = v.shape[2]
+        C = int(chunk)
+        if C & (C - 1):
+            raise ValueError("a chunk of %d positions is no power of two" % C)
+        N = -(-T // C)
+        g, beta = g.astype(f32), beta.astype(f32)
+        if valid_len is not None:
+            on = (jnp.arange(T) < valid_len - 1)[:, None]
+            g, beta = jnp.where(on, g, 0.0), jnp.where(on, beta, 0.0)
+        pad = N * C - T                     # padded positions: g = 0, beta = 0
 
-    def chunks(a):                      # (T, H, ...) -> (N, H, C, ...)
-        a = jnp.pad(a.astype(f32), [(0, pad)] + [(0, 0)] * (a.ndim - 1))
-        a = a.reshape((N, C) + a.shape[1:])
-        return jnp.moveaxis(a, 2, 1)
+        def chunks(a):                      # (T, H, ...) -> (N, H, C, ...)
+            a = jnp.pad(a.astype(f32), [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+            a = a.reshape((N, C) + a.shape[1:])
+            return jnp.moveaxis(a, 2, 1)
 
-    q, k, v, g, beta = (chunks(a) for a in (q, k, v, g, beta))
-    gam = jnp.cumsum(g, axis=-1)                                # (N, H, C)
-    # e^(gamma_i - gamma_j) for j <= i, 0 above the diagonal
-    low = jnp.tril(jnp.ones((C, C), bool))
-    dec = jnp.where(low, jnp.exp(jnp.where(
-        low, gam[..., :, None] - gam[..., None, :], 0.0)), 0.0)
-    kk = jnp.einsum("nhik,nhjk->nhij", k, k, precision=_HI)
-    a = jnp.tril(beta[..., :, None] * dec * kk, -1)
-    rhs = jnp.concatenate(
-        [beta[..., None] * v,
-         (beta * jnp.exp(gam))[..., None] * k], axis=-1)
-    sol = jnp.einsum("nhij,nhjk->nhik", _unit_lower_inverse(a), rhs,
-                     precision=_HI)
-    ut, w = sol[..., :dv], sol[..., dv:]
-    qg = q * jnp.exp(gam)[..., None]
-    m = jnp.einsum("nhik,nhjk->nhij", q, k, precision=_HI) * dec
-    krt = jnp.swapaxes(k * jnp.exp(gam[..., -1:] - gam)[..., None], -1, -2)
-    decay = jnp.exp(gam[..., -1])
-    o, state = _chunk_scan(ut, w, qg, m, krt, decay,
-                           jnp.zeros((H, dk, dv), f32))
-    o = jnp.moveaxis(o, 1, 2).reshape(N * C, H, dv)[:T]
-    return o, state
+        q, k, v, g, beta = (chunks(a) for a in (q, k, v, g, beta))
+        gam = jnp.cumsum(g, axis=-1)                                # (N, H, C)
+        # e^(gamma_i - gamma_j) for j <= i, 0 above the diagonal
+        low = jnp.tril(jnp.ones((C, C), bool))
+        dec = jnp.where(low, jnp.exp(jnp.where(
+            low, gam[..., :, None] - gam[..., None, :], 0.0)), 0.0)
+        kk = jnp.einsum("nhik,nhjk->nhij", k, k, precision=_HI)
+        a = jnp.tril(beta[..., :, None] * dec * kk, -1)
+        rhs = jnp.concatenate(
+            [beta[..., None] * v,
+             (beta * jnp.exp(gam))[..., None] * k], axis=-1)
+        sol = jnp.einsum("nhij,nhjk->nhik", _unit_lower_inverse(a), rhs,
+                         precision=_HI)
+        ut, w = sol[..., :dv], sol[..., dv:]
+        qg = q * jnp.exp(gam)[..., None]
+        m = jnp.einsum("nhik,nhjk->nhij", q, k, precision=_HI) * dec
+        krt = jnp.swapaxes(k * jnp.exp(gam[..., -1:] - gam)[..., None], -1, -2)
+        decay = jnp.exp(gam[..., -1])
+        o, state = _chunk_scan(ut, w, qg, m, krt, decay,
+                               jnp.zeros((H, dk, dv), f32))
+        o = jnp.moveaxis(o, 1, 2).reshape(N * C, H, dv)[:T]
+        return o, state
 
 
 # -- one token a slot, against the slot cache ------------------------------
@@ -315,16 +317,17 @@ def gated_delta_step(q, k, v, g, beta, states, layer):
     `layer` (a traced scalar) the layer whose state this step reads and
     rewrites.  Returns (o (B, H, dv) float32, the leaf with that layer's
     states advanced one token)."""
-    f32 = jnp.float32
-    args = (jnp.asarray(layer, jnp.int32).reshape(1), q.astype(f32),
-            k.astype(f32), v.astype(f32), jnp.exp(g.astype(f32)),
-            beta.astype(f32), states)
-    if _interpret():
-        return _step_pallas(*args)
-    if not _step_fits(states):
-        return _step_xla(*args)
-    return jax.lax.platform_dependent(*args, tpu=_step_pallas,
-                                      default=_step_xla)
+    with _costs.part("state"):
+        f32 = jnp.float32
+        args = (jnp.asarray(layer, jnp.int32).reshape(1), q.astype(f32),
+                k.astype(f32), v.astype(f32), jnp.exp(g.astype(f32)),
+                beta.astype(f32), states)
+        if _interpret():
+            return _step_pallas(*args)
+        if not _step_fits(states):
+            return _step_xla(*args)
+        return jax.lax.platform_dependent(*args, tpu=_step_pallas,
+                                          default=_step_xla)
 
 
 # -- the short causal convolution -------------------------------------------
@@ -335,21 +338,24 @@ def causal_conv(x, w, valid_len=None):
     (y (T, C) float32, the K - 1 input rows BEFORE position valid_len - 1
     (before T without it), in x's type: what `causal_conv_step` needs to
     go on from there)."""
-    T, C = x.shape
-    K = w.shape[1]
-    xp = jnp.pad(x, [(K - 1, 0), (0, 0)])
-    wf = w.astype(jnp.float32)
-    y = sum(xp[j:j + T].astype(jnp.float32) * wf[:, j] for j in range(K))
-    end = T if valid_len is None else valid_len - 1     # rows [end-K+1, end)
-    rows = jax.lax.dynamic_slice_in_dim(xp, jnp.maximum(end, 0), K - 1, 0)
-    return y, rows
+    with _costs.part("state"):
+        T, C = x.shape
+        K = w.shape[1]
+        xp = jnp.pad(x, [(K - 1, 0), (0, 0)])
+        wf = w.astype(jnp.float32)
+        y = sum(xp[j:j + T].astype(jnp.float32) * wf[:, j] for j in range(K))
+        # rows [end - K + 1, end)
+        end = T if valid_len is None else valid_len - 1
+        rows = jax.lax.dynamic_slice_in_dim(xp, jnp.maximum(end, 0), K - 1, 0)
+        return y, rows
 
 
 def causal_conv_step(x, rows, w):
     """One position a slot: x (B, C) the new input, rows (B, K - 1, C) the
     inputs before it.  Returns (y (B, C) float32, the rows for the next
     position)."""
-    full = jnp.concatenate([rows, x[:, None].astype(rows.dtype)], axis=1)
-    y = jnp.einsum("bkc,ck->bc", full.astype(jnp.float32),
-                   w.astype(jnp.float32))
-    return y, full[:, 1:]
+    with _costs.part("state"):
+        full = jnp.concatenate([rows, x[:, None].astype(rows.dtype)], axis=1)
+        y = jnp.einsum("bkc,ck->bc", full.astype(jnp.float32),
+                       w.astype(jnp.float32))
+        return y, full[:, 1:]

@@ -204,7 +204,8 @@ def _build_train_step(raw, opname, static_kv, nparam, nstates, gidx,
     # the fused imperative train step (fwd+vjp+update, ONE program) —
     # the headline row in the cost registry's train family
     return _costs.metered_jit(f, donate_argnums=donate,
-                              label="gluon.train_step", kind="train")
+                              label="gluon.train_step", kind="train",
+                              role="gluon_train_step")
 
 
 def _train_step_dispatch(prod, pending, opname, static_kv, weights,

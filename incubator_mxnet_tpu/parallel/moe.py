@@ -37,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..monitor import events
 from ..ops.attention import _interpret
+from ..telemetry import costs as _costs
 
 __all__ = ["switch_route", "moe_apply", "moe_ffn", "topk_route",
            "group_limited_route", "swiglu", "held_experts",
@@ -154,9 +155,10 @@ def topk_route(router_logits, k):
     """Softmax over all experts, the k largest, renormalised over those
     k.  router_logits (T, E) -> (gate (T, k) float32, expert (T, k)
     int32), best first, ties to the lower expert."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    top, expert = lax.top_k(probs, k)
-    return top / jnp.sum(top, axis=-1, keepdims=True), expert
+    with _costs.part("experts"):
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        top, expert = lax.top_k(probs, k)
+        return top / jnp.sum(top, axis=-1, keepdims=True), expert
 
 
 def group_limited_route(router_logits, k, n_group, topk_group, scale=1.0):
@@ -166,16 +168,18 @@ def group_limited_route(router_logits, k, n_group, topk_group, scale=1.0):
     softmax's own values times `scale`, NOT renormalised.  router_logits
     (T, E) -> (gate (T, k) float32, expert (T, k) int32) in `topk_route`'s
     form, best first, ties to the lower group and to the lower expert."""
-    T, E = router_logits.shape
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    best = jnp.max(probs.reshape(T, n_group, E // n_group), axis=-1)
-    _, groups = lax.top_k(best, topk_group)
-    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group)[None, None, :],
-                   axis=1)                                      # (T, n_group)
-    # a softmax's values are positive, so -1 is below every kept expert
-    inside = jnp.where(jnp.repeat(kept, E // n_group, axis=1), probs, -1.0)
-    top, expert = lax.top_k(inside, k)
-    return top * scale, expert
+    with _costs.part("experts"):
+        T, E = router_logits.shape
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        best = jnp.max(probs.reshape(T, n_group, E // n_group), axis=-1)
+        _, groups = lax.top_k(best, topk_group)
+        kept = jnp.any(
+            groups[:, :, None] == jnp.arange(n_group)[None, None, :],
+            axis=1)                                             # (T, n_group)
+        # a softmax's values are positive, so -1 is below every kept expert
+        inside = jnp.where(jnp.repeat(kept, E // n_group, axis=1), probs, -1.0)
+        top, expert = lax.top_k(inside, k)
+        return top * scale, expert
 
 
 def held_load(expert, first_held, n_held):
@@ -183,13 +187,14 @@ def held_load(expert, first_held, n_held):
     token's k experts that are held, at_fullest (T,) how many of them are
     the held expert with the most tokens).  Their sums over tokens are
     the held picks and the fullest held expert's tokens."""
-    local = expert - first_held
-    held = (local >= 0) & (local < n_held)
-    load = jnp.sum(jax.nn.one_hot(jnp.where(held, local, n_held), n_held,
-                                  dtype=jnp.int32), axis=(0, 1))
-    fullest = jnp.argmax(load)
-    return (jnp.sum(held, axis=-1, dtype=jnp.int32),
-            jnp.sum(held & (local == fullest), axis=-1, dtype=jnp.int32))
+    with _costs.part("experts"):
+        local = expert - first_held
+        held = (local >= 0) & (local < n_held)
+        load = jnp.sum(jax.nn.one_hot(jnp.where(held, local, n_held), n_held,
+                                      dtype=jnp.int32), axis=(0, 1))
+        fullest = jnp.argmax(load)
+        return (jnp.sum(held, axis=-1, dtype=jnp.int32),
+                jnp.sum(held & (local == fullest), axis=-1, dtype=jnp.int32))
 
 
 def swiglu(x, wg, wu, wd):
@@ -439,6 +444,13 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
     wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself),
     `_held_experts_loop` elsewhere and for shapes the kernel does not
     tile."""
+    with _costs.part("experts"):
+        return _held_experts(x, gate, expert, wg, wu, wd, first_held, tile,
+                             run_tile, layer)
+
+
+def _held_experts(x, gate, expert, wg, wu, wd, first_held, tile, run_tile,
+                  layer):
     T, D = x.shape
     k = gate.shape[1]
     n_held = wg.shape[0 if layer is None else 1]

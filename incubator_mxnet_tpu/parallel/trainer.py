@@ -390,7 +390,7 @@ class ShardedTrainer:
         return _costs.metered_jit(
             step, donate_argnums=(0, 1) if donate else (),
             kind="train", label="sharded.step",
-            expect_donated=(0, 1))
+            expect_donated=(0, 1), role="sharded_step")
 
     def _build_step_zero(self, donate=True):
         """The overlap-first ZeRO-2/3 step (ISSUE 10 tentpole): ONE

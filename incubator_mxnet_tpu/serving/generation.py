@@ -577,15 +577,22 @@ class GenerationEngine:
             # zero-recompile contract is asserted on (the same
             # serve.traces the one-shot engine meters)
             events.incr("serve.traces")
-            return _split_start(
-                pure_init(params, src, valid, max_len, mem_len), bos)
+            row = pure_init(params, src, valid, max_len, mem_len)
+            with _costs.part("cache"):
+                return _split_start(row, bos)
 
         def decode_step(params, cache):
             events.incr("serve.traces")
             tok, pos, left = cache["tok"], cache["pos"], cache["left"]
             logits, new_m = pure_step(params, tok, pos, cache["m"],
                                       left > 0)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with _costs.part("head"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # the engine's own bookkeeping is part `cache`, as `join` is
+            with _costs.part("cache"):
+                return _after_step(cache, new_m, nxt, pos, left)
+
+        def _after_step(cache, new_m, nxt, pos, left):
             # the device-resident emitted-token record (ISSUE 14
             # contract: per-sequence state lives in device arrays
             # indexed by slot).  Host streaming is authoritative
@@ -625,13 +632,14 @@ class GenerationEngine:
                 return jax.lax.dynamic_update_slice(
                     c, r.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1))
 
-            return {"m": jax.tree_util.tree_map(put, cache["m"],
-                                                row["m"]),
-                    "tok": put(cache["tok"], row["tok"]),
-                    "pos": put(cache["pos"], row["pos"]),
-                    "left": put(cache["left"], budget[None]),
-                    "out": put(cache["out"],
-                               jnp.full((1, L), eos, jnp.int32))}
+            with _costs.part("cache"):
+                return {"m": jax.tree_util.tree_map(put, cache["m"],
+                                                    row["m"]),
+                        "tok": put(cache["tok"], row["tok"]),
+                        "pos": put(cache["pos"], row["pos"]),
+                        "left": put(cache["left"], budget[None]),
+                        "out": put(cache["out"],
+                                   jnp.full((1, L), eos, jnp.int32))}
 
         # prefill: one signature per prompt bucket, warmed; decode
         # and join donate the cache — the PR 10 audit arms the

@@ -35,7 +35,13 @@ raising degrades to a row with the
 walls and invocation counts but zeroed cost fields — never a crash.
 The per-call hot path is gated on `flightrec.enabled()`:
 MXNET_BLACKBOX=0 makes `MeteredJit.__call__` a bool read + the inner
-jit call.
+jit call + one list read.
+
+The device's time by model part (docs/observability.md, "Model parts"):
+`part(name)` is the one scope the models and ops enter while they are
+traced, and `op_parts(role)` reads a compiled executable's text for the
+part of each of its instructions.  A device trace bears instruction
+names and no scope, so the two are joined outside the program.
 """
 from __future__ import annotations
 
@@ -48,7 +54,8 @@ from . import spans as _spans
 
 __all__ = ["note_executable", "note_collective", "invoke", "table",
            "totals", "snapshot", "reset", "metered_jit", "MeteredJit",
-           "footprint_bytes", "suggest_bucket_mb", "lowerings"]
+           "footprint_bytes", "suggest_bucket_mb", "lowerings",
+           "PARTS", "part", "op_parts"]
 
 _LOCK = threading.Lock()
 _ROWS = {}                      # key -> dict row
@@ -369,6 +376,7 @@ def snapshot():
 def reset():
     with _LOCK:
         _ROWS.clear()
+        _RETIRED.clear()
 
 
 def traced_as(fn, label, role=None):
@@ -385,6 +393,102 @@ def traced_as(fn, label, role=None):
 
     _traced.__name__ = _traced.__qualname__ = "_traced_" + slug[:40]
     return _traced
+
+
+# -- the device's time by model part --------------------------------------
+
+# the vocabulary of `part`; docs/observability.md draws each boundary
+PARTS = ("embed", "head", "proj", "attn", "index", "state", "cache",
+         "experts", "ffn")
+
+_PART_RE = re.compile(r"mx\.([a-z_]+)")
+_INSTR_RE = re.compile(r"\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def part(name):
+    """The scope of one model part: `jax.named_scope("mx." + name)`, for
+    a `name` of `PARTS`.  Entered where the work is written, in the
+    models and at the top of the ops' entry points, so it runs only
+    while a function is traced: a compiled step never enters it.  It is
+    metadata: the lowered program without debug info is the same with
+    and without it.  The innermost scope names an op (`op_parts`)."""
+    if name not in PARTS:
+        raise ValueError("%r is no model part: %s" % (name, ", ".join(PARTS)))
+    import jax
+    return jax.named_scope("mx." + name)
+
+
+_LIVE = weakref.WeakSet()       # every MeteredJit that is alive
+_RETIRED = {}                   # traced name -> the programs of the
+                                # newest dead executable built with a role
+
+
+def _parse_parts(text):
+    """{instruction name: part or None} of a compiled module's text: the
+    innermost `mx.<part>` of the instruction's `op_name`.  An instruction
+    whose metadata XLA dropped has none (on the v5e: the scatter fusions,
+    `kind=kCustom`, whose roots lose it too)."""
+    parts = {}
+    for line in text.splitlines():
+        m = _INSTR_RE.match(line)
+        if m:
+            op = _OP_NAME_RE.search(line)
+            scopes = _PART_RE.findall(op.group(1)) if op else ()
+            parts[m.group(1)] = scopes[-1] if scopes else None
+    return parts
+
+
+def op_parts(role):
+    """The program's map from compiled op to model part: one entry for
+    every traced signature of every executable whose traced name
+    (`jit__traced_gen_prefill`) contains `role`,
+
+        {"name": traced name, "stale": bool,
+         "instructions": {HLO instruction name: part or None}}
+
+    over every computation of the compiled module (the ops of while and
+    conditional bodies are events of their own in a device trace, under
+    these names).  An instruction's part is the innermost `mx.<part>`
+    scope of its `op_name` (of its root's, for a fusion); None outside
+    every scope.
+
+    Built when asked and kept after: the signature's traced program is
+    lowered and compiled again (the persistent compile cache answers;
+    the first call of a signature noted where its arguments lay, so the
+    lowering is the call's own), and its text read once.  Nothing
+    happens at warm-up or on a call, and the flight recorder need not
+    be on.  It answers for the executables that are alive and for the
+    newest dead one of each role-named executable
+    (`metered_jit(role=)`), so a reader may ask once the engine or the
+    trainer is shut and gone.
+
+    `stale`: the text names no part at all.  That is an executable
+    loaded from a compile cache filled before the program had scopes
+    (the cache's key leaves metadata out,
+    `jax_compilation_cache_include_metadata_in_key`), not a model
+    without parts: take no share from it."""
+    with _LOCK:
+        groups = [(mj._name, mj._programs) for mj in list(_LIVE)]
+        groups += list(_RETIRED.items())
+    out = []
+    for name, programs in groups:
+        if role not in name:
+            continue
+        for program in programs:
+            if program[1] is None:
+                try:
+                    text = program[0].lower().compile().as_text()
+                except Exception as e:      # noqa: BLE001 — forensics
+                    from . import flightrec as _bb
+                    _bb.record("costs", "op_parts_failed", name=name,
+                               error=repr(e)[:200])
+                    continue
+                instructions = _parse_parts(text)
+                program[1] = {"name": name, "instructions": instructions,
+                              "stale": not any(instructions.values())}
+            out.append(program[1])
+    return out
 
 
 _DONATION_WARNED = set()
@@ -421,8 +525,14 @@ class MeteredJit:
     `train.traces` pattern — a jit cache hit never runs the python
     body): the tracer avals are captured THERE, at trace cost, and the
     steady-state call pays one bool read, two int compares and one
-    locked counter bump.  Recorder off: one bool read, then the inner
-    jit."""
+    locked counter bump.  Recorder off: one bool read, the inner jit,
+    one list read.
+
+    The first call of a signature also keeps its traced program
+    (`jax.stages.Traced`: the jaxpr and where the call's arguments lay;
+    neither the function nor an array), from which `op_parts` compiles
+    the call's own lowering again.  An executable built with a `role`
+    leaves these behind when it dies."""
 
     def __init__(self, fn, donate_argnums=(), kind="jit", label=None,
                  expect_donated=None, role=None):
@@ -430,8 +540,10 @@ class MeteredJit:
         self._kind = kind
         self._label = label or getattr(fn, "__name__", "fn")
         _audit_donation(self._label, donate_argnums, expect_donated)
+        self._role = role
         self._keys = []             # registry row key per traced sig
         self._pending = []          # avals captured at trace time
+        self._programs = []         # [Traced, its op_parts entry or None]
         # suppresses the hook during lazy cost resolution (its lower()
         # may re-trace).  THREAD-local: a resolver running on the
         # exporter thread must not swallow a genuinely new signature
@@ -449,11 +561,52 @@ class MeteredJit:
                     a))
             return out
 
-        self._jit = jax.jit(traced_as(hooked, self._label, role),
-                            donate_argnums=donate_argnums)
+        traced = traced_as(hooked, self._label, role)
+        self._name = "jit_" + traced.__name__
+        self._jit = jax.jit(traced, donate_argnums=donate_argnums)
+        _LIVE.add(self)
 
-    def _register_pending(self, wall_s):
-        """Turn trace-time aval captures into pending cost rows (the
+    def __del__(self):
+        # the newest dead executable of a role still answers `op_parts`
+        # (a dict store: no work for the collector's thread)
+        try:
+            if self._role and self._programs:
+                _RETIRED[self._name] = self._programs
+        except AttributeError:          # __init__ did not finish
+            pass
+
+    def _trace(self, avals, args):
+        """The signature `avals` as the call `args` traced it: each
+        argument's aval with the sharding it was committed to and its
+        weak type, so that the lowering is the call's own, letter for
+        letter, and the compile cache knows it.  Where `args` are
+        another thread's (two traced at once), the plain avals."""
+        import jax
+
+        def placed(s, x):
+            if tuple(getattr(x, "shape", ())) != tuple(s.shape):
+                raise ValueError("another call's arguments")
+            return jax.ShapeDtypeStruct(
+                s.shape, s.dtype,
+                sharding=x.sharding if getattr(x, "committed", False)
+                else None,
+                weak_type=bool(getattr(
+                    x, "weak_type",
+                    isinstance(x, (bool, int, float, complex)))))
+
+        try:
+            avals = jax.tree_util.tree_map(placed, avals, args)
+        except (ValueError, TypeError):
+            pass
+        self._tls.resolving = True
+        try:
+            return self._jit.trace(*avals)
+        finally:
+            self._tls.resolving = False
+
+    def _register_pending(self, args, wall_s):
+        """Turn trace-time aval captures into kept programs and, with
+        the recorder on (`wall_s`), pending cost rows (the
         lowering/analysis happens at table()/dump time — jit shares
         its trace cache with .lower(), so resolution usually re-traces
         nothing).  `wall_s` (this call's wall, which included the
@@ -462,6 +615,12 @@ class MeteredJit:
         me = weakref.ref(self)
         while self._pending:
             avals = self._pending.pop(0)
+            try:
+                self._programs.append([self._trace(avals, args), None])
+            except Exception:           # noqa: BLE001 — `op_parts`
+                pass                    # then lacks this signature
+            if wall_s is None:
+                continue
 
             def resolver(avals=avals):
                 j, s = jref(), me()
@@ -483,7 +642,10 @@ class MeteredJit:
     def __call__(self, *args):
         from . import flightrec as _bb
         if not _bb.enabled():
-            return self._jit(*args)
+            out = self._jit(*args)
+            if self._pending:           # this call traced a signature
+                self._register_pending(args, None)
+            return out
         t0 = time.monotonic()
         out = self._jit(*args)
         if self._pending:
@@ -493,7 +655,7 @@ class MeteredJit:
             # which step it was that recompiled
             t1 = time.monotonic()
             _spans.phase_at("compile.call", t0, t1, self._label)
-            self._register_pending(t1 - t0)
+            self._register_pending(args, t1 - t0)
         if self._keys:
             # cache-hit calls attribute to the newest row — knowing the
             # exact signature would cost a per-call pytree flatten,
