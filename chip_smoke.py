@@ -549,6 +549,58 @@ def _latent_case(run):
             "tpu_custom_calls": n_calls, "rel_err": round(worst, 6)}
 
 
+def _latent_prefill_case(run):
+    """`latent_prefill_attention` as `LatentAttention.prompt` calls it (on
+    the chip: the Mosaic kernel at the published widths, 128 heads, keys of
+    128 + the shared 64, values of 128, prompts of 2048 and 1536 positions)
+    against causal attention in NumPy float64, every head."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import attention as att
+
+    H, prompts = (4, (1024, 256)) if run.dry else (128, (2048, 1536))
+    dev = run.ctx().jax_device
+    cases = []
+    for T in prompts:
+        rs = np.random.RandomState(T)
+        put = lambda *shape: jax.device_put(
+            rs.randn(*shape).astype(np.float32), dev).astype(jnp.bfloat16)
+        args = (put(T, H * 128), put(H, T, 64), put(T, H * 128), put(T, 64),
+                put(T, H * 128))
+        run.on_device(list(args), "kernels input")
+        compiled = jax.jit(lambda *a: att.latent_prefill_attention(
+            *a, 0.1147)).lower(*args).compile()
+        n_calls = compiled.as_text().count("tpu_custom_call")
+        check(run.dry or n_calls == 1,
+              "latent_prefill_attention compiled %d tpu_custom_call(s) on "
+              "the chip, want the kernel" % n_calls)
+        out = compiled(*args)
+        run.on_device([out], "kernels output")
+        out = np.asarray(out.astype(jnp.float32)).reshape(T, H, 128)
+        check(np.isfinite(out).all(), "non-finite latent prefill output")
+        f64 = lambda a: np.asarray(a.astype(jnp.float32)).astype(np.float64)
+        qn, qr, kn, kr, v = (f64(a) for a in args)
+        qn, kn, v = (a.reshape(T, H, 128) for a in (qn, kn, v))
+        causal = np.tril(np.ones((T, T), bool))
+        worst = 0.0
+        for h in range(H):
+            sc = np.where(causal, (qn[:, h] @ kn[:, h].T + qr[h] @ kr.T)
+                          * 0.1147, -np.inf)
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            ref = (p / p.sum(-1, keepdims=True)) @ v[:, h]
+            worst = max(worst, float(np.abs(out[:, h] - ref).max()
+                                     / np.abs(ref).max()))
+        # the probabilities and the result are rounded to bfloat16
+        check(worst < 1e-2, "latent prefill attention is %.3g (relative) "
+              "away from float64 at %d positions" % (worst, T))
+        cases.append({"positions": T, "heads": H,
+                      "block": att.latent_prefill_block(T),
+                      "tpu_custom_calls": n_calls,
+                      "rel_err": round(worst, 6)})
+    return cases
+
+
 def phase_kernels(run):
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import attention as att
@@ -565,7 +617,8 @@ def phase_kernels(run):
                               for dt in (jnp.float32, jnp.bfloat16)],
             "gated_delta": _delta_case(run),
             "grouped_experts": _grouped_case(run),
-            "latent_decode": _latent_case(run)}
+            "latent_decode": _latent_case(run),
+            "latent_prefill": _latent_prefill_case(run)}
 
 
 # --------------------------------------------------------------------------

@@ -19,8 +19,10 @@ The cache holds c and k_r, `kv_rank + rope` values a position a layer, and
 nothing else.  The two forms of the same attention:
 
 - **expanded** (`prompt`): k_n = Wkn c and v = Wv c are formed once for the
-  prompt's rows, and attention is causal multi-head attention in blocks
-  (`ops.attention.blocked_causal_attention`).
+  prompt's rows, and attention is causal multi-head attention
+  (`ops.attention.latent_prefill_attention`: a Pallas flash kernel where
+  the prefill is lowered for a TPU, `blocked_causal_attention`'s blocks
+  elsewhere).
 - **absorbed** (`step`): q~ = Wkn^T q_n a head, scores q~ . c + q_r . k_r
   over the cached rows, u = sum p c in the latent space, o = Wv u
   (`ops.attention.latent_decode_attention`: a Pallas kernel over the
@@ -168,21 +170,33 @@ class LatentAttention(_Stacked):
     def prompt(self, p, h, block, chunk):
         """One layer over a whole prompt h (T, D), expanded: (h +
         attention, the rows c (T, kv_rank) and k_r (T, rope) for the
-        cache)."""
+        cache).  Every head's 128-wide parts come out of their products
+        side by side, (T, H * d), and the rotary queries head-major: as the
+        attention's kernel reads them, with no copy between
+        (`ops.attention.latent_prefill_attention`).  The products and
+        roundings are `project`'s."""
+        import jax
         import jax.numpy as jnp
-        from ..ops.attention import blocked_causal_attention
-        T, f32 = h.shape[0], jnp.float32
+        from ..ops.attention import latent_prefill_attention
+        H, dn, dt = self._H, self._dn, p["wqa"].dtype
+        rot = functools.partial(_rotary_pairs, pos=jnp.arange(h.shape[0]),
+                                inv_freq=self.inv_freq,
+                                mscale=self._rot_scale)
+        flat = lambda w: w.reshape(-1, w.shape[-1])
         with _costs.part("proj"):
-            qn, qr, c, kr = self.project(p, h, jnp.arange(T))
-            kn = jnp.einsum("tc,hnc->thn", c, p["wkn"],
-                            preferred_element_type=f32).astype(c.dtype)
-            v = jnp.einsum("tc,hvc->thv", c, p["wv"],
-                           preferred_element_type=f32).astype(c.dtype)
-            k = jnp.concatenate(
-                [kn, jnp.broadcast_to(kr[:, None, :],
-                                      (T, self._H, self._dr))], -1)
-            q = jnp.concatenate([qn, qr], -1)
-        o = blocked_causal_attention(q, k, v, self.scale, block, chunk)
+            x = _rms(h, p["ln"], self._eps).astype(dt)
+            cq = _rms(_dense(x, p["wqa"]), p["gq"], self._eps).astype(dt)
+            c = _rms(_dense(x, p["wkc"]), p["gkv"], self._eps).astype(dt)
+            kr = rot(_dense(x, p["wkr"])).astype(dt)
+            wq = p["wqb"].reshape(H, dn + self._dr, -1)
+            qn = _dense(cq, flat(wq[:, :dn])).astype(dt)
+            qr = jax.vmap(rot)(jnp.einsum(
+                "tr,hdr->htd", cq, wq[:, dn:],
+                preferred_element_type=jnp.float32)).astype(dt)
+            kn = _dense(c, flat(p["wkn"])).astype(dt)
+            v = _dense(c, flat(p["wv"])).astype(dt)
+        o = latent_prefill_attention(qn, qr, kn, kr, v, self.scale, block,
+                                     chunk)
         return self._out(p, h, o), c, kr
 
     def step(self, p, h, pos, layer, cache):

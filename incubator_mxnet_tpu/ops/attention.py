@@ -39,6 +39,7 @@ from .registry import register
 __all__ = ["flash_attention", "naive_attention", "index_scores",
            "select_mask", "masked_decode_attention",
            "blocked_select_attention", "blocked_causal_attention",
+           "latent_prefill_attention", "latent_prefill_block",
            "latent_decode_attention", "latent_rows_read", "latent_row_block",
            "decode_attention",
            "ragged_decode_attention", "dense_decode_attention",
@@ -729,6 +730,185 @@ def blocked_causal_attention(q, k, v, scale, block=512, chunk=512):
                               mask, scale, chunk)           # (H, 1, bq, dv)
             out.append(o[:, 0].transpose(1, 0, 2))
         return jnp.concatenate(out, 0)
+
+
+# ---------------------------------------------------------------------------
+# latent prefill attention: causal attention over a whole prompt, every head's
+# key a part of its own without a position and ONE rotary part all heads share
+# ---------------------------------------------------------------------------
+# The expanded form of a latent-attention prompt: a head's score is
+# q_n . k_n + q_r . k_r with k_r (T, rope) the same for every head, its values
+# narrower than its keys.
+#
+# - `_prefill_blocked`: the rotary key copied to every head and joined to the
+#   keys, then `blocked_causal_attention`: float32 score chunks (H, 1, block,
+#   chunk) in memory.  The reference, what the CPU runs, and what runs for
+#   shapes the kernel does not tile.
+# - `_prefill_pallas`: the kernel `latent_prefill_attention`.  Grid (groups of
+#   heads, causal pairs of a query block and a key block): the pairs on or
+#   under the diagonal are listed on the host and prefetched as scalars, so a
+#   key block above the diagonal is no grid step at all: neither fetched nor
+#   computed.  The operands lie as the projections leave them, (T, H * d)
+#   (a (T, H, d) array is OTHER bytes on the TPU, whose tiles span the two
+#   minor axes): a head's 128-wide part is a column block of whole lane
+#   tiles, and so is its part of the result, which the output projection
+#   takes as it is; only the rotary queries (64 wide, no whole tile a head)
+#   come head-major, and the shared rotary key transposed, (rope, T), once.
+#   A pair's scores, the flash recurrence (float32) and the probabilities
+#   stay in VMEM; only the pairs that the diagonal crosses are masked.
+# - `latent_prefill_attention` chooses between them where the prompt is
+#   LOWERED (`lax.platform_dependent`), as `latent_decode_attention` does.
+
+_PREFILL_BLOCK = 512    # query rows, and key rows, a grid step, at most
+_PREFILL_HEADS = 4      # heads a grid step, at most
+
+
+def latent_prefill_block(T):
+    """Rows of a query block and of a key block of the prefill kernel over a
+    prompt of T positions: the largest divisor of T that is whole lane tiles
+    (a pair's scores lie with the keys on the lanes) up to `_PREFILL_BLOCK`,
+    T itself where the prompt is shorter than that; 0 if there is none."""
+    return _largest_divisor(T, _PREFILL_BLOCK, 128)
+
+
+def _prefill_fits(T, dn, dv):
+    """Whether the kernel tiles a prompt of T positions with `dn` key dims
+    without a position and `dv` value dims a head: whole blocks, and a
+    head's part of a (T, H * d) operand whole lane tiles.  The interpreter
+    takes any widths (the tests' tiny model)."""
+    tb = latent_prefill_block(T)
+    return tb > 0 and (_interpret() or not (tb % 128 or dn % 128 or dv % 128))
+
+
+def latent_prefill_attention(qn, qr, kn, kr, v, scale, block=512, chunk=512):
+    """Causal attention of a whole prompt in the expanded latent form, over
+    the operands as the projections leave them.  qn, kn (T, H * dn) every
+    head's query and key parts without a position side by side, qr
+    (H, T, dr) the rotary queries, head-major, kr (T, dr) the ONE rotary key
+    a position, v (T, H * dv).  Scores (qn . kn + qr . kr) * scale over keys
+    <= the query, operands in their own type summed in float32, softmax in
+    float32, the probabilities rounded to the values' type for the context.
+    Returns (T, H * dv) in the values' type.
+
+    The kernel where the prompt is lowered for a TPU (and wherever
+    `MXNET_PALLAS_INTERPRET` runs the kernel itself), and
+    `blocked_causal_attention` in query blocks of `block` and key chunks of
+    `chunk` elsewhere and for shapes the kernel does not tile."""
+    with _costs.part("attn"):
+        H, T, _ = qr.shape
+        args = (qn, qr, kn, kr, v)
+        kernel = functools.partial(_prefill_pallas, scale=scale)
+        blocked = functools.partial(_prefill_blocked, scale=scale,
+                                    block=block, chunk=chunk)
+        if not _prefill_fits(T, qn.shape[1] // H, v.shape[1] // H):
+            return blocked(*args)
+        if _interpret() or jax.default_backend() == "tpu":
+            # trace-time side effect only, as `serve.traces` is: one for each
+            # layer body that is lowered with the kernel
+            events.incr("mla.prefill_kernel_traces")
+        if _interpret():
+            return kernel(*args)
+        return jax.lax.platform_dependent(*args, tpu=kernel, default=blocked)
+
+
+def _prefill_blocked(qn, qr, kn, kr, v, scale, block, chunk):
+    H, T, dr = qr.shape
+    heads = lambda a: a.reshape(T, H, -1)
+    k = jnp.concatenate(
+        [heads(kn), jnp.broadcast_to(kr[:, None, :], (T, H, dr))], -1)
+    q = jnp.concatenate([heads(qn), qr.transpose(1, 0, 2)], -1)
+    return blocked_causal_attention(q, k, heads(v), scale, block, chunk) \
+        .astype(v.dtype).reshape(T, -1)
+
+
+def _prefill_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                    o_ref, m_s, l_s, acc_s, *, scale, tb, hb, dn, dv):
+    p = pl.program_id(1)
+    i, j = qi_ref[p], kj_ref[p]
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, _NEG_INF, f32)
+        l_s[...] = jnp.zeros(l_s.shape, f32)
+        acc_s[...] = jnp.zeros(acc_s.shape, f32)
+
+    def pair(diagonal):
+        kr_t = kr_ref[...]                                      # (dr, tb)
+        lanes = l_s.shape[2]
+        for h in range(hb):
+            n, o = slice(h * dn, (h + 1) * dn), slice(h * dv, (h + 1) * dv)
+            s = jax.lax.dot_general(
+                qn_ref[:, n], kn_ref[:, n], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) \
+                + jnp.dot(qr_ref[h], kr_t, preferred_element_type=f32)
+            s = s * scale                                       # (tb, tb)
+            if diagonal:
+                s = jnp.where(
+                    jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                    >= jax.lax.broadcasted_iota(jnp.int32, s.shape, 1),
+                    s, _NEG_INF)
+            m_prev = m_s[h]                                     # (tb, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            e = jnp.exp(s - m_new)
+            shrink = jnp.exp(m_prev - m_new)
+            # the row sums stay a lane apart until the query block's end:
+            # adding lane tiles is elementwise, a sum ACROSS lanes is not
+            # (measured, PERF.md PR 40: a tenth of the kernel)
+            l_s[h] = l_s[h] * shrink + sum(
+                e[:, t:t + lanes] for t in range(0, tb, lanes))
+            acc_s[:, o] = acc_s[:, o] * shrink + jnp.dot(
+                e.astype(v_ref.dtype), v_ref[:, o],
+                preferred_element_type=f32)
+            m_s[h] = m_new
+
+    # query and key blocks are alike, so the diagonal crosses pair (i, i)
+    # alone, which is also a query block's last
+    pl.when(j < i)(functools.partial(pair, False))
+
+    @pl.when(j == i)
+    def _last():
+        pair(True)
+        for h in range(hb):
+            o = slice(h * dv, (h + 1) * dv)
+            o_ref[:, o] = (acc_s[:, o] / jnp.sum(
+                l_s[h], axis=1, keepdims=True)).astype(o_ref.dtype)
+
+
+def _prefill_pallas(qn, qr, kn, kr, v, scale):
+    H, T, dr = qr.shape
+    dn, dv = qn.shape[1] // H, v.shape[1] // H
+    tb = latent_prefill_block(T)
+    hb = math.gcd(H, _PREFILL_HEADS)
+    pairs = [(i, j) for i in range(T // tb) for j in range(i + 1)]
+    qi, kj = (jnp.asarray(a, jnp.int32) for a in zip(*pairs))
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=float(scale), tb=tb, hb=hb,
+                          dn=dn, dv=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H // hb, len(pairs)),
+            in_specs=[
+                pl.BlockSpec((tb, hb * dn), lambda g, p, qi, kj: (qi[p], g)),
+                pl.BlockSpec((hb, tb, dr),
+                             lambda g, p, qi, kj: (g, qi[p], 0)),
+                pl.BlockSpec((tb, hb * dn), lambda g, p, qi, kj: (kj[p], g)),
+                pl.BlockSpec((dr, tb), lambda g, p, qi, kj: (0, kj[p])),
+                pl.BlockSpec((tb, hb * dv), lambda g, p, qi, kj: (kj[p], g)),
+            ],
+            out_specs=pl.BlockSpec((tb, hb * dv),
+                                   lambda g, p, qi, kj: (qi[p], g)),
+            scratch_shapes=[pltpu.VMEM((hb, tb, 1), jnp.float32),
+                            pltpu.VMEM((hb, tb, math.gcd(tb, 128)),
+                                       jnp.float32),
+                            pltpu.VMEM((tb, hb * dv), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((T, H * dv), v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="latent_prefill_attention",
+        interpret=_interpret(),
+    )(qi, kj, qn, qr, kn, kr.T, v)
 
 
 # ---------------------------------------------------------------------------

@@ -167,8 +167,14 @@ def test_latent_decoder_step_and_prefill_fit_the_chip(one_chip):
     scan's body, over the leaves as they lie: no float32 scores (S, 128,
     3072) and no copy of a layer's rows are among its temporaries, 69.1 MB
     in all (815.7 MB with the einsums, before the kernel); the prefill
-    holds no such kernel; and with the layers, experts and vocabulary rows
-    left out here added back, the step and the prefill stay under 16 GB."""
+    attends through the kernel `latent_prefill_attention`, once for the
+    dense layer and once in the scan's body, and holds no
+    `latent_decode_attention`: no float32 scores (128, 512, 512) and no
+    keys joined to a copy of the rotary key a head, (T, 128, 192), are among
+    its instructions, and its temporaries are 476.8 MB (667.1 MB with
+    `blocked_causal_attention`, before the kernel); and with the layers,
+    experts and vocabulary rows left out here added back, the step and the
+    prefill stay under 16 GB."""
     from incubator_mxnet_tpu.models.latent_decoder import LatentDecoder
 
     S, L, V, held, layers = 256, 3072, 1024, 2, 3
@@ -205,6 +211,12 @@ def test_latent_decoder_step_and_prefill_fit_the_chip(one_chip):
     text = prefill.as_text()
     assert _kernel_calls(text, "held_experts_grouped") == 1
     assert _kernel_calls(text, "latent_decode_attention") == 0
+    assert _kernel_calls(text, "latent_prefill_attention") == 2
+    # a chunk's scores, or every head's keys with the rotary key joined on
+    scored = [(op, name, dims) for op, name, dims in _results(text)
+              if sorted(d for d in dims if d > 1) in ([128, 512, 512],
+                                                      [128, 192, 2048])]
+    assert not scored, scored
     # what this test left off the chip: two sparse layers (attention
     # 149.23 M, router and shared experts 48.0 M, ten experts of 23.59 M),
     # eight experts of each one here, 11 776 rows of the embedding and of
@@ -217,6 +229,7 @@ def test_latent_decoder_step_and_prefill_fit_the_chip(one_chip):
     assert size(mem) + absent + rows < 16e9, size(mem) + absent + rows
     # a prefill runs beside the resident cache
     pre = prefill.memory_analysis()
+    assert pre.temp_size_in_bytes < 0.55e9, pre.temp_size_in_bytes
     assert size(pre) + total + absent + rows < 16e9, \
         size(pre) + total + absent + rows
 

@@ -97,6 +97,19 @@ def _interpret_kernels(monkeypatch):
     assert attention._interpret()
 
 
+def _kernels_off(monkeypatch):
+    """A plain CPU trace, whatever `tests/benchmark`'s rehearsals left in
+    `os.environ` (`benchmark/run.py` sets MXNET_PALLAS_INTERPRET for good,
+    ROADMAP S0 (e))."""
+    from incubator_mxnet_tpu import config
+    from incubator_mxnet_tpu.ops import attention
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(config, "_OVERRIDES", {
+        k: v for k, v in config._OVERRIDES.items()
+        if k != "MXNET_PALLAS_INTERPRET"})
+    assert not attention._interpret()
+
+
 # ---- rotary positions and the softmax scale of the published block ---------
 
 PUBLISHED_YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40,
@@ -397,6 +410,89 @@ def test_blocked_causal_attention_takes_values_narrower_than_keys():
                                    jnp.zeros((20, H, 5)), 1.0, block=8)
 
 
+def _prefill_operands(T, H, dtype, seed):
+    """(qn, qr, kn, kr, v) of `latent_prefill_attention` at keys of 128 + 64
+    and values of 128, values of `dtype` held as float32."""
+    import jax.numpy as jnp
+    rs = onp.random.RandomState(seed)
+    rnd = lambda *s: jnp.asarray(rs.randn(*s).astype(onp.float32),
+                                 jnp.dtype(dtype))
+    return (rnd(T, H * 128), rnd(H, T, 64), rnd(T, H * 128), rnd(T, 64),
+            rnd(T, H * 128))
+
+
+def _causal_by_hand(qn, qr, kn, kr, v, scale):
+    """A loop over heads in NumPy float64: (T, H * dv)."""
+    import jax.numpy as jnp
+    f64 = lambda a: onp.asarray(a.astype(jnp.float32), onp.float64)
+    qn, qr, kn, kr, v = (f64(a) for a in (qn, qr, kn, kr, v))
+    H, T, _ = qr.shape
+    qn, kn, v = (a.reshape(T, H, -1) for a in (qn, kn, v))
+    out = []
+    for h in range(H):
+        sc = onp.where(onp.tril(onp.ones((T, T), bool)),
+                       (qn[:, h] @ kn[:, h].T + qr[h] @ kr.T) * scale,
+                       -onp.inf)
+        p = onp.exp(sc - sc.max(-1, keepdims=True))
+        out.append((p / p.sum(-1, keepdims=True)) @ v[:, h])
+    return onp.stack(out, 1).reshape(T, -1)
+
+
+@pytest.mark.parametrize("T", [1024, 1536])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_latent_prefill_kernel_is_causal_attention(monkeypatch, dtype, T):
+    """The kernel in interpret mode at keys of 192 (128 + the shared 64)
+    and values of 128, eight heads (two groups of four), a prompt of two and
+    of three blocks of 512: equal to `blocked_causal_attention` over the
+    joined keys (the plain CPU trace of the same call) and to a loop over
+    heads in NumPy float64.  `mla.prefill_kernel_traces` counts the one
+    body traced with the kernel, and none without it."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import attention as A
+    assert A.latent_prefill_block(T) == 512
+    args = _prefill_operands(T, 8, dtype, T + len(dtype))
+    run = lambda: jax.jit(
+        lambda *a: A.latent_prefill_attention(*a, 0.11))(*args)
+    _kernels_off(monkeypatch)
+    traced = events.get("mla.prefill_kernel_traces") or 0
+    blocked = run()
+    assert (events.get("mla.prefill_kernel_traces") or 0) == traced
+    _interpret_kernels(monkeypatch)
+    got = run()
+    assert (events.get("mla.prefill_kernel_traces") or 0) == traced + 1
+    assert got.shape == (T, 8 * 128) and got.dtype == jnp.dtype(dtype)
+    assert blocked.shape == got.shape and blocked.dtype == got.dtype
+    got, blocked = (onp.asarray(a.astype(jnp.float32))
+                    for a in (got, blocked))
+    want = _causal_by_hand(*args, 0.11)
+    assert onp.isfinite(got).all()
+    # bfloat16: the probabilities and the result are rounded to 8 bits
+    tol = 1e-2 if dtype == "bfloat16" else 2e-5
+    assert onp.abs(got - want).max() < tol * onp.abs(want).max()
+    assert onp.abs(got - blocked).max() < tol * onp.abs(want).max()
+
+
+@pytest.mark.parametrize("T,block", [(600, 200), (1000, 500)])
+def test_a_prompt_that_does_not_tile_takes_the_xla_form(monkeypatch, T,
+                                                        block):
+    """No divisor of T up to 512 is whole lane tiles: the call is
+    `blocked_causal_attention`, in interpret mode too, and counts no
+    kernel."""
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import attention as A
+    assert A.latent_prefill_block(T) == 0
+    args = _prefill_operands(T, 2, "float32", T)
+    _interpret_kernels(monkeypatch)
+    monkeypatch.setattr(A, "_prefill_pallas", lambda *a, **k: 1 / 0)
+    traced = events.get("mla.prefill_kernel_traces") or 0
+    got = onp.asarray(A.latent_prefill_attention(*args, 0.2, block=block,
+                                                 chunk=100))
+    assert (events.get("mla.prefill_kernel_traces") or 0) == traced
+    want = _causal_by_hand(*args, 0.2)
+    assert onp.abs(got - want).max() < 2e-5 * onp.abs(want).max()
+
+
 # ---- the model against the plain reference --------------------------------
 
 # the whole forward pass, in query blocks of 8
@@ -451,6 +547,52 @@ def test_prefill_then_decode_logits_match_the_reference(tiny):
     share = system._net.step_weight_bytes() // 1024 // 2
     assert list(counts[:, 3] - counts[:, 2]) == [share, share, 0]
     assert list(counts[:, 4]) == [2 * 3] * 3
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+@pytest.mark.parametrize("path", ["forward", "prefill_then_decode"])
+def test_the_prefill_kernel_in_the_model(monkeypatch, tiny, path, kernel):
+    """A new trace of `forward` and of `init_cache` with the prefill's
+    attention as its kernel (interpret mode: the tiny widths are no whole
+    lane tiles, which only the interpreter takes) and as the XLA form (a
+    plain CPU trace): the logits are the reference's either way, and an
+    executable counts two layer bodies traced with the kernel (the dense
+    layer's and the scan's), or none."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.parallel.functional import extract_params
+    from incubator_mxnet_tpu.serving.generation import _pure_method
+    cfg, ref, w, system = tiny
+    net, L = system._net, cfg["serving"]["max_len"]
+    (_interpret_kernels if kernel else _kernels_off)(monkeypatch)
+    params = extract_params(net)
+    rs = onp.random.RandomState(40 + kernel)
+    traced = events.get("mla.prefill_kernel_traces") or 0
+    if path == "forward":
+        tok = rs.randint(3, 128, (1, 24)).astype(onp.int32)
+        out = jax.jit(_pure_method(net, "forward"))(params, jnp.asarray(tok))
+        assert onp.abs(onp.asarray(out)[0]
+                       - _ref_logits(ref, w, cfg, tok[0])).max() < 2e-4
+    else:
+        n = 13
+        seq = rs.randint(3, cfg["vocab_size"], n + 4).astype(onp.int32)
+        want = _ref_logits(ref, w, cfg, seq)
+        prompt = rs.randint(3, 128, (1, 16)).astype(onp.int32)
+        prompt[0, :n] = seq[:n]
+        pure = _pure_method(net, "init_cache")
+        cache = dict(jax.jit(lambda pv, t, m: pure(pv, t, m, L, None))(
+            params, jnp.asarray(prompt), jnp.asarray([n], jnp.int32)))
+        assert int(cache.pop("start_tok")[0]) == seq[n - 1]
+        assert int(cache.pop("start_pos")[0]) == n - 1
+        _, step = _model_fns(net, cfg)
+        for j in range(4):
+            logits, cache = step(jnp.asarray(seq[n - 1 + j:n + j]),
+                                 jnp.asarray([n - 1 + j], jnp.int32), cache,
+                                 jnp.asarray([True]))
+            assert onp.abs(onp.asarray(logits)[0] - want[n - 1 + j]).max() \
+                < 2e-4, j
+    assert (events.get("mla.prefill_kernel_traces") or 0) - traced \
+        == (2 if kernel else 0)
 
 
 @pytest.mark.parametrize("n_prompt,n_new", [(3, 14), (8, 9), (16, 16),
