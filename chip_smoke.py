@@ -320,9 +320,12 @@ def _kernel_case(run, B, H, T, d, causal):
             "rel_err": {name: round(e, 5) for name, e in errs.items()}}
 
 
-def _ragged_case(run, dtype):
+def _ragged_case(run, dtype, stacked=False):
     """`decode_attention` as the decode step calls it (on the chip: the
-    Mosaic kernel) against the same attention in NumPy float64."""
+    Mosaic kernel) against the same attention in NumPy float64.  `stacked`:
+    the leaves of a model whose layers run several times a token, (S, 3,
+    G, T, W) read at index 2, one 128-wide head a row, at the served
+    model's slots, heads and rows."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -330,10 +333,15 @@ def _ragged_case(run, dtype):
 
     S, G, T, W, heads = (12, 2, 64, 128, 2) if run.dry \
         else (40, 4, 256, 128, 2)
+    if stacked:
+        S, G, T, W, heads = (12, 2, 96, 128, 1) if run.dry \
+            else (24, 16, 480, 128, 1)
+    at = 2 if stacked else None
     rs = np.random.RandomState(S)
     dev = run.ctx().jax_device
     q = jax.device_put(rs.randn(S, G, W).astype(np.float32), dev)
-    k, v = (jax.device_put(rs.randn(S, G, T, W).astype(np.float32), dev)
+    leaf = (S, 3, G, T, W) if stacked else (S, G, T, W)
+    k, v = (jax.device_put(rs.randn(*leaf).astype(np.float32), dev)
             .astype(dtype) for _ in range(2))
     tb = att.ragged_row_block(T, dtype)
     lens = rs.randint(0, T + 1, (S,)).astype(np.int32)
@@ -341,7 +349,8 @@ def _ragged_case(run, dtype):
     lens[S // 2:S // 2 + 3] = 0
     run.on_device([q, k, v], "kernels input")
     compiled = jax.jit(lambda q, k, v, n: att.decode_attention(
-        q, k, v, n, heads=heads, scale=0.125)).lower(
+        q, k, v, n, heads=heads, scale=0.125,
+        layer=None if at is None else jnp.int32(at))).lower(
             q, k, v, jax.device_put(lens, dev)).compile()
     n_calls = compiled.as_text().count("tpu_custom_call")
     check(run.dry or n_calls == 1,
@@ -353,7 +362,8 @@ def _ragged_case(run, dtype):
     check(np.isfinite(out).all(), "non-finite ragged decode output")
     d = W // heads
     q64, k64, v64 = (np.asarray(a.astype(jnp.float32)).astype(np.float64)
-                     for a in (q, k, v))
+                     for a in (q, k if at is None else k[:, at],
+                               v if at is None else v[:, at]))
     worst = 0.0
     for s in np.nonzero(lens)[0]:
         for h in range(heads):
@@ -367,7 +377,8 @@ def _ragged_case(run, dtype):
     check(worst < 2e-5, "ragged decode attention over %s leaves is %.3g "
           "away from float64" % (jnp.dtype(dtype).name, worst))
     return {"dtype": jnp.dtype(dtype).name, "slots": S, "rows": T,
-            "row_block": tb, "tpu_custom_calls": n_calls,
+            "stacked": stacked, "row_block": tb,
+            "tpu_custom_calls": n_calls,
             "max_abs_err": round(worst, 8)}
 
 
@@ -614,7 +625,8 @@ def phase_kernels(run):
     return {"compile_s": round(sum(c["compile_s"] for c in cases), 2),
             "cases": cases,
             "ragged_decode": [_ragged_case(run, dt)
-                              for dt in (jnp.float32, jnp.bfloat16)],
+                              for dt in (jnp.float32, jnp.bfloat16)]
+            + [_ragged_case(run, jnp.bfloat16, stacked=True)],
             "gated_delta": _delta_case(run),
             "grouped_experts": _grouped_case(run),
             "latent_decode": _latent_case(run),

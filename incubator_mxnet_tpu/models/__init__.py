@@ -13,6 +13,7 @@ from .sparse_decoder import (SparseDecoder, SelectAttention, HeldExperts,
                              RMSNorm)
 from .hybrid_decoder import HybridDecoder, GatedAttention, GatedDeltaNet
 from .latent_decoder import LatentDecoder, LatentAttention, DenseSwiGLU
+from .looped_decoder import LoopedDecoder, SandwichAttention
 from .faster_rcnn import (FasterRCNN, faster_rcnn_toy,
                           faster_rcnn_resnet50_v1b,
                           rcnn_training_targets, RCNNTrainLoss)
@@ -30,4 +31,4 @@ __all__ = ["transformer", "BERTModel", "TransformerEncoder", "bert_base",
            "gnmt_sym_gen", "SparseDecoder", "SelectAttention",
            "HeldExperts", "RMSNorm", "HybridDecoder", "GatedAttention",
            "GatedDeltaNet", "LatentDecoder", "LatentAttention",
-           "DenseSwiGLU"]
+           "DenseSwiGLU", "LoopedDecoder", "SandwichAttention"]

@@ -226,12 +226,14 @@ class LatentAttention(_Stacked):
 
 
 class DenseSwiGLU(_Stacked):
-    """The feed-forward half of a leading dense layer: pre-norm and one
-    SwiGLU of width `hidden`."""
+    """The feed-forward half of a dense layer: pre-norm and one SwiGLU of
+    width `hidden`; with `post_norm`, a norm of what it gives as well
+    (`ln_post`), before the residual stream takes it."""
 
     _names = ("ln", "wg", "wu", "wd")
 
-    def __init__(self, layers, units, hidden, eps=1e-6, **kwargs):
+    def __init__(self, layers, units, hidden, eps=1e-6, post_norm=False,
+                 **kwargs):
         super().__init__(**kwargs)
         self._layers, self._eps = int(layers), float(eps)
         D, F = int(units), int(hidden)
@@ -239,12 +241,17 @@ class DenseSwiGLU(_Stacked):
         self.wg = self._param("wg", (F, D))
         self.wu = self._param("wu", (F, D))
         self.wd = self._param("wd", (D, F))
+        if post_norm:
+            self._names = self._names + ("ln_post",)
+            self.ln_post = self._param("ln_post", (D,), "ones")
 
     def apply(self, p, h):
         from ..parallel import moe
         with _costs.part("ffn"):
             x = _rms(h, p["ln"], self._eps).astype(p["wg"].dtype)
-            return h + moe.swiglu(x, p["wg"], p["wu"], p["wd"])
+            y = moe.swiglu(x, p["wg"], p["wu"], p["wd"])
+            return h + (_rms(y, p["ln_post"], self._eps) if "ln_post" in p
+                        else y)
 
 
 class LatentDecoder(HybridBlock):

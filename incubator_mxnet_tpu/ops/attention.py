@@ -43,7 +43,7 @@ __all__ = ["flash_attention", "naive_attention", "index_scores",
            "latent_decode_attention", "latent_rows_read", "latent_row_block",
            "decode_attention",
            "ragged_decode_attention", "dense_decode_attention",
-           "decode_rows_read", "ragged_row_block"]
+           "decode_rows_read", "ragged_row_block", "decode_rows_write"]
 
 _NEG_INF = -1e30
 
@@ -1126,6 +1126,10 @@ def _latent_pallas(q_abs, q_rope, ckv, kr, layer, lengths, scale):
 # - `decode_attention` chooses between them where the step is LOWERED
 #   (`lax.platform_dependent`), so a compile for a described TPU sees the
 #   kernel while the CPU runs the einsums.
+# - With `layer`, the leaves are STACKED, (S, layers, G, T, W), and read at
+#   that index of axis 1 (a traced scalar, prefetched with the plan): the
+#   kernel's blocks are cut out of the leaves as they lie.  A layer's slice
+#   in front of the kernel would be copied, a leaf's worth a layer body.
 
 _SLOT_BLOCK = 16        # slots a grid step
 _ROW_BLOCK = 64         # cached rows a grid step, at most
@@ -1144,10 +1148,10 @@ def ragged_row_block(T, dtype=jnp.float32):
 
 
 def _ragged_fits(k):
-    """Whether the ragged kernel tiles the leaf k (S, G, T, W) on a TPU:
-    full-lane rows, row blocks of whole tiles, a block that fits VMEM
-    twice over beside V's."""
-    S, G, T, W = k.shape
+    """Whether the ragged kernel tiles the leaf k (S, G, T, W), or
+    (S, layers, G, T, W), on a TPU: full-lane rows, row blocks of whole
+    tiles, a block that fits VMEM twice over beside V's."""
+    S, (G, T, W) = k.shape[0], k.shape[-3:]
     tb = ragged_row_block(T, k.dtype)
     block = min(S, _SLOT_BLOCK) * G * tb * W * jnp.dtype(k.dtype).itemsize
     return W % 128 == 0 and tb % _tile_rows(k.dtype) == 0 \
@@ -1155,30 +1159,35 @@ def _ragged_fits(k):
 
 
 def decode_rows_read(lengths, k):
-    """Rows of the leaf k (S, G, T, W) that `decode_attention` covers for
-    each slot, (S,) int32: the slot's length rounded up to the kernel's
-    row block, or all T where the leaf does not tile."""
-    T = k.shape[2]
+    """Rows of the leaf k (S, G, T, W), or (S, layers, G, T, W), that
+    `decode_attention` covers for each slot in one layer, (S,) int32: the
+    slot's length rounded up to the kernel's row block, or all T where the
+    leaf does not tile."""
+    T = k.shape[-2]
     tb = ragged_row_block(T, k.dtype) if _ragged_fits(k) else T
     return (-(-jnp.clip(lengths, 0, T) // tb) * tb).astype(jnp.int32)
 
 
-def decode_attention(q, k, v, lengths, heads=1, scale=1.0):
+def decode_attention(q, k, v, lengths, heads=1, scale=1.0, layer=None):
     """`ragged_decode_attention` where the step is lowered for a TPU (and
     wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself),
     `dense_decode_attention` elsewhere and for leaves the kernel does not
-    tile.  The two agree on every slot with lengths > 0."""
+    tile.  The two agree on every slot with lengths > 0.  With `layer` (a
+    traced scalar), k and v are stacked leaves (S, layers, G, T, W), read
+    at that layer."""
     with _costs.part("attn"):
         ragged = functools.partial(ragged_decode_attention, heads=heads,
                                    scale=scale)
         dense = functools.partial(dense_decode_attention, heads=heads,
                                   scale=scale)
+        args = (q, k, v, lengths)
+        if layer is not None:
+            args += (jnp.asarray(layer, jnp.int32).reshape(1),)
         if _interpret():
-            return ragged(q, k, v, lengths)
+            return ragged(*args)
         if not _ragged_fits(k):
-            return dense(q, k, v, lengths)
-        return jax.lax.platform_dependent(q, k, v, lengths, tpu=ragged,
-                                          default=dense)
+            return dense(*args)
+        return jax.lax.platform_dependent(*args, tpu=ragged, default=dense)
 
 
 def _lane_owner(heads, W):
@@ -1187,11 +1196,14 @@ def _lane_owner(heads, W):
     return own[:, None] == own[None, :]
 
 
-def dense_decode_attention(q, k, v, lengths, heads=1, scale=1.0):
+def dense_decode_attention(q, k, v, lengths, layer=None, heads=1, scale=1.0):
     """q (S, G, W) against k, v (S, G, T, W) under rows < lengths (S,):
     (S, G, W) float32.  Head j of a group reads its own d = W/heads of the
     row's lanes: its query is zero on the others', and of the (heads, W)
-    context it keeps its own d."""
+    context it keeps its own d.  With `layer` (1,), the leaves are stacked
+    (S, layers, G, T, W) and that layer's rows are taken."""
+    if layer is not None:
+        k, v = jnp.take(k, layer[0], axis=1), jnp.take(v, layer[0], axis=1)
     S, G, T, W = k.shape
     d = W // heads
     own = jnp.eye(heads, dtype=q.dtype)[:, :, None]             # (P, P, 1)
@@ -1205,9 +1217,10 @@ def dense_decode_attention(q, k, v, lengths, heads=1, scale=1.0):
     return ctx.reshape(S, G, W)
 
 
-def _ragged_kernel(fetch_ref, need_ref, lo_ref, hi_ref, len_ref,
-                   q_ref, own_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
+def _ragged_kernel(fetch_ref, need_ref, lo_ref, hi_ref, len_ref, *refs,
                    scale, sb, tb):
+    # stacked leaves prefetch their layer as well: the index maps read it
+    q_ref, own_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs[-8:]
     i, j = pl.program_id(0), pl.program_id(1)
     f32, bf16 = jnp.float32, jnp.bfloat16
     G, W = k_ref.shape[1], k_ref.shape[3]
@@ -1277,21 +1290,31 @@ def _ragged_plan(lengths, S, sb, tb):
     return [a.astype(jnp.int32) for a in (fetch, need, lo, hi, lens)]
 
 
-def ragged_decode_attention(q, k, v, lengths, heads=1, scale=1.0):
+def ragged_decode_attention(q, k, v, lengths, layer=None, heads=1,
+                            scale=1.0):
     """One query row a slot over the slot's rows [0, lengths[slot]) of
     slot-major leaves: q (S, G, W), k and v (S, G, T, W) float32 or
     bfloat16, lengths (S,) int32 in [0, T]; W = heads * d lanes hold
     `heads` heads side by side.  Returns (S, G, W) float32.  Reads only
     the key blocks below a slot's length and nothing of a slot of length
-    0, whose rows of the result are finite and unspecified."""
-    S, G, T, W = k.shape
+    0, whose rows of the result are finite and unspecified.  With `layer`
+    (1,) int32, k and v are stacked leaves (S, layers, G, T, W) and the
+    blocks are that layer's, cut out of the leaves where they lie."""
+    S, (G, T, W) = k.shape[0], k.shape[-3:]
     sb = min(S, _SLOT_BLOCK)
     tb = ragged_row_block(T, k.dtype)
     nB = -(-S // sb)
     plan = _ragged_plan(jnp.clip(lengths, 0, T), S, sb, tb)
     one = lambda i, j, fetch, *_: (fetch[i], 0, 0, 0)
-    rows = lambda i, j, fetch, need, lo, hi, lens: (
-        fetch[i], 0, jnp.clip(j, lo[i], hi[i]), 0)
+    if layer is None:
+        block = (sb, G, tb, W)
+        rows = lambda i, j, fetch, need, lo, hi, lens: (
+            fetch[i], 0, jnp.clip(j, lo[i], hi[i]), 0)
+    else:
+        plan.append(layer)
+        block = (sb, None, G, tb, W)     # the layer's axis squeezed away
+        rows = lambda i, j, fetch, need, lo, hi, lens, layer: (
+            fetch[i], layer[0], 0, jnp.clip(j, lo[i], hi[i]), 0)
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, scale=float(scale), sb=sb, tb=tb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1300,8 +1323,8 @@ def ragged_decode_attention(q, k, v, lengths, heads=1, scale=1.0):
             in_specs=[
                 pl.BlockSpec((sb, G, 1, W), one),
                 pl.BlockSpec((W, W), lambda i, j, *_: (0, 0)),
-                pl.BlockSpec((sb, G, tb, W), rows),
-                pl.BlockSpec((sb, G, tb, W), rows),
+                pl.BlockSpec(block, rows),
+                pl.BlockSpec(block, rows),
             ],
             out_specs=pl.BlockSpec((sb, G, 1, W),
                                    lambda i, j, *_: (i, 0, 0, 0)),
@@ -1316,3 +1339,90 @@ def ragged_decode_attention(q, k, v, lengths, heads=1, scale=1.0):
     )(*plan, q.reshape(S, G, 1, W).astype(jnp.float32),
       _lane_owner(heads, W).astype(jnp.bfloat16), k, v)
     return out.reshape(S, G, W)
+
+
+# ---------------------------------------------------------------------------
+# a decode step's new rows, written into stacked leaves where they lie
+# ---------------------------------------------------------------------------
+# One row a slot and head, each slot at its own position: k[s, layer, g,
+# pos[s]] = k_new[s, g].  As an indexed update that is an XLA scatter of
+# S x G rows, which the v5e runs a row at a time: 0.1 us a row, 39 us a leaf
+# and layer body at 24 slots of 16 heads, a fifth of a looped model's step
+# (PERF.md, PR 41; promising sorted, unique indices changes nothing, and one
+# window (G, W) a slot makes XLA lay the leaves out anew and copy them whole).
+# The kernel `decode_rows_write` takes the tile of rows that holds a slot's
+# position, for all its heads, out of both leaves, sets the one row and puts
+# the tile back: the leaves are aliased to the results, nothing else moves.
+
+
+def _rows_fit(k):
+    """Whether the row-write kernel tiles the leaf k (S, layers, G, T, W):
+    full-lane rows in whole tiles."""
+    T, W = k.shape[-2:]
+    return W % 128 == 0 and T % _tile_rows(k.dtype) == 0
+
+
+def decode_rows_write(k, v, k_new, v_new, layer, pos):
+    """The stacked leaves k, v (S, layers, G, T, W) with row pos[s] of
+    `layer` (a traced scalar) set to k_new[s], v_new[s] (S, G, W) for every
+    slot s; pos (S,) int32 in [0, T).  The kernel where the step is lowered
+    for a TPU (and wherever `MXNET_PALLAS_INTERPRET` runs the kernel
+    itself), an indexed update elsewhere and for leaves the kernel does not
+    tile."""
+    with _costs.part("cache"):
+        args = (k, v, k_new.astype(k.dtype), v_new.astype(v.dtype),
+                jnp.asarray(layer, jnp.int32).reshape(1),
+                pos.astype(jnp.int32))
+        if not _rows_fit(k):
+            return _rows_scatter(*args)
+        if _interpret():
+            return _rows_pallas(*args)
+        return jax.lax.platform_dependent(*args, tpu=_rows_pallas,
+                                          default=_rows_scatter)
+
+
+def _rows_scatter(k, v, k_new, v_new, layer, pos):
+    S, G = k_new.shape[:2]
+    at = (jnp.arange(S)[:, None], layer[0], jnp.arange(G)[None, :],
+          pos[:, None])
+    return k.at[at].set(k_new), v.at[at].set(v_new)
+
+
+def _rows_kernel(layer_ref, pos_ref, kn_ref, vn_ref, k_ref, v_ref, ko_ref,
+                 vo_ref, *, tr):
+    del layer_ref                       # read by the index maps
+    f32 = jnp.float32
+    here = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 1) \
+        == pos_ref[pl.program_id(0)] % tr
+    # through float32 and back: exact, and the v5e selects no bfloat16
+    for new, old, out in ((kn_ref, k_ref, ko_ref), (vn_ref, v_ref, vo_ref)):
+        out[...] = jnp.where(here, new[...].astype(f32),
+                             old[...].astype(f32)).astype(out.dtype)
+
+
+def _rows_pallas(k, v, k_new, v_new, layer, pos):
+    S, _, G, T, W = k.shape
+    tr = _tile_rows(k.dtype)
+    new = pl.BlockSpec((None, G, 1, W), lambda s, layer, pos: (s, 0, 0, 0))
+    tile = pl.BlockSpec((None, None, G, tr, W),
+                        lambda s, layer, pos: (s, layer[0], 0, pos[s] // tr,
+                                               0))
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, tr=tr),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[new, new, tile, tile],
+            out_specs=[tile, tile],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        # operands 4 and 5 count the two prefetched scalars: the leaves
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="decode_rows_write",
+        interpret=_interpret(),
+    )(layer, jnp.clip(pos, 0, T - 1), k_new.reshape(S, G, 1, W),
+      v_new.reshape(S, G, 1, W), k, v)
+

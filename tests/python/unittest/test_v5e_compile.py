@@ -335,3 +335,66 @@ def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
              if op in ("copy", "copy-start", "transpose", "select")
              and onp.prod(dims) >= leaf]
     assert not moved, moved
+
+
+def test_looped_decoder_step_and_prefill_fit_the_chip(one_chip):
+    """`LoopedDecoder` at the published widths of the benchmark's
+    configuration and at its serving size (24 slots of 480 rows, a
+    192-token prefill, four passes), with TWO of the 24 layers and 1024
+    vocabulary rows so that the host's copy of the weights stays small (the
+    two nested scans compile one layer body whatever the depth): the leaves
+    are (S, passes x layers, 16, 480, 128) bfloat16 and come back aliased
+    through both scans; the step attends through the kernel
+    `ragged_decode_attention`, once in the inner scan's body, over the
+    stacked leaves as they lie (row blocks of 48), and writes its new rows
+    through the kernel `decode_rows_write`, the leaves aliased to its
+    results (no scatter is left in the program): no result of the
+    optimized program is as large as one (pass, layer)'s rows of a leaf, so
+    no layer's slice is copied out in front of the kernel; and with the
+    layers and vocabulary rows left out here added back, the step and a
+    prefill beside the resident cache stay under 16 GB."""
+    from incubator_mxnet_tpu.models.looped_decoder import LoopedDecoder
+
+    S, L, V, layers, R = 24, 480, 1024, 2, 4
+    net = LoopedDecoder(V, 2048, layers, 16, 128, 5632, loops=R)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    net.cast("bfloat16")
+    eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
+                           max_len=L, prompt_buckets=(192,), queue_cap=4)
+    cache, step, join, prefill = _compile_for(eng, one_chip, S, L, 192,
+                                              join=True, prefill=True)
+    m = cache["m"]
+    assert sorted(m) == ["counts", "k", "v"]
+    assert m["k"].shape == m["v"].shape == (S, R * layers, 16, L, 128)
+    assert m["k"].dtype == "bfloat16"
+    rows = S * 16 * L * 128                 # one (pass, layer) of a leaf
+    total = 2 * R * layers * rows * 2
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= total
+    assert mem.temp_size_in_bytes < rows * 2 // 2, mem.temp_size_in_bytes
+    assert join.memory_analysis().alias_size_in_bytes >= total
+    text = step.as_text()
+    assert _kernel_calls(text, "ragged_decode_attention") == 1
+    # the step's new rows go in by the kernel, not by a scatter of 384 rows
+    assert _kernel_calls(text, "decode_rows_write") == 1
+    assert not [name for op, name, _ in _results(text) if op == "scatter"]
+    moved = [(op, name, dims) for op, name, dims in _results(text)
+             if op in ("copy", "copy-start", "transpose", "select", "slice",
+                       "dynamic-slice", "gather")
+             and onp.prod(dims) >= rows]
+    assert not moved, moved
+    assert _kernel_calls(prefill.as_text(), "ragged_decode_attention") == 0
+    # what this test left off the chip: 22 layers of 51.39 M, 48 128 rows
+    # of the embedding and of the head; and the 22 layers' rows of the cache
+    absent = 2 * (22 * 51.39e6 + 2 * (49152 - V) * 2048)
+    more_rows = 2 * R * 22 * rows * 2
+    size = lambda a: a.argument_size_in_bytes + a.output_size_in_bytes \
+        - a.alias_size_in_bytes + a.temp_size_in_bytes
+    assert size(mem) + absent + more_rows < 16e9, \
+        size(mem) + absent + more_rows
+    pre = prefill.memory_analysis()
+    # a prefill's row of 24 layers is twelve times this one's
+    assert size(pre) + 11 * pre.output_size_in_bytes + total + absent \
+        + more_rows < 16e9
+
