@@ -546,9 +546,9 @@ def _mem_mask_for(F, src, src_valid_len):
 # every leaf slot-major and in the layout a step's attention reads:
 # (B, G, T, W), G groups of P heads whose d-wide rows lie side by side
 # in one W = P·d wide row (`_lane_heads`).  A step writes the one new
-# row of every slot at the slot's own position by an indexed update of
-# the donated leaf (continuous batching = slots at DIFFERENT positions
-# in one fixed-shape executable) and contracts over the leaves as they
+# row of every live slot at the slot's own position into the donated
+# leaf (continuous batching = slots at DIFFERENT positions in one
+# fixed-shape executable) and contracts over the leaves as they
 # lie, so it reads each byte of the cache once and copies none.
 # Padding is exactly neutral: attention masks underflow pad weights to
 # 0 and every other op is position-wise.
@@ -612,11 +612,13 @@ def _nmt_decode_step(self, tok, pos, cache, live):
     """One decode step: token `tok` (B,) at target position `pos`
     (B,) against the cached K/V; `live` (B,) bool says which slots
     hold a stream.  Returns (logits (B, V), updated cache).  Each
-    layer's new K/V row is written at the slot's own position by an
-    indexed update (a position outside the cache writes nothing), then
-    `ops.attention.decode_attention` reads the leaves as they lie, a
-    live slot's rows up to its position (self-attention: one query row
-    per slot, each at its OWN position — the continuous-batching point)
+    layer's new K/V row of every live slot is written at the slot's own
+    position by `ops.attention.live_rows_write` (a position outside the
+    cache writes nothing; a dead slot's rows may be written or not,
+    nothing reads them), then `ops.attention.decode_attention` reads the
+    leaves as they lie, a live slot's rows up to its position
+    (self-attention: one query row per slot, each at its OWN position —
+    the continuous-batching point)
     or its source length (cross-attention), and nothing of the others:
     no cache leaf is reshaped, transposed or rewritten.  The logits of
     a slot that is not live are finite and mean nothing.  The `counts`
@@ -624,7 +626,8 @@ def _nmt_decode_step(self, tok, pos, cache, live):
     one layer, self + memory (`step_counts`)."""
     import jax.numpy as jnp
     from ..ndarray.ndarray import NDArray
-    from ..ops.attention import decode_attention, decode_rows_read
+    from ..ops.attention import (decode_attention, decode_rows_read,
+                                 live_rows_plan, live_rows_write)
     H, U = self._num_heads, self._units
     d = U // H
     P = _lane_heads(H, d)
@@ -637,15 +640,17 @@ def _nmt_decode_step(self, tok, pos, cache, live):
     live = live._data
     self_len = jnp.where(live, pos._data + 1, 0).astype(jnp.int32)
     mem_len = jnp.where(live, cache["src_len"]._data, 0).astype(jnp.int32)
-    row = (jnp.arange(B)[:, None], jnp.arange(G)[None, :],
-           pos._data.reshape((-1, 1)))
+    with _costs.part("cache"):
+        plan = live_rows_plan(pos._data, live, L)     # once for all layers
     new_cache = dict(cache)
 
-    def _write(leaf, new):
-        leaf = leaf._data
-        with _costs.part("cache"):
-            return NDArray(leaf.at[row].set(new._data.reshape(B, G, W)
-                                            .astype(leaf.dtype)))
+    def _write(i, k_new, v_new):
+        k, v = live_rows_write(cache["k%d" % i]._data, cache["v%d" % i]._data,
+                               k_new._data.reshape(B, G, W),
+                               v_new._data.reshape(B, G, W), plan)
+        kc = new_cache["k%d" % i] = NDArray(k)
+        vc = new_cache["v%d" % i] = NDArray(v)
+        return kc, vc
 
     def _attend(q, k, v, lengths):
         # the P heads of a group lie side by side on the row's W lanes,
@@ -660,9 +665,7 @@ def _nmt_decode_step(self, tok, pos, cache, live):
         # and `_attend` (`attn`) name themselves
         with _costs.part("proj"):
             sa = layer.self_attn
-            kc = new_cache["k%d" % i] = _write(cache["k%d" % i], sa.key(x))
-            vc = new_cache["v%d" % i] = _write(cache["v%d" % i],
-                                               sa.value(x))
+            kc, vc = _write(i, sa.key(x), sa.value(x))
             x = layer.ln1(x + sa.proj(_attend(sa.query(x), kc, vc,
                                               self_len)))
             ca = layer.cross_attn

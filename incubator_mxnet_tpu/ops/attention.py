@@ -41,7 +41,7 @@ __all__ = ["flash_attention", "naive_attention", "index_scores",
            "blocked_select_attention", "blocked_causal_attention",
            "latent_prefill_attention", "latent_prefill_block",
            "latent_decode_attention", "latent_rows_read", "latent_row_block",
-           "decode_attention",
+           "decode_attention", "live_rows_plan", "live_rows_write",
            "ragged_decode_attention", "dense_decode_attention",
            "decode_rows_read", "ragged_row_block", "decode_rows_write"]
 
@@ -1426,3 +1426,123 @@ def _rows_pallas(k, v, k_new, v_new, layer, pos):
     )(layer, jnp.clip(pos, 0, T - 1), k_new.reshape(S, G, 1, W),
       v_new.reshape(S, G, 1, W), k, v)
 
+
+# ---------------------------------------------------------------------------
+# a decode step's new rows, written at the live slots alone
+# ---------------------------------------------------------------------------
+# Slot-major leaves (S, G, T, W) whose slots are mostly empty: `nmt_base`
+# holds 512 slots with about 7 live at 37 req/s.  The indexed update writes
+# every slot's row, a scatter of S x G rows a leaf run a row at a time (1.72
+# of a 2.56 ms step, PERF.md, PR 34), and `decode_rows_write`, a grid step a
+# slot, costs as much whether the slot is live or not.  `live_rows_write`
+# copies each live slot's new rows, all its groups, into both aliased leaves
+# at the slot's position: a DMA a slot and leaf, in a loop as long as the
+# live count, a few of them in flight.  A dead slot costs nothing.
+
+_ROWS_IN_FLIGHT = 16    # copies a leaf before the loop waits for the oldest
+
+
+def live_rows_plan(pos, live, T):
+    """What `live_rows_write` needs of a step, computed once for all its
+    layers: the slots that are live with a position in [0, T), in order at
+    the front of an (S,) int32 list; how many, (1,) int32; and pos (S,)
+    int32."""
+    S = pos.shape[0]
+    ok = live & (pos >= 0) & (pos < T)
+    rank = jnp.cumsum(ok) - 1
+    idx = jnp.arange(S)
+    at = ok[None, :] & (rank[None, :] == idx[:, None])          # (S, S)
+    slots = jnp.sum(jnp.where(at, idx[None, :], 0), axis=1)
+    return (slots.astype(jnp.int32), jnp.sum(ok, dtype=jnp.int32).reshape(1),
+            pos.astype(jnp.int32))
+
+
+def _live_fits(k):
+    """Whether the kernel of `live_rows_write` takes the leaf k (S, G, T,
+    W): full-lane rows of 32 bits, which a DMA moves one at a time (a
+    bfloat16 row is half of a packed pair of rows)."""
+    return k.shape[-1] % 128 == 0 and jnp.dtype(k.dtype).itemsize == 4
+
+
+def live_rows_write(k, v, k_new, v_new, plan):
+    """The leaves k, v (S, G, T, W) with row pos[s] set to k_new[s], v_new[s]
+    (S, G, W) for every slot s of the plan (`live_rows_plan`); every other
+    row of a live slot as it was.  The kernel where the step is lowered for
+    a TPU (and wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself): it
+    writes nothing else.  The indexed update elsewhere and for leaves the
+    kernel does not take: it writes every slot's row whose position is in
+    [0, T), live or not, and nothing reads a dead slot's rows."""
+    with _costs.part("cache"):
+        args = (k, v, k_new.astype(k.dtype), v_new.astype(v.dtype)) \
+            + tuple(plan)
+        if not _live_fits(k):
+            return _live_update(*args)
+        if _interpret() or jax.default_backend() == "tpu":
+            # trace-time side effect only, as `serve.traces` is: one for each
+            # layer body that is lowered with the kernel
+            events.incr("cache.rows_kernel_traces")
+        if _interpret():
+            return _live_pallas(*args)
+        return jax.lax.platform_dependent(*args, tpu=_live_pallas,
+                                          default=_live_update)
+
+
+def _live_update(k, v, k_new, v_new, slots, count, pos):
+    del slots, count
+    S, G = k_new.shape[:2]
+    at = (jnp.arange(S)[:, None], jnp.arange(G)[None, :], pos[:, None])
+    return k.at[at].set(k_new), v.at[at].set(v_new)
+
+
+def _live_kernel(slot_ref, count_ref, pos_ref, kn_ref, vn_ref, k_ref, v_ref,
+                 ko_ref, vo_ref, sem):
+    del k_ref, v_ref                    # the same buffers as ko_ref, vo_ref
+
+    def copies(i):
+        s = slot_ref[i]
+        row = pl.ds(pos_ref[s], 1)
+        return [pltpu.make_async_copy(new.at[s], out.at[s, :, row, :],
+                                      sem.at[j])
+                for j, (new, out) in enumerate(((kn_ref, ko_ref),
+                                                (vn_ref, vo_ref)))]
+
+    def wait_oldest():
+        # a copy of the same size on the same semaphore: which one is moot
+        for c in copies(0):
+            c.wait()
+
+    def start(i, carry):
+        pl.when(i >= _ROWS_IN_FLIGHT)(wait_oldest)
+        for c in copies(i):
+            c.start()
+        return carry
+
+    def drain(i, carry):
+        wait_oldest()
+        return carry
+
+    n = count_ref[0]
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, jnp.minimum(n, _ROWS_IN_FLIGHT), drain, 0)
+
+
+def _live_pallas(k, v, k_new, v_new, slots, count, pos):
+    S, G, _, W = k.shape
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _live_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(),
+            in_specs=[hbm] * 4,
+            out_specs=[hbm, hbm],
+            scratch_shapes=[pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        # operands 5 and 6 count the three prefetched scalars: the leaves
+        input_output_aliases={5: 0, 6: 1},
+        name="live_rows_write",
+        interpret=_interpret(),
+    )(slots, count, pos, k_new.reshape(S, G, 1, W),
+      v_new.reshape(S, G, 1, W), k, v)

@@ -281,3 +281,61 @@ def test_decode_attention_lowers_the_kernel_for_a_tpu_only():
     assert not att._ragged_fits(narrow)
     assert list(np.asarray(att.decode_rows_read(lens, narrow))) == \
         [0, T, T, T]
+
+
+# -- a decode step's new rows, written at the live slots alone -------------
+
+def _live_mask(case, S):
+    return {"none": np.zeros(S, bool),
+            "one": np.arange(S) == 3,
+            "every_other": np.arange(S) % 2 == 0,
+            "all": np.ones(S, bool)}[case]
+
+
+@pytest.mark.parametrize("case", ["none", "one", "every_other", "all"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_live_rows_write_is_the_indexed_update_at_live_slots(
+        monkeypatch, dtype, case):
+    """`live_rows_write` at `nmt_base`'s leaf geometry (G 4, T 256, W 128)
+    over 8 slots at positions 0, T - 1, T and past it (nothing written),
+    and others: at the live slots, bit for bit, what the indexed update
+    writes.  float32 leaves take the kernel itself (interpret mode), which
+    leaves every other byte as it was, dead slots included, and counts one
+    trace; bfloat16 leaves (a row is half of a packed pair) take the
+    indexed update and count none.  Lowered for a TPU, the kernel's
+    results are its leaves (aliased operands)."""
+    from incubator_mxnet_tpu.monitor import events
+    S, G, T, W = 8, 4, 256, 128
+    rs = np.random.RandomState(len(case))
+    mk = lambda *shape: jnp.asarray(rs.randn(*shape), dtype)
+    k, v, kn, vn = mk(S, G, T, W), mk(S, G, T, W), mk(S, G, W), mk(S, G, W)
+    pos_np = np.array([0, T - 1, T, T + 9, 17, 8, 7, 200], np.int32)
+    live_np = _live_mask(case, S)
+    pos, live = jnp.asarray(pos_np), jnp.asarray(live_np)
+    plan = att.live_rows_plan(pos, live, T)
+    ok = live_np & (pos_np < T)
+    assert int(plan[1][0]) == ok.sum()
+    assert list(np.asarray(plan[0])[:ok.sum()]) == list(np.nonzero(ok)[0])
+    f32 = lambda a: np.asarray(jnp.asarray(a, jnp.float32))
+    want = att._live_update(k, v, kn, vn, *plan)
+    fits = dtype == "float32"
+    assert att._live_fits(k) == fits
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    traced = events.get("cache.rows_kernel_traces") or 0
+    # a function of its own: a trace of this file's last case is not reused
+    got = jax.jit(lambda *a: att.live_rows_write(*a))(k, v, kn, vn, plan)
+    assert (events.get("cache.rows_kernel_traces") or 0) - traced == fits
+    for new, ref, old in zip(got, want, (k, v)):
+        assert new.dtype == old.dtype and new.shape == old.shape
+        assert f32(new)[ok].tobytes() == f32(ref)[ok].tobytes()
+        if fits:        # nothing else is written
+            expect = f32(old).copy()
+            expect[ok] = f32(ref)[ok]
+            assert f32(new).tobytes() == expect.tobytes()
+    if fits:
+        monkeypatch.delenv("MXNET_PALLAS_INTERPRET")
+        text = jax.jit(att.live_rows_write).trace(k, v, kn, vn, plan).lower(
+            lowering_platforms=("tpu",)).as_text()
+        call = [l for l in text.splitlines() if "tpu_custom_call" in l]
+        assert len(call) == 1 and "live_rows_write" in text
+        assert call[0].count("stablehlo.output_operand_alias<") == 2

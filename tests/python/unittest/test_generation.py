@@ -479,6 +479,71 @@ def test_kernel_serves_the_same_tokens_and_the_device_knows_who_is_live(
     assert (events.get("gen.attn_rows_read") or 0) - rows0 == 2 * expect
 
 
+# the slots live at each step, and the slot a new request joins before it
+_ROWS_LIVE = [([0, 1, 2], None), ([0, 1, 2], None), ([0, 1, 2], None),
+              ([0, 2], None), ([0, 2, 3], 3), ([0, 2, 3], None),
+              ([2, 3], None), ([1, 2, 3], 1), ([1, 2, 3], None)]
+
+
+def test_nmt_step_through_the_row_kernel_is_the_indexed_update(monkeypatch):
+    """`decode_step` of a `TransformerNMT` whose K/V leaves the kernel
+    `live_rows_write` takes (float32, 128-lane rows), with the kernel
+    (interpret mode) and with the indexed update, step after step while
+    slots retire (left dead at their last position, or past the end) and
+    new requests join them (a fresh prefill written over the slot): the
+    same greedy tokens, and logits and every row of the live slots' caches
+    equal bit for bit; a dead slot's rows are left as they were by the
+    kernel.  Attention takes the einsums in both runs, so the row writes
+    are all that differ.  Two kernel traces a step: one a layer."""
+    from incubator_mxnet_tpu.ops import attention as att
+    monkeypatch.setattr(att, "ragged_decode_attention",
+                        att.dense_decode_attention)
+    net = _wide_transformer(seed=42)
+    S, L, M = 4, 16, 8
+    rs = onp.random.RandomState(9)
+
+    def prefilled(n):
+        src = nd.array(rs.randint(3, V, (n, M)), dtype="int32")
+        return net.init_cache(src, nd.array(rs.randint(2, M + 1, (n,)),
+                                            dtype="int32"), L, M)
+
+    ref = prefilled(S)
+    assert att._live_fits(ref["k0"]._data)
+    kern = dict(ref)
+    pos = onp.array([0, 0, 0, 5])
+    tok = onp.full(S, BOS)
+    traced = events.get("cache.rows_kernel_traces") or 0
+    for n, (slots, joins) in enumerate(_ROWS_LIVE):
+        if joins is not None:
+            row = prefilled(1)
+            for c in (ref, kern):
+                for name in c:
+                    a = onp.array(c[name].asnumpy())
+                    a[joins] = row[name].asnumpy()[0]
+                    c[name] = nd.array(a, dtype=a.dtype)
+            pos[joins], tok[joins] = 0, BOS
+        if n == 6:
+            pos[0] = L                      # retired past the last row
+        live = onp.isin(onp.arange(S), slots)
+        args = (nd.array(tok, dtype="int32"), nd.array(pos, dtype="int32"))
+        flag = nd.array(live, dtype="bool")
+        monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+        want, ref = net.decode_step(*args, ref, flag)
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+        before = {k: kern[k].asnumpy() for k in kern}
+        got, kern = net.decode_step(*args, kern, flag)
+        assert got.asnumpy()[live].tobytes() == want.asnumpy()[live].tobytes()
+        for name in ref:
+            have, need = kern[name].asnumpy(), ref[name].asnumpy()
+            assert have[live].tobytes() == need[live].tobytes(), (name, n)
+            if name[0] in "kv":
+                assert have[~live].tobytes() == before[name][~live].tobytes()
+        tok = onp.where(live, want.asnumpy().argmax(-1), tok)
+        pos = onp.where(live, pos + 1, pos)
+    assert (events.get("cache.rows_kernel_traces") or 0) - traced \
+        == 2 * len(_ROWS_LIVE)
+
+
 # -- join: indexed in-place write of one slot ---------------------------
 
 def _join_operands(eng, seed, cast_leaf=None):

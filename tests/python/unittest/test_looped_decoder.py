@@ -311,6 +311,49 @@ def test_the_row_write_kernel_is_the_indexed_update(dtype, monkeypatch):
             == f32(kn[..., :64])).all()
 
 
+def test_its_step_keeps_decode_rows_write_and_not_the_live_rows_kernel(
+        monkeypatch):
+    """The looped model keeps its own row write: the engine's decode
+    executable of a `LoopedDecoder` whose stacked leaves the kernels tile
+    (two heads of 128 lanes), lowered for a TPU, calls `decode_rows_write`
+    and never `live_rows_write`, and no trace of it, for the chip or in
+    interpret mode, counts `cache.rows_kernel_traces`."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.models.looped_decoder import LoopedDecoder
+    from incubator_mxnet_tpu.serving import GenerationEngine
+    S, L, bucket = 3, 32, 16
+    net = LoopedDecoder(97, 256, 2, 2, 128, 96, loops=2)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
+                           max_len=L, prompt_buckets=(bucket,), queue_cap=4)
+    try:
+        sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        params = {n: sds(v) for n, v in eng._params.items()}
+        row = eng._prefill._jit.trace(params, ints(1, bucket),
+                                      ints(1)).out_info
+        cache = {"m": {k: jax.ShapeDtypeStruct((S,) + v.shape[1:], v.dtype)
+                       for k, v in row["m"].items()},
+                 "tok": ints(S), "pos": ints(S), "left": ints(S),
+                 "out": ints(S, L)}
+        step = eng._decode._jit.__wrapped__
+        # a function of its own for each trace: JAX keeps a function's
+        # trace whatever MXNET_PALLAS_INTERPRET says
+        trace = lambda: jax.jit(lambda *a: step(*a)).trace(params, cache)
+        traced = events.get("cache.rows_kernel_traces") or 0
+        text = trace().lower(lowering_platforms=("tpu",)).as_text()
+        calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+        assert any("decode_rows_write" in l for l in calls), len(calls)
+        assert "live_rows_write" not in text
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+        assert "tpu_custom_call" not in trace().lower().as_text()
+        assert (events.get("cache.rows_kernel_traces") or 0) == traced
+    finally:
+        eng.close()
+
+
 def test_the_engine_serves_it_and_updates_its_cache_in_place():
     """Through `GenerationEngine.submit`: three streams over three slots
     give the tokens the model's own contract gives, the step's cache update

@@ -301,11 +301,12 @@ def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
     own size (6 layers, 512 units, 8 heads; 512 slots of 256 rows; at a
     fraction of it XLA parks whole memory leaves in VMEM and the program is
     another): compiled for the described v5e it holds the ragged attention
-    kernel, twice a layer, the donated cache comes back aliased, the
-    step's temporaries stay under a quarter of one K leaf, and no copy,
-    transpose or select in the optimized program is as large as a cache
-    leaf: each row is written where it lies and attention reads the
-    leaves as they lie."""
+    kernel, twice a layer, and the kernel `live_rows_write`, once a layer,
+    in place of the scatters of the new rows, the donated cache comes back
+    aliased, the step's temporaries stay under a quarter of one K leaf,
+    and no copy, transpose or select in the optimized program is as large
+    as a cache leaf: each row is written where it lies and attention reads
+    the leaves as they lie."""
     import jax.numpy as jnp
     from incubator_mxnet_tpu import nd
     from incubator_mxnet_tpu.models.transformer import transformer_nmt_base
@@ -329,7 +330,12 @@ def test_nmt_decode_step_writes_its_cache_in_place(one_chip):
     assert mem.alias_size_in_bytes >= total
     assert mem.temp_size_in_bytes < leaf * 4 // 4, mem.temp_size_in_bytes
     text = step.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    assert _kernel_calls(text, "ragged_decode_attention") == 12
+    # the new rows go in by the kernel, once a layer for K and V, not by a
+    # scatter of 2048 rows a leaf
+    assert _kernel_calls(text, "live_rows_write") == 6
+    assert text.count('custom_call_target="tpu_custom_call"') == 18
+    assert not [name for op, name, _ in _results(text) if op == "scatter"]
     # results as large as a memory leaf
     moved = [(op, name, dims) for op, name, dims in _results(text)
              if op in ("copy", "copy-start", "transpose", "select")
