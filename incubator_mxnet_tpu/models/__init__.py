@@ -14,6 +14,7 @@ from .sparse_decoder import (SparseDecoder, SelectAttention, HeldExperts,
 from .hybrid_decoder import HybridDecoder, GatedAttention, GatedDeltaNet
 from .latent_decoder import LatentDecoder, LatentAttention, DenseSwiGLU
 from .looped_decoder import LoopedDecoder, SandwichAttention
+from .window_decoder import WindowDecoder, KindAttention
 from .faster_rcnn import (FasterRCNN, faster_rcnn_toy,
                           faster_rcnn_resnet50_v1b,
                           rcnn_training_targets, RCNNTrainLoss)
@@ -31,4 +32,5 @@ __all__ = ["transformer", "BERTModel", "TransformerEncoder", "bert_base",
            "gnmt_sym_gen", "SparseDecoder", "SelectAttention",
            "HeldExperts", "RMSNorm", "HybridDecoder", "GatedAttention",
            "GatedDeltaNet", "LatentDecoder", "LatentAttention",
-           "DenseSwiGLU", "LoopedDecoder", "SandwichAttention"]
+           "DenseSwiGLU", "LoopedDecoder", "SandwichAttention",
+           "WindowDecoder", "KindAttention"]

@@ -39,7 +39,8 @@ from .registry import register
 __all__ = ["flash_attention", "naive_attention", "index_scores",
            "select_mask", "masked_decode_attention",
            "blocked_select_attention", "blocked_causal_attention",
-           "latent_prefill_attention", "latent_prefill_block",
+           "blocked_window_attention", "latent_prefill_attention",
+           "latent_prefill_block",
            "latent_decode_attention", "latent_rows_read", "latent_row_block",
            "decode_attention", "live_rows_plan", "live_rows_write",
            "ragged_decode_attention", "dense_decode_attention",
@@ -621,12 +622,13 @@ def select_mask(scores, valid, k):
                                     lambda _: above | equal, None)
 
 
-def masked_decode_attention(q, k_rows, v_rows, mask, scale):
+def masked_decode_attention(q, k_rows, v_rows, mask, scale, part="attn"):
     """One query a slot over the slot's cached rows under `mask`.
     q (S, H, d); k_rows, v_rows (S, G, L, d), head-major, H a multiple of
     the G key/value heads (query head i reads head i // (H/G)); mask
-    (S, L).  Returns (S, H, d) float32."""
-    with _costs.part("attn"):
+    (S, L).  Returns (S, H, d) float32.  `part` names the model part the
+    work is counted under (`costs.part`)."""
+    with _costs.part(part):
         S, H, d = q.shape
         G = k_rows.shape[1]
         qg = q.reshape(S, G, H // G, d)
@@ -729,6 +731,44 @@ def blocked_causal_attention(q, k, v, scale, block=512, chunk=512):
             o = _masked_block(qg[:, :, q0:end], kg[:, :end], vg[:, :end],
                               mask, scale, chunk)           # (H, 1, bq, dv)
             out.append(o[:, 0].transpose(1, 0, 2))
+        return jnp.concatenate(out, 0)
+
+
+def blocked_window_attention(q, k, v, scale, window=None, block=512,
+                             chunk=512, part="attn"):
+    """Grouped-query attention over a whole prompt, causal or under a band:
+    q (T, H, d); k, v (T, G, d), H a multiple of G (query head i reads
+    head i // (H/G)).  Position t sees keys j <= t, and with `window` only
+    t - window < j <= t.  Query block b reads the key chunks that meet its
+    band, from the chunk that holds its first query's earliest key to the
+    block's end (`_masked_block`, the query heads grouped by key/value
+    head); the blocks are unrolled with static shapes, so a chunk outside
+    the band is neither sliced, scored nor read.  `part` names the model
+    part the work is counted under.  Returns (T, H, d) float32."""
+    with _costs.part(part):
+        T, H, d = q.shape
+        G = k.shape[1]
+        bq = min(int(block), T)
+        chunk = min(int(chunk), bq)
+        if T % bq or bq % chunk:
+            raise ValueError("%d positions are no whole number of query "
+                             "blocks of %d in key chunks of %d"
+                             % (T, bq, chunk))
+        qg = q.reshape(T, G, H // G, d).transpose(1, 2, 0, 3)   # (G, h, T, d)
+        kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)     # (G, T, d)
+        out = []
+        for q0 in range(0, T, bq):
+            end = q0 + bq
+            lo = 0 if window is None else \
+                max(0, (q0 - int(window) + 1) // chunk * chunk)
+            key = lo + jnp.arange(end - lo)[None, :]
+            at = q0 + jnp.arange(bq)[:, None]
+            mask = key <= at
+            if window is not None:
+                mask = mask & (key > at - int(window))
+            o = _masked_block(qg[:, :, q0:end], kg[:, lo:end], vg[:, lo:end],
+                              mask, scale, chunk)               # (G, h, bq, d)
+            out.append(o.transpose(2, 0, 1, 3).reshape(bq, H, d))
         return jnp.concatenate(out, 0)
 
 

@@ -151,14 +151,16 @@ def moe_ffn(d_model, d_hidden, n_experts, key=None):
 
 # -- dropless top-k over the experts a device holds ----------------------
 
-def topk_route(router_logits, k):
+def topk_route(router_logits, k, scale=1.0):
     """Softmax over all experts, the k largest, renormalised over those
-    k.  router_logits (T, E) -> (gate (T, k) float32, expert (T, k)
-    int32), best first, ties to the lower expert."""
+    k, times `scale`.  router_logits (T, E) -> (gate (T, k) float32,
+    expert (T, k) int32), best first, ties to the lower expert."""
     with _costs.part("experts"):
         probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
         top, expert = lax.top_k(probs, k)
-        return top / jnp.sum(top, axis=-1, keepdims=True), expert
+        gate = top / jnp.sum(top, axis=-1, keepdims=True)
+        # a scale of 1 adds no product to the lowered program
+        return (gate if scale == 1.0 else gate * scale), expert
 
 
 def group_limited_route(router_logits, k, n_group, topk_group, scale=1.0):

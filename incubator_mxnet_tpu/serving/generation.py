@@ -147,7 +147,8 @@ models:
   name.
 - A stream ends at ``eos``, at its token budget, or when its position
   reaches ``max_len``: a prompt that lives in the cache and its new
-  tokens share the slot's ``max_len`` rows.
+  tokens share the slot's ``max_len`` rows.  An engine built with
+  ``eos=None`` has no end token: every stream runs to its budget.
 """
 from __future__ import annotations
 
@@ -452,7 +453,8 @@ class GenerationEngine:
         must be initialized.
     bos / eos: special token ids (decode starts from bos unless the
         prefilled row names its own start; an emitted eos retires the
-        sequence).
+        sequence; eos None: no token does, a stream ends at its budget
+        or max_len, the fixed answer lengths of a throughput benchmark).
     slots / max_len: the (slot-count bucket, max_len bucket) the ONE
         decode executable is specialized to (`MXNET_GEN_SLOTS`,
         `MXNET_GEN_MAX_LEN`).  max_len bounds prompt length AND
@@ -478,7 +480,10 @@ class GenerationEngine:
                     "generation needs a model with the explicit-cache "
                     "decode contract (missing %r) — see "
                     "models/seq2seq.py / models/transformer.py" % m)
-        self._bos, self._eos = int(bos), int(eos)
+        self._bos = int(bos)
+        self._eos = None if eos is None else int(eos)
+        # what fills a slot's token and record before it holds a stream
+        self._pad = self._bos if eos is None else self._eos
         # the names of the model's per-slot counts (decode_step contract)
         self._count_names = tuple(getattr(block, "step_counts", ()))
         self._ctx = ctx if isinstance(ctx, Context) else (
@@ -564,9 +569,10 @@ class GenerationEngine:
         from ..parallel.functional import extract_params
         block = self._block
         L = self._L
-        bos, eos = self._bos, self._eos     # not `self`: a closure that
-        # held the engine would tie it into a cycle with its own
-        # executables, and its cache would wait for the collector
+        # not `self`: a closure that held the engine would tie it into a
+        # cycle with its own executables, and its cache would wait for
+        # the collector
+        bos, eos, pad = self._bos, self._eos, self._pad
         pure_init = _pure_method(block, "init_cache")
         pure_step = _pure_method(block, "decode_step")
         mem_len = self._mem_len
@@ -617,8 +623,9 @@ class GenerationEngine:
                 # told is live.  The host's rules retire a stream (eos,
                 # budget, max_len, deadline, cancel); this only has to
                 # cover them: device-live ⊇ host-live at every step
-                "left": jnp.where(nxt == eos, 0,
-                                  jnp.maximum(left - 1, 0)).astype(jnp.int32),
+                "left": (jnp.maximum(left - 1, 0) if eos is None else
+                         jnp.where(nxt == eos, 0, jnp.maximum(left - 1, 0))
+                         ).astype(jnp.int32),
                 "out": out}
 
         def join(cache, row, seat):
@@ -639,7 +646,7 @@ class GenerationEngine:
                         "pos": put(cache["pos"], row["pos"]),
                         "left": put(cache["left"], budget[None]),
                         "out": put(cache["out"],
-                                   jnp.full((1, L), eos, jnp.int32))}
+                                   jnp.full((1, L), pad, jnp.int32))}
 
         # prefill: one signature per prompt bucket, warmed; decode
         # and join donate the cache — the PR 10 audit arms the
@@ -685,11 +692,11 @@ class GenerationEngine:
         self._cache = {
             "m": m,
             "tok": jax.device_put(
-                jnp.full((S,), self._eos, jnp.int32), dev),
+                jnp.full((S,), self._pad, jnp.int32), dev),
             "pos": jax.device_put(jnp.zeros((S,), jnp.int32), dev),
             "left": jax.device_put(jnp.zeros((S,), jnp.int32), dev),
             "out": jax.device_put(
-                jnp.full((S, L), self._eos, jnp.int32), dev)}
+                jnp.full((S, L), self._pad, jnp.int32), dev)}
         self._slot_bytes = sum(
             int(_np.prod(a.shape[1:])) * _np.dtype(a.dtype).itemsize
             for a in jax.tree_util.tree_leaves(self._cache))

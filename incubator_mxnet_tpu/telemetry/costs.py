@@ -399,7 +399,7 @@ def traced_as(fn, label, role=None):
 
 # the vocabulary of `part`; docs/observability.md draws each boundary
 PARTS = ("embed", "head", "proj", "attn", "index", "state", "cache",
-         "experts", "ffn")
+         "experts", "ffn", "window")
 
 _PART_RE = re.compile(r"mx\.([a-z_]+)")
 _INSTR_RE = re.compile(r"\s+(?:ROOT )?%?([\w.\-]+) = ")

@@ -33,7 +33,7 @@ def _fresh_registry():
 
 def test_a_name_outside_the_vocabulary_raises():
     assert costs.PARTS == ("embed", "head", "proj", "attn", "index", "state",
-                           "cache", "experts", "ffn")
+                           "cache", "experts", "ffn", "window")
     with pytest.raises(ValueError, match="nonsense"):
         costs.part("nonsense")
     with costs.part("attn"):
@@ -181,6 +181,21 @@ def _ouro():
     return LoopedDecoder(128, 64, 2, 4, 16, 96, loops=3, exit_threshold=0.5)
 
 
+def _laguna():
+    from incubator_mxnet_tpu.models.window_decoder import WindowDecoder
+    types = ["full_attention"] + ["sliding_attention", "full_attention"] * 2
+    rope = {"full_attention": {"rope_type": "yarn", "rope_theta": 5e5,
+                               "factor": 64,
+                               "original_max_position_embeddings": 4096,
+                               "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_theta": 1e4}}
+    return WindowDecoder(128, 64, types, ["dense"] + ["sparse"] * 4,
+                         [4, 6, 4, 6, 4], 2, 16, 8, 96, 16, 16, 4, rope,
+                         shared_hidden=24, routed_scale=2.5, first_held=0,
+                         experts_held=4, query_block=8, key_chunk=8,
+                         expert_tile=8)
+
+
 def _nmt():
     from incubator_mxnet_tpu.models.transformer import transformer_nmt_small
     return transformer_nmt_small(128, 128, dropout=0.0)
@@ -193,6 +208,7 @@ SERVED = {          # model -> (builder, the parts its layers add)
     "deepseek_v2": (_deepseek_v2, {"experts", "ffn"}),
     "nmt_base": (_nmt, {"ffn"}),
     "ouro_2_6b": (_ouro, {"ffn"}),
+    "laguna_xs2": (_laguna, {"window", "experts", "ffn"}),
 }
 
 

@@ -404,3 +404,83 @@ def test_looped_decoder_step_and_prefill_fit_the_chip(one_chip):
     assert size(pre) + 11 * pre.output_size_in_bytes + total + absent \
         + more_rows < 16e9
 
+
+
+def test_window_decoder_step_and_prefill_fit_the_chip(one_chip):
+    """`WindowDecoder` at the published widths of the benchmark's
+    configuration and at its serving size (nine layers: a full dense layer,
+    then two periods of three window layers and a full one; 64 slots of
+    9216 rows; an 8192-token prefill), two of the 32 experts held and 1024
+    vocabulary rows so that the host's copy of the weights stays small: the
+    full layers' leaves are (S, 3, 8, 9216, 128) and the rings
+    (S, 6, 8, 512, 128), bfloat16, and come back aliased; the step writes
+    its new rows through the kernel `decode_rows_write`, once a layer (the
+    dense layer's and the period's four), and no scatter is left; the
+    step's temporaries stay under a quarter of one full layer's rows of a
+    leaf, so no layer's slice is copied out in front of the attention; the
+    prefill multiplies the period's four expert halves in the kernel
+    `held_experts_grouped` and holds no float32 scores of a query block
+    against all 8192 keys of its prompt; and with
+    the experts and vocabulary rows left out here added back, the step and
+    the prefill beside the resident cache stay under 16 GB."""
+    import re
+    from incubator_mxnet_tpu.models.window_decoder import WindowDecoder
+
+    S, L, V, held, bucket = 64, 9216, 1024, 2, 8192
+    types = ["full_attention"] + (["sliding_attention"] * 3
+                                  + ["full_attention"]) * 2
+    rope = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000,
+                               "factor": 64,
+                               "original_max_position_embeddings": 4096,
+                               "beta_fast": 64, "beta_slow": 1,
+                               "attention_factor": 1.4158883083359672,
+                               "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000}}
+    net = WindowDecoder(V, 2048, types, ["dense"] + ["sparse"] * 8,
+                        [48 if t == "full_attention" else 64 for t in types],
+                        8, 128, 512, 8192, 512, 256, 8, rope,
+                        shared_hidden=512, routed_scale=2.5, first_held=0,
+                        experts_held=held)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    net.cast("bfloat16")
+    eng = GenerationEngine(net, bos=1, eos=2, ctx=mx.cpu(0), slots=S,
+                           max_len=L, prompt_buckets=(bucket,), queue_cap=4)
+    cache, step, join, prefill = _compile_for(eng, one_chip, S, L, bucket,
+                                              join=True, prefill=True)
+    m = cache["m"]
+    assert sorted(m) == ["counts", "kf", "kw", "vf", "vw"]
+    assert m["kf"].shape == m["vf"].shape == (S, 3, 8, L, 128)
+    assert m["kw"].shape == m["vw"].shape == (S, 6, 8, 512, 128)
+    assert m["kf"].dtype == m["kw"].dtype == "bfloat16"
+    total = 2 * S * 8 * 128 * 2 * (3 * L + 6 * 512)         # 8.05 GB
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= total
+    assert join.memory_analysis().alias_size_in_bytes >= total
+    text = step.as_text()
+    assert _kernel_calls(text, "decode_rows_write") == 5
+    assert not [name for op, name, _ in _results(text) if op == "scatter"]
+    # a layer's rows taken out of a leaf are read inside the attention's
+    # fusions: the step's temporaries are not one full layer's rows of a
+    # leaf (1.21 GB)
+    rows = S * 8 * L * 128 * 2
+    assert mem.temp_size_in_bytes < rows // 4, mem.temp_size_in_bytes
+    # float32 scores of a query block against every key of the prompt, in
+    # the attention of either kind: the blocks read only their band
+    text = prefill.as_text()
+    assert _kernel_calls(text, "held_experts_grouped") == 4
+    whole = [line[:120] for line in text.splitlines()
+             if re.search(r"= f32\[[\d,]*\b512\b[\d,]*\]", line)
+             and re.search(r"= f32\[[\d,]*\b%d\b" % bucket, line)
+             and re.search(r'op_name="[^"]*mx\.(attn|window)', line)]
+    assert not whole, whole
+    pre = prefill.memory_analysis()
+    assert pre.temp_size_in_bytes < 2.5e9, pre.temp_size_in_bytes
+    # what this test left off the chip: 30 experts of 3.146 M in each of
+    # the eight sparse layers, 11 520 rows of the embedding and of the head
+    absent = 2 * (8 * 30 * 3.146e6 + 2 * (12544 - V) * 2048)
+    size = lambda a: a.argument_size_in_bytes + a.output_size_in_bytes \
+        - a.alias_size_in_bytes + a.temp_size_in_bytes
+    assert size(mem) + absent < 16e9, size(mem) + absent
+    assert size(pre) + total + absent < 16e9, size(pre) + total + absent
