@@ -560,6 +560,63 @@ def _latent_case(run):
             "tpu_custom_calls": n_calls, "rel_err": round(worst, 6)}
 
 
+def _grouped_attention_case(run):
+    """`grouped_decode_attention` as `KindAttention.step` calls it, over
+    whole bfloat16 leaves at a layer that is not 0 (on the chip: the Mosaic
+    kernel at `laguna_xs2`'s published widths, 48 query heads over 8
+    key/value heads of 128 in 9216-row full layers, 64 over 8 in 512-row
+    rings) against the same attention in NumPy float64."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import attention as att
+
+    kinds = ((("full", 5, 12, 2, 1024, 3), ("ring", 5, 16, 2, 512, 6))
+             if run.dry else (("full", 8, 48, 8, 9216, 3),
+                              ("ring", 8, 64, 8, 512, 6)))
+    dev = run.ctx().jax_device
+    cases = []
+    for kind, S, H, G, T, layers in kinds:
+        rs = np.random.RandomState(T)
+        put = lambda *shape: jax.device_put(
+            rs.randn(*shape).astype(np.float32), dev).astype(jnp.bfloat16)
+        q, k, v = put(S, H, 128), put(S, layers, G, T, 128), \
+            put(S, layers, G, T, 128)
+        tb = att.grouped_row_block(T)
+        lens = rs.randint(1, T + 1, (S,)).astype(np.int32)
+        lens[:5] = [0, 1, tb, tb + 1, T]
+        run.on_device([q, k, v], "kernels input")
+        args = (q, k, v, jnp.int32(1), jax.device_put(lens, dev))
+        compiled = jax.jit(lambda *a: att.grouped_decode_attention(
+            *a, 128 ** -0.5)).lower(*args).compile()
+        n_calls = compiled.as_text().count("tpu_custom_call")
+        check(run.dry or n_calls == 1,
+              "grouped_decode_attention compiled %d tpu_custom_call(s) on "
+              "the chip, want the kernel" % n_calls)
+        out = compiled(*args)
+        run.on_device([out], "kernels output")
+        out = np.asarray(out)
+        check(np.isfinite(out).all(), "non-finite grouped decode output")
+        f64 = lambda a: np.asarray(a.astype(jnp.float32)).astype(np.float64)
+        q, k, v = f64(q), f64(k[:, 1]), f64(v[:, 1])
+        worst = 0.0
+        for s, n in enumerate(lens):
+            for i in range(H if n else 0):
+                g = i // (H // G)
+                sc = k[s, g, :n] @ q[s, i] * 128 ** -0.5
+                p = np.exp(sc - sc.max())
+                ref = (p / p.sum()) @ v[s, g, :n]
+                worst = max(worst, float(np.abs(out[s, i] - ref).max()
+                                         / np.abs(ref).max()))
+        # the probabilities are rounded to bfloat16 for the context
+        check(worst < 1e-2, "grouped decode attention (%s) is %.3g "
+              "(relative) away from float64" % (kind, worst))
+        cases.append({"kind": kind, "slots": S, "heads": H, "rows": T,
+                      "row_block": tb, "tpu_custom_calls": n_calls,
+                      "rel_err": round(worst, 6)})
+    return cases
+
+
 def _latent_prefill_case(run):
     """`latent_prefill_attention` as `LatentAttention.prompt` calls it (on
     the chip: the Mosaic kernel at the published widths, 128 heads, keys of
@@ -630,7 +687,8 @@ def phase_kernels(run):
             "gated_delta": _delta_case(run),
             "grouped_experts": _grouped_case(run),
             "latent_decode": _latent_case(run),
-            "latent_prefill": _latent_prefill_case(run)}
+            "latent_prefill": _latent_prefill_case(run),
+            "grouped_decode": _grouped_attention_case(run)}
 
 
 # --------------------------------------------------------------------------

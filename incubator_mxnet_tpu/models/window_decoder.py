@@ -45,8 +45,8 @@ row with what it held).  ``decode_step`` writes row ``pos`` of the full
 leaves and row ``pos mod window`` of the rings in place
 (`ops.attention.decode_rows_write`), then reads the full layers' rows
 <= ``pos`` and the ring's rows that the slot has filled (ring index
-<= ``pos``: all of them once pos >= window - 1), under masks
-(`ops.attention.masked_decode_attention`).
+<= ``pos``: all of them once pos >= window - 1) of each live slot, up to
+those lengths (`ops.attention.grouped_decode_attention`).
 """
 from __future__ import annotations
 
@@ -174,24 +174,30 @@ class KindAttention(_Stacked):
         with _costs.part("cache"):
             return h, k.transpose(1, 0, 2), v.transpose(1, 0, 2)
 
-    def step(self, p, h, pos, layer, k_leaf, v_leaf):
+    def rows(self, pos, live):
+        """(S,) the rows [0, n) of a slot's leaf that its step reads: pos + 1,
+        at most `window` in a ring, 0 where the slot is not live."""
+        import jax.numpy as jnp
+        # ring index j holds position pos - ((pos - j) mod window): filled
+        # by this slot where j <= pos
+        n = pos + 1 if self.window is None else jnp.minimum(pos + 1,
+                                                            self.window)
+        return jnp.where(live, n, 0).astype(jnp.int32)
+
+    def step(self, p, h, pos, live, layer, k_leaf, v_leaf):
         """One layer, one token a slot: h (S, D) at pos (S,).  Writes the
         row of pos (at pos mod window in a ring) into the stacked leaves
         k_leaf, v_leaf (S, layers, G, rows, d) at `layer`, then attends
-        over the slot's rows <= pos of them.  Returns (h + attention, the
-        two leaves)."""
-        import jax.numpy as jnp
-        from ..ops.attention import decode_rows_write, masked_decode_attention
+        over the rows of them that a live slot has filled (`rows`).
+        Returns (h + attention, the two leaves)."""
+        from ..ops.attention import decode_rows_write, grouped_decode_attention
         with _costs.part("proj"):
             q, k, v, gate = self.project(p, h, pos)
         at = pos if self.window is None else pos % self.window
         k_leaf, v_leaf = decode_rows_write(k_leaf, v_leaf, k, v, layer, at)
-        # ring index j holds position pos - ((pos - j) mod window): filled
-        # by this slot where j <= pos
-        mask = jnp.arange(k_leaf.shape[3])[None, :] <= pos[:, None]
-        o = masked_decode_attention(q, jnp.take(k_leaf, layer, axis=1),
-                                    jnp.take(v_leaf, layer, axis=1), mask,
-                                    self.scale, self.part)
+        o = grouped_decode_attention(q, k_leaf, v_leaf, layer,
+                                     self.rows(pos, live), self.scale,
+                                     self.part)
         return self._out(p, h, o, gate), k_leaf, v_leaf
 
 
@@ -242,14 +248,16 @@ class WindowDecoder(HybridBlock):
     `sliding_attention`)."""
 
     # what a decode step did for each slot, in the columns of `counts`: rows
-    # the full layers attended from; the window layers' rows in the band
+    # the full layers attended from, and the rows their attention covered
+    # (`grouped_rows_read`); the window layers' rows in the band
     # (min(pos + 1, window) a layer) and the rows their attention read (the
     # ring's); KiB of cache the step needs moved, the same plus the slot's
     # share of the weights (read once a step); expert picks, picks of held
     # experts, picks at each layer's fullest held expert
-    step_counts = ("gen.attn_context", "window.rows_needed",
-                   "window.rows_read", "gen.cache_kib", "gen.step_kib",
-                   "moe.picks", "moe.picks_held", "moe.expert_max")
+    step_counts = ("gen.attn_context", "gen.attn_rows_read",
+                   "window.rows_needed", "window.rows_read", "gen.cache_kib",
+                   "gen.step_kib", "moe.picks", "moe.picks_held",
+                   "moe.expert_max")
 
     def __init__(self, vocab_size, units, layer_types, mlp_layer_types,
                  heads_per_layer, num_kv_heads, head_dim, window,
@@ -410,10 +418,12 @@ class WindowDecoder(HybridBlock):
     def decode_step(self, tok, pos, cache, live):
         """Token `tok` (S,) at position `pos` (S,) against the cache:
         (logits (S, V) float32, the cache with the rows of `pos` written).
-        `live` (S,; which slots hold a stream) only shares the weights'
-        bytes out among `counts`: the attention reads every slot."""
+        `live` (S,; which slots hold a stream) shares the weights' bytes
+        out among `counts`; the attention reads no row of a slot that is not
+        live."""
         import jax
         import jax.numpy as jnp
+        from ..ops.attention import grouped_rows_read
         tok, pos, live = tok._data, pos._data, live._data
         names = {FULL: ("kf", "vf"), WINDOW: ("kw", "vw")}
         leaves = {n: cache[n]._data for n in ("kf", "vf", "kw", "vw")}
@@ -424,7 +434,7 @@ class WindowDecoder(HybridBlock):
         def layer(h, leaves, kind, n):
             kn, vn = names[kind]
             h, k_leaf, v_leaf = self._attn(kind).step(
-                _at(p[kind], n), h, pos, n, leaves[kn], leaves[vn])
+                _at(p[kind], n), h, pos, live, n, leaves[kn], leaves[vn])
             return h, dict(leaves, **{kn: k_leaf, vn: v_leaf})
 
         for j, (kind, n) in enumerate(self._lead_at):
@@ -458,7 +468,9 @@ class WindowDecoder(HybridBlock):
             share = (self.step_weight_bytes() // 1024) \
                 // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
             counts = jnp.stack(
-                [NF * (pos + 1), NW * band, jnp.full((S,), NW * read, jnp.int32),
+                [NF * (pos + 1),
+                 NF * grouped_rows_read(self.full.rows(pos, live), k),
+                 NW * band, jnp.full((S,), NW * read, jnp.int32),
                  cache_kib, cache_kib + jnp.where(live, share, 0),
                  jnp.full((S,), (self._layers - self._lead)
                           * self._per_token, jnp.int32),

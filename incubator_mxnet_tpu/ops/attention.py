@@ -42,6 +42,8 @@ __all__ = ["flash_attention", "naive_attention", "index_scores",
            "blocked_window_attention", "latent_prefill_attention",
            "latent_prefill_block",
            "latent_decode_attention", "latent_rows_read", "latent_row_block",
+           "grouped_decode_attention", "grouped_rows_read",
+           "grouped_row_block",
            "decode_attention", "live_rows_plan", "live_rows_write",
            "ragged_decode_attention", "dense_decode_attention",
            "decode_rows_read", "ragged_row_block", "decode_rows_write"]
@@ -1140,6 +1142,193 @@ def _latent_pallas(q_abs, q_rope, ckv, kr, layer, lengths, scale):
         name="latent_decode_attention",
         interpret=_interpret(),
     )(layer, need, lens, q_abs, q_rope, ckv, jnp.swapaxes(kr, 2, 3))
+
+
+# ---------------------------------------------------------------------------
+# grouped decode attention: several query heads a key/value head, rows to a length
+# ---------------------------------------------------------------------------
+# One query a slot and head over the slot's rows [0, length) of one layer of
+# stacked head-major leaves (S, layers, G, T, d), query head i reading
+# key/value head i // (H/G): a layer of full rows (length pos + 1) or a ring
+# (length min(pos + 1, window)).
+#
+# - `_grouped_einsums`: the layer's rows taken out of the leaves and
+#   `masked_decode_attention` over ALL T rows of every slot under a mask of
+#   the lengths, the float32 scores (S, H, T) in memory between its two
+#   einsums.  The reference, what the CPU runs, and what runs for leaves the
+#   kernel does not tile.
+# - `_grouped_pallas`: the kernel `grouped_decode_attention`.  Grid (slots,
+#   row blocks); the layer, each slot's count of needed blocks and its length
+#   are prefetched as scalars.  The leaves come WHOLE, the layer only in their
+#   index maps.  A block holds all G heads' rows, (G, tb, d); each head's
+#   group of query rows meets its rows on the MXU, and the scores, the flash
+#   recurrence (float32) and the probabilities rounded to the leaves' type
+#   stay in VMEM.  A step past a slot's last needed block computes nothing
+#   and fetches nothing new: its index map names the NEXT slot's first block,
+#   as `latent_decode_attention`'s does.
+# - `grouped_decode_attention` chooses between them where the step is LOWERED
+#   (`lax.platform_dependent`).
+
+# Measured alone on a TPU v5e at 64 slots (PERF.md, section 6): over
+# (3, 8, 9216, 128) bfloat16 leaves at lengths 6144-9216, 2.83 ms with
+# blocks of 256 rows and 2.86 with 512 (the einsums 3.91); over full rings
+# (6, 8, 512, 128) 0.230 either way (the einsums 0.223).  With the query
+# rows as the stationary operand of the scores' product, 3.13 and 2.88 over
+# the full leaves.
+_GROUPED_ROW_BLOCK = 256    # cached rows a grid step, at most
+
+
+def grouped_row_block(T):
+    """Rows of one block of the grouped kernel over leaves of T rows: the
+    largest divisor of T up to `_GROUPED_ROW_BLOCK` that is whole lane
+    tiles (a block's scores lie with the rows on the lanes); 0 if there is
+    none."""
+    tb = _largest_divisor(T, _GROUPED_ROW_BLOCK, 128)
+    return tb if tb % 128 == 0 else 0
+
+
+def _grouped_fits(k):
+    """Whether the kernel tiles the leaf k (S, layers, G, T, d): full-lane
+    rows and a whole number of row blocks."""
+    return k.shape[4] % 128 == 0 and grouped_row_block(k.shape[3]) > 0
+
+
+def grouped_rows_read(lengths, k):
+    """Rows of the leaf k (S, layers, G, T, d) that
+    `grouped_decode_attention` covers for each slot in one layer, (S,)
+    int32: the slot's length rounded up to the kernel's row block, or all T
+    where the leaf does not tile."""
+    T = k.shape[3]
+    tb = grouped_row_block(T) if _grouped_fits(k) else T
+    return (-(-jnp.clip(lengths, 0, T) // tb) * tb).astype(jnp.int32)
+
+
+def grouped_decode_attention(q, k, v, layer, lengths, scale, part="attn"):
+    """One query a slot and head over the slot's rows [0, lengths[slot]) of
+    one layer.  q (S, H, d); k, v (S, layers, G, T, d) the cache's stacked
+    leaves, whole, H a multiple of G (query head i reads head i // (H/G));
+    `layer` (a traced scalar) the layer whose rows are read; lengths (S,)
+    int32 in [0, T].  Products of operands in the leaves' type summed in
+    float32, softmax in float32, the probabilities rounded to the leaves'
+    type for the context.  Returns (S, H, d) float32, counted under the
+    model part `part`; a slot of length 0 reads nothing, and its rows of
+    the result are finite and unspecified.
+
+    The kernel where the step is lowered for a TPU (and wherever
+    `MXNET_PALLAS_INTERPRET` runs the kernel itself), the einsums elsewhere
+    and for leaves the kernel does not tile."""
+    with _costs.part(part):
+        args = (q, k, v, jnp.asarray(layer, jnp.int32).reshape(1),
+                jnp.clip(lengths.astype(jnp.int32), 0, k.shape[3]))
+        kernel = functools.partial(_grouped_pallas, scale=scale)
+        einsums = functools.partial(_grouped_einsums, scale=scale, part=part)
+        if not _grouped_fits(k):
+            return einsums(*args)
+        if _interpret() or jax.default_backend() == "tpu":
+            # trace-time side effect only, as `serve.traces` is: one for each
+            # layer body that is lowered with the kernel
+            events.incr("attn.grouped_kernel_traces")
+        if _interpret():
+            return kernel(*args)
+        return jax.lax.platform_dependent(*args, tpu=kernel, default=einsums)
+
+
+def _grouped_einsums(q, k, v, layer, lengths, scale, part):
+    live = jnp.arange(k.shape[3])[None, :] < lengths[:, None]
+    return masked_decode_attention(q, jnp.take(k, layer[0], axis=1),
+                                   jnp.take(v, layer[0], axis=1), live, scale,
+                                   part)
+
+
+def _grouped_kernel(layer_ref, need_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                    m_s, l_s, acc_s, *, scale, tb):
+    del layer_ref                       # read by the index maps
+    s, j = pl.program_id(0), pl.program_id(1)
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, _NEG_INF, f32)
+        l_s[...] = jnp.zeros(l_s.shape, f32)
+        acc_s[...] = jnp.zeros(acc_s.shape, f32)
+
+    @pl.when(j < need_ref[s])
+    def _block():
+        # each key/value head's h query rows against its tb rows
+        v = v_ref[...]                                          # (G, tb, d)
+        sc = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                 (((2,), (2,)), ((0,), (0,))),
+                                 preferred_element_type=f32)    # (G, h, tb)
+        row = j * tb + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
+        sc = jnp.where(row < len_ref[s], sc * scale, _NEG_INF)
+        m_prev = m_s[...]                                       # (G, h, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        shrink = jnp.exp(m_prev - m_new)
+        l_s[...] = l_s[...] * shrink + jnp.sum(p, axis=2, keepdims=True)
+        acc_s[...] = acc_s[...] * shrink + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=f32)                         # (G, h, d)
+        m_s[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        total = l_s[...]
+        o_ref[...] = jnp.where(total > 0, acc_s[...] / total, 0.0)
+
+
+def _grouped_pallas(q, k, v, layer, lengths, scale):
+    S, H, d = q.shape
+    G, T = k.shape[2], k.shape[3]
+    h = H // G
+    tb = grouped_row_block(T)
+    need = -(-lengths // tb)                # row blocks a slot computes
+
+    def at(s, j, need):
+        """(slot, row block) that step (s, j) holds: its own while the slot
+        needs block j, then the next slot's first (the last slot keeps its
+        last)."""
+        done, last = j >= need[s], s == S - 1
+        return (jnp.where(done & ~last, s + 1, s),
+                jnp.where(done, jnp.where(last, jnp.maximum(need[s] - 1, 0),
+                                          0), j))
+
+    def query(s, j, layer, need, lens):
+        return (at(s, j, need)[0], 0, 0, 0)
+
+    def rows(s, j, layer, need, lens):
+        slot, block = at(s, j, need)
+        return (slot, layer[0], 0, block, 0)
+
+    item = jnp.dtype(k.dtype).itemsize
+    # K and V blocks and the query's, each twice (the pipeline's two
+    # buffers), the result's twice, the recurrence's scratch, and room for
+    # a block's float32 scores and probabilities
+    vmem = 2 * (2 * G * tb * d * item + H * d * jnp.dtype(q.dtype).itemsize
+                + H * d * 4) + 3 * H * d * 4 + 4 * G * 8 * tb * 4
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, scale=float(scale), tb=tb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, T // tb),
+            in_specs=[
+                pl.BlockSpec((None, G, h, d), query),
+                pl.BlockSpec((None, None, G, tb, d), rows),
+                pl.BlockSpec((None, None, G, tb, d), rows),
+            ],
+            out_specs=pl.BlockSpec((None, G, h, d),
+                                   lambda s, j, *_: (s, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((G, h, 1), jnp.float32),
+                            pltpu.VMEM((G, h, 1), jnp.float32),
+                            pltpu.VMEM((G, h, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, G, h, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem + (4 << 20)),
+        name="grouped_decode_attention",
+        interpret=_interpret(),
+    )(layer, need, lengths, q.reshape(S, G, h, d), k, v).reshape(S, H, d)
 
 
 # ---------------------------------------------------------------------------

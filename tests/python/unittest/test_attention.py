@@ -339,3 +339,109 @@ def test_live_rows_write_is_the_indexed_update_at_live_slots(
         call = [l for l in text.splitlines() if "tpu_custom_call" in l]
         assert len(call) == 1 and "live_rows_write" in text
         assert call[0].count("stablehlo.output_operand_alias<") == 2
+
+
+# -- grouped decode attention: several query heads a key/value head ---------
+
+def _grouped_by_hand(q, k, v, layer, lengths, scale):
+    """A loop over slots and query heads in NumPy float64 over rows
+    [0, length) of `layer`; query head i reads key/value head i // (H/G)."""
+    f64 = lambda a: np.asarray(jnp.asarray(a, jnp.float32), np.float64)
+    q, k, v = f64(q), f64(k), f64(v)
+    S, H, d = q.shape
+    h = H // k.shape[2]
+    out = np.zeros((S, H, d))
+    for s, n in enumerate(lengths):
+        for i in range(H if n else 0):
+            sc = k[s, layer, i // h, :n] @ q[s, i] * scale
+            p = np.exp(sc - sc.max())
+            out[s, i] = (p / p.sum()) @ v[s, layer, i // h, :n]
+    return out
+
+
+@pytest.mark.parametrize("T,lengths", [
+    # a full layer: 0, 1, a block border and the row after it, T, a short
+    # slot between long ones (its steps past its rows hold the next slot's
+    # first block), the last slot short
+    (768, [0, 1, 256, 257, 768, 700, 3, 600, 40]),
+    # a full ring of 512 in every slot, and rings still filling
+    (512, [512, 512, 512, 1, 300, 512]),
+])
+@pytest.mark.parametrize("group", [6, 8])
+def test_grouped_kernel_reads_each_slots_rows_up_to_its_length(
+        pallas_interpret, T, lengths, group):
+    """The kernel (interpret mode) over whole bfloat16 leaves (S, 3 layers,
+    2, T, 128) at layer 1, groups of 6 and 8 query heads a key/value head,
+    against the loop in float64 and against `masked_decode_attention` over
+    the layer's rows under the lengths' mask.  Rows past a slot's length
+    hold huge values, blocks past its last needed one and both other layers
+    NaN: none may enter.  A slot of length 0 reads nothing and gives finite
+    rows.  `grouped_rows_read` is the length rounded up to the row block,
+    and `attn.grouped_kernel_traces` counts the one body traced."""
+    from incubator_mxnet_tpu.monitor import events
+    S, G, d, layers, dt = len(lengths), 2, 128, 3, jnp.bfloat16
+    H = G * group
+    tb = att.grouped_row_block(T)
+    assert tb == 256 and T % tb == 0
+    rs = np.random.RandomState(T + group)
+    rnd = lambda *s: np.asarray(jnp.asarray(rs.randn(*s), dt), np.float32)
+    q = rnd(S, H, d)
+    k = np.full((S, layers, G, T, d), np.nan, np.float32)
+    v = k.copy()
+    for s, n in enumerate(lengths):
+        end = -(-n // tb) * tb
+        k[s, 1, :, :end], v[s, 1, :, :end] = 1e30, -1e30
+        k[s, 1, :, :n], v[s, 1, :, :n] = rnd(G, n, d), rnd(G, n, d)
+    want = _grouped_by_hand(q, k, v, 1, lengths, 0.088)
+    q, k, v = (jnp.asarray(a, dt) for a in (q, k, v))
+    lens = jnp.asarray(lengths, jnp.int32)
+    traced = events.get("attn.grouped_kernel_traces") or 0
+    got = np.asarray(jax.jit(lambda *a: att.grouped_decode_attention(
+        *a, 0.088))(q, k, v, jnp.int32(1), lens))
+    assert (events.get("attn.grouped_kernel_traces") or 0) == traced + 1
+    assert got.shape == (S, H, d) and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    live = np.asarray(lengths) > 0
+    # bfloat16: the probabilities are rounded to 8 bits for the context
+    tol = 1e-2 * np.abs(want).max()
+    assert np.abs(got - want)[live].max() < tol
+    # the masked einsums over the same rows, with the leaves' garbage made
+    # finite (their probability-0 rows would carry a NaN into the context)
+    clean = lambda a: jnp.where(
+        jnp.arange(T)[None, None, :, None] < lens[:, None, None, None],
+        a[:, 1], 0).astype(dt)
+    ref = np.asarray(att.masked_decode_attention(
+        q, clean(k), clean(v), jnp.arange(T)[None, :] < lens[:, None],
+        0.088))
+    assert np.abs(got - ref)[live].max() < tol
+    assert list(np.asarray(att.grouped_rows_read(lens, k))) == \
+        [-(-n // tb) * tb for n in lengths]
+
+
+def test_grouped_einsums_are_the_masked_form_off_the_chip():
+    """On the CPU (no interpreter), and for leaves the kernel does not tile
+    (d 16, 48 rows), `grouped_decode_attention` is `masked_decode_attention`
+    over the layer's rows under rows < length, bit for bit, and its text
+    holds no kernel; the count of rows read is all T there; nothing is
+    counted as traced with the kernel."""
+    from incubator_mxnet_tpu.monitor import events
+    rs = np.random.RandomState(2)
+    traced = events.get("attn.grouped_kernel_traces") or 0
+    for (S, H, G, T, d) in ((4, 12, 2, 512, 128), (3, 6, 2, 48, 16)):
+        mk = lambda *s: jnp.asarray(rs.randn(*s).astype(np.float32))
+        q, k, v = mk(S, H, d), mk(S, 2, G, T, d), mk(S, 2, G, T, d)
+        lens = jnp.asarray(rs.randint(0, T + 1, S), jnp.int32)
+        got = att.grouped_decode_attention(q, k, v, 1, lens, 0.3,
+                                           part="window")
+        want = att.masked_decode_attention(
+            q, k[:, 1], v[:, 1], jnp.arange(T)[None, :] < lens[:, None], 0.3)
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+        text = jax.jit(lambda *a: att.grouped_decode_attention(
+            *a, 0.3)).lower(q, k, v, 1, lens).as_text()
+        assert "dot_general" in text and "custom_call" not in text
+        fits = d == 128
+        assert att._grouped_fits(k) == fits
+        tb = 256 if fits else T
+        assert list(np.asarray(att.grouped_rows_read(lens, k))) == \
+            [-(-int(n) // tb) * tb for n in lens]
+    assert (events.get("attn.grouped_kernel_traces") or 0) == traced

@@ -418,7 +418,12 @@ def test_window_decoder_step_and_prefill_fit_the_chip(one_chip):
     dense layer's and the period's four), and no scatter is left; the
     step's temporaries stay under a quarter of one full layer's rows of a
     leaf, so no layer's slice is copied out in front of the attention; the
-    prefill multiplies the period's four expert halves in the kernel
+    step attends through the kernel `grouped_decode_attention`, once a layer
+    (the dense layer's and the period's four), over the leaves as they lie:
+    no float32 scores of every row, (S, 8, 6, 9216) of a full layer or
+    (S, 8, 8, 512) of a ring, are among its instructions, its temporaries
+    are 7.9 MB (157.0 MB with the masked einsums, before the kernel) and
+    the step's bytes 8.963 GB (9.112 GB); the prefill multiplies the period's four expert halves in the kernel
     `held_experts_grouped` and holds no float32 scores of a query block
     against all 8192 keys of its prompt; and with
     the experts and vocabulary rows left out here added back, the step and
@@ -466,6 +471,12 @@ def test_window_decoder_step_and_prefill_fit_the_chip(one_chip):
     # leaf (1.21 GB)
     rows = S * 8 * L * 128 * 2
     assert mem.temp_size_in_bytes < rows // 4, mem.temp_size_in_bytes
+    assert _kernel_calls(text, "grouped_decode_attention") == 5
+    scores = [line[:120] for line in text.splitlines()
+              if re.search(r"= f32\[%d,(8,6,%d|48,%d|8,8,512|64,512)\]"
+                           % (S, L, L), line)]
+    assert not scores, scores
+    assert mem.temp_size_in_bytes < 20e6, mem.temp_size_in_bytes
     # float32 scores of a query block against every key of the prompt, in
     # the attention of either kind: the blocks read only their band
     text = prefill.as_text()
@@ -483,4 +494,5 @@ def test_window_decoder_step_and_prefill_fit_the_chip(one_chip):
     size = lambda a: a.argument_size_in_bytes + a.output_size_in_bytes \
         - a.alias_size_in_bytes + a.temp_size_in_bytes
     assert size(mem) + absent < 16e9, size(mem) + absent
+    assert size(mem) < 9.112e9, size(mem)         # the masked einsums' step
     assert size(pre) + total + absent < 16e9, size(pre) + total + absent

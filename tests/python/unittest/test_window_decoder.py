@@ -78,13 +78,14 @@ def _ref_logits(ref, w, cfg, seq):
     return onp.asarray(ref.forward(w, cfg, jnp.asarray(seq, jnp.int32)))
 
 
-def _model_fns(net, cfg):
+def _model_fns(net, cfg, max_len=None):
     """The model's `init_cache` and `decode_step` as the engine traces them
-    (pure functions of the parameters), jitted."""
+    (pure functions of the parameters), jitted; slots of `max_len` rows, or
+    the configuration's."""
     import jax
     from incubator_mxnet_tpu.parallel.functional import extract_params
     from incubator_mxnet_tpu.serving.generation import _pure_method
-    L = cfg["serving"]["max_len"]
+    L = max_len or cfg["serving"]["max_len"]
     pure = _pure_method(net, "init_cache")
     params = extract_params(net)
     init = jax.jit(lambda pv, tok, n: pure(pv, tok, n, L, None))
@@ -155,6 +156,8 @@ def test_prefill_then_decode_is_the_full_forward(n_prompt, bucket):
                       onp.asarray(cache["counts"])[0]))
     last = len(seq) - 1             # the position of the last step
     assert counts["gen.attn_context"] == 3 * (last + 1)
+    # leaves of 16-wide heads are no kernel's: the einsums read all 48 rows
+    assert counts["gen.attn_rows_read"] == 3 * 48
     assert counts["window.rows_needed"] == 6 * 8
     assert counts["window.rows_read"] == 6 * 8
     assert counts["moe.picks"] == 8 * 4
@@ -278,6 +281,57 @@ def test_an_engine_with_no_end_token_runs_every_answer_to_its_budget():
     finally:
         eng.close()
     assert short == full[:full.index(end) + 1]
+
+
+def _interpret_kernels(monkeypatch, on):
+    from incubator_mxnet_tpu import config
+    monkeypatch.setattr(config, "_OVERRIDES", dict(
+        {k: v for k, v in config._OVERRIDES.items()
+         if k != "MXNET_PALLAS_INTERPRET"},
+        **({"MXNET_PALLAS_INTERPRET": True} if on else {})))
+    if not on:
+        monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+
+
+def test_a_step_through_the_grouped_kernel_is_the_masked_step(monkeypatch):
+    """A model whose leaves the kernel tiles (heads of 128, a ring of 256,
+    slots of 768 rows: three row blocks of 256), float32: a prefill of 300
+    tokens in a bucket of 512 (past a block border; the ring wrapped), then
+    three steps.  With the kernel itself (interpret mode) the logits are
+    the masked einsums' (the CPU's path); tracing the decode step counts
+    five layer bodies lowered with the kernel (the leading full layer and
+    the period's four), and none on the CPU; `gen.attn_rows_read` is each
+    full layer's length rounded up to the row block, `gen.attn_context`
+    the length itself."""
+    from incubator_mxnet_tpu.models import WindowDecoder
+    from incubator_mxnet_tpu.monitor import events
+    cfg = _config()
+    types = ["full_attention"] + (["sliding_attention"] * 3
+                                  + ["full_attention"]) * 2
+    net = WindowDecoder(97, 64, types, ["dense"] + ["sparse"] * 8,
+                        [4 if t == "full_attention" else 6 for t in types],
+                        2, 128, 256, 96, 16, 16, 4, cfg["rope_parameters"],
+                        shared_hidden=24, routed_scale=2.5, first_held=0,
+                        experts_held=4, expert_tile=8)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(ctx=mx.cpu(0))
+    seq = onp.random.RandomState(9).randint(3, 97, size=303)
+    runs = {}
+    for on in (False, True):
+        _interpret_kernels(monkeypatch, on)
+        traced = events.get("attn.grouped_kernel_traces") or 0
+        got, _, cache = _served_logits(_model_fns(net, cfg, 768), seq, 300,
+                                       512, onp.random.RandomState(1))
+        runs[on] = got
+        assert (events.get("attn.grouped_kernel_traces") or 0) - traced == \
+            (5 if on else 0)
+        counts = dict(zip(net.step_counts, onp.asarray(cache["counts"])[0]))
+        assert counts["gen.attn_context"] == 3 * 303
+        assert counts["gen.attn_rows_read"] == 3 * 512
+        assert counts["window.rows_needed"] == counts["window.rows_read"] \
+            == 6 * 256
+    assert onp.abs(runs[True] - runs[False]).max() < \
+        1e-5 * max(1.0, onp.abs(runs[False]).max())
 
 
 # -- the other served decoders lower as they did -----------------------------
