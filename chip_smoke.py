@@ -449,9 +449,9 @@ def _delta_case(run):
 
 def _grouped_case(run):
     """`held_experts`' many-token form as the served models call it (on the
-    chip: the Mosaic kernel `held_experts_grouped`, stacked weights read at
-    a layer) against the same sum in NumPy float64: uneven runs, one held
-    expert that no token picks."""
+    chip: the Mosaic kernels `held_experts_grouped`, stacked weights read at
+    a layer, and `held_experts_combine`) against the same sum in NumPy
+    float64: uneven runs, one held expert that no token picks."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -478,9 +478,9 @@ def _grouped_case(run):
     compiled = jax.jit(lambda *a: moe.held_experts(
         *a, 0, tile=tile, layer=jnp.int32(layer))).lower(*args).compile()
     n_calls = compiled.as_text().count("tpu_custom_call")
-    check(run.dry or n_calls == 1,
+    check(run.dry or n_calls == 2,
           "held_experts compiled %d tpu_custom_call(s) on the chip, want "
-          "the grouped kernel" % n_calls)
+          "the grouped kernel and the combine" % n_calls)
     out = compiled(*args)
     run.on_device([out], "kernels output")
     out = np.asarray(out)
@@ -505,6 +505,49 @@ def _grouped_case(run):
           "float64" % err)
     return {"tokens": T, "held": held, "tile": tile, "runs": runs,
             "tpu_custom_calls": n_calls, "rel_err": round(err, 6)}
+
+
+def _combine_case(run):
+    """`held_experts_combine` at `laguna_xs2`'s widths (8192 tokens, 2048
+    columns, 8 picks, one in eight held, as 32 of 256 experts give) against
+    the same sum in NumPy float64, with NaN in every row that no held pick
+    names: on the chip the Mosaic kernel, one DMA a held pick."""
+    import numpy as np
+    import jax
+    from incubator_mxnet_tpu.parallel import moe
+
+    T, D, k = (64, 128, 8) if run.dry else (8192, 2048, 8)
+    N = 2 * T
+    rs = np.random.RandomState(T + k)
+    where = rs.randint(0, N, (T, k)).astype(np.int32)
+    held = rs.rand(T, k) < 1.0 / 8
+    held[0], held[1] = False, True                      # none and all k
+    gate = rs.rand(T, k).astype(np.float32)
+    rows = np.full((N, 1, D), np.nan, np.float32)
+    named = np.unique(where[held])
+    rows[named] = rs.randn(len(named), 1, D)
+    dev = run.ctx().jax_device
+    args = [jax.device_put(a, dev) for a in (rows, where, gate, held)]
+    run.on_device(args, "kernels input")
+    compiled = jax.jit(moe.held_experts_combine).lower(*args).compile()
+    n_calls = compiled.as_text().count("tpu_custom_call")
+    check(run.dry or n_calls == 1, "held_experts_combine compiled %d "
+          "tpu_custom_call(s) on the chip, want 1" % n_calls)
+    out = compiled(*args)
+    run.on_device([out], "kernels output")
+    out = np.asarray(out)
+    r64 = rows[:, 0].astype(np.float64)
+    want = np.zeros((T, D))
+    for i in range(k):
+        m = held[:, i]
+        want[m] += r64[where[m, i]] * gate[m, i, None]
+    check(np.isfinite(out).all(), "the combine read a row no held pick "
+          "names (non-finite output)")
+    err = float(np.abs(out - want).max() / np.abs(want).max())
+    check(err < 4e-6, "the combine is %.3g of max|ref| away from float64"
+          % err)
+    return {"tokens": T, "picks": k, "held_picks": int(held.sum()),
+            "tpu_custom_calls": n_calls, "rel_err": err}
 
 
 def _latent_case(run):
@@ -686,6 +729,7 @@ def phase_kernels(run):
             + [_ragged_case(run, jnp.bfloat16, stacked=True)],
             "gated_delta": _delta_case(run),
             "grouped_experts": _grouped_case(run),
+            "combine_experts": _combine_case(run),
             "latent_decode": _latent_case(run),
             "latent_prefill": _latent_prefill_case(run),
             "grouped_decode": _grouped_attention_case(run)}

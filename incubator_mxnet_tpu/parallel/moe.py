@@ -41,7 +41,7 @@ from ..telemetry import costs as _costs
 
 __all__ = ["switch_route", "moe_apply", "moe_ffn", "topk_route",
            "group_limited_route", "swiglu", "held_experts",
-           "held_experts_grouped", "held_load"]
+           "held_experts_grouped", "held_experts_combine", "held_load"]
 
 
 def switch_route(router_logits, capacity):
@@ -231,13 +231,13 @@ def _grouped_hidden_block(F, D, itemsize):
 
 def _grouped_vmem(tile, fb, D, itemsize):
     """Bytes of VMEM `held_experts_grouped` asks for: its weight blocks and
-    result tile twice over, the two float32 row buffers, one tile's
+    float32 result tile twice over, the two float32 row buffers, one tile's
     operands and products, and an eighth more; no less than the 16 MB a
     fusion gets anyway.  What a kernel reserves, XLA cannot use to keep the
     operands of the OTHER ops of the executable near: under a flat 64 MB
     `keye_vl2_30b_a3b`'s prefill lost 0.7 ms a layer in its attention
     loops."""
-    need = 2 * 3 * fb * D * itemsize + 2 * tile * D * (4 + itemsize) \
+    need = 2 * 3 * fb * D * itemsize + 2 * tile * D * 8 \
         + tile * (D * (4 + itemsize) + fb * (8 + itemsize))
     return max(16 << 20, need + need // 8)
 
@@ -262,11 +262,12 @@ def _tile_plan(count, start, tile, steps):
 
 def _grouped_kernel(layer_ref, used_ref, exp_ref, first_ref, valid_ref,
                     tok_ref, x_hbm, wg_ref, wu_ref, wd_ref, o_ref, xbuf, sem,
-                    *acc, nf):
+                    *acc, nf, dtype):
     del layer_ref, exp_ref              # read by the weights' index maps
     j, f = pl.program_id(0), pl.program_id(1)
     used = used_ref[0]
     f32 = jnp.float32
+    rows_shape = (o_ref.shape[0], o_ref.shape[2])
 
     def rows_of(t, wait):
         """Tile t's rows of x, one DMA a valid row, into buffer t % 2."""
@@ -295,11 +296,12 @@ def _grouped_kernel(layer_ref, used_ref, exp_ref, first_ref, valid_ref,
 
     def store(y):
         row = lax.broadcasted_iota(jnp.int32, y.shape, 0)
-        o_ref[...] = jnp.where(row < valid_ref[j], y, 0.0).astype(o_ref.dtype)
+        y = jnp.where(row < valid_ref[j], y, 0.0).astype(dtype)
+        o_ref[...] = y.astype(f32).reshape(o_ref.shape)
 
     @pl.when(j < used)
     def _tile():
-        xt = xbuf[j % 2].reshape(o_ref.shape).astype(o_ref.dtype)
+        xt = xbuf[j % 2].reshape(rows_shape).astype(dtype)
         nt = (((1,), (1,)), ((), ()))   # both operands contract their last
         g = lax.dot_general(xt, wg_ref[...], nt, preferred_element_type=f32)
         u = lax.dot_general(xt, wu_ref[...], nt, preferred_element_type=f32)
@@ -333,9 +335,10 @@ def held_experts_grouped(x, token, plan, wg, wu, wd, layer, *, tile):
     `plan` the first four of `_tile_plan` over len(plan[1]) tiles of `tile`
     rows; wg, wu (layers, n_held, F, D), wd (layers, n_held, D, F) the
     stacks of all layers, read at [layer, expert] where they lie.  Returns
-    the tiles' rows (tiles * tile, D) in x's type: rows of a tile past its
-    run's end are 0, tiles past the last used one are not written (nothing
-    reads them).
+    the tiles' rows (tiles * tile, 1, D), each rounded to x's type and held
+    in float32, the layout in which `held_experts_combine` moves one row by
+    one DMA: rows of a tile past its run's end are 0, tiles past the last
+    used one are not written (nothing reads them).
 
     Grid (tiles, blocks of F).  Whose tile a step multiplies, where its
     rows start and how many are valid are prefetched scalars; the weights'
@@ -349,7 +352,7 @@ def held_experts_grouped(x, token, plan, wg, wu, wd, layer, *, tile):
     last used tile repeats the last block indices and does nothing, so the
     cost follows the held picks.  Rounding as `swiglu`'s: operands in x's type,
     float32 accumulation, silu(g) * u in float32, cast to x's type before
-    the down projection, float32 until the result is stored."""
+    the down projection, float32 until the result is rounded to x's type."""
     D, F = x.shape[1], wg.shape[-2]
     steps = plan[1].shape[0]
     fb = _grouped_hidden_block(F, D, jnp.dtype(wg.dtype).itemsize)
@@ -371,16 +374,17 @@ def held_experts_grouped(x, token, plan, wg, wu, wd, layer, *, tile):
     if nf > 1:                          # the down projection's running sum
         scratch.append(pltpu.VMEM((tile, D), jnp.float32))
     return pl.pallas_call(
-        functools.partial(_grouped_kernel, nf=nf),
+        functools.partial(_grouped_kernel, nf=nf, dtype=x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(steps, nf),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY), up, up, down],
             out_specs=pl.BlockSpec(
-                (tile, D), lambda j, f, l, used, *_: (tile_of(j, used), 0)),
+                (tile, 1, D),
+                lambda j, f, l, used, *_: (tile_of(j, used), 0, 0)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((steps * tile, D), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((steps * tile, 1, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_grouped_vmem(
@@ -391,10 +395,11 @@ def held_experts_grouped(x, token, plan, wg, wu, wd, layer, *, tile):
 
 
 def _held_experts_loop(x, token, plan, wg, wu, wd, layer, *, tile):
-    """`held_experts_grouped`'s rows by a loop over the used tiles, each a
-    slice of the sorted picks, a gather of its rows of x, `swiglu` against
-    one expert's weights read at [layer, expert], and an update of the
-    result: the CPU's form and the tests' oracle.  (Rows of a tile past its
+    """`held_experts_grouped`'s rows, (tiles * tile, D) in x's type, by a
+    loop over the used tiles, each a slice of the sorted picks, a gather of
+    its rows of x, `swiglu` against one expert's weights read at
+    [layer, expert], and an update of the result: the CPU's form and the
+    tests' oracle.  (Rows of a tile past its
     run's end are the next picks' under this expert: nothing reads them.)"""
     used, expert, first, _ = plan
     token = jnp.concatenate([token, jnp.zeros((tile,), jnp.int32)])
@@ -419,6 +424,123 @@ def _grouped_fits(x, wg, tile):
         and x.dtype == wg.dtype
 
 
+# the rows of a block of tokens' held picks, both buffers, that
+# `held_experts_combine` may keep in VMEM
+_COMBINE_ROW_BYTES = 8 << 20
+
+
+def _combine_block(T, k, D):
+    """Tokens a grid step of `held_experts_combine` sums: the most, of 256
+    down to 8 in powers of two, that divide T and whose picks' rows fit
+    both buffers in `_COMBINE_ROW_BYTES`; else all T where they are at most
+    256 and fit (a block of the whole dimension); else None.  (A token's
+    place in its block is packed in 8 bits beside its row.)"""
+    fits = lambda bt: 2 * k * bt * D * 4 <= _COMBINE_ROW_BYTES
+    for bt in (256, 128, 64, 32, 16, 8):
+        if T % bt == 0 and fits(bt):
+            return bt
+    return T if T <= 256 and fits(T) else None
+
+
+def _combine_vmem(bt, k, D):
+    """Bytes of VMEM `held_experts_combine` asks for: both buffers of a
+    block's held rows, the running sums, the float32 result block twice
+    over, and an eighth more; no less than the 16 MB a fusion gets
+    anyway."""
+    need = 2 * k * bt * D * 4 + 3 * bt * D * 4
+    return max(16 << 20, need + need // 8)
+
+
+def _combine_kernel(start_ref, pick_ref, gate_ref, rows_hbm, o_ref, buf, acc,
+                    sem):
+    b = pl.program_id(0)
+
+    def rows_of(blk, wait):
+        """Block blk's held rows, one DMA each, into buffer blk % 2."""
+        slot, first = blk % 2, start_ref[blk]
+
+        def one(n, carry):
+            cp = pltpu.make_async_copy(
+                rows_hbm.at[pl.ds(pick_ref[first + n] >> 8, 1)],
+                buf.at[slot, pl.ds(n, 1)], sem.at[slot])
+            cp.wait() if wait else cp.start()
+            return carry
+
+        lax.fori_loop(0, start_ref[blk + 1] - first, one, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        rows_of(0, False)
+
+    @pl.when(b + 1 < pl.num_programs(0))   # the next block's, under this
+    def _ahead():                          # block's sums
+        rows_of(b + 1, False)
+
+    rows_of(b, True)
+    acc[...] = jnp.zeros(acc.shape, jnp.float32)
+    slot, first = b % 2, start_ref[b]
+
+    def add(n, carry):
+        """A held pick's term into its token's running sum: a token's held
+        picks come in pick order."""
+        t = pick_ref[first + n] & 255
+        acc[pl.ds(t, 1)] += buf[slot, pl.ds(n, 1)] * gate_ref[first + n]
+        return carry
+
+    lax.fori_loop(0, start_ref[b + 1] - first, add, 0)
+    o_ref[...] = acc[...].reshape(o_ref.shape)
+
+
+def held_experts_combine(rows, where, gate, held):
+    """The held experts' rows handed back to their tokens (Pallas, TPU):
+    for each token, the sum over its held picks of gate * rows[where].
+    rows (N, 1, D) float32 from `held_experts_grouped`; where, gate, held
+    (T, k): a pick's row among them, its gate, whether it is held.  Returns
+    (T, D) float32.
+
+    Grid (blocks of tokens, `_combine_block`).  The held picks alone, in
+    token and pick order, are prefetched scalars: where each block's
+    begin, each one's row and token in its block (packed, row << 8 |
+    token) and its gate.  A loop over a block's held picks moves each one's
+    row by one DMA from rows in HBM into a VMEM buffer, the next block's
+    under this block's sums; a pick that is not held costs nothing.  A
+    token's sum runs in float32 from zeros over its held picks in pick
+    order, out + row_i * gate_i: the gathers' sum (`_gathers`), whose
+    picks that are not held add 0, term for term; a row that no held pick
+    names is never read."""
+    T, k = where.shape
+    D = rows.shape[-1]
+    bt = _combine_block(T, k, D)
+    flat = held.reshape(-1)
+    at = jnp.cumsum(flat.astype(jnp.int32))        # held picks up to each
+    into = jnp.where(flat, at - 1, T * k)           # its place, or dropped
+    token = jnp.arange(T * k, dtype=jnp.int32) // k % bt
+    pick = jnp.zeros((T * k,), jnp.int32).at[into].set(
+        (where.reshape(-1).astype(jnp.int32) << 8) | token, mode="drop")
+    gates = jnp.zeros((T * k,), jnp.float32).at[into].set(
+        gate.reshape(-1).astype(jnp.float32), mode="drop")
+    start = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                             at.reshape(T // bt, bt * k)[:, -1]])
+    return pl.pallas_call(
+        _combine_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(T // bt,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((bt, D), lambda b, *_: (b, 0)),
+            scratch_shapes=[pltpu.VMEM((2, bt * k, 1, D), jnp.float32),
+                            pltpu.VMEM((bt, 1, D), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_combine_vmem(bt, k, D)),
+        interpret=_interpret(),
+        name="held_experts_combine",
+    )(start, pick, gates, rows)
+
+
 def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
                  run_tile=None, layer=None):
     """sum over a token's picks e that this device holds of
@@ -441,11 +563,13 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
     stream once either way and there is nothing to sort.  Many tokens (a
     prefill): the held picks are sorted by expert and each expert's run
     is multiplied in tiles of `tile` rows, as many tiles as its tokens
-    need and no more, so the work follows the picks, however uneven:
-    `held_experts_grouped` where the layer is lowered for a TPU (and
-    wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself),
-    `_held_experts_loop` elsewhere and for shapes the kernel does not
-    tile."""
+    need and no more, so the work follows the picks, however uneven, and
+    the rows come back to their tokens: `held_experts_grouped` and
+    `held_experts_combine` where the layer is lowered for a TPU (and
+    wherever `MXNET_PALLAS_INTERPRET` runs the kernels themselves),
+    `_held_experts_loop` and a gather of T rows a pick elsewhere and for
+    shapes the kernels do not tile.  That form is ONE jitted function
+    (`_held_many`) that the call sites of one shape share."""
     with _costs.part("experts"):
         return _held_experts(x, gate, expert, wg, wu, wd, first_held, tile,
                              run_tile, layer)
@@ -453,13 +577,11 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
 
 def _held_experts(x, gate, expert, wg, wu, wd, first_held, tile, run_tile,
                   layer):
-    T, D = x.shape
-    k = gate.shape[1]
-    n_held = wg.shape[0 if layer is None else 1]
-    f32 = jnp.float32
-    local = expert - first_held
-    held = (local >= 0) & (local < n_held)
-    if T <= tile:
+    if x.shape[0] <= tile:
+        n_held = wg.shape[0 if layer is None else 1]
+        f32 = jnp.float32
+        local = expert - first_held
+        held = (local >= 0) & (local < n_held)
         if layer is not None:
             wg, wu, wd = wg[layer], wu[layer], wd[layer]
         g = jnp.sum(jax.nn.one_hot(jnp.where(held, local, n_held), n_held,
@@ -472,6 +594,44 @@ def _held_experts(x, gate, expert, wg, wu, wd, first_held, tile, run_tile,
 
     if run_tile is not None:
         tile = run_tile
+    if layer is None:                   # one layer's weights: a stack of one
+        wg, wu, wd, layer = wg[None], wu[None], wd[None], 0
+    return _held_many(x, gate, expert, wg, wu, wd,
+                      jnp.asarray(layer, jnp.int32), first_held=first_held,
+                      tile=tile, form=_many_form(x, gate.shape[1], wg, tile))
+
+
+def _many_form(x, k, wg, tile):
+    """What `_held_many` runs for these shapes, read where the layer is
+    traced: "interpret" where `MXNET_PALLAS_INTERPRET` runs the kernels
+    themselves, "kernel" where the kernels tile the shapes (they run where
+    the layer is lowered for a TPU, the loop and the gathers elsewhere),
+    "loop" where they do not."""
+    T, D = x.shape
+    bt = _combine_block(T, k, D)
+    if bt is None:
+        return "loop"
+    if _interpret():
+        return "interpret"
+    return "kernel" if _grouped_fits(x, wg, tile) and bt % 8 == 0 \
+        else "loop"
+
+
+@functools.partial(jax.jit, static_argnames=("first_held", "tile", "form"))
+def _held_many(x, gate, expert, wg, wu, wd, layer, *, first_held, tile,
+               form):
+    """`held_experts`' many-token form over stacked weights, traced and
+    lowered ONCE for all its call sites of one shape: a period's four
+    expert halves in a model's scan share one jaxpr, and a module lowers it,
+    kernels and all, once (XLA inlines the call: one kernel call a layer
+    after that).  `form` (`_many_form`) is read by the caller, so a trace
+    made under another choice is never handed back.  The counters are
+    trace-time side effects, as `serve.traces` is: one a trace that holds
+    the kernels, however many call sites share it."""
+    T, k = gate.shape
+    n_held = wg.shape[1]
+    local = expert - first_held
+    held = (local >= 0) & (local < n_held)
     # picks in token order, flattened; held ones first after the sort,
     # grouped by expert
     key = jnp.where(held, local, n_held).reshape(-1)             # (T*k,)
@@ -480,30 +640,43 @@ def _held_experts(x, gate, expert, wg, wu, wd, first_held, tile, run_tile,
     count = jnp.sum(jax.nn.one_hot(key, n_held, dtype=jnp.int32), axis=0)
     start = jnp.cumsum(count) - count                   # first row of e
     plan = _tile_plan(count, start, tile, T * k // tile + n_held)
-    if layer is None:                   # one layer's weights: a stack of one
-        wg, wu, wd, layer = wg[None], wu[None], wd[None], 0
-    grouped = functools.partial(held_experts_grouped, tile=tile)
-    loop = functools.partial(_held_experts_loop, tile=tile)
-    args = (x, order // k, plan[:4], wg, wu, wd, jnp.asarray(layer, jnp.int32))
-    fits = _grouped_fits(x, wg, tile)
-    if _interpret():
-        rows = grouped(*args)
-    elif not fits:
-        rows = loop(*args)
-    else:
-        rows = lax.platform_dependent(*args, tpu=grouped, default=loop)
-    if _interpret() or (fits and jax.default_backend() == "tpu"):
-        # trace-time side effect only, as `serve.traces` is: one for each
-        # layer body that is lowered with the kernel
-        events.incr("moe.grouped_traces")
     # a held pick's row: its place in its expert's run, from the run's
-    # first row among the tiles' rows.  One gather of T rows a pick, which
-    # XLA fuses with its multiply-add: gathered whole, (T, k, D) pads k to a
-    # sublane tile and is copied, (k, T, D) is widened to float32 in a pass
-    # of its own (v5e, 8192 tokens x 8: 4.2 and 5.5 ms a layer against 3.8)
+    # first row among the tiles' rows
     where = (plan[4] - start)[jnp.where(held, local, 0)] + rank.reshape(T, k)
+    args = (x, order // k, plan[:4], wg, wu, wd, layer, where, gate, held)
+    kernels = functools.partial(_kernels, tile=tile)
+    gathers = functools.partial(_gathers, tile=tile)
+    if form == "interpret":
+        out = kernels(*args)
+    elif form == "loop":
+        out = gathers(*args)
+    else:
+        out = lax.platform_dependent(*args, tpu=kernels, default=gathers)
+    if form == "interpret" or (form == "kernel"
+                               and jax.default_backend() == "tpu"):
+        events.incr("moe.grouped_traces")
+        events.incr("moe.combine_traces")
+    return out
+
+
+def _kernels(x, token, plan, wg, wu, wd, layer, where, gate, held, *, tile):
+    """The TPU's many-token form: `held_experts_grouped`, then
+    `held_experts_combine`."""
+    rows = held_experts_grouped(x, token, plan, wg, wu, wd, layer, tile=tile)
+    return held_experts_combine(rows, where, gate, held)
+
+
+def _gathers(x, token, plan, wg, wu, wd, layer, where, gate, held, *, tile):
+    """The CPU's many-token form and the kernels' oracle:
+    `_held_experts_loop`, then the sum over a token's picks, one gather of T
+    rows a pick fused with its multiply-add, in pick order from zeros; a
+    pick that is not held reads row 0 times a gate of 0.  (Gathered whole,
+    (T, k, D) pads k to a sublane tile and is copied, (k, T, D) is widened
+    to float32 in a pass of its own: v5e, 8192 tokens x 8, 4.2 and 5.5 ms
+    a layer against 3.8.)"""
+    rows = _held_experts_loop(x, token, plan, wg, wu, wd, layer, tile=tile)
     where, gate = jnp.where(held, where, 0), jnp.where(held, gate, 0.0)
-    out = jnp.zeros((T, D), f32)
-    for i in range(k):
-        out = out + rows[where[:, i]].astype(f32) * gate[:, i:i + 1]
+    out = jnp.zeros(x.shape, jnp.float32)
+    for i in range(gate.shape[1]):
+        out = out + rows[where[:, i]].astype(jnp.float32) * gate[:, i:i + 1]
     return out

@@ -251,11 +251,17 @@ def _grouped_is_the_loop(monkeypatch, T, E, k, held, tile, route, D=32, F=16,
         monkeypatch.setattr(moe, "_GROUPED_WEIGHT_BYTES", weight_bytes)
         assert moe._grouped_hidden_block(F, D, 4) == F // 2
     traced = events.get("moe.grouped_traces") or 0
+    combined = events.get("moe.combine_traces") or 0
     _interpret_kernels(monkeypatch)
+    # the counters count traces of the many-token form, which the call sites
+    # of one shape share: a case of the same shapes before this one left its
+    # trace behind
+    moe._held_many.clear_cache()
     got = run()
-    # the counter that says the kernel engages: this trace, and not the
+    # the counters that say the kernels engage: this trace, and not the
     # CPU's of the loop above
     assert (events.get("moe.grouped_traces") or 0) == traced + 1
+    assert (events.get("moe.combine_traces") or 0) == combined + 1
     assert onp.isfinite(got).all()
     # float32 rounding: the blocks of F are summed in another order
     assert onp.abs(got - want).max() < 2e-6 * max(1.0, onp.abs(want).max())

@@ -366,18 +366,21 @@ def _ouro():
 # sha256 of the lowered text (no debug info) of prefill (16-token bucket),
 # join and decode step of each tiny decoder, as the tree lowered them before
 # `masked_decode_attention` took `part`, `topk_route` took `scale` and
-# `costs.PARTS` took `window`
+# `costs.PARTS` took `window`; the prefills of the three with experts as
+# they lower since the many-token form of `moe.held_experts` is one jitted
+# function (`moe._held_many`) whose kernel form ends in the combine: their
+# joins and decode steps are the same programs as before
 LOWERED = {
     "keye_vl2_30b_a3b": (
-        "4d55fd7ce51b336687ecb4ebf52f49842ba427a0879e814802549847e35b4eab",
+        "3fb87e597024c772717ddc3b9c419eb333600bd58fb59b5c18acb4155c2dec4a",
         "1379ddef5ab750d512dc75b5a4971316879e9b69c1cf76af42df5a6e051c7c94",
         "0891656ad5cb6bb3e2cb8e07ad338bcdcfaea7576843c9c5ff0a43e94c270f0e"),
     "qwen3_next_80b_a3b": (
-        "0e03c8047269638c7045f080c8e9b98231476edea20dffc3e69fa8eb3e2128a4",
+        "93401fd3931c4ff1cac5125c369faabb2b38b59ade008bfc37da2e914e5597cf",
         "15d53285859d63dcad7d4ed7d4b562ee736ddd92546ee3927e12e7e06c0441b1",
         "f4288a75bf10ed3c8be6d21b137a0037446f815e3df3b8c3719dc9e0bd85cc5c"),
     "deepseek_v2": (
-        "2898212d5d4a3a27df29dcb165040d229846ed1dd5b09f195626c5a3586d9e4d",
+        "a6aac5d3ac113c5cdcb6e9f1bf4f9834b0140805a55feab9c56926917fb55e80",
         "aeb0c75f20ae9ce5c06bce374c9908013515909007a94bfbe349a9c50f0580d2",
         "16296867cc51a7cb18761fcf11c6e97d384d8205afead011556d38260aebd8eb"),
     "ouro_2_6b": (
