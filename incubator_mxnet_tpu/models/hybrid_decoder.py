@@ -32,13 +32,12 @@ A block holds its parameters for all of ITS layers stacked on a leading
 axis, and the model scans over PERIODS: the period's layers compile once,
 whatever the depth.
 
-Serving contract (`serving.GenerationEngine`), as `SparseDecoder`'s, with
-leaves that have NO time axis beside K/V.  ``init_cache`` returns,
+Served through `sparse_decoder._ServedDecoder` (docs/serving.md, "A
+served decoder"), with leaves that have NO time axis beside K/V,
 slot-major: ``k``, ``v`` (B, full layers, G, max_len, d); ``s``
 (B, DeltaNet layers, Hv, dk, dv) float32, the recurrent states; ``c``
 (B, DeltaNet layers, K - 1, conv channels), the convolution's last input
-rows; ``counts``; ``start_tok``/``start_pos`` = the prompt's last token
-and its position.  The first decode step reads that token AGAIN.  Rows of
+rows.  The first decode step reads the prompt's last token AGAIN.  Rows of
 K/V shrug that off (the step rewrites row `pos` with what was there); a
 recurrence would apply the token twice.  So the prefill hands over ``s``
 and ``c`` as they stand BEFORE the prompt's last token, and its scans stop
@@ -50,11 +49,9 @@ from __future__ import annotations
 
 import math
 
-from ..gluon.block import HybridBlock
-from ..ndarray.ndarray import NDArray
 from ..telemetry import costs as _costs
-from .sparse_decoder import (HeldExperts, RMSNorm, _Stacked, _at, _dense,
-                             _f32, _rms, rotary)
+from .sparse_decoder import (HeldExperts, RMSNorm, _ServedDecoder, _Stacked,
+                             _at, _dense, _f32, _rms, rotary)
 
 __all__ = ["GatedAttention", "GatedDeltaNet", "HybridDecoder"]
 
@@ -255,7 +252,7 @@ class GatedDeltaNet(_Stacked):
         return self._out(p, h, o, z), cache
 
 
-class HybridDecoder(HybridBlock):
+class HybridDecoder(_ServedDecoder):
     """Embedding, `num_layers` layers in periods of `period` (period - 1 x
     (GatedDeltaNet, HeldExperts), then (GatedAttention, HeldExperts)), a
     final RMSNorm and the output projection over the vocabulary rows
@@ -267,6 +264,7 @@ class HybridDecoder(HybridBlock):
     # held experts, picks at each layer's fullest held expert
     step_counts = ("gen.attn_context", "gdn.state_kib", "gen.cache_kib",
                    "moe.picks", "moe.picks_held", "moe.expert_max")
+    cache_leaves = ("k", "v", "s", "c")
 
     def __init__(self, vocab_size, units, num_layers, period, num_heads,
                  num_kv_heads, head_dim, rotary_dim, key_heads, value_heads,
@@ -299,22 +297,6 @@ class HybridDecoder(HybridBlock):
         return {"attn": self.attn.stacked(), "gdn": self.gdn.stacked(),
                 "experts": self.experts.stacked()}
 
-    def _experts(self, p, h, layer):
-        """Layer `layer`'s expert half over h.  The expert weights go down
-        whole with the layer's index: the many-token form reads one expert
-        at a time, at [layer, expert]."""
-        return self.experts.apply(
-            _at(p["experts"], layer, ("wg", "wu", "wd")), h, layer)
-
-    def _embed(self, tokens):
-        with _costs.part("embed"):
-            return _f32(self.embed.data()._data[tokens])
-
-    def _logits(self, h):
-        g, w = self.norm.gamma.data()._data, self.head.data()._data
-        with _costs.part("head"):
-            return _dense(_rms(h, g, self.norm._eps, 1.0).astype(w.dtype), w)
-
     def _run_prompt(self, tokens, valid_len=None):
         """tokens (T,) -> (h (T, D), k, v (full layers, G, T, d), s
         (DeltaNet layers, Hv, dk, dv), c (DeltaNet layers, K - 1,
@@ -330,11 +312,13 @@ class HybridDecoder(HybridBlock):
             for j in range(P - 1):
                 h, s, c = self.gdn.prompt(
                     _at(p["gdn"], i * (P - 1) + j), h, valid_len)
-                h = self._experts(p, h, i * P + j)[0]
+                h = self.experts.apply_stacked(p["experts"], h,
+                                               i * P + j)[0]
                 states.append(s)
                 rows.append(c)
             h, k, v = self.attn.prompt(_at(p["attn"], i), h)
-            h = self._experts(p, h, i * P + P - 1)[0]
+            h = self.experts.apply_stacked(p["experts"], h,
+                                           i * P + P - 1)[0]
             return h, (k, v, jnp.stack(states), jnp.stack(rows))
 
         h, (k, v, s, c) = jax.lax.scan(period, self._embed(tokens),
@@ -342,45 +326,15 @@ class HybridDecoder(HybridBlock):
         return h, k, v, s.reshape((-1,) + s.shape[2:]), \
             c.reshape((-1,) + c.shape[2:])
 
-    def forward(self, tokens):
-        """Logits (B, T, V) of `tokens` (B, T)."""
-        import jax
-        return NDArray(jax.vmap(lambda t: self._logits(
-            self._run_prompt(t)[0]))(tokens._data))
+    def _prompt_leaves(self, run, valid_len, pad):
+        _, k, v, s, c = run
+        return {"k": pad(k), "v": pad(v), "s": s, "c": c}
 
-    def init_cache(self, prompt, valid_len, max_len, mem_len=None):
-        """Prefill `prompt` (B, T), of which `valid_len` (B,) tokens are
-        real; `mem_len` is the encoder-memory length of models that have
-        one and is not used."""
+    def _step(self, tok, pos, leaves, live):
+        """`live` is not used: a dead slot's state decays on, and `join`
+        rewrites the whole slot before it is read."""
         import jax
         import jax.numpy as jnp
-        tokens, n = prompt._data, valid_len._data.reshape(-1)
-        B, T = tokens.shape
-        if T > int(max_len):
-            raise ValueError("a prompt bucket of %d exceeds max_len %d"
-                             % (T, max_len))
-        _, k, v, s, c = jax.vmap(self._run_prompt)(tokens, n)
-        pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 2)
-                                + [(0, int(max_len) - T), (0, 0)])
-        last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        with _costs.part("cache"):
-            out = {"k": pad(k), "v": pad(v), "s": s, "c": c,
-                   "counts": jnp.zeros((B, len(self.step_counts)),
-                                       jnp.int32),
-                   "start_tok": jnp.take_along_axis(
-                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-                   "start_pos": last}
-        return {name: NDArray(a) for name, a in out.items()}
-
-    def decode_step(self, tok, pos, cache, live):
-        """Token `tok` (S,) at position `pos` (S,) against the cache:
-        (logits (S, V) float32, the cache advanced one token).  `live`
-        (S,; which slots hold a stream) is not used: a dead slot's state
-        decays on, and `join` rewrites the whole slot before it is read."""
-        import jax
-        import jax.numpy as jnp
-        tok, pos = tok._data, pos._data
-        leaves = {n: cache[n]._data for n in ("k", "v", "s", "c")}
         S, P = tok.shape[0], self._period
         zero = jnp.zeros((S,), jnp.int32)
 
@@ -392,11 +346,13 @@ class HybridDecoder(HybridBlock):
                 n = i * (P - 1) + j
                 h, leaves = self.gdn.step(_at(p["gdn"], n), h, n,
                                           leaves)
-                h, n_held, n_full = self._experts(p, h, i * P + j)
+                h, n_held, n_full = self.experts.apply_stacked(
+                    p["experts"], h, i * P + j)
                 held, full = held + n_held, full + n_full
             h, leaves = self.attn.step(_at(p["attn"], i), h, pos, i,
                                        leaves)
-            h, n_held, n_full = self._experts(p, h, i * P + P - 1)
+            h, n_held, n_full = self.experts.apply_stacked(
+                p["experts"], h, i * P + P - 1)
             return (h, leaves, held + n_held, full + n_full), None
 
         (h, leaves, held, full), _ = jax.lax.scan(
@@ -414,7 +370,4 @@ class HybridDecoder(HybridBlock):
                  (state + row * (pos + 2)) // 1024,
                  jnp.full((S,), self._layers * self._per_token, jnp.int32),
                  held, full], axis=1).astype(jnp.int32)
-        new = dict(cache)
-        new.update({n: NDArray(a) for n, a in leaves.items()})
-        new["counts"] = NDArray(counts)
-        return NDArray(self._logits(h)), new
+        return self._logits(h), leaves, counts

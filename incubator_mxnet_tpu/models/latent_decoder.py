@@ -39,13 +39,11 @@ A block holds its parameters for all of ITS layers stacked on a leading
 axis.  The leading dense layers run one by one, then the model scans over
 the expert layers; every leaf is sliced where it is used (`_at`).
 
-Serving contract (`serving.GenerationEngine`), as `SparseDecoder`'s:
-``init_cache`` returns, slot-major, ``ckv`` (B, layers, max_len, kv_rank)
-and ``kr`` (B, layers, max_len, rope), no head axis, ``counts`` and the
-stream's start (the prompt's last token and its position, read again by
-the first step, which rewrites that row with what it held).
-``decode_step`` writes row ``pos[slot]`` of both leaves in place and reads
-rows <= ``pos``.
+Served through `sparse_decoder._ServedDecoder` (docs/serving.md, "A
+served decoder"); the leaves, slot-major, are ``ckv`` (B, layers, max_len,
+kv_rank) and ``kr`` (B, layers, max_len, rope), no head axis.  A step
+writes row ``pos[slot]`` of both leaves in place and reads rows <=
+``pos``.
 """
 from __future__ import annotations
 
@@ -54,12 +52,10 @@ import math
 
 import numpy as np
 
-from ..gluon.block import HybridBlock
 from ..monitor import events
-from ..ndarray.ndarray import NDArray
 from ..telemetry import costs as _costs
-from .sparse_decoder import (HeldExperts, RMSNorm, _Stacked, _at, _dense,
-                             _f32, _rms)
+from .sparse_decoder import (HeldExperts, RMSNorm, _ServedDecoder, _Stacked,
+                             _at, _dense, _rms)
 
 __all__ = ["LatentAttention", "DenseSwiGLU", "LatentDecoder",
            "yarn_inv_freq", "yarn_mscale"]
@@ -254,7 +250,7 @@ class DenseSwiGLU(_Stacked):
                         else y)
 
 
-class LatentDecoder(HybridBlock):
+class LatentDecoder(_ServedDecoder):
     """Embedding, `first_dense` layers of (LatentAttention, DenseSwiGLU),
     `num_layers - first_dense` of (LatentAttention, HeldExperts routed
     inside `topk_group` of `n_group` groups, gates times `routed_scale`,
@@ -269,6 +265,7 @@ class LatentDecoder(HybridBlock):
     step_counts = ("gen.attn_context", "gen.attn_rows_read", "gen.cache_kib",
                    "gen.step_kib", "moe.picks", "moe.picks_held",
                    "moe.expert_max")
+    cache_leaves = ("ckv", "kr")
 
     def __init__(self, vocab_size, units, num_layers, first_dense, num_heads,
                  q_rank, kv_rank, nope_dim, rope_dim, v_dim, dense_hidden,
@@ -305,23 +302,7 @@ class LatentDecoder(HybridBlock):
         return {"attn": self.attn.stacked(), "ffn": self.ffn.stacked(),
                 "experts": self.experts.stacked()}
 
-    def _experts(self, p, h, i):
-        """Expert layer `i`'s (from 0) expert half over h.  The expert
-        weights go down whole with the layer's index: the many-token form
-        reads one expert at a time, at [layer, expert]."""
-        return self.experts.apply(
-            _at(p["experts"], i, ("wg", "wu", "wd")), h, i)
-
-    def _embed(self, tokens):
-        with _costs.part("embed"):
-            return _f32(self.embed.data()._data[tokens])
-
-    def _logits(self, h):
-        g, w = self.norm.gamma.data()._data, self.head.data()._data
-        with _costs.part("head"):
-            return _dense(_rms(h, g, self.norm._eps).astype(w.dtype), w)
-
-    def _run_prompt(self, tokens):
+    def _run_prompt(self, tokens, valid_len=None):
         """tokens (T,) -> (h (T, D), c (layers, T, kv_rank), k_r (layers,
         T, rope))."""
         import jax
@@ -337,62 +318,25 @@ class LatentDecoder(HybridBlock):
 
         def layer(h, i):
             h, c, kr = mix(_at(p["attn"], self._dense + i), h)
-            return self._experts(p, h, i)[0], (c, kr)
+            return self.experts.apply_stacked(p["experts"], h, i)[0], (c, kr)
 
         h, (c, kr) = jax.lax.scan(layer, h, jnp.arange(self._sparse))
         return h, jnp.concatenate([jnp.stack([r[0] for r in rows]), c]), \
             jnp.concatenate([jnp.stack([r[1] for r in rows]), kr])
 
-    def forward(self, tokens):
-        """Logits (B, T, V) of `tokens` (B, T), in the expanded form."""
-        import jax
-        return NDArray(jax.vmap(lambda t: self._logits(
-            self._run_prompt(t)[0]))(tokens._data))
+    def _prompt_leaves(self, run, valid_len, pad):
+        _, c, kr = run                                  # (B, layers, T, .)
+        return {"ckv": pad(c), "kr": pad(kr)}
 
-    def init_cache(self, prompt, valid_len, max_len, mem_len=None):
-        """Prefill `prompt` (B, T), of which `valid_len` (B,) tokens are
-        real; `mem_len` is the encoder-memory length of models that have
-        one and is not used."""
-        import jax
-        import jax.numpy as jnp
-        tokens, n = prompt._data, valid_len._data.reshape(-1)
-        B, T = tokens.shape
-        if T > int(max_len):
-            raise ValueError("a prompt bucket of %d exceeds max_len %d"
-                             % (T, max_len))
-        _, c, kr = jax.vmap(self._run_prompt)(tokens)   # (B, layers, T, .)
-        pad = lambda a: jnp.pad(a, [(0, 0), (0, 0), (0, int(max_len) - T),
-                                    (0, 0)])
-        last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        with _costs.part("cache"):
-            out = {"ckv": pad(c), "kr": pad(kr),
-                   "counts": jnp.zeros((B, len(self.step_counts)),
-                                       jnp.int32),
-                   "start_tok": jnp.take_along_axis(
-                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-                   "start_pos": last}
-        return {name: NDArray(a) for name, a in out.items()}
-
-    def step_weight_bytes(self):
-        """Bytes of weights one decode step reads once: every parameter but
-        the embedding, of which a step reads a row a slot."""
-        return sum(math.prod(q.shape) * np.dtype(q.dtype).itemsize
-                   for q in self.collect_params().values()
-                   if q is not self.embed)
-
-    def decode_step(self, tok, pos, cache, live):
-        """Token `tok` (S,) at position `pos` (S,) against the cache:
-        (logits (S, V) float32, the cache with row `pos` written), in the
-        absorbed form.  `live` (S,; which slots hold a stream) only shares
-        the weights' bytes out among `counts`: the attention reads every
-        slot up to its position."""
+    def _step(self, tok, pos, leaves, live):
+        """In the absorbed form.  `live` only shares the weights' bytes out
+        among `counts`: the attention reads every slot up to its
+        position."""
         import jax
         import jax.numpy as jnp
         from ..ops.attention import latent_rows_read
         # trace-time side effect only, as `serve.traces` is
         events.incr("mla.absorbed_traces")
-        tok, pos, live = tok._data, pos._data, live._data
-        leaves = {n: cache[n]._data for n in ("ckv", "kr")}
         S = tok.shape[0]
         zero = jnp.zeros((S,), jnp.int32)
         p, h = self._stacks(), self._embed(tok)
@@ -404,7 +348,7 @@ class LatentDecoder(HybridBlock):
             h, leaves, held, full = carry
             n = self._dense + i
             h, leaves = self.attn.step(_at(p["attn"], n), h, pos, n, leaves)
-            h, n_held, n_full = self._experts(p, h, i)
+            h, n_held, n_full = self.experts.apply_stacked(p["experts"], h, i)
             return (h, leaves, held + n_held, full + n_full), None
 
         (h, leaves, held, full), _ = jax.lax.scan(
@@ -415,15 +359,11 @@ class LatentDecoder(HybridBlock):
         row = (ckv.shape[-1] + kr.shape[-1]) * ckv.dtype.itemsize
         with _costs.part("cache"):
             cache_kib = self._layers * row * (pos + 2) // 1024
-            share = (self.step_weight_bytes() // 1024) \
-                // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
+            share = self._weights_share(live)
             counts = jnp.stack(
                 [self._layers * (pos + 1),
                  self._layers * latent_rows_read(pos + 1, ckv),
                  cache_kib, cache_kib + jnp.where(live, share, 0),
                  jnp.full((S,), self._sparse * self._per_token, jnp.int32),
                  held, full], axis=1).astype(jnp.int32)
-        new = dict(cache)
-        new.update({n: NDArray(a) for n, a in leaves.items()})
-        new["counts"] = NDArray(counts)
-        return NDArray(self._logits(h)), new
+        return self._logits(h), leaves, counts

@@ -37,13 +37,11 @@ rounding its normed input to the weights' type for its matrix products,
 which accumulate in float32; norms, rotary positions, softmax, the gate and
 the exit rule float32; the cached K/V in the weights' type.
 
-Serving contract (`serving.GenerationEngine`), as `SparseDecoder`'s:
-``init_cache`` returns, slot-major and head-major, ``k``, ``v``
+Served through `sparse_decoder._ServedDecoder` (docs/serving.md, "A
+served decoder"); the leaves, slot-major and head-major, are ``k``, ``v``
 (B, R * L, H, max_len, d) with the rows of pass r of layer l at index
-r * L + l, ``counts`` and the stream's start (the prompt's last token and
-its position, read again by the first step, which rewrites those rows with
-what they held).  ``decode_step`` writes row ``pos[slot]`` of every (r, l)
-in place, through both scans, and reads rows <= ``pos``: the attention
+r * L + l.  A step writes row ``pos[slot]`` of every (r, l) in place,
+through both scans, and reads rows <= ``pos``: the attention
 (`ops.attention.decode_attention`) takes the leaves whole with the index
 r * L + l, for a slice in front of its kernel would be copied, a leaf's
 worth a layer body.
@@ -54,13 +52,12 @@ import math
 
 import numpy as np
 
-from ..gluon.block import HybridBlock
 from ..monitor import events
 from ..ndarray.ndarray import NDArray
 from ..telemetry import costs as _costs
 from .latent_decoder import DenseSwiGLU
-from .sparse_decoder import (RMSNorm, _Stacked, _at, _dense, _f32, _rms,
-                             rotary)
+from .sparse_decoder import (RMSNorm, _ServedDecoder, _Stacked, _at, _dense,
+                             _f32, _rms, rotary)
 
 __all__ = ["SandwichAttention", "LoopedDecoder", "exit_pass"]
 
@@ -154,7 +151,7 @@ class SandwichAttention(_Stacked):
         return self._out(p, layer, h, o), dict(cache, k=k_rows, v=v_rows)
 
 
-class LoopedDecoder(HybridBlock):
+class LoopedDecoder(_ServedDecoder):
     """Embedding, `num_layers` of (SandwichAttention, DenseSwiGLU with its
     closing norm) run `loops` times over the same weights, a final RMSNorm
     that closes every pass, the exit gate and the output projection."""
@@ -166,6 +163,7 @@ class LoopedDecoder(HybridBlock):
     # loops - 1 at a threshold of 1)
     step_counts = ("gen.attn_context", "gen.cache_kib", "gen.step_kib",
                    "loop.passes", "loop.tokens", "loop.exit_pass")
+    cache_leaves = ("k", "v")
 
     def __init__(self, vocab_size, units, num_layers, num_heads, head_dim,
                  hidden, loops, exit_threshold=1.0, rope_theta=1e6,
@@ -188,10 +186,6 @@ class LoopedDecoder(HybridBlock):
 
     def _stacks(self):
         return {"attn": self.attn.stacked(), "ffn": self.ffn.stacked()}
-
-    def _embed(self, tokens):
-        with _costs.part("embed"):
-            return _f32(self.embed.data()._data[tokens])
 
     def _close(self, h):
         """What ends a pass over h (T, D): (the normed state, which feeds
@@ -221,7 +215,7 @@ class LoopedDecoder(HybridBlock):
         with _costs.part("ffn"):        # the layer's slices among them
             return self.ffn.apply(_at(p["ffn"], layer), h)
 
-    def _run_prompt(self, tokens):
+    def _run_prompt(self, tokens, valid_len=None):
         """tokens (T,) -> (hs (R, T, D), lam (R, T), k, v (R * L, H, T,
         d))."""
         import jax
@@ -253,29 +247,9 @@ class LoopedDecoder(HybridBlock):
 
         return NDArray(jax.vmap(one)(tokens._data))
 
-    def init_cache(self, prompt, valid_len, max_len, mem_len=None):
-        """Prefill `prompt` (B, T), of which `valid_len` (B,) tokens are
-        real; `mem_len` is the encoder-memory length of models that have
-        one and is not used."""
-        import jax
-        import jax.numpy as jnp
-        tokens, n = prompt._data, valid_len._data.reshape(-1)
-        B, T = tokens.shape
-        if T > int(max_len):
-            raise ValueError("a prompt bucket of %d exceeds max_len %d"
-                             % (T, max_len))
-        _, _, k, v = jax.vmap(self._run_prompt)(tokens)  # (B, R*L, H, T, d)
-        pad = lambda a: jnp.pad(a, [(0, 0)] * 3
-                                + [(0, int(max_len) - T), (0, 0)])
-        last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        with _costs.part("cache"):
-            out = {"k": pad(k), "v": pad(v),
-                   "counts": jnp.zeros((B, len(self.step_counts)),
-                                       jnp.int32),
-                   "start_tok": jnp.take_along_axis(
-                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-                   "start_pos": last}
-        return {name: NDArray(a) for name, a in out.items()}
+    def _prompt_leaves(self, run, valid_len, pad):
+        _, _, k, v = run                                # (B, R*L, H, T, d)
+        return {"k": pad(k), "v": pad(v)}
 
     def step_weight_bytes(self):
         """Bytes of weights one decode step needs moved: the layers' once
@@ -288,18 +262,14 @@ class LoopedDecoder(HybridBlock):
         return self._loops * sum(map(size, layers)) + sum(
             size(q) for q in (self.norm.gamma, self.we, self.be, self.head))
 
-    def decode_step(self, tok, pos, cache, live):
-        """Token `tok` (S,) at position `pos` (S,) against the cache:
-        (logits (S, V) float32, the cache with row `pos` of every (pass,
-        layer) written).  A slot that is not `live` (S,; which slots hold a
-        stream) attends over nothing: its logits are finite and mean
+    def _step(self, tok, pos, leaves, live):
+        """Row `pos` of every (pass, layer) written.  A slot that is not
+        `live` attends over nothing: its logits are finite and mean
         nothing."""
         import jax
         import jax.numpy as jnp
         # trace-time side effect only, as `serve.traces` is
         events.incr("loop.traces")
-        tok, pos, live = tok._data, pos._data, live._data
-        leaves = {n: cache[n]._data for n in ("k", "v")}
         S, L, R = tok.shape[0], self._layers, self._loops
         lengths = jnp.where(live, pos + 1, 0).astype(jnp.int32)
         p = self._stacks()
@@ -324,14 +294,10 @@ class LoopedDecoder(HybridBlock):
         row = 2 * k.shape[2] * k.shape[4] * k.dtype.itemsize
         with _costs.part("cache"):
             cache_kib = R * L * row * (pos + 2) // 1024
-            share = (self.step_weight_bytes() // 1024) \
-                // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
+            share = self._weights_share(live)
             counts = jnp.stack(
                 [R * L * (pos + 1), cache_kib,
                  cache_kib + jnp.where(live, share, 0),
                  jnp.full((S,), R, jnp.int32), jnp.ones((S,), jnp.int32),
                  at], axis=1).astype(jnp.int32)
-        new = dict(cache)
-        new.update({n: NDArray(a) for n, a in leaves.items()})
-        new["counts"] = NDArray(counts)
-        return NDArray(logits), new
+        return logits, leaves, counts

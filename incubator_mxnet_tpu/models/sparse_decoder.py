@@ -28,25 +28,15 @@ indexer) and `HeldExperts` (router and the held experts).  A block holds
 its parameters for ALL layers, stacked on a leading axis, and the model
 scans over that axis: the layers compile as one, whatever their number.
 
-Serving contract (`serving.GenerationEngine`):
-
-- ``init_cache(prompt, valid_len, max_len=)`` runs the prompt and returns
-  slot-major leaves: ``k``, ``v`` (B, layers, G, max_len, d), head-major
-  so that a step's attention streams each head's rows as they lie, and
-  the indexer's keys ``ki`` (B, layers, max_len, di), ``counts`` (B, 5) and
-  the stream's start: ``start_tok`` = the prompt's last token,
-  ``start_pos`` = its position.  The first decode step reads that token
-  again at that position and yields the first new one, so a stream is
-  one token a step from the start, like any other model's.
-- ``decode_step(tok, pos, cache, live)`` writes row ``pos[slot]`` of every
-  leaf by an indexed update, scores the slot's cached indexer keys,
-  selects, and attends over the slot's rows under the selection
-  (`ops.attention` says why under a mask and not by a gather).
-  ``counts`` is what the step did for each slot, under the names of
-  ``step_counts``.
-
-Rows of a slot past its position hold what an earlier occupant or the
-prompt's padding left: a step writes row `pos` before it reads rows
+Served through `_ServedDecoder` (docs/serving.md, "A served decoder").
+The leaves, slot-major: ``k``, ``v`` (B, layers, G, max_len, d),
+head-major so that a step's attention streams each head's rows as they
+lie, and the indexer's keys ``ki`` (B, layers, max_len, di).  A step
+writes row ``pos[slot]`` of every leaf by an indexed update, scores the
+slot's cached indexer keys, selects, and attends over the slot's rows
+under the selection (`ops.attention` says why under a mask and not by a
+gather).  Rows of a slot past its position hold what an earlier occupant
+or the prompt's padding left: a step writes row `pos` before it reads rows
 <= `pos`, so they are never read.
 """
 from __future__ import annotations
@@ -317,6 +307,13 @@ class HeldExperts(_Stacked):
         with _costs.part("experts"):
             return self._apply(p, h, layer)
 
+    def apply_stacked(self, p, h, layer):
+        """`apply` of layer `layer`, `p` the whole `stacked()`: every leaf
+        sliced at the layer but the expert weights, which go down whole
+        with the layer's index (the many-token form reads one expert at a
+        time, at [layer, expert])."""
+        return self.apply(_at(p, layer, ("wg", "wu", "wd")), h, layer)
+
     def _apply(self, p, h, layer):
         from ..parallel import moe
         import jax
@@ -341,7 +338,100 @@ class HeldExperts(_Stacked):
         return (h + y,) + moe.held_load(expert, self._first, self._held)
 
 
-class SparseDecoder(HybridBlock):
+class _ServedDecoder(HybridBlock):
+    """What every decoder-only model served by `serving.GenerationEngine`
+    shares (docs/serving.md, "A served decoder"): the embedding, the final
+    norm and the head (`embed`, `norm`, `head`), `forward`, and the
+    engine's contract, `init_cache` and `decode_step`.  A subclass gives
+    its mechanism:
+
+    - `step_counts`, the names of the columns of the leaf ``counts``;
+    - `_run_prompt(tokens (T,), valid_len ())` -> (h (T, D), ...): the
+      prompt through every layer (a model with a recurrence stops it at
+      `valid_len`, one of cached rows alone need not read it);
+    - `_prompt_leaves(run, valid_len (B,), pad)` -> {name: leaf}: the
+      cache's leaves of a batch of prompts from what the vmapped
+      `_run_prompt` gave, `pad` padding a leaf's T axis (the one before
+      last) out to `max_len`;
+    - `_step(tok, pos, leaves, live)` -> (logits (S, V) float32, the
+      leaves, counts (S, len(step_counts)) int32): one token a slot
+      against the leaves named by `cache_leaves`."""
+
+    def _embed(self, tokens):
+        """The residual stream's start.  The stream is float32 from here to
+        the logits: each block rounds its normed input to the weights' type
+        for its matrix products and adds their float32 results to it."""
+        with _costs.part("embed"):
+            return _f32(self.embed.data()._data[tokens])
+
+    def _logits(self, h):
+        g, w = self.norm.gamma.data()._data, self.head.data()._data
+        with _costs.part("head"):
+            return _dense(_rms(h, g, self.norm._eps, self.norm._offset)
+                          .astype(w.dtype), w)
+
+    def forward(self, tokens):
+        """Logits (B, T, V) of `tokens` (B, T)."""
+        import jax
+        return NDArray(jax.vmap(lambda t: self._logits(
+            self._run_prompt(t)[0]))(tokens._data))
+
+    def init_cache(self, prompt, valid_len, max_len, mem_len=None):
+        """Prefill `prompt` (B, T), of which `valid_len` (B,) tokens are
+        real; `mem_len` is the encoder-memory length of models that have
+        one and is not used.  The leaves, ``counts`` (zeros) and the
+        stream's start: ``start_tok`` = the prompt's last token,
+        ``start_pos`` = its position, which the first step reads again."""
+        import jax
+        import jax.numpy as jnp
+        tokens, n = prompt._data, valid_len._data.reshape(-1)
+        B, T = tokens.shape
+        if T > int(max_len):
+            raise ValueError("a prompt bucket of %d exceeds max_len %d"
+                             % (T, max_len))
+        run = jax.vmap(self._run_prompt)(tokens, n)
+        pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 2)
+                                + [(0, int(max_len) - T), (0, 0)])
+        last = jnp.maximum(n - 1, 0).astype(jnp.int32)
+        with _costs.part("cache"):
+            out = dict(self._prompt_leaves(run, n, pad),
+                       counts=jnp.zeros((B, len(self.step_counts)),
+                                        jnp.int32),
+                       start_tok=jnp.take_along_axis(
+                           tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
+                       start_pos=last)
+        return {name: NDArray(a) for name, a in out.items()}
+
+    def decode_step(self, tok, pos, cache, live):
+        """Token `tok` (S,) at position `pos` (S,) against the cache:
+        (logits (S, V) float32, the cache advanced one token, its
+        ``counts`` what the step did for each slot).  `live` (S,; which
+        slots hold a stream) is the model's to use or ignore."""
+        logits, leaves, counts = self._step(
+            tok._data, pos._data,
+            {n: cache[n]._data for n in self.cache_leaves}, live._data)
+        new = dict(cache)
+        new.update({n: NDArray(a) for n, a in leaves.items()})
+        new["counts"] = NDArray(counts)
+        return NDArray(logits), new
+
+    def step_weight_bytes(self):
+        """Bytes of weights one decode step reads once: every parameter but
+        the embedding, of which a step reads a row a slot."""
+        import numpy as np
+        return sum(math.prod(q.shape) * np.dtype(q.dtype).itemsize
+                   for q in self.collect_params().values()
+                   if q is not self.embed)
+
+    def _weights_share(self, live):
+        """KiB of `step_weight_bytes` a live slot's step stands for: the
+        weights' bytes shared out among the live slots (S,) bool."""
+        import jax.numpy as jnp
+        return (self.step_weight_bytes() // 1024) \
+            // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
+
+
+class SparseDecoder(_ServedDecoder):
     """Embedding, `num_layers` of (SelectAttention, HeldExperts), a final
     RMSNorm and the output projection over the vocabulary rows held."""
 
@@ -350,6 +440,7 @@ class SparseDecoder(HybridBlock):
     # held experts, picks at each layer's fullest held expert
     step_counts = ("gen.attn_context", "gen.attn_selected", "moe.picks",
                    "moe.picks_held", "moe.expert_max")
+    cache_leaves = ("k", "v", "ki")
 
     def __init__(self, vocab_size, units, num_layers, num_heads,
                  num_kv_heads, head_dim, expert_hidden, num_experts,
@@ -374,20 +465,9 @@ class SparseDecoder(HybridBlock):
     def _stacks(self):
         return {"attn": self.attn.stacked(), "experts": self.experts.stacked()}
 
-    def _embed(self, tokens):
-        """The residual stream's start.  The stream is float32 from here to
-        the logits: each block rounds its normed input to the weights' type
-        for its matrix products and adds their float32 results to it."""
-        with _costs.part("embed"):
-            return _f32(self.embed.data()._data[tokens])
-
-    def _logits(self, h):
-        g, w = self.norm.gamma.data()._data, self.head.data()._data
-        with _costs.part("head"):
-            return _dense(_rms(h, g, self.norm._eps).astype(w.dtype), w)
-
-    def _run_prompt(self, tokens):
-        """tokens (T,) -> (h (T, D), k, v, ki each (layers, T, .))."""
+    def _run_prompt(self, tokens, valid_len=None):
+        """tokens (T,) -> (h (T, D), (k, v (layers, G, T, d), ki (layers,
+        T, di)))."""
         import jax
         import jax.numpy as jnp
         stacks = self._stacks()
@@ -406,47 +486,15 @@ class SparseDecoder(HybridBlock):
         return jax.lax.scan(layer, self._embed(tokens),
                             (stacks, jnp.arange(self._layers)))
 
-    def forward(self, tokens):
-        """Logits (B, T, V) of `tokens` (B, T), every position attending
-        causally through its own selection."""
-        import jax
-        return NDArray(jax.vmap(lambda t: self._logits(
-            self._run_prompt(t)[0]))(tokens._data))
+    def _prompt_leaves(self, run, valid_len, pad):
+        _, (k, v, ki) = run
+        return {"k": pad(k), "v": pad(v), "ki": pad(ki)}
 
-    def init_cache(self, prompt, valid_len, max_len, mem_len=None):
-        """Prefill `prompt` (B, T), of which `valid_len` (B,) tokens are
-        real; `mem_len` is the encoder-memory length of models that have
-        one and is not used."""
+    def _step(self, tok, pos, leaves, live):
+        """Row `pos` written; `live` is not used: the attention streams
+        every slot under the selection's mask."""
         import jax
         import jax.numpy as jnp
-        tokens, n = prompt._data, valid_len._data.reshape(-1)
-        B, T = tokens.shape
-        if T > int(max_len):
-            raise ValueError("a prompt bucket of %d exceeds max_len %d"
-                             % (T, max_len))
-        # k, v (B, NL, G, T, d); ki (B, NL, T, di): pad the T axis
-        _, (k, v, ki) = jax.vmap(self._run_prompt)(tokens)
-        pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 2)
-                                + [(0, int(max_len) - T), (0, 0)])
-        last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        with _costs.part("cache"):
-            out = {"k": pad(k), "v": pad(v), "ki": pad(ki),
-                   "counts": jnp.zeros((B, len(self.step_counts)),
-                                       jnp.int32),
-                   "start_tok": jnp.take_along_axis(
-                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-                   "start_pos": last}
-        return {name: NDArray(a) for name, a in out.items()}
-
-    def decode_step(self, tok, pos, cache, live):
-        """Token `tok` (S,) at position `pos` (S,) against the cache:
-        (logits (S, V) float32, the cache with row `pos` written).
-        `live` (S,; which slots hold a stream) is not used: the
-        attention streams every slot under the selection's mask."""
-        import jax
-        import jax.numpy as jnp
-        tok, pos = tok._data, pos._data
-        leaves = {n: cache[n]._data for n in ("k", "v", "ki")}
         S = tok.shape[0]
         zero = jnp.zeros((S,), jnp.int32)
 
@@ -465,7 +513,4 @@ class SparseDecoder(HybridBlock):
                 [self._layers * (pos + 1), sel,
                  jnp.full((S,), self._layers * self._per_token, jnp.int32),
                  held, full], axis=1).astype(jnp.int32)
-        new = dict(cache)
-        new.update({n: NDArray(a) for n, a in leaves.items()})
-        new["counts"] = NDArray(counts)
-        return NDArray(self._logits(h)), new
+        return self._logits(h), leaves, counts

@@ -32,17 +32,15 @@ scans over PERIODS of the pattern (`layer_types` after the dense layers
 must repeat); a layer slices each stacked leaf at its own index where it
 is used (`_at`: a period's slice sliced again is a copy).
 
-Serving contract (`serving.GenerationEngine`), as `SparseDecoder`'s, with
-two kinds of K/V leaves in one slot.  ``init_cache`` returns, slot-major
-and head-major, ``kf``, ``vf`` (B, full layers, G, max_len, d), the full
+Served through `sparse_decoder._ServedDecoder` (docs/serving.md, "A
+served decoder"), with two kinds of K/V leaves in one slot, slot-major and
+head-major: ``kf``, ``vf`` (B, full layers, G, max_len, d), the full
 layers' rows at their positions, and ``kw``, ``vw`` (B, window layers, G,
 window, d), RINGS: the row of position t lies at t mod window.  A prefill
 of a prompt padded to its bucket hands over the rows of positions
 [max(0, valid_len - window), valid_len) at their ring index, never the
-bucket's padding; ``counts`` and the stream's start (the prompt's last
-token and its position, read again by the first step, which rewrites that
-row with what it held).  ``decode_step`` writes row ``pos`` of the full
-leaves and row ``pos mod window`` of the rings in place
+bucket's padding.  A step writes row ``pos`` of the full leaves and row
+``pos mod window`` of the rings in place
 (`ops.attention.decode_rows_write`), then reads the full layers' rows
 <= ``pos`` and the ring's rows that the slot has filled (ring index
 <= ``pos``: all of them once pos >= window - 1) of each live slot, up to
@@ -55,12 +53,10 @@ import math
 
 import numpy as np
 
-from ..gluon.block import HybridBlock
-from ..ndarray.ndarray import NDArray
 from ..telemetry import costs as _costs
 from .latent_decoder import DenseSwiGLU, yarn_inv_freq, yarn_mscale
-from .sparse_decoder import (HeldExperts, RMSNorm, _Stacked, _at, _dense,
-                             _f32, _rms)
+from .sparse_decoder import (HeldExperts, RMSNorm, _ServedDecoder, _Stacked,
+                             _at, _dense, _rms)
 
 __all__ = ["KindAttention", "WindowDecoder", "rope_frequencies"]
 
@@ -236,7 +232,7 @@ def _pattern(layer_types, mlp_layer_types, heads):
     return lead, period
 
 
-class WindowDecoder(HybridBlock):
+class WindowDecoder(_ServedDecoder):
     """Embedding, layers whose attention is `KindAttention` of their kind
     (`layer_types`: full or sliding, with `heads_per_layer` query heads)
     and whose feed-forward is `DenseSwiGLU` (the leading `dense` layers of
@@ -258,6 +254,7 @@ class WindowDecoder(HybridBlock):
                    "window.rows_needed", "window.rows_read", "gen.cache_kib",
                    "gen.step_kib", "moe.picks", "moe.picks_held",
                    "moe.expert_max")
+    cache_leaves = ("kf", "vf", "kw", "vw")
 
     def __init__(self, vocab_size, units, layer_types, mlp_layer_types,
                  heads_per_layer, num_kv_heads, head_dim, window,
@@ -315,23 +312,7 @@ class WindowDecoder(HybridBlock):
         return {FULL: self.full.stacked(), WINDOW: self.sliding.stacked(),
                 "ffn": self.ffn.stacked(), "experts": self.experts.stacked()}
 
-    def _experts(self, p, h, i):
-        """Sparse layer `i`'s (from 0) expert half over h.  The expert
-        weights go down whole with the layer's index: the many-token form
-        reads one expert at a time, at [layer, expert]."""
-        return self.experts.apply(
-            _at(p["experts"], i, ("wg", "wu", "wd")), h, i)
-
-    def _embed(self, tokens):
-        with _costs.part("embed"):
-            return _f32(self.embed.data()._data[tokens])
-
-    def _logits(self, h):
-        g, w = self.norm.gamma.data()._data, self.head.data()._data
-        with _costs.part("head"):
-            return _dense(_rms(h, g, self.norm._eps).astype(w.dtype), w)
-
-    def _run_prompt(self, tokens):
+    def _run_prompt(self, tokens, valid_len=None):
         """tokens (T,) -> (h (T, D), {kind: (k, v) (kind's layers, G, T,
         d)})."""
         import jax
@@ -348,7 +329,8 @@ class WindowDecoder(HybridBlock):
             for j, (kind, off) in enumerate(self._pattern_at):
                 n = self._first[kind] + i * self._per[kind] + off
                 h, k, v = self._attn(kind).prompt(_at(p[kind], n), h)
-                h = self._experts(p, h, i * self._period + j)[0]
+                h = self.experts.apply_stacked(
+                    p["experts"], h, i * self._period + j)[0]
                 out[kind].append((k, v))
             return h, {kind: tuple(jnp.stack(a) for a in zip(*kv))
                        for kind, kv in out.items() if kv}
@@ -361,12 +343,6 @@ class WindowDecoder(HybridBlock):
                                vs.reshape((-1,) + vs.shape[2:])))
         return h, {kind: tuple(jnp.concatenate(parts) for parts in zip(*kv))
                    for kind, kv in rows.items()}
-
-    def forward(self, tokens):
-        """Logits (B, T, V) of `tokens` (B, T)."""
-        import jax
-        return NDArray(jax.vmap(lambda t: self._logits(
-            self._run_prompt(t)[0]))(tokens._data))
 
     def _ring(self, rows, n):
         """A window layer's rows (layers, G, T, d) of a prompt with n valid
@@ -381,52 +357,22 @@ class WindowDecoder(HybridBlock):
         return jnp.where((at >= 0)[None, None, :, None], got,
                          jnp.zeros((), rows.dtype))
 
-    def init_cache(self, prompt, valid_len, max_len, mem_len=None):
-        """Prefill `prompt` (B, T), of which `valid_len` (B,) tokens are
-        real; `mem_len` is the encoder-memory length of models that have
-        one and is not used."""
+    def _prompt_leaves(self, run, valid_len, pad):
         import jax
-        import jax.numpy as jnp
-        tokens, n = prompt._data, valid_len._data.reshape(-1)
-        B, T = tokens.shape
-        if T > int(max_len):
-            raise ValueError("a prompt bucket of %d exceeds max_len %d"
-                             % (T, max_len))
-        _, rows = jax.vmap(self._run_prompt)(tokens)    # (B, layers, G, T, d)
-        pad = lambda a: jnp.pad(a, [(0, 0)] * 3
-                                + [(0, int(max_len) - T), (0, 0)])
-        last = jnp.maximum(n - 1, 0).astype(jnp.int32)
-        with _costs.part("cache"):
-            ring = jax.vmap(self._ring)
-            out = {"kf": pad(rows[FULL][0]), "vf": pad(rows[FULL][1]),
-                   "kw": ring(rows[WINDOW][0], n),
-                   "vw": ring(rows[WINDOW][1], n),
-                   "counts": jnp.zeros((B, len(self.step_counts)),
-                                       jnp.int32),
-                   "start_tok": jnp.take_along_axis(
-                       tokens, last[:, None], 1)[:, 0].astype(jnp.int32),
-                   "start_pos": last}
-        return {name: NDArray(a) for name, a in out.items()}
+        _, rows = run                                   # (B, layers, G, T, d)
+        ring = jax.vmap(self._ring)
+        return {"kf": pad(rows[FULL][0]), "vf": pad(rows[FULL][1]),
+                "kw": ring(rows[WINDOW][0], valid_len),
+                "vw": ring(rows[WINDOW][1], valid_len)}
 
-    def step_weight_bytes(self):
-        """Bytes of weights one decode step reads once: every parameter but
-        the embedding, of which a step reads a row a slot."""
-        return sum(math.prod(q.shape) * np.dtype(q.dtype).itemsize
-                   for q in self.collect_params().values()
-                   if q is not self.embed)
-
-    def decode_step(self, tok, pos, cache, live):
-        """Token `tok` (S,) at position `pos` (S,) against the cache:
-        (logits (S, V) float32, the cache with the rows of `pos` written).
-        `live` (S,; which slots hold a stream) shares the weights' bytes
-        out among `counts`; the attention reads no row of a slot that is not
+    def _step(self, tok, pos, leaves, live):
+        """The rows of `pos` written.  `live` shares the weights' bytes out
+        among `counts`; the attention reads no row of a slot that is not
         live."""
         import jax
         import jax.numpy as jnp
         from ..ops.attention import grouped_rows_read
-        tok, pos, live = tok._data, pos._data, live._data
         names = {FULL: ("kf", "vf"), WINDOW: ("kw", "vw")}
-        leaves = {n: cache[n]._data for n in ("kf", "vf", "kw", "vw")}
         S = tok.shape[0]
         zero = jnp.zeros((S,), jnp.int32)
         p, h = self._stacks(), self._embed(tok)
@@ -446,8 +392,8 @@ class WindowDecoder(HybridBlock):
             for j, (kind, off) in enumerate(self._pattern_at):
                 h, leaves = layer(h, leaves, kind, self._first[kind]
                                   + i * self._per[kind] + off)
-                h, n_held, n_full = self._experts(p, h,
-                                                  i * self._period + j)
+                h, n_held, n_full = self.experts.apply_stacked(
+                    p["experts"], h, i * self._period + j)
                 held, full = held + n_held, full + n_full
             return (h, leaves, held, full), None
 
@@ -465,8 +411,7 @@ class WindowDecoder(HybridBlock):
             # a step reads rows 0..pos of the full leaves and the band of
             # the rings, and writes one row of each, in every layer
             cache_kib = (NF * (pos + 2) + NW * (band + 1)) * row // 1024
-            share = (self.step_weight_bytes() // 1024) \
-                // jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1)
+            share = self._weights_share(live)
             counts = jnp.stack(
                 [NF * (pos + 1),
                  NF * grouped_rows_read(self.full.rows(pos, live), k),
@@ -475,7 +420,4 @@ class WindowDecoder(HybridBlock):
                  jnp.full((S,), (self._layers - self._lead)
                           * self._per_token, jnp.int32),
                  held, full], axis=1).astype(jnp.int32)
-        new = dict(cache)
-        new.update({n: NDArray(a) for n, a in leaves.items()})
-        new["counts"] = NDArray(counts)
-        return NDArray(self._logits(h)), new
+        return self._logits(h), leaves, counts

@@ -79,6 +79,38 @@ def _interpret():
     return bool(_cfg.get("MXNET_PALLAS_INTERPRET"))
 
 
+def _kernel_form(fits):
+    """What `_kernel_or_reference` runs, as a static value: "reference"
+    where the kernel does not take the shapes (`fits` false), "interpret"
+    where `MXNET_PALLAS_INTERPRET` runs the kernel itself, "kernel"
+    otherwise."""
+    if not fits:
+        return "reference"
+    return "interpret" if _interpret() else "kernel"
+
+
+def _kernel_or_reference(kernel, reference, args, fits, counters=()):
+    """The one rule that picks a Pallas kernel: `reference(*args)` where the
+    kernel does not take the shapes (`fits` false, in every mode: a fits
+    test that lets the interpreter take more says so itself); the kernel
+    itself in interpret mode; otherwise the choice is made where the caller
+    is LOWERED (`lax.platform_dependent`): the kernel for a TPU, the
+    reference (the `jax.numpy` form, what the CPU runs and the tests'
+    oracle) elsewhere, so a compile for a described TPU sees the kernel.
+    Each counter in `counters` counts once a trace that holds the kernel
+    (interpret mode, or a TPU backend): a trace-time side effect, as
+    `serve.traces` is."""
+    form = _kernel_form(fits)
+    if form == "reference":
+        return reference(*args)
+    if form == "interpret" or jax.default_backend() == "tpu":
+        for name in counters:
+            events.incr(name)
+    if form == "interpret":
+        return kernel(*args)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=reference)
+
+
 def _tiles(T, bq, bk):
     return bool(bq and bk and T % bq == 0 and T % bk == 0
                 and (bq % 8 == 0 or bq == T) and (bk % 8 == 0 or bk == T))
@@ -798,8 +830,7 @@ def blocked_window_attention(q, k, v, scale, window=None, block=512,
 #   come head-major, and the shared rotary key transposed, (rope, T), once.
 #   A pair's scores, the flash recurrence (float32) and the probabilities
 #   stay in VMEM; only the pairs that the diagonal crosses are masked.
-# - `latent_prefill_attention` chooses between them where the prompt is
-#   LOWERED (`lax.platform_dependent`), as `latent_decode_attention` does.
+# - `latent_prefill_attention` chooses between them (`_kernel_or_reference`).
 
 _PREFILL_BLOCK = 512    # query rows, and key rows, a grid step, at most
 _PREFILL_HEADS = 4      # heads a grid step, at most
@@ -832,25 +863,19 @@ def latent_prefill_attention(qn, qr, kn, kr, v, scale, block=512, chunk=512):
     float32, the probabilities rounded to the values' type for the context.
     Returns (T, H * dv) in the values' type.
 
-    The kernel where the prompt is lowered for a TPU (and wherever
-    `MXNET_PALLAS_INTERPRET` runs the kernel itself), and
-    `blocked_causal_attention` in query blocks of `block` and key chunks of
-    `chunk` elsewhere and for shapes the kernel does not tile."""
+    `_kernel_or_reference` picks the kernel or `blocked_causal_attention` in
+    query blocks of `block` and key chunks of `chunk`; the counter
+    `mla.prefill_kernel_traces` counts the layer bodies lowered with the
+    kernel."""
     with _costs.part("attn"):
         H, T, _ = qr.shape
-        args = (qn, qr, kn, kr, v)
-        kernel = functools.partial(_prefill_pallas, scale=scale)
-        blocked = functools.partial(_prefill_blocked, scale=scale,
-                                    block=block, chunk=chunk)
-        if not _prefill_fits(T, qn.shape[1] // H, v.shape[1] // H):
-            return blocked(*args)
-        if _interpret() or jax.default_backend() == "tpu":
-            # trace-time side effect only, as `serve.traces` is: one for each
-            # layer body that is lowered with the kernel
-            events.incr("mla.prefill_kernel_traces")
-        if _interpret():
-            return kernel(*args)
-        return jax.lax.platform_dependent(*args, tpu=kernel, default=blocked)
+        return _kernel_or_reference(
+            functools.partial(_prefill_pallas, scale=scale),
+            functools.partial(_prefill_blocked, scale=scale, block=block,
+                              chunk=chunk),
+            (qn, qr, kn, kr, v),
+            _prefill_fits(T, qn.shape[1] // H, v.shape[1] // H),
+            ("mla.prefill_kernel_traces",))
 
 
 def _prefill_blocked(qn, qr, kn, kr, v, scale, block, chunk):
@@ -980,8 +1005,7 @@ def _prefill_pallas(qn, qr, kn, kr, v, scale):
 #   block's scores, its probabilities and the flash recurrence (float32)
 #   stay in VMEM; the context is summed from the same block's latent rows,
 #   which is why a row is read once.
-# - `latent_decode_attention` chooses between them where the step is LOWERED
-#   (`lax.platform_dependent`), as `decode_attention` does.
+# - `latent_decode_attention` chooses between them (`_kernel_or_reference`).
 #
 # The rotary keys' leaf kr (S, layers, T, rope) has rope < 128: XLA lays
 # such a leaf on the TPU with T on the lanes (minor-to-major {2,3,1,0}), so
@@ -1030,23 +1054,15 @@ def latent_decode_attention(q_abs, q_rope, ckv, kr, layer, lengths, scale):
     the probabilities rounded to the leaves' type for the context.  Returns
     the context in latent space, (S, H, rank) float32.
 
-    The kernel where the step is lowered for a TPU (and wherever
-    `MXNET_PALLAS_INTERPRET` runs the kernel itself), the einsums elsewhere
-    and for leaves the kernel does not tile."""
+    `_kernel_or_reference` picks the kernel or the einsums; the counter
+    `mla.kernel_traces` counts the layer bodies lowered with the kernel."""
     with _costs.part("attn"):
-        args = (q_abs, q_rope, ckv, kr,
-                jnp.asarray(layer, jnp.int32).reshape(1), lengths)
-        kernel = functools.partial(_latent_pallas, scale=scale)
-        einsums = functools.partial(_latent_einsums, scale=scale)
-        if not _latent_fits(ckv):
-            return einsums(*args)
-        if _interpret() or jax.default_backend() == "tpu":
-            # trace-time side effect only, as `serve.traces` is: one for each
-            # layer body that is lowered with the kernel
-            events.incr("mla.kernel_traces")
-        if _interpret():
-            return kernel(*args)
-        return jax.lax.platform_dependent(*args, tpu=kernel, default=einsums)
+        return _kernel_or_reference(
+            functools.partial(_latent_pallas, scale=scale),
+            functools.partial(_latent_einsums, scale=scale),
+            (q_abs, q_rope, ckv, kr,
+             jnp.asarray(layer, jnp.int32).reshape(1), lengths),
+            _latent_fits(ckv), ("mla.kernel_traces",))
 
 
 def _latent_einsums(q_abs, q_rope, ckv, kr, layer, lengths, scale):
@@ -1166,8 +1182,7 @@ def _latent_pallas(q_abs, q_rope, ckv, kr, layer, lengths, scale):
 #   stay in VMEM.  A step past a slot's last needed block computes nothing
 #   and fetches nothing new: its index map names the NEXT slot's first block,
 #   as `latent_decode_attention`'s does.
-# - `grouped_decode_attention` chooses between them where the step is LOWERED
-#   (`lax.platform_dependent`).
+# - `grouped_decode_attention` chooses between them (`_kernel_or_reference`).
 
 # Measured alone on a TPU v5e at 64 slots (PERF.md, section 6): over
 # (3, 8, 9216, 128) bfloat16 leaves at lengths 6144-9216, 2.83 ms with
@@ -1214,23 +1229,16 @@ def grouped_decode_attention(q, k, v, layer, lengths, scale, part="attn"):
     model part `part`; a slot of length 0 reads nothing, and its rows of
     the result are finite and unspecified.
 
-    The kernel where the step is lowered for a TPU (and wherever
-    `MXNET_PALLAS_INTERPRET` runs the kernel itself), the einsums elsewhere
-    and for leaves the kernel does not tile."""
+    `_kernel_or_reference` picks the kernel or the einsums; the counter
+    `attn.grouped_kernel_traces` counts the layer bodies lowered with the
+    kernel."""
     with _costs.part(part):
-        args = (q, k, v, jnp.asarray(layer, jnp.int32).reshape(1),
-                jnp.clip(lengths.astype(jnp.int32), 0, k.shape[3]))
-        kernel = functools.partial(_grouped_pallas, scale=scale)
-        einsums = functools.partial(_grouped_einsums, scale=scale, part=part)
-        if not _grouped_fits(k):
-            return einsums(*args)
-        if _interpret() or jax.default_backend() == "tpu":
-            # trace-time side effect only, as `serve.traces` is: one for each
-            # layer body that is lowered with the kernel
-            events.incr("attn.grouped_kernel_traces")
-        if _interpret():
-            return kernel(*args)
-        return jax.lax.platform_dependent(*args, tpu=kernel, default=einsums)
+        return _kernel_or_reference(
+            functools.partial(_grouped_pallas, scale=scale),
+            functools.partial(_grouped_einsums, scale=scale, part=part),
+            (q, k, v, jnp.asarray(layer, jnp.int32).reshape(1),
+             jnp.clip(lengths.astype(jnp.int32), 0, k.shape[3])),
+            _grouped_fits(k), ("attn.grouped_kernel_traces",))
 
 
 def _grouped_einsums(q, k, v, layer, lengths, scale, part):
@@ -1352,9 +1360,7 @@ def _grouped_pallas(q, k, v, layer, lengths, scale):
 #   bfloat16 parts, so the sum keeps float32), and stay broadcast over those
 #   lanes: softmax and the context are then plain elementwise work and
 #   reductions over rows, the flash recurrence in float32.
-# - `decode_attention` chooses between them where the step is LOWERED
-#   (`lax.platform_dependent`), so a compile for a described TPU sees the
-#   kernel while the CPU runs the einsums.
+# - `decode_attention` chooses between them (`_kernel_or_reference`).
 # - With `layer`, the leaves are STACKED, (S, layers, G, T, W), and read at
 #   that index of axis 1 (a traced scalar, prefetched with the plan): the
 #   kernel's blocks are cut out of the leaves as they lie.  A layer's slice
@@ -1397,26 +1403,28 @@ def decode_rows_read(lengths, k):
     return (-(-jnp.clip(lengths, 0, T) // tb) * tb).astype(jnp.int32)
 
 
+def _ragged_takes(k):
+    """Whether `decode_attention` runs the ragged kernel on the leaf k:
+    where it tiles it (`_ragged_fits`), and on any leaf in interpret
+    mode."""
+    return _interpret() or _ragged_fits(k)
+
+
 def decode_attention(q, k, v, lengths, heads=1, scale=1.0, layer=None):
-    """`ragged_decode_attention` where the step is lowered for a TPU (and
-    wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself),
-    `dense_decode_attention` elsewhere and for leaves the kernel does not
-    tile.  The two agree on every slot with lengths > 0.  With `layer` (a
-    traced scalar), k and v are stacked leaves (S, layers, G, T, W), read
-    at that layer."""
+    """`ragged_decode_attention` or `dense_decode_attention`, as
+    `_kernel_or_reference` picks them (`_ragged_takes`).  The two agree on
+    every slot with lengths > 0.  With `layer` (a traced scalar), k and v
+    are stacked leaves (S, layers, G, T, W), read at that layer."""
     with _costs.part("attn"):
-        ragged = functools.partial(ragged_decode_attention, heads=heads,
-                                   scale=scale)
-        dense = functools.partial(dense_decode_attention, heads=heads,
-                                  scale=scale)
         args = (q, k, v, lengths)
         if layer is not None:
             args += (jnp.asarray(layer, jnp.int32).reshape(1),)
-        if _interpret():
-            return ragged(*args)
-        if not _ragged_fits(k):
-            return dense(*args)
-        return jax.lax.platform_dependent(*args, tpu=ragged, default=dense)
+        return _kernel_or_reference(
+            functools.partial(ragged_decode_attention, heads=heads,
+                              scale=scale),
+            functools.partial(dense_decode_attention, heads=heads,
+                              scale=scale),
+            args, _ragged_takes(k))
 
 
 def _lane_owner(heads, W):
@@ -1594,20 +1602,14 @@ def _rows_fit(k):
 def decode_rows_write(k, v, k_new, v_new, layer, pos):
     """The stacked leaves k, v (S, layers, G, T, W) with row pos[s] of
     `layer` (a traced scalar) set to k_new[s], v_new[s] (S, G, W) for every
-    slot s; pos (S,) int32 in [0, T).  The kernel where the step is lowered
-    for a TPU (and wherever `MXNET_PALLAS_INTERPRET` runs the kernel
-    itself), an indexed update elsewhere and for leaves the kernel does not
-    tile."""
+    slot s; pos (S,) int32 in [0, T).  The kernel or an indexed update, as
+    `_kernel_or_reference` picks them."""
     with _costs.part("cache"):
-        args = (k, v, k_new.astype(k.dtype), v_new.astype(v.dtype),
-                jnp.asarray(layer, jnp.int32).reshape(1),
-                pos.astype(jnp.int32))
-        if not _rows_fit(k):
-            return _rows_scatter(*args)
-        if _interpret():
-            return _rows_pallas(*args)
-        return jax.lax.platform_dependent(*args, tpu=_rows_pallas,
-                                          default=_rows_scatter)
+        return _kernel_or_reference(
+            _rows_pallas, _rows_scatter,
+            (k, v, k_new.astype(k.dtype), v_new.astype(v.dtype),
+             jnp.asarray(layer, jnp.int32).reshape(1), pos.astype(jnp.int32)),
+            _rows_fit(k))
 
 
 def _rows_scatter(k, v, k_new, v_new, layer, pos):
@@ -1696,24 +1698,18 @@ def _live_fits(k):
 def live_rows_write(k, v, k_new, v_new, plan):
     """The leaves k, v (S, G, T, W) with row pos[s] set to k_new[s], v_new[s]
     (S, G, W) for every slot s of the plan (`live_rows_plan`); every other
-    row of a live slot as it was.  The kernel where the step is lowered for
-    a TPU (and wherever `MXNET_PALLAS_INTERPRET` runs the kernel itself): it
-    writes nothing else.  The indexed update elsewhere and for leaves the
-    kernel does not take: it writes every slot's row whose position is in
-    [0, T), live or not, and nothing reads a dead slot's rows."""
+    row of a live slot as it was.  The kernel, which writes nothing else,
+    or the indexed update, which writes every slot's row whose position is
+    in [0, T), live or not (nothing reads a dead slot's rows), as
+    `_kernel_or_reference` picks them; the counter
+    `cache.rows_kernel_traces` counts the layer bodies lowered with the
+    kernel."""
     with _costs.part("cache"):
-        args = (k, v, k_new.astype(k.dtype), v_new.astype(v.dtype)) \
-            + tuple(plan)
-        if not _live_fits(k):
-            return _live_update(*args)
-        if _interpret() or jax.default_backend() == "tpu":
-            # trace-time side effect only, as `serve.traces` is: one for each
-            # layer body that is lowered with the kernel
-            events.incr("cache.rows_kernel_traces")
-        if _interpret():
-            return _live_pallas(*args)
-        return jax.lax.platform_dependent(*args, tpu=_live_pallas,
-                                          default=_live_update)
+        return _kernel_or_reference(
+            _live_pallas, _live_update,
+            (k, v, k_new.astype(k.dtype), v_new.astype(v.dtype))
+            + tuple(plan),
+            _live_fits(k), ("cache.rows_kernel_traces",))
 
 
 def _live_update(k, v, k_new, v_new, slots, count, pos):

@@ -37,10 +37,8 @@ nothing and write nothing (g = 0, beta = 0), and the state handed over is
 the one after valid_len - 1 tokens.  `causal_conv`'s rows follow the same
 rule.
 
-The kernels are chosen where the step is LOWERED
-(`lax.platform_dependent`), as `ops.attention.decode_attention` is: a
-compile for a TPU sees them, the CPU runs the `jax.numpy` forms, and
-`MXNET_PALLAS_INTERPRET` runs the kernels themselves in interpret mode.
+The kernels and the `jax.numpy` forms are picked by
+`ops.attention._kernel_or_reference`.
 """
 from __future__ import annotations
 
@@ -52,7 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..telemetry import costs as _costs
-from .attention import _interpret
+from .attention import _interpret, _kernel_or_reference
 
 __all__ = ["gated_delta_scan", "gated_delta_chunked", "gated_delta_step",
            "causal_conv", "causal_conv_step"]
@@ -153,10 +151,9 @@ def _chunk_scan_pallas(ut, w, qg, m, krt, decay, state):
 
 
 def _chunk_scan(*args):
-    if _interpret():
-        return _chunk_scan_pallas(*args)
-    return jax.lax.platform_dependent(*args, tpu=_chunk_scan_pallas,
-                                      default=_chunk_scan_xla)
+    # the kernel takes every shape the chunked form makes
+    return _kernel_or_reference(_chunk_scan_pallas, _chunk_scan_xla, args,
+                                True)
 
 
 def _unit_lower_inverse(a):
@@ -269,9 +266,10 @@ def _step_kernel(layer_ref, qt_ref, kt_ref, v_ref, a_ref, b_ref, s_ref,
 def _step_fits(states):
     """Whether the step kernel takes the leaf (B, layers, H, dk, dv): one
     slot's heads of one layer are a block, in VMEM twice over as input and
-    twice as output."""
+    twice as output.  The interpreter takes any leaf."""
     _, _, H, dk, dv = states.shape
-    return dk % 8 == 0 and dv % 128 == 0 and 4 * H * dk * dv * 4 <= 48 << 20
+    return _interpret() or (dk % 8 == 0 and dv % 128 == 0
+                            and 4 * H * dk * dv * 4 <= 48 << 20)
 
 
 def _step_pallas(layer, q, k, v, alpha, beta, states):
@@ -319,15 +317,12 @@ def gated_delta_step(q, k, v, g, beta, states, layer):
     states advanced one token)."""
     with _costs.part("state"):
         f32 = jnp.float32
-        args = (jnp.asarray(layer, jnp.int32).reshape(1), q.astype(f32),
-                k.astype(f32), v.astype(f32), jnp.exp(g.astype(f32)),
-                beta.astype(f32), states)
-        if _interpret():
-            return _step_pallas(*args)
-        if not _step_fits(states):
-            return _step_xla(*args)
-        return jax.lax.platform_dependent(*args, tpu=_step_pallas,
-                                          default=_step_xla)
+        return _kernel_or_reference(
+            _step_pallas, _step_xla,
+            (jnp.asarray(layer, jnp.int32).reshape(1), q.astype(f32),
+             k.astype(f32), v.astype(f32), jnp.exp(g.astype(f32)),
+             beta.astype(f32), states),
+            _step_fits(states))
 
 
 # -- the short causal convolution -------------------------------------------

@@ -35,8 +35,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..monitor import events
-from ..ops.attention import _interpret
+from ..ops.attention import _interpret, _kernel_form, _kernel_or_reference
 from ..telemetry import costs as _costs
 
 __all__ = ["switch_route", "moe_apply", "moe_ffn", "topk_route",
@@ -416,12 +415,18 @@ def _held_experts_loop(x, token, plan, wg, wu, wd, layer, *, tile):
         jnp.zeros((expert.shape[0] * tile, x.shape[1]), x.dtype))
 
 
-def _grouped_fits(x, wg, tile):
-    """Whether the kernel takes these shapes: lanes and sublanes whole."""
-    D, F = x.shape[1], wg.shape[-2]
+def _many_fits(x, k, wg, tile):
+    """Whether the many-token form's kernels take these shapes: a block of
+    tokens' picks that the combine holds in VMEM, and, where they are
+    compiled, lanes and sublanes whole.  The interpreter takes any widths."""
+    T, D = x.shape
+    bt = _combine_block(T, k, D)
+    if bt is None:
+        return False
     sub = 32 // jnp.dtype(x.dtype).itemsize
-    return D % 128 == 0 and F % 128 == 0 and tile % sub == 0 \
-        and x.dtype == wg.dtype
+    return _interpret() or (D % 128 == 0 and wg.shape[-2] % 128 == 0
+                            and tile % sub == 0 and x.dtype == wg.dtype
+                            and bt % 8 == 0)
 
 
 # the rows of a block of tokens' held picks, both buffers, that
@@ -565,11 +570,9 @@ def held_experts(x, gate, expert, wg, wu, wd, first_held, tile=256,
     is multiplied in tiles of `tile` rows, as many tiles as its tokens
     need and no more, so the work follows the picks, however uneven, and
     the rows come back to their tokens: `held_experts_grouped` and
-    `held_experts_combine` where the layer is lowered for a TPU (and
-    wherever `MXNET_PALLAS_INTERPRET` runs the kernels themselves),
-    `_held_experts_loop` and a gather of T rows a pick elsewhere and for
-    shapes the kernels do not tile.  That form is ONE jitted function
-    (`_held_many`) that the call sites of one shape share."""
+    `held_experts_combine`, or `_held_experts_loop` and a gather of T rows
+    a pick, as `_kernel_or_reference` picks them.  That form is ONE jitted
+    function (`_held_many`) that the call sites of one shape share."""
     with _costs.part("experts"):
         return _held_experts(x, gate, expert, wg, wu, wd, first_held, tile,
                              run_tile, layer)
@@ -603,18 +606,8 @@ def _held_experts(x, gate, expert, wg, wu, wd, first_held, tile, run_tile,
 
 def _many_form(x, k, wg, tile):
     """What `_held_many` runs for these shapes, read where the layer is
-    traced: "interpret" where `MXNET_PALLAS_INTERPRET` runs the kernels
-    themselves, "kernel" where the kernels tile the shapes (they run where
-    the layer is lowered for a TPU, the loop and the gathers elsewhere),
-    "loop" where they do not."""
-    T, D = x.shape
-    bt = _combine_block(T, k, D)
-    if bt is None:
-        return "loop"
-    if _interpret():
-        return "interpret"
-    return "kernel" if _grouped_fits(x, wg, tile) and bt % 8 == 0 \
-        else "loop"
+    traced: `_kernel_form` of `_many_fits`."""
+    return _kernel_form(_many_fits(x, k, wg, tile))
 
 
 @functools.partial(jax.jit, static_argnames=("first_held", "tile", "form"))
@@ -625,9 +618,9 @@ def _held_many(x, gate, expert, wg, wu, wd, layer, *, first_held, tile,
     expert halves in a model's scan share one jaxpr, and a module lowers it,
     kernels and all, once (XLA inlines the call: one kernel call a layer
     after that).  `form` (`_many_form`) is read by the caller, so a trace
-    made under another choice is never handed back.  The counters are
-    trace-time side effects, as `serve.traces` is: one a trace that holds
-    the kernels, however many call sites share it."""
+    made under another choice is never handed back.  The counters
+    `moe.grouped_traces` and `moe.combine_traces` count one a trace that
+    holds the kernels, however many call sites share it."""
     T, k = gate.shape
     n_held = wg.shape[1]
     local = expert - first_held
@@ -643,20 +636,11 @@ def _held_many(x, gate, expert, wg, wu, wd, layer, *, first_held, tile,
     # a held pick's row: its place in its expert's run, from the run's
     # first row among the tiles' rows
     where = (plan[4] - start)[jnp.where(held, local, 0)] + rank.reshape(T, k)
-    args = (x, order // k, plan[:4], wg, wu, wd, layer, where, gate, held)
-    kernels = functools.partial(_kernels, tile=tile)
-    gathers = functools.partial(_gathers, tile=tile)
-    if form == "interpret":
-        out = kernels(*args)
-    elif form == "loop":
-        out = gathers(*args)
-    else:
-        out = lax.platform_dependent(*args, tpu=kernels, default=gathers)
-    if form == "interpret" or (form == "kernel"
-                               and jax.default_backend() == "tpu"):
-        events.incr("moe.grouped_traces")
-        events.incr("moe.combine_traces")
-    return out
+    return _kernel_or_reference(
+        functools.partial(_kernels, tile=tile),
+        functools.partial(_gathers, tile=tile),
+        (x, order // k, plan[:4], wg, wu, wd, layer, where, gate, held),
+        form != "reference", ("moe.grouped_traces", "moe.combine_traces"))
 
 
 def _kernels(x, token, plan, wg, wu, wd, layer, where, gate, held, *, tile):

@@ -112,10 +112,9 @@ Beside the log: `gen.*` counters, a slot-occupancy gauge
 and per-lane TTFT SLO targets (`slo_targets()`) that
 `telemetry/slo.py`'s default generation rules alert on.
 
-Model contract (``models/seq2seq.py``, ``models/transformer.py``,
-``models/sparse_decoder.py``, ``models/hybrid_decoder.py``,
-``models/latent_decoder.py``), one for encoder-decoder and decoder-only
-models:
+Model contract (``models/seq2seq.py``, ``models/transformer.py``, and for
+the decoder-only models one shell, ``models/sparse_decoder.py``
+``_ServedDecoder``), one for encoder-decoder and decoder-only models:
 
 - ``init_cache(prompt, valid_len, max_len=, mem_len=)`` → dict of
   NDArray leaves, ALL slot-major (axis 0 = request), shapes a pure

@@ -445,3 +445,70 @@ def test_grouped_einsums_are_the_masked_form_off_the_chip():
         assert list(np.asarray(att.grouped_rows_read(lens, k))) == \
             [-(-int(n) // tb) * tb for n in lens]
     assert (events.get("attn.grouped_kernel_traces") or 0) == traced
+
+
+# -- the one rule that picks a Pallas kernel -------------------------------
+
+def _interpreting(monkeypatch, on):
+    from incubator_mxnet_tpu import config
+    monkeypatch.setattr(config, "_OVERRIDES", {
+        k: v for k, v in config._OVERRIDES.items()
+        if k != "MXNET_PALLAS_INTERPRET"})
+    if on:
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+
+
+@pytest.mark.parametrize("form,fits,interpret", [
+    # shapes the kernel does not take: the reference, in interpret mode too
+    ("reference", False, True),
+    ("interpret", True, True),
+    # lowered for the CPU: the default branch
+    ("kernel", True, False),
+])
+def test_one_rule_picks_the_kernel_or_its_reference(monkeypatch, form, fits,
+                                                    interpret):
+    """`_kernel_or_reference`: the reference where `fits` is false, and
+    nothing counted; the kernel itself in interpret mode, and one count a
+    trace; otherwise both traced and the CPU's lowering holds the reference
+    alone, and nothing counted.  `_kernel_form` names the same choice."""
+    from incubator_mxnet_tpu.monitor import events
+    _interpreting(monkeypatch, interpret)
+    traced = []
+    kernel = lambda x: (traced.append("kernel"), x * 3.0)[1]
+    reference = lambda x: (traced.append("reference"), x + 1.0)[1]
+    name = "test.kernel_rule_traces"
+    before = events.get(name) or 0
+    x = jnp.arange(4.0)
+    lowered = jax.jit(lambda x: att._kernel_or_reference(
+        kernel, reference, (x,), fits, (name,))).lower(x)
+    assert att._kernel_form(fits) == form
+    assert (events.get(name) or 0) - before == (form == "interpret")
+    assert traced == {"reference": ["reference"], "interpret": ["kernel"],
+                      "kernel": ["kernel", "reference"]}[form]
+    text = lowered.as_text()
+    assert ("multiply" in text) == (form == "interpret")
+    want = x * 3.0 if form == "interpret" else x + 1.0
+    assert np.asarray(lowered.compile()(x)).tolist() == \
+        np.asarray(want).tolist()
+
+
+@pytest.mark.parametrize("form,width,interpret", [
+    ("reference", 64, False),       # no whole lane tile: the loop
+    ("interpret", 64, True),        # the interpreter takes any width
+    ("kernel", 128, False),
+])
+def test_the_many_token_experts_read_the_same_rule(monkeypatch, form, width,
+                                                   interpret):
+    """`moe._many_form`, the static form of `held_experts`' many-token
+    path, is `_kernel_form` of its fits test: the loop where the kernels
+    do not take the widths, the kernels in interpret mode at any width,
+    and the choice at lowering otherwise."""
+    from incubator_mxnet_tpu.parallel import moe
+    _interpreting(monkeypatch, interpret)
+    x = jnp.zeros((64, width), jnp.bfloat16)
+    wg = jnp.zeros((2, 4, width, width), jnp.bfloat16)
+    assert moe._many_fits(x, 2, wg, 16) == (form != "reference")
+    assert moe._many_form(x, 2, wg, 16) == form == \
+        att._kernel_form(moe._many_fits(x, 2, wg, 16))

@@ -8,9 +8,8 @@ jax.numpy, no cache, every layer over the whole sequence under its mask),
 on the same seeded weights.  Also: the ring wraps and its rows are the
 band's; the four shares of the experts add up to the uncut layer; a pattern
 that does not repeat is refused; an engine with no end token runs an answer
-to its budget; and the other served decoders lower to the
-programs they lowered to before this model's arguments were added to the
-code they share."""
+to its budget; and the five served decoders lower to the programs they
+lowered to before the code they share last changed."""
 import hashlib
 import json
 import os
@@ -363,13 +362,26 @@ def _ouro():
     return LoopedDecoder(128, 64, 2, 4, 16, 96, loops=3, exit_threshold=0.5)
 
 
+def _laguna():
+    from incubator_mxnet_tpu.models import WindowDecoder
+    types = ["full_attention"] + (["sliding_attention"] * 3
+                                  + ["full_attention"]) * 2
+    return WindowDecoder(97, 64, types, ["dense"] + ["sparse"] * 8,
+                         [4 if t == "full_attention" else 6 for t in types],
+                         2, 16, 8, 96, 16, 16, 4, _config()["rope_parameters"],
+                         shared_hidden=24, routed_scale=2.5, first_held=0,
+                         experts_held=4, query_block=8, key_chunk=8,
+                         expert_tile=8)
+
+
 # sha256 of the lowered text (no debug info) of prefill (16-token bucket),
 # join and decode step of each tiny decoder, as the tree lowered them before
 # `masked_decode_attention` took `part`, `topk_route` took `scale` and
 # `costs.PARTS` took `window`; the prefills of the three with experts as
 # they lower since the many-token form of `moe.held_experts` is one jitted
 # function (`moe._held_many`) whose kernel form ends in the combine: their
-# joins and decode steps are the same programs as before
+# joins and decode steps are the same programs as before.  `laguna_xs2` as
+# it lowered before its served-decoder shell was shared with the others
 LOWERED = {
     "keye_vl2_30b_a3b": (
         "3fb87e597024c772717ddc3b9c419eb333600bd58fb59b5c18acb4155c2dec4a",
@@ -387,9 +399,14 @@ LOWERED = {
         "3a62df0d81f4b6a673c7c1d9b7ada4192de10963bbea318fb7758a87e578c5f1",
         "3762c0cc888399a9e1a2439e330f208dd54240a88129a9e5285d4a740feca134",
         "b35db9172c80cedce1cebd70d8e06cc1b23243cb3e9e57c5892644e1dbe466bf"),
+    "laguna_xs2": (
+        "436755b40da62787c5d32bdce6bade30b37ff026dd820b1193228112ce4573b1",
+        "87e9337a31a19a868a7245f82618d8686c3a58b5e4df599247c0dfac29b2359e",
+        "9908696aa28b4208190a0ba43e1c19ef42228041b9a09d74f1b9269722c94e05"),
 }
 BUILD = {"keye_vl2_30b_a3b": _keye, "qwen3_next_80b_a3b": _qwen3_next,
-         "deepseek_v2": _deepseek_v2, "ouro_2_6b": _ouro}
+         "deepseek_v2": _deepseek_v2, "ouro_2_6b": _ouro,
+         "laguna_xs2": _laguna}
 
 
 def _lowered(net):
